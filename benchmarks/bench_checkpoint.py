@@ -28,8 +28,6 @@ def _pct(xs, q):
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import paddle_tpu as paddle
     from paddle_tpu import nn, optimizer
     from paddle_tpu.distributed.checkpoint import TrainState

@@ -36,10 +36,6 @@ TRIALS = 15
 
 
 def main():
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     import paddle_tpu as paddle
     from paddle_tpu.observability import telemetry
     from paddle_tpu.observability.runtime import dispatch_armed

@@ -159,8 +159,6 @@ def _optimizer_ab(n_params=24, steps=20, reps=3):
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.models import llama as L
     from paddle_tpu.ops._common import is_tpu_platform
 

@@ -102,10 +102,6 @@ TRIM_PCT = 12   # % trimmed off EACH tail before a pool's mean — parity
 
 
 def main():
-    import jax
-
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                                GenerationConfig)
     from paddle_tpu.models import llama as L

@@ -29,8 +29,6 @@ def _next_pow2(n, minimum=32):
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                                GenerationConfig)
     from paddle_tpu.models import llama as L

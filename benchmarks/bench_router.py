@@ -560,8 +560,6 @@ def _diurnal_scenario(cfg, params, max_new, num_slots, chunk, page_size,
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.models import llama as L
     from paddle_tpu.ops._common import is_tpu_platform
 

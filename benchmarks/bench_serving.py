@@ -19,8 +19,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 def main():
     import jax
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.models import llama as L
     from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                                GenerationConfig)

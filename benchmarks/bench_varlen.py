@@ -20,8 +20,6 @@ def main():
     import jax
     import jax.numpy as jnp
 
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.ops import flash_attention as fa
     from paddle_tpu.ops._common import is_tpu_platform
     from paddle_tpu import flags

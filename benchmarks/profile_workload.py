@@ -15,7 +15,7 @@ import numpy as np
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from bench import detect_peak
+from bench import device_peaks
 
 
 def _parse_trace(path):
@@ -51,9 +51,6 @@ def _parse_trace(path):
     total = sum(agg.values()) / 1e3
     top.append(("TOTAL-device-op-time", total))
     return top, step_ms
-
-HBM_GBPS = {"v5e": 819, "v5p": 2765, "v4": 1228, "v6e": 1640}
-
 
 def _build_bert(jax, smoke):
     import paddle_tpu as paddle
@@ -202,8 +199,6 @@ def main():
     import jax
 
     name = sys.argv[1] if len(sys.argv) > 1 else "bert"
-    if os.environ.get("JAX_PLATFORMS", "") == "cpu":
-        jax.config.update("jax_platforms", "cpu")
     from paddle_tpu.ops._common import is_tpu_platform
 
     smoke = not is_tpu_platform(jax.devices()[0].platform)
@@ -233,12 +228,13 @@ def main():
     except Exception as e:
         top_ops = [(f"trace failed: {type(e).__name__}: {e}", 0.0)]
 
-    peak, gen = detect_peak()
-    mfu = flops_unit * units_per_step / step_s / peak if not smoke else 0.0
+    kind = jax.devices()[0].device_kind
+    mfu = (flops_unit * units_per_step / step_s
+           / device_peaks(kind)["bf16_flops"]) if not smoke else 0.0
     lines = [
         f"# {name} step profile — round 5",
         "",
-        f"Config: {desc}, single {gen} chip.",
+        f"Config: {desc}, single {kind} chip.",
         "",
         f"- device step time: **{step_s * 1e3:.1f} ms** "
         f"({units_per_step / step_s:,.0f} units/s)",

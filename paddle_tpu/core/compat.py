@@ -1,93 +1,28 @@
-"""Version-tolerant resolvers for jax APIs that moved between releases.
+"""The one place the repo names the jax APIs that have moved between
+releases (``shard_map``'s home and its ``check_vma`` kwarg,
+``lax.axis_size``, ``jax.distributed.is_initialized``, ``jax.export``).
 
-``shard_map`` has lived in three places across the jax versions this
-framework targets: ``jax.experimental.shard_map.shard_map`` (<= 0.4.x),
-``jax.experimental.shard_map`` re-exported at ``jax.shard_map`` (>= 0.5),
-and historical ``jax.experimental.maps``-era spellings. Every module in
-this repo imports it from HERE so the resolution logic exists exactly
-once; a lint test (tests/test_serving.py::test_no_direct_shard_map_imports)
-forbids new direct imports.
+The code targets the installed jax (0.9) only, so these are direct
+aliases — no resolution logic. The module and its names stay so the next
+move is a one-file change; a lint rule (tpu-lint ``layer-shard-map``,
+tests/test_serving.py::test_no_direct_shard_map_imports) forbids direct
+``jax.shard_map`` imports elsewhere.
 """
 
 from __future__ import annotations
 
-import functools
-import inspect
+import jax
+import jax.export
+from jax import lax
 
-
-def _resolve_shard_map():
-    import jax
-
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm
-    try:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm
-    except ImportError as e:  # pragma: no cover - depends on installed jax
-        raise ImportError(
-            "paddle_tpu requires a jax with shard_map (jax.shard_map or "
-            "jax.experimental.shard_map.shard_map); installed jax "
-            f"{jax.__version__} has neither") from e
-
-
-_raw_shard_map = _resolve_shard_map()
-try:
-    _accepted = frozenset(inspect.signature(_raw_shard_map).parameters)
-except (TypeError, ValueError):  # pragma: no cover - exotic wrappers
-    _accepted = frozenset()
-
-# the replication-check kwarg was renamed check_rep -> check_vma around the
-# varying-manual-axes rework; accept either spelling at every call site
-_CHECK_ALIASES = ("check_vma", "check_rep")
-
-
-@functools.wraps(_raw_shard_map)
-def shard_map(f, *args, **kwargs):
-    for given in _CHECK_ALIASES:
-        if given in kwargs and given not in _accepted:
-            other = _CHECK_ALIASES[1 - _CHECK_ALIASES.index(given)]
-            if other in _accepted:
-                kwargs[other] = kwargs.pop(given)
-            else:
-                kwargs.pop(given)
-    return _raw_shard_map(f, *args, **kwargs)
-
-
-def axis_size(axis_name):
-    """``lax.axis_size`` where it exists (jax >= 0.5); otherwise
-    ``lax.psum(1, axis)``, which inside shard_map reduces a static 1 and
-    therefore still returns a Python int usable in shapes/range()."""
-    from jax import lax
-
-    fn = getattr(lax, "axis_size", None)
-    if fn is not None:
-        return fn(axis_name)
-    return lax.psum(1, axis_name)
+shard_map = jax.shard_map
+axis_size = lax.axis_size
 
 
 def distributed_is_initialized() -> bool:
-    """``jax.distributed.is_initialized`` (jax >= 0.5) with a fallback to
-    the coordination-service client handle on older releases."""
-    import jax
-
-    fn = getattr(jax.distributed, "is_initialized", None)
-    if fn is not None:
-        return bool(fn())
-    try:  # pragma: no cover - depends on installed jax
-        from jax._src import distributed as _dist
-        return _dist.global_state.client is not None
-    except Exception:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def jax_export():
-    """The jax export module: ``jax.export`` is a lazily-imported
-    submodule on some releases and lived in ``jax.experimental.export``
-    before that."""
-    try:
-        import jax.export as export
-        return export
-    except ImportError:  # pragma: no cover - depends on installed jax
-        from jax.experimental import export
-        return export
+    """The ``jax.export`` module."""
+    return jax.export

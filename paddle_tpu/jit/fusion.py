@@ -70,10 +70,7 @@ from ..observability.profiling import (PROFILE_VERSION, chain_armed,
 from ..observability.registry import get_registry
 from ..observability.runtime import recompiles
 
-try:  # jax >= 0.4.16 keeps the stable alias in jax.extend
-    from jax.extend.core import Literal as _JaxprLiteral
-except ImportError:  # pragma: no cover - older jax
-    from jax.core import Literal as _JaxprLiteral
+from jax.extend.core import Literal as _JaxprLiteral
 
 #: the artifact this pass consumes (DispatchChainProfiler.export)
 ARTIFACT_KIND = "paddle_tpu.hot_chains"

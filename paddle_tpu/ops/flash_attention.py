@@ -146,6 +146,7 @@ def _flash_fwd_pallas(q, k, v, scale, causal, bq, bk, seg_q=None, seg_k=None,
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nkv=nkv, has_seg=has_seg,
                           kv_valid=kv_valid, causal_offset=causal_offset),
+        name="flash_attention_fwd",
         grid=grid,
         in_specs=in_specs,
         out_specs=[
@@ -312,6 +313,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, scale, causal, bq, bk,
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nkv=nkv, has_seg=has_seg,
                           kv_valid=kv_valid, causal_offset=causal_offset),
+        name="flash_attention_bwd_dq",
         grid=(bh, nq, nkv),
         in_specs=dq_in_specs,
         out_specs=pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
@@ -338,6 +340,7 @@ def _flash_bwd_pallas(q, k, v, out, lse, g, scale, causal, bq, bk,
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           bq=bq, bk=bk, nq=nq, has_seg=has_seg,
                           kv_valid=kv_valid, causal_offset=causal_offset),
+        name="flash_attention_bwd_dkv",
         grid=(bh, nkv, nq),
         in_specs=dkv_in_specs,
         out_specs=[

@@ -2,9 +2,8 @@
 fallback, mirroring ops/rms_norm.py's structure.
 
 Rebuild target: the reference's fused LayerNorm CUDA kernels
-(paddle/phi/kernels/gpu/layer_norm_kernel.cu — SURVEY.md §2.2). Round-4
-motivation: the ViT-L profile (benchmarks/PROFILE_vit_r4.md) shows the
-encoder's 49 LayerNorm instances compiling to multiply_reduce +
+(paddle/phi/kernels/gpu/layer_norm_kernel.cu — SURVEY.md §2.2). Motivation:
+the round-4 ViT-L profile showed the encoder's 49 LayerNorm instances compiling to multiply_reduce +
 convert_reduce chains worth 19.2 ms/step — a single-pass kernel holds the
 row block in VMEM across mean, variance, normalize, and the backward's
 three reductions.

@@ -336,6 +336,7 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
         n_rows=n_rows, scale=s, nh=nh, nkv=nkv, d=d, t=t)
     return pl.pallas_call(
         kernel,
+        name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nh, d), v_pages.dtype),
         interpret=interpret,
@@ -424,20 +425,46 @@ class PagedKVCacheManager:
     """
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
-                 num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16):
+                 num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
+                 mesh=None, mp_axis: str = "mp"):
+        """``mesh`` head-shards both page pools over its ``mp_axis``:
+        whole GQA (kv-head) groups per chip, so every page's bytes split
+        evenly across the TP mesh and attention stays head-local. Pure
+        LAYOUT — the allocator metadata (free list, tables, lens) is
+        host-side and chip-agnostic, which is what makes an elastic
+        resize a rebuild-and-replay rather than a data migration. The
+        pools are ALLOCATED sharded (never whole on one chip first): a
+        full-depth pool sized for the mesh does not fit a single chip."""
         self.page_size = page_size
         self.num_pages = num_pages
         shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
-        self.k_pages = jnp.zeros(shape, dtype)
-        self.v_pages = jnp.zeros(shape, dtype)
+        #: TP chips the pool is head-sharded over (1 = single-chip) — the
+        #: memory ledger splits per-chip bytes off it and the engine
+        #: stamps it into its compile keys
+        self.mesh_chips: int = 1
+        sharding = None
+        if mesh is not None:
+            from jax.sharding import NamedSharding
+            self.mesh_chips = int(mesh.shape[mp_axis])
+            if num_kv_heads % self.mesh_chips:
+                raise ValueError(
+                    f"num_kv_heads={num_kv_heads} must divide by the TP "
+                    f"degree {self.mesh_chips} (whole GQA groups per chip; "
+                    "a split group would split single heads across chips)")
+            sharding = NamedSharding(mesh, self.pool_spec(mp_axis))
+        self.k_pages = jnp.zeros(shape, dtype, device=sharding)
+        self.v_pages = jnp.zeros(shape, dtype, device=sharding)
         self._free: List[int] = list(range(num_pages - 1, 0, -1))  # 0 reserved
         self._tables: dict = {}   # seq_id -> List[int]
         self._lens: dict = {}     # seq_id -> int
         self._page_nb: int = 0    # page_nbytes memo (geometry is fixed)
-        #: TP chips the pool is head-sharded over (1 = single-chip);
-        #: set by shard_heads — the memory ledger splits per-chip bytes
-        #: off it and the engine stamps it into its compile keys
-        self.mesh_chips: int = 1
+
+    @staticmethod
+    def pool_spec(mp_axis: str = "mp"):
+        """PartitionSpec of a (L, P, page, nkv, d) pool on a TP mesh: the
+        kv-head axis over ``mp_axis``."""
+        from jax.sharding import PartitionSpec as P
+        return P(None, None, None, mp_axis, None)
 
     # -- allocation ---------------------------------------------------------
 
@@ -601,27 +628,6 @@ class PagedKVCacheManager:
 
     # -- multi-chip layout (TP-sharded serving) ------------------------------
 
-    def shard_heads(self, mesh, mp_axis: str = "mp") -> None:
-        """Head-shard both page pools over the mesh's ``mp_axis``: whole
-        GQA (kv-head) groups per chip, so every page's bytes split
-        evenly across the TP mesh and attention stays head-local. Pure
-        LAYOUT — the allocator metadata (free list, tables, lens) is
-        host-side and chip-agnostic, which is what makes an elastic
-        resize a rebuild-and-replay rather than a data migration. The
-        kv-head axis must divide by the mesh degree (whole groups per
-        chip; a split group would split single heads across chips)."""
-        from jax.sharding import NamedSharding, PartitionSpec as P
-        chips = int(mesh.shape[mp_axis])
-        nkv = self.k_pages.shape[3]
-        if nkv % chips:
-            raise ValueError(
-                f"num_kv_heads={nkv} must divide by the TP degree "
-                f"{chips} (whole GQA groups per chip)")
-        ns = NamedSharding(mesh, P(None, None, None, mp_axis, None))
-        self.k_pages = jax.device_put(self.k_pages, ns)
-        self.v_pages = jax.device_put(self.v_pages, ns)
-        self.mesh_chips = chips
-
     # -- views for the op ---------------------------------------------------
 
     @property
@@ -749,6 +755,7 @@ def paged_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
         nh=nh, nkv=nkv, d=d)
     return pl.pallas_call(
         kernel,
+        name="paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, nh, d), v_pages.dtype),
         interpret=interpret,

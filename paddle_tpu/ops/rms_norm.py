@@ -19,15 +19,16 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ._common import use_pallas, next_multiple
+from ._common import use_pallas
+from ..core.compat import shard_map
+from ..core.dispatch import apply
+from ..flags import flag_value
 
 
 def _use_pallas_rms() -> bool:
-    # dedicated knob so the round-4 win-or-delete decision (VERDICT r3
-    # weak-4) can isolate rms_norm from the other Pallas kernels
-    from ..flags import flag_value
+    # dedicated knob so an end-to-end A/B can isolate rms_norm from the
+    # other Pallas kernels
     return use_pallas() and flag_value("use_pallas_rms_norm")
-from ..core.dispatch import apply
 
 
 # ---------------------------------------------------------------------------
@@ -101,6 +102,7 @@ def _pallas_fwd(x2, w, eps, interpret=False):
     grid = (rows // br,)
     y = pl.pallas_call(
         functools.partial(_fwd_kernel, eps=eps),
+        name="rms_norm_fwd",
         grid=grid,
         interpret=interpret,
         in_specs=[
@@ -119,6 +121,7 @@ def _pallas_bwd(x2, w, g2, eps, interpret=False):
     nb = rows // br
     dx, dw_part = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps),
+        name="rms_norm_bwd",
         grid=(nb,),
         interpret=interpret,
         in_specs=[
@@ -180,6 +183,25 @@ def _rms_bwd(eps, res, g):
 
 
 rms_norm_array.defvjp(_rms_fwd, _rms_bwd)
+
+
+def rms_norm_replicated(x, w, eps, mesh):
+    """``rms_norm_array`` over operands that are REPLICATED inside a
+    GSPMD-partitioned program on ``mesh`` (the TP serving step:
+    activations and norm weights replicate, only the matmuls shard).
+    ``pallas_call`` cannot be auto-partitioned ("Mosaic kernels cannot be
+    automatically partitioned"), so when the kernel is selected it runs
+    under ``shard_map`` with replicated specs — every chip normalizes the
+    same rows with the same kernel as the single-chip program, and there
+    is no collective. The XLA path needs no wrapper."""
+    # not a traced-shape branch: Mesh.size is a construction-time constant
+    # tpu-lint: disable=trace-shape-branch
+    if mesh is None or mesh.size == 1 or not _use_pallas_rms():
+        return rms_norm_array(x, w, eps)
+    from jax.sharding import PartitionSpec as P
+    return shard_map(lambda xl, wl: rms_norm_array(xl, wl, eps), mesh=mesh,
+                     in_specs=(P(), P()), out_specs=P(),
+                     check_vma=False)(x, w)
 
 
 # ---------------------------------------------------------------------------

@@ -115,7 +115,7 @@ def vit_tiny_test(**kwargs):
 # Functional stacked path (round 4): lax.scan over the encoder stack
 # ===========================================================================
 # The imperative module above runs ~400 separate parameter tensors through
-# ~838 XLA fusions per train step (PROFILE_vit_r4) — per-tensor optimizer
+# ~838 XLA fusions per train step (round-4 profile) — per-tensor optimizer
 # updates and per-layer kernel launches cap the measured MFU near 41%. The
 # stacked form is the same TPU-first design the llama flagship uses
 # (models/llama.py): per-layer weights stack on a leading L axis, the

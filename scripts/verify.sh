@@ -24,6 +24,13 @@
 #
 #   python scripts/bench_sentinel.py --fresh /tmp/bench_line.json
 #
+# The chip is not a stage here: `python chip_smoke.py` runs on the TPU
+# (through the chip tool) and refuses to run anywhere else. What CAN be
+# checked without a chip is — the serving/train steps compile for a
+# described v5e:2x2 host with their Pallas kernels (tests/test_tpu_aot.py)
+# and chip_smoke.py's control flow holds at a toy size
+# (tests/test_chip_smoke.py); both are `slow` and run as stage 11.
+#
 # Exit codes: 0 all green; first failing stage's code otherwise.
 set -u -o pipefail
 cd "$(dirname "$0")/.."
@@ -39,50 +46,54 @@ if [ "$only_sentinel" = "1" ]; then
     exit $?
 fi
 
-echo "== [1/11] tpu-lint (python -m paddle_tpu.analysis; incl. dataflow: page-leak/dtype-flow/cache-key) =="
+echo "== [1/12] tpu-lint (python -m paddle_tpu.analysis; incl. dataflow: page-leak/dtype-flow/cache-key) =="
 s0=$SECONDS
 python -m paddle_tpu.analysis || exit $?
 echo "tpu-lint stage wall: $((SECONDS - s0))s (in-process budget 5s — regressions show here)"
 
-echo "== [2/11] bench_obs_overhead (armed sensor+timeline plane, 3% budget) =="
+echo "== [2/12] bench_obs_overhead (armed sensor+timeline plane, 3% budget) =="
 JAX_PLATFORMS=cpu python benchmarks/bench_obs_overhead.py || exit $?
 
 if [ "$fast" = "1" ]; then
-    echo "== [3-11/11] fusion + multichip + multihost + disagg + replay + sentinel + tier-1 skipped (--fast) =="
+    echo "== [3-12/12] fusion + multichip + multihost + disagg + replay + sentinel + chip bring-up + tier-1 skipped (--fast) =="
     exit 0
 fi
 
-echo "== [3/11] fusion pass smoke (profile -> pass -> install, stale skips) =="
+echo "== [3/12] fusion pass smoke (profile -> pass -> install, stale skips) =="
 JAX_PLATFORMS=cpu python scripts/fusion_smoke.py || exit $?
 
-echo "== [4/11] bench_fusion ABBA gates + sentinel fresh-line judgement =="
+echo "== [4/12] bench_fusion ABBA gates + sentinel fresh-line judgement =="
 JAX_PLATFORMS=cpu python benchmarks/bench_fusion.py > /tmp/_fusion_line.json \
     || exit $?
 tail -n 1 /tmp/_fusion_line.json | python scripts/bench_sentinel.py \
     --fresh - --min-history 1 --rel-floor 0.3 || exit $?
 
-echo "== [5/11] multichip serve smoke (mp=2 storm, chip kill, byte-identical rejoin) =="
+echo "== [5/12] multichip serve smoke (mp=2 storm, chip kill, byte-identical rejoin) =="
 JAX_PLATFORMS=cpu python scripts/multichip_serve_smoke.py || exit $?
 
-echo "== [6/11] multihost serve smoke (2 processes, page migration, seeded host kill) =="
+echo "== [6/12] multihost serve smoke (2 processes, page migration, seeded host kill) =="
 JAX_PLATFORMS=cpu python scripts/multihost_serve_smoke.py || exit $?
 
-echo "== [7/11] disagg serve smoke (prefill/decode handoff byte-identity, autoscaler vs 10x burst) =="
+echo "== [7/12] disagg serve smoke (prefill/decode handoff byte-identity, autoscaler vs 10x burst) =="
 JAX_PLATFORMS=cpu python scripts/disagg_serve_smoke.py || exit $?
 
-echo "== [8/11] replay smoke (journal -> bundle -> byte-identical replay, planted divergence) =="
+echo "== [8/12] replay smoke (journal -> bundle -> byte-identical replay, planted divergence) =="
 JAX_PLATFORMS=cpu python scripts/replay_smoke.py || exit $?
 
-echo "== [9/11] bench_router resize recovery + sentinel fresh-line judgement =="
+echo "== [9/12] bench_router resize recovery + sentinel fresh-line judgement =="
 JAX_PLATFORMS=cpu python benchmarks/bench_router.py > /tmp/_router_line.json \
     || exit $?
 tail -n 1 /tmp/_router_line.json | python scripts/bench_sentinel.py \
     --fresh - --min-history 1 --rel-floor 0.4 || exit $?
 
-echo "== [10/11] bench_sentinel (trajectory replay) =="
+echo "== [10/12] bench_sentinel (trajectory replay) =="
 python scripts/bench_sentinel.py --replay || exit $?
 
-echo "== [11/11] tier-1 test suite =="
+echo "== [11/12] chip bring-up without a chip (AOT compile for v5e:2x2 + chip_smoke.py rehearsal) =="
+JAX_PLATFORMS=cpu python -m pytest tests/test_tpu_aot.py \
+    tests/test_chip_smoke.py -q -p no:cacheprovider || exit $?
+
+echo "== [12/12] tier-1 test suite =="
 rm -f /tmp/_t1.log
 timeout -k 10 870 env JAX_PLATFORMS=cpu python -m pytest tests/ -q \
     -m 'not slow' --continue-on-collection-errors -p no:cacheprovider \

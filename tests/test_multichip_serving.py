@@ -3,7 +3,7 @@ continuous-batching engine over an ``mp`` mesh.
 
 The acceptance bar: sharding is a LAYOUT problem — Megatron-placed
 weights (``models.llama.shard_params_tp``) + a head-sharded paged KV
-pool (``PagedKVCacheManager.shard_heads``, whole GQA groups per chip) —
+pool (``PagedKVCacheManager(mesh=...)``, whole GQA groups per chip) —
 so the sharded engine's greedy output is byte-identical to the
 single-chip engine at mp=2 and mp=4 (prefix cache on/off, COW wave,
 speculation on/off) and the O(1)-recompile contract survives a sharded

@@ -443,31 +443,23 @@ def test_sentinel_replay_of_checked_in_trajectory_passes():
     assert doc["pass"] and doc["entries"] >= 2
 
 
-@pytest.mark.skipif(not list(REPO.glob("BENCH_r*.json")),
-                    reason="no checked-in trajectory")
 def test_sentinel_flags_synthetic_itl_regression(tmp_path):
-    # newest entry that is actually judgeable against default history:
-    # carries tokens_per_sec AND has >= 2 same-(metric, unit) peers
-    # (the fusion_ab series seeded in round 18 starts with one entry,
-    # so the bare newest file would exit 3 on no_comparable_history)
-    paths = sorted(REPO.glob("BENCH_r*.json"))
-    parsed = [json.loads(p.read_text())["parsed"] for p in paths]
-    groups: dict = {}
-    for e in parsed:
-        key = (e.get("metric"), e.get("unit"))
-        groups[key] = groups.get(key, 0) + 1
-    judgeable = [p for p, e in zip(paths, parsed)
-                 if "tokens_per_sec" in e
-                 and groups[(e.get("metric"), e.get("unit"))] >= 3]
-    if not judgeable:
-        pytest.skip("no BENCH entry with tokens_per_sec and >=2 "
-                    "same-(metric,unit) peers in the trajectory")
-    newest = judgeable[-1]
-    entry = json.loads(newest.read_text())["parsed"]
-    entry["tokens_per_sec"] /= 2.0          # 2x ITL == half throughput
+    # a judgeable trajectory needs >= 3 same-(metric, unit) entries with
+    # tokens_per_sec; it is built here so the test does not depend on
+    # which records happen to be checked in
+    entries = [{"metric": "llama_877M_train_mfu_v5e", "unit": "MFU",
+                "value": mfu, "tokens_per_sec": tps}
+               for mfu, tps in ((0.5187, 19428.0), (0.5198, 19468.0),
+                                (0.5287, 19801.0))]
+    for i, e in enumerate(entries):
+        (tmp_path / f"BENCH_r{i:02d}.json").write_text(
+            json.dumps({"parsed": e}))
+    glob_arg = str(tmp_path / "BENCH_r*.json")
     bad = tmp_path / "regressed.json"
-    bad.write_text(json.dumps(entry))
-    r = _run_sentinel("--fresh", str(bad))
+    bad.write_text(json.dumps(dict(
+        entries[-1],                        # 2x ITL == half throughput
+        tokens_per_sec=entries[-1]["tokens_per_sec"] / 2.0)))
+    r = _run_sentinel("--fresh", str(bad), "--trajectory", glob_arg)
     assert r.returncode == 1, r.stdout + r.stderr
     doc = json.loads(r.stdout)
     assert not doc["pass"]
@@ -475,9 +467,8 @@ def test_sentinel_flags_synthetic_itl_regression(tmp_path):
                for row in doc["regressions"])
     # the unmodified line sails through
     good = tmp_path / "fresh.json"
-    good.write_text(json.dumps(
-        json.loads(newest.read_text())["parsed"]))
-    r = _run_sentinel("--fresh", str(good))
+    good.write_text(json.dumps(entries[-1]))
+    r = _run_sentinel("--fresh", str(good), "--trajectory", glob_arg)
     assert r.returncode == 0, r.stdout + r.stderr
 
 

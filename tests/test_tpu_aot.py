@@ -1,0 +1,28 @@
+"""Ahead-of-time compile check against a described v5e host (no chip):
+scripts/tpu_aot_check.py compiles the unified serving step (one chip and
+serving_mesh(4)) and the train step with the real XLA:TPU + Mosaic compiler.
+~45 s of compiling, so outside tier-1; scripts/verify.sh runs it as a stage."""
+
+import sys
+from pathlib import Path
+
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parent.parent / "scripts"))
+
+import tpu_aot_check  # noqa: E402
+
+
+@pytest.mark.slow
+def test_serving_and_train_steps_compile_for_v5e_with_their_kernels():
+    try:
+        tpu_aot_check.v5e_devices()
+    except Exception as e:  # noqa: BLE001 - any libtpu/topology failure
+        pytest.skip(f"libtpu cannot describe a v5e:2x2 topology here: {e}")
+    out = tpu_aot_check.run_checks()
+    assert out["device_kind"] == "TPU v5 lite"
+    # _compile() raised if a kernel was missing or a program did not compile
+    for name in ("unified_step_mp1", "unified_step_mp4"):
+        assert out[name]["kernels"]["ragged_paged_attention"] == 1
+        assert out[name]["kernels"]["rms_norm_fwd"] == 3
+    assert out["train_step"]["kernels"]["flash_attention_bwd_dkv"] >= 1
