@@ -29,7 +29,8 @@ from ..observability.memory import memory_armed, memory_ledger
 from ..observability.profiling import chain_armed as _chain_armed
 from ..observability.profiling import note_chain as _note_chain
 from ..observability.runtime import recompiles
-from ..profiler.record import emit_span, emit_spans, make_span, spans_armed
+from ..profiler.record import (emit_span, emit_spans, make_span, phase,
+                               spans_armed)
 from . import constrain as _constrain
 from . import sampling as _sampling
 from .sampling import SamplerConfig
@@ -563,6 +564,9 @@ class ContinuousBatchingEngine:
         #: their cached prefix; benchmarks diff this against submitted
         #: prompt lengths for the skip ratio)
         self._prefill_tokens = 0
+        #: unified dispatches since the engine was built: the ``n`` of the
+        #: work record that rides on each ``cbe.dispatch`` span
+        self._dispatches = 0
         # HBM memory ledger (observability/memory.py): when armed, every
         # step feeds the pool's byte split + per-request holdings and
         # runs the byte conservation audit alongside check_conservation.
@@ -1125,19 +1129,21 @@ class ContinuousBatchingEngine:
         (bucketed prefill waves + per-shape decode chunk). Speculative
         mode folds draft verification into the same single dispatch
         (``_step_spec``)."""
-        if self._mesh is not None:
-            params = self._place_params(params)
-        if self._speculative:
-            n = self._step_spec(params)
-        elif self._unified:
-            n = self._step_unified(params)
-        else:
-            n = self._step_legacy(params)
-        if memory_armed[0]:
-            # the memory half of the per-step audit: byte split by class
-            # + per-request holdings + byte conservation, run alongside
-            # check_conservation (one list index when disarmed)
-            self._note_memory(params)
+        with phase("cbe.step"):
+            if self._mesh is not None:
+                params = self._place_params(params)
+            if self._speculative:
+                n = self._step_spec(params)
+            elif self._unified:
+                n = self._step_unified(params)
+            else:
+                n = self._step_legacy(params)
+            if memory_armed[0]:
+                # the memory half of the per-step audit: byte split by
+                # class + per-request holdings + byte conservation, run
+                # alongside check_conservation (one list index when
+                # disarmed)
+                self._note_memory(params)
         return n
 
     def _note_memory(self, params) -> None:
@@ -1503,26 +1509,58 @@ class ContinuousBatchingEngine:
         plan_tt, plan_tr = pack_plan(*plan)
         return plan_tt, plan_tr, emit, emit_counts, fed
 
+    def _dispatch_record(self, token_row, positions, kv_lens, emit_counts,
+                         fed) -> Dict[str, int]:
+        """What one unified dispatch asks of the device, as ten integers
+        from the plan arrays — they ride on the ``cbe.dispatch`` span into
+        any profiler trace, where ``perfbench/program_trace.py`` reads the
+        ragged kernel's live grid share and required bytes/FLOPs off them.
+        Per layer: the kernel's grid takes ``grid_steps`` steps and only
+        ``attended_pages`` of them have a page to read (a starved row's
+        ``kv_lens`` is 0); ``causal_pairs`` query-key pairs pass the mask.
+        Computed on every dispatch (a few vectorised numpy lines)."""
+        ps = self.page_size
+        n = self._dispatches
+        self._dispatches = n + 1
+        return {
+            "n": n,
+            "rounds": self.chunk,
+            "token_slots": self.chunk * self._step_tokens,
+            "prefill_tokens": sum(fed),
+            "decode_tokens": sum(emit_counts),
+            "live_rows": self.num_slots - self._slot_rid.count(None),
+            "attended_pages": int(((kv_lens + (ps - 1)) // ps).sum()),
+            "grid_steps": self.chunk * self.num_slots * self._table_width,
+            "causal_pairs": int((positions + 1)[token_row >= 0].sum()),
+            "page_size": ps,
+        }
+
     def _step_unified(self, params) -> int:
         """One ragged round: host-only admission, ONE dispatch serving
         the mixed prefill+decode batch, unpack. The single device→host
         transfer is the step's emitted tokens — identical host-fence
-        discipline to the legacy path, minus its prefill dispatches."""
-        picked = self._admit_pick()
-        for s, req, pages, lp, nc in picked:
-            self._slot_rid[s] = req.rid
-            self._live[req.rid] = req
-            self._pos[s] = nc                 # next position to write
-            self._bt[s] = 0
-            self._bt[s, :len(pages)] = pages
-            # a warm/COW suffix row IS "a row whose first position > 0";
-            # cold rows just start at 0 — one code path for all three
-            # legacy programs
-            self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
-            self._set_row_sampler(s, req)
+        discipline to the legacy path, minus its prefill dispatches.
+
+        Every stretch of host work sits in a ``phase`` (``cbe.admit`` ..
+        ``cbe.audit``), so a profiler trace says what the host did in each
+        gap the device idles through."""
+        with phase("cbe.admit"):
+            picked = self._admit_pick()
+            for s, req, pages, lp, nc in picked:
+                self._slot_rid[s] = req.rid
+                self._live[req.rid] = req
+                self._pos[s] = nc                 # next position to write
+                self._bt[s] = 0
+                self._bt[s, :len(pages)] = pages
+                # a warm/COW suffix row IS "a row whose first position >
+                # 0"; cold rows just start at 0 — one code path for all
+                # three legacy programs
+                self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
+                self._set_row_sampler(s, req)
         if not self._live:
             if self._check_invariants:
-                self.mgr.check_conservation()
+                with phase("cbe.audit"):
+                    self.mgr.check_conservation()
             return 0
         fresh = (self._unified_step is None
                  or self._unified_flags != _prefill_flags())
@@ -1545,11 +1583,17 @@ class ContinuousBatchingEngine:
         # (jit/fusion.py); disarmed cost is one list index per step
         armed_chain = _chain_armed[0]
         tc0 = time.perf_counter_ns() if armed_chain else 0
-        if self._fused_tail:
-            plan_tt, plan_tr, emit, emit_counts, fed = \
-                self._plan_step_packed()
-        else:
-            plan, emit, emit_counts, fed = self._plan_step()
+        with phase("cbe.plan"):
+            if self._fused_tail:
+                plan_tt, plan_tr, emit, emit_counts, fed = \
+                    self._plan_step_packed()
+                plan = (plan_tt, plan_tr)
+                record = self._dispatch_record(
+                    plan_tt[2], plan_tt[3], plan_tr[0], emit_counts, fed)
+            else:
+                plan, emit, emit_counts, fed = self._plan_step()
+                record = self._dispatch_record(
+                    plan[2], plan[3], plan[4], emit_counts, fed)
         if armed_chain:
             tc1 = time.perf_counter_ns()
             _note_chain(op_name="cbe.plan_step", dur_ns=tc1 - tc0)
@@ -1560,25 +1604,22 @@ class ContinuousBatchingEngine:
         if fresh:
             c0 = time.perf_counter()   # dispatch-only window, like legacy
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
-        if self._fused_tail:
+        with phase("cbe.upload"):
+            plan_dev = [jnp.asarray(a) for a in plan]
+            gtable = self._arena.device_table()
+            bt = jnp.asarray(self._bt)
+        with phase("cbe.dispatch", **record):       # enqueue only
             (toks, self._tok_dev, self._gstate_dev, self.mgr.k_pages,
              self.mgr.v_pages) = self._unified_step(
-                params, jnp.asarray(plan_tt), jnp.asarray(plan_tr),
+                params, *plan_dev,
                 self._tok_dev, self._gstate_dev, self._samp_dev,
-                self._arena.device_table(), self.mgr.k_pages,
-                self.mgr.v_pages, jnp.asarray(self._bt))
-        else:
-            (toks, self._tok_dev, self._gstate_dev, self.mgr.k_pages,
-             self.mgr.v_pages) = self._unified_step(
-                params, *(jnp.asarray(a) for a in plan),
-                self._tok_dev, self._gstate_dev, self._samp_dev,
-                self._arena.device_table(), self.mgr.k_pages,
-                self.mgr.v_pages, jnp.asarray(self._bt))
-        if fresh:
-            jax.block_until_ready(toks)
-            recompiles.observe_compile("cbe.unified_step",
-                                       time.perf_counter() - c0)
-        toks = np.asarray(toks)                    # the one fence
+                gtable, self.mgr.k_pages, self.mgr.v_pages, bt)
+        with phase("cbe.fence"):
+            if fresh:
+                jax.block_until_ready(toks)
+                recompiles.observe_compile("cbe.unified_step",
+                                           time.perf_counter() - c0)
+            toks = np.asarray(toks)                    # the one fence
         if armed_chain:
             tc1 = time.perf_counter_ns()
             if self._fused_tail:
@@ -1592,58 +1633,61 @@ class ContinuousBatchingEngine:
                 # pass's chain mining (REGIONS["sampling_epilogue"])
                 _note_chain(op_name="cbe.sample_epilogue", dur_ns=0)
             tc0 = tc1
-        if t0_ns:
-            # per-request phase bookkeeping over the dispatch window:
-            # the trace keeps its prefill/decode lanes even though both
-            # ride one program. Runs EVERY armed step, so it only
-            # updates the per-slot coalesced windows (a few list ops) —
-            # spans materialise at phase change / retire, keeping the
-            # armed loop inside bench_obs_overhead's budget
-            t1_ns = time.perf_counter_ns()
-            batch: list = []
-            win = self._win
+        with phase("cbe.unpack"):
+            if t0_ns:
+                # per-request phase bookkeeping over the dispatch window:
+                # the trace keeps its prefill/decode lanes even though
+                # both ride one program. Runs EVERY armed step, so it only
+                # updates the per-slot coalesced windows (a few list ops)
+                # — spans materialise at phase change / retire, keeping
+                # the armed loop inside bench_obs_overhead's budget
+                t1_ns = time.perf_counter_ns()
+                batch: list = []
+                win = self._win
+                for s in range(self.num_slots):
+                    if self._slot_rid[s] is None:
+                        continue
+                    c = emit_counts[s]
+                    f = fed[s]
+                    if (c == 0) != (f == 0):
+                        # steady-state single-phase round: extend the
+                        # window inline (no function call — this branch
+                        # is the armed hot path every decode step takes)
+                        w = win[s]
+                        kind = "decode" if c else "prefill"
+                        if w is not None and w[0] == kind:
+                            w[2] = t1_ns
+                            w[3] += c or f
+                            continue
+                    if f > 0:
+                        self._note_win(s, "prefill", t0_ns, t1_ns, f, batch)
+                    if c:
+                        self._note_win(s, "decode", t0_ns, t1_ns, c, batch)
+                if batch:
+                    emit_spans(batch)
             for s in range(self.num_slots):
                 if self._slot_rid[s] is None:
                     continue
-                c = emit_counts[s]
-                f = fed[s]
-                if (c == 0) != (f == 0):
-                    # steady-state single-phase round: extend the
-                    # window inline (no function call — this branch is
-                    # the armed hot path every decode step takes)
-                    w = win[s]
-                    kind = "decode" if c else "prefill"
-                    if w is not None and w[0] == kind:
-                        w[2] = t1_ns
-                        w[3] += c or f
-                        continue
-                if f > 0:
-                    self._note_win(s, "prefill", t0_ns, t1_ns, f, batch)
-                if c:
-                    self._note_win(s, "decode", t0_ns, t1_ns, c, batch)
-            if batch:
-                emit_spans(batch)
-        for s in range(self.num_slots):
-            if self._slot_rid[s] is None:
-                continue
-            if self._fused_tail and emit_counts[s] == self.chunk:
-                # fused-tail fast unpack: the slot emitted every round,
-                # so its column IS the emission (no K-wide mask filter)
-                self._deliver_tokens(s, toks[:, s])
-            else:
-                self._deliver_tokens(
-                    s, (toks[k, s] for k in range(self.chunk)
-                        if emit[k, s]))
+                if self._fused_tail and emit_counts[s] == self.chunk:
+                    # fused-tail fast unpack: the slot emitted every
+                    # round, so its column IS the emission (no K-wide
+                    # mask filter)
+                    self._deliver_tokens(s, toks[:, s])
+                else:
+                    self._deliver_tokens(
+                        s, (toks[k, s] for k in range(self.chunk)
+                            if emit[k, s]))
         if armed_chain:
             _note_chain(op_name="cbe.decode_tail",
                         dur_ns=time.perf_counter_ns() - tc0)
         if self.cache is not None:
-            if self._check_invariants:
-                # the ownership-model anchor: every page is free, live
-                # (refcounted) or cached — checked after EVERY ragged
-                # step, COW suffix rows included
-                self.mgr.check_conservation()
-            self.cache.update_gauges()
+            with phase("cbe.audit"):
+                if self._check_invariants:
+                    # the ownership-model anchor: every page is free, live
+                    # (refcounted) or cached — checked after EVERY ragged
+                    # step, COW suffix rows included
+                    self.mgr.check_conservation()
+                self.cache.update_gauges()
         return len(self._live)
 
     # -- speculative decoding (draft + verify in ONE ragged dispatch) --------

@@ -4,8 +4,8 @@ Reference: RecordEvent (python/paddle/profiler/utils.py) backed by the C++
 thread-local HostEventRecorder (paddle/fluid/platform/profiler/
 host_tracer.cc — SURVEY.md §5.1). Here the recorder is a process-global,
 thread-aware span list; when a capture is active each span additionally
-enters a ``jax.profiler.TraceAnnotation`` so it shows up in XLA xplane
-traces (TensorBoard) correlated with device activity.
+enters a :data:`phase` (``jax.profiler.TraceAnnotation``) so it shows up in
+XLA xplane traces (TensorBoard) correlated with device activity.
 
 Spans carry the ambient trace id (``observability.trace``) so one serving
 request / training step can be followed across scheduler, engine and op
@@ -21,10 +21,22 @@ import threading
 import time
 from typing import List, NamedTuple, Optional
 
+from jax.profiler import TraceAnnotation
+
 from ..observability import runtime as _obs_runtime
 from ..observability.flight import flight_armed, flight_recorder
 from ..observability.timeline import span_collector, timeline_armed
 from ..observability.trace import current_trace
+
+
+#: ``with phase(name, **ints):`` — a span of the program's own in the
+#: PROFILER'S trace: a no-op (well under a microsecond) outside a profiler
+#: session, and inside one a host event on the device trace's clock whose
+#: keyword arguments come back as the event's stats — whoever started the
+#: session (``jax.profiler.start_trace`` / ``trace``, or a
+#: :class:`~paddle_tpu.profiler.Profiler` with a TPU target). Needs no
+#: arming, so it is what hot loops use for spans every trace must hold.
+phase = TraceAnnotation
 
 
 class HostSpan(NamedTuple):
@@ -160,7 +172,8 @@ class RecordEvent:
         # precomputed: the timeline collector only consumes request
         # envelopes (every other categorised span arrives via emit_span)
         self._is_request = name.endswith(".request")
-        # light spans record ONLY inside a profiler capture window: the
+        # light spans record a HostSpan ONLY inside a profiler capture
+        # window (their ``phase`` is in every profiler session's trace): the
         # per-STEP scheduler span fires hundreds of times a second and
         # would otherwise pay the full HostSpan+ring cost on every armed
         # serving step just to wrap the 256-deep flight ring in under a
@@ -170,6 +183,12 @@ class RecordEvent:
 
     def begin(self) -> None:
         capture = host_recorder._enabled
+        if capture or self._light:
+            # a light span's ``phase`` is in EVERY profiler session's trace,
+            # whoever started it (a no-op outside one); any other span's
+            # belongs to the capture window, like its HostSpan
+            self._jax_ann = phase(self.name)
+            self._jax_ann.__enter__()
         # zero-overhead fast path; the timeline term only arms request
         # envelopes — with just the collector armed, step/mark spans
         # nobody would consume never pay the span bookkeeping
@@ -181,23 +200,14 @@ class RecordEvent:
             ctx = current_trace()
             self._trace_id = ctx.trace_id if ctx is not None else ""
         self._start_ns = time.perf_counter_ns()
-        if not capture:      # flight-only: skip the jax annotation (the
-            return           # xplane trace belongs to capture windows)
-        try:
-            import jax.profiler as jprof
-            self._jax_ann = jprof.TraceAnnotation(self.name)
-            self._jax_ann.__enter__()
-        except Exception:
-            self._jax_ann = None
 
     def end(self) -> None:
+        ann = self._jax_ann
+        if ann is not None:
+            self._jax_ann = None
+            ann.__exit__(None, None, None)
         if self._start_ns is None:        # never began (or capture was off)
             return
-        if self._jax_ann is not None:
-            try:
-                self._jax_ann.__exit__(None, None, None)
-            finally:
-                self._jax_ann = None
         # light spans feed ONLY the capture window — a light span begun
         # under capture with the flight recorder also armed must still
         # stay out of the ring (it would wrap the 256-deep postmortem

@@ -1,0 +1,243 @@
+"""Engine step phases and the per-dispatch work record in the PROFILER'S own
+trace (ISSUE 24): ``paddle_serving.step`` > ``cbe.step`` > the seven inner
+phases of ``_step_unified``, with the record's ten integers on
+``cbe.dispatch`` — in any ``jax.profiler`` session, no arming.
+
+A tiny engine as ``tests/test_serving.py`` builds one, traced on the CPU
+with ``python_tracer_level = 0``."""
+
+import glob
+import os
+import types
+
+import jax
+import numpy as np
+import pytest
+
+from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
+                                           GenerationConfig)
+from paddle_tpu.models import llama as L
+from paddle_tpu.profiler import Profiler, ProfilerTarget
+from paddle_tpu.serving import SchedulerConfig, ServingScheduler
+
+INNER = ["cbe.admit", "cbe.plan", "cbe.upload", "cbe.dispatch", "cbe.fence",
+         "cbe.unpack", "cbe.audit"]
+RECORD_KEYS = {"n", "rounds", "token_slots", "prefill_tokens",
+               "decode_tokens", "live_rows", "attended_pages", "grid_steps",
+               "causal_pairs", "page_size"}
+PAGE, SLOTS, MAX_SEQ, MAX_NEW = 4, 3, 32, 6
+
+
+def _build(chunk, fused_tail):
+    cfg = L.llama_tiny(num_hidden_layers=6)
+    params = L.init_stacked_params(cfg, seed=3)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
+        num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=chunk,
+        prefix_cache=True, fused_tail=fused_tail)
+    sched = ServingScheduler(eng, SchedulerConfig(max_queue_depth=64))
+    return cfg, params, eng, sched
+
+
+def _prompts(cfg, n, seed):
+    rng = np.random.RandomState(seed)
+    return [rng.randint(1, cfg.vocab_size, (int(rng.randint(3, 14)),)
+                        ).astype(np.int32) for _ in range(n)]
+
+
+def _drain(sched, params):
+    rounds = 0
+    while sched.pending:
+        sched.step(params)
+        rounds += 1
+    return rounds
+
+
+def _warm(cfg, params, sched):
+    """Compile outside the traced run (one request, drained)."""
+    sched.submit(_prompts(cfg, 1, seed=99)[0], max_new_tokens=2)
+    _drain(sched, params)
+
+
+def _host_events(trace_dir):
+    """Every ``cbe.*`` / ``paddle_serving.*`` host event of the newest
+    xplane under ``trace_dir``: (name, start, end, stats) by start."""
+    from jax.profiler import ProfileData
+    path = sorted(glob.glob(os.path.join(
+        trace_dir, "plugins", "profile", "*", "*.xplane.pb")))[-1]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith(("cbe.", "paddle_serving.")):
+                    out.append((e.name, e.start_ns, e.start_ns + e.duration_ns,
+                                dict(e.stats)))
+    return sorted(out, key=lambda e: (e[1], -e[2]))
+
+
+def _inside(events, outer):
+    return [e for e in events
+            if e is not outer and outer[1] <= e[1] and e[2] <= outer[2]]
+
+
+def _traced_serve(tmp_path, chunk, fused_tail, n_requests=5):
+    cfg, params, eng, sched = _build(chunk, fused_tail)
+    _warm(cfg, params, sched)
+    # one traced serve: the events, what the planner laid out for each
+    # dispatch (token_row, positions, kv_lens), what was sent and delivered
+    run = types.SimpleNamespace(plans=[])
+    if fused_tail:
+        packed = eng._plan_step_packed
+
+        def spy():
+            out = packed()
+            run.plans.append((out[0][2].copy(), out[0][3].copy(),
+                              out[1][0].copy()))
+            return out
+        eng._plan_step_packed = spy
+    else:
+        plain = eng._plan_step
+
+        def spy():
+            out = plain()
+            run.plans.append(tuple(a.copy() for a in out[0][2:5]))
+            return out
+        eng._plan_step = spy
+    run.prompts = _prompts(cfg, n_requests, seed=1)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        run.handles = [sched.submit(p, max_new_tokens=MAX_NEW)
+                       for p in run.prompts]
+        run.rounds = _drain(sched, params)
+    finally:
+        jax.profiler.stop_trace()
+    run.eng = eng
+    run.events = _host_events(str(tmp_path))
+    run.delivered = sum(len(h.stream.tokens) for h in run.handles)
+    return run
+
+
+@pytest.fixture(scope="module", params=[(1, False), (3, False), (3, True)],
+                ids=["chunk1-plain", "chunk3-plain", "chunk3-fused"])
+def run(request, tmp_path_factory):
+    chunk, fused = request.param
+    r = _traced_serve(tmp_path_factory.mktemp("trace"), chunk, fused)
+    r.chunk = chunk
+    return r
+
+
+def test_one_round_and_one_engine_step_per_scheduler_step(run):
+    rounds = [e for e in run.events if e[0] == "paddle_serving.step"]
+    steps = [e for e in run.events if e[0] == "cbe.step"]
+    assert len(rounds) == run.rounds == len(steps)
+    for rnd, step in zip(rounds, steps):
+        assert rnd[1] <= step[1] and step[2] <= rnd[2]      # round > step
+
+
+def test_phases_once_in_order_disjoint_and_covering(run):
+    steps = [e for e in run.events if e[0] == "cbe.step"]
+    dispatching, covered, whole = 0, 0.0, 0.0
+    for step in steps:
+        inner = _inside(run.events, step)
+        names = [e[0] for e in inner]
+        if "cbe.dispatch" not in names:
+            assert set(names) <= {"cbe.admit", "cbe.audit"}
+            continue
+        dispatching += 1
+        # prefix cache on: every phase's code runs in a dispatching step
+        assert names == INNER
+        for a, b in zip(inner, inner[1:]):
+            assert a[2] <= b[1], (a[0], b[0])               # no overlap
+        covered += sum(e[2] - e[1] for e in inner)
+        whole += step[2] - step[1]
+    assert dispatching == len(run.plans) > 0
+    # what is left is the frame's teardown (the uploads' device buffers)
+    # and the taps between phases: ~60 us of a ~2 ms CPU step here
+    assert covered >= 0.95 * whole
+
+
+def test_record_matches_the_plan_and_the_tokens(run):
+    eng = run.eng
+    records = [e[3] for e in run.events if e[0] == "cbe.dispatch"]
+    assert len(records) == len(run.plans)
+    assert all(set(r) == RECORD_KEYS for r in records)
+    assert all(isinstance(v, int) for r in records for v in r.values())
+    ordinals = [r["n"] for r in records]
+    assert ordinals == list(range(ordinals[0], ordinals[0] + len(records)))
+    width = eng._table_width
+    for rec, (token_row, positions, kv_lens) in zip(records, run.plans):
+        assert rec["rounds"] == run.chunk and rec["page_size"] == PAGE
+        assert rec["token_slots"] == run.chunk * eng._step_tokens
+        assert rec["grid_steps"] == run.chunk * SLOTS * width
+        assert 1 <= rec["live_rows"] <= SLOTS
+        # the kernel's own test, step by step: page j of row s runs in
+        # round k iff j * page_size < kv_lens[k, s]
+        assert rec["attended_pages"] == sum(
+            1 for k in range(run.chunk) for s in range(SLOTS)
+            for j in range(width) if j * PAGE < kv_lens[k, s])
+        assert rec["causal_pairs"] == sum(
+            int(positions[k, t]) + 1 for k in range(run.chunk)
+            for t in range(token_row.shape[1]) if token_row[k, t] >= 0)
+        assert rec["prefill_tokens"] + rec["decode_tokens"] == \
+            int((token_row >= 0).sum())
+    # distinct prompts, nothing cached: every prompt token is fed once
+    assert sum(r["prefill_tokens"] for r in records) == \
+        sum(len(p) for p in run.prompts)
+    decoded = sum(r["decode_tokens"] for r in records)
+    assert run.delivered == len(run.prompts) * MAX_NEW
+    if run.chunk == 1:
+        assert decoded == run.delivered
+    else:   # a request that ends inside a dispatch leaves planned rounds
+        assert run.delivered <= decoded <= \
+            run.delivered + len(run.prompts) * (run.chunk - 1)
+
+
+@pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
+def test_token_streams_identical_with_and_without_a_session(
+        tmp_path, fused_tail):
+    traced = _traced_serve(tmp_path, 3, fused_tail)
+    cfg, params, eng, sched = _build(3, fused_tail)
+    _warm(cfg, params, sched)
+    handles = [sched.submit(p, max_new_tokens=MAX_NEW)
+               for p in traced.prompts]
+    _drain(sched, params)
+    assert [list(h.stream.tokens) for h in handles] == \
+        [list(h.stream.tokens) for h in traced.handles]
+
+
+def test_profiler_capture_holds_each_round_once(tmp_path):
+    """Under ``paddle_tpu.profiler.Profiler`` the light round span enters
+    ONE annotation (and one HostSpan), the engine phases ride along."""
+    cfg, params, eng, sched = _build(3, False)
+    _warm(cfg, params, sched)
+    prof = Profiler(targets=[ProfilerTarget.TPU], log_dir=str(tmp_path))
+    with prof:
+        for p in _prompts(cfg, 3, seed=2):
+            sched.submit(p, max_new_tokens=MAX_NEW)
+        rounds = _drain(sched, params)
+    events = _host_events(str(tmp_path))
+    count = {n: sum(1 for e in events if e[0] == n)
+             for n in ["paddle_serving.step", "cbe.step"] + INNER}
+    assert count["paddle_serving.step"] == rounds == count["cbe.step"]
+    dispatches = count["cbe.dispatch"]
+    assert 0 < dispatches <= rounds
+    assert all(count[n] == dispatches for n in INNER[1:6])
+    assert all(set(e[3]) == RECORD_KEYS
+               for e in events if e[0] == "cbe.dispatch")
+    assert sum(1 for s in prof.collected_spans
+               if s.name == "paddle_serving.step") == rounds
+
+
+def test_phase_is_inert_outside_a_session():
+    from paddle_tpu.profiler.record import RecordEvent, phase
+    with phase("cbe.step"), phase("cbe.dispatch", n=1, rounds=2):
+        pass
+    light = RecordEvent("paddle_serving.step", light=True)
+    for _ in range(2):                  # reusable, as the scheduler's is
+        with light:
+            assert light._start_ns is None      # no HostSpan off-capture
+        assert light._jax_ann is None
