@@ -311,8 +311,11 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
     errs = {}
 
     # -- ragged paged attention at the unified step's shapes: T = slots
-    # packed tokens, one pool layer. Rows: a long decode row, a short decode
-    # row, a prefill span starting mid-page, idle rows; one pad slot.
+    # packed tokens, one pool layer, three mixes through ONE compiled
+    # kernel (its grid's extent is data). dense: a long decode row, a short
+    # decode row, a prefill span starting mid-page, idle rows between, one
+    # pad slot. sparse: two decode rows far apart, every other row starved.
+    # empty: a round of pad slots only (no page to read; zeros out).
     t, rows, width = sz.slots, sz.slots, sz.max_seq // sz.page
     n_pages = rows * width + 1
     kq, kk, kv = jax.random.split(key, 3)
@@ -321,31 +324,42 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
     v_pages = jax.random.normal(kv, (n_pages, sz.page, nkv, d), jnp.bfloat16)
     tables = (1 + rng.permutation(n_pages - 1)).reshape(rows, width)
     span = t - 3                    # prefill tokens; one slot stays a pad
-    token_row = np.full((t,), -1, np.int32)
-    positions = np.zeros((t,), np.int32)
-    kv_lens = np.zeros((rows,), np.int32)
-    for tok, row, kv_len in ((0, 0, sz.max_seq - 1), (1, 1, sz.page + 3)):
-        token_row[tok], positions[tok], kv_lens[row] = row, kv_len - 1, kv_len
-    token_row[2:2 + span] = rows - 1
-    positions[2:2 + span] = 3 * sz.page + 1 + np.arange(span)
-    kv_lens[rows - 1] = 3 * sz.page + 1 + span
-    args = (q, k_pages, v_pages, jnp.asarray(tables, jnp.int32),
-            jnp.asarray(token_row), jnp.asarray(positions),
-            jnp.asarray(kv_lens))
+    mixes = {   # name -> [(row, first position, tokens)], packed in order
+        "dense": [(0, sz.max_seq - 2, 1), (1, sz.page + 2, 1),
+                  (rows - 1, 3 * sz.page + 1, span)],
+        "sparse": [(1, 2 * sz.page - 1, 1), (rows - 2, sz.page, 1)],
+        "empty": [],
+    }
     scale = 1.0 / d ** 0.5
     ragged = jax.jit(lambda *a: pa.ragged_paged_attention_pallas(
         *a, scale=scale, interpret=interpret))
-    if not interpret:
-        require_kernels(ragged.lower(*args), ("ragged_paged_attention",),
-                        "ragged parity")
-    got = ragged(*args)
-    ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
-        *a, scale=scale))(*args)
-    pad = token_row < 0
-    check(bool(jnp.all(got[pad] == 0)), "ragged kernel: pad slots not 0")
-    check(bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))),
-          "ragged kernel: non-finite output")
-    errs["ragged_paged_attention"] = _err(got[~pad], ref[~pad])
+    ragged_ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
+        *a, scale=scale))
+    for name, spans in mixes.items():
+        token_row = np.full((t,), -1, np.int32)
+        positions = np.zeros((t,), np.int32)
+        kv_lens = np.zeros((rows,), np.int32)
+        at = 0
+        for row, first, n in spans:
+            token_row[at:at + n] = row
+            positions[at:at + n] = first + np.arange(n)
+            kv_lens[row] = first + n
+            at += n
+        args = (q, k_pages, v_pages, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(token_row), jnp.asarray(positions),
+                jnp.asarray(kv_lens))
+        if not interpret and name == "dense":   # one program for all three
+            require_kernels(ragged.lower(*args),
+                            ("ragged_paged_attention",), "ragged parity")
+        got = ragged(*args)
+        pad = token_row < 0
+        check(bool(jnp.all(got[pad] == 0)),
+              f"ragged kernel ({name}): pad slots not 0")
+        check(bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))),
+              f"ragged kernel ({name}): non-finite output")
+        if spans:
+            errs[f"ragged_paged_attention.{name}"] = _err(
+                got[~pad], ragged_ref(*args)[~pad])
 
     # -- rms_norm fwd+bwd at the serve (T x h) and train (B*S x h) row counts
     for rows_ in (sz.slots, sz.batch * sz.seq):

@@ -1515,11 +1515,15 @@ class ContinuousBatchingEngine:
         from the plan arrays — they ride on the ``cbe.dispatch`` span into
         any profiler trace, where ``perfbench/program_trace.py`` reads the
         ragged kernel's live grid share and required bytes/FLOPs off them.
-        Per layer: the kernel's grid takes ``grid_steps`` steps and only
-        ``attended_pages`` of them have a page to read (a starved row's
-        ``kv_lens`` is 0); ``causal_pairs`` query-key pairs pass the mask.
+        Per layer: the kernel's grid walks each micro-round's live pages
+        (``ops.paged_attention.ragged_live_pages``; a starved row's
+        ``kv_lens`` is 0) and takes one step in a round that has none, so
+        ``attended_pages`` of its ``grid_steps`` steps have a page to
+        read; ``causal_pairs`` query-key pairs pass the mask.
         Computed on every dispatch (a few vectorised numpy lines)."""
+        from ..ops.paged_attention import ragged_live_pages
         ps = self.page_size
+        live_pages = ragged_live_pages(kv_lens, ps, self._table_width)
         n = self._dispatches
         self._dispatches = n + 1
         return {
@@ -1529,8 +1533,8 @@ class ContinuousBatchingEngine:
             "prefill_tokens": sum(fed),
             "decode_tokens": sum(emit_counts),
             "live_rows": self.num_slots - self._slot_rid.count(None),
-            "attended_pages": int(((kv_lens + (ps - 1)) // ps).sum()),
-            "grid_steps": self.chunk * self.num_slots * self._table_width,
+            "attended_pages": int(live_pages.sum()),
+            "grid_steps": int(np.maximum(live_pages, 1).sum()),
             "causal_pairs": int((positions + 1)[token_row >= 0].sum()),
             "page_size": ps,
         }

@@ -212,33 +212,84 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
     return jnp.einsum("ths,tshd->thd", probs.astype(v.dtype), v)
 
 
-def _ragged_attention_kernel(block_tables_ref, kv_lens_ref, token_row_ref,
-                             positions_ref, q_ref, k_ref, v_ref, o_ref,
-                             m_ref, l_ref, acc_ref, *, page: int,
-                             n_pages: int, n_rows: int, scale: float,
-                             nh: int, nkv: int, d: int, t: int):
-    r = pl.program_id(0)
-    j = pl.program_id(1)
+def ragged_live_pages(kv_lens, page: int, max_pages: int) -> np.ndarray:
+    """Live (row, page) pairs of each ragged kernel call, on the host in
+    numpy: the work list's ``n_live`` (:func:`_ragged_work_list`), for the
+    engine's work record. ``kv_lens``: (..., R) attendable spans, one
+    call per row of the leading axes. A call's grid walks its live pairs,
+    and takes one step when it has none (the step that zeroes the
+    output)."""
+    return np.minimum(-(-np.asarray(kv_lens, np.int64) // page),
+                      max_pages).sum(axis=-1)
 
-    @pl.when((r == 0) & (j == 0))
+
+def _work_item_bits(max_pages: int) -> int:
+    """Bits of a work item that hold the page index (static; the row sits
+    above them, and a list that SMEM can hold is far from 31 bits)."""
+    return max(1, (max_pages - 1).bit_length())
+
+
+def _unpack_work_item(item, page_bits: int):
+    """(row, page index within the row, is the row's last page)."""
+    return (item >> (page_bits + 1), (item >> 1) & ((1 << page_bits) - 1),
+            (item & 1) == 1)
+
+
+def _ragged_work_list(kv_lens, page: int, max_pages: int):
+    """The kernel's grid as data: the live (row, page) pairs of one call,
+    row-major, one packed int32 each (see :func:`_unpack_work_item`).
+    Returns (items (n_rows * max_pages,), n_live); only the first
+    ``n_live`` items are work, the rest hold in-range indices. Depends on
+    ``kv_lens`` alone, so under a scan over layers it is loop-invariant."""
+    n_rows = kv_lens.shape[0]
+    page_bits = _work_item_bits(max_pages)
+    # the min keeps over-decoded rows (kv_lens past the table span) inside
+    # their table
+    pages_r = jnp.minimum((kv_lens.astype(jnp.int32) + (page - 1)) // page,
+                          max_pages)[:, None]               # (R, 1)
+    ends = jnp.cumsum(pages_r, axis=0)
+    i = jnp.arange(n_rows * max_pages, dtype=jnp.int32)[None, :]
+    # done[r, i]: row r's pages all come before item i. Three reductions
+    # of it (no gather): the item's row, the row's first item, and
+    # whether the next item belongs to a later row
+    done = ends <= i
+    row = jnp.sum(done, axis=0, dtype=jnp.int32)
+    first = jnp.sum(jnp.where(done, pages_r, 0), axis=0, dtype=jnp.int32)
+    last = jnp.sum(ends <= i + 1, axis=0, dtype=jnp.int32) > row
+    # items past the list: keep their indices inside the block table
+    row = jnp.minimum(row, n_rows - 1)
+    j = jnp.minimum(i[0] - first, max_pages - 1)
+    return ((row << (page_bits + 1)) | (j << 1) | last.astype(jnp.int32),
+            ends[-1, 0])
+
+
+def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
+                             token_row_ref, positions_ref, q_ref, k_ref,
+                             v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                             page: int, page_bits: int, scale: float,
+                             nh: int, nkv: int, d: int, t: int):
+    del block_tables_ref                    # read by the K/V index maps
+    i = pl.program_id(0)
+    r, j, last = _unpack_work_item(work_ref[i], page_bits)
+    # false only in the one step of a call that has no live page
+    live = i < n_live_ref[0]
+
+    @pl.when(i == 0)
     def _zero_out():
-        # pad slots (token_row -1) belong to no row and are never merged;
-        # zero the whole output once so their lanes hold finite values
-        # (uninitialized VMEM garbage scattered into the pool could poison
-        # masked softmax lanes of OTHER rows via 0 * NaN)
+        # pad slots (token_row -1) and tokens of rows with no page belong
+        # to no work item and are never merged; zero the whole output
+        # once so their lanes hold finite values (uninitialized VMEM
+        # garbage scattered into the pool could poison masked softmax
+        # lanes of OTHER rows via 0 * NaN)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(j == 0)
+    @pl.when(live & (j == 0))
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # skip pages beyond this row's attendable span (rows with no tokens
-    # this round carry kv_len 0 and stream nothing)
-    run = j * page < kv_lens_ref[r]
-
-    @pl.when(run)
+    @pl.when(live)
     def _compute():
         rep = nh // nkv
         q = q_ref[...].astype(jnp.float32)          # (T, nh, d)
@@ -282,7 +333,7 @@ def _ragged_attention_kernel(block_tables_ref, kv_lens_ref, token_row_ref,
         acc_ref[...] = acc_ref[...] * alpha + pv2
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(j == n_pages - 1)
+    @pl.when(live & last)
     def _finalize():
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
@@ -299,32 +350,41 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     """Pallas ragged kernel: same contract as
     :func:`ragged_paged_attention_array`.
 
-    Grid (rows, pages): each step streams exactly ONE physical page of
-    one row HBM→VMEM via the scalar-prefetched block table (Mosaic
-    double-buffers consecutive steps) and folds it into the online
-    softmax of every packed token that belongs to the row — decode and
-    prefill tokens alike, so a mixed batch is one dispatch whose shape
-    is invariant to the request mix (PAPERS.md ragged paged attention).
+    The grid is a work list of the call's live (row, page) pairs
+    (:func:`_ragged_work_list`, built here from ``kv_lens``), its extent
+    their number — a traced scalar, so a call costs what its rows attend
+    to, not rows x table width. Each step streams exactly ONE physical
+    page of one row HBM→VMEM via the scalar-prefetched work list and
+    block table (consecutive steps walk consecutive pages of a row, so
+    Mosaic double-buffers them) and folds it into the online softmax of
+    every packed token that belongs to the row — decode and prefill
+    tokens alike, so a mixed batch is one dispatch whose shape is
+    invariant to the request mix (PAPERS.md ragged paged attention). A
+    call with no live page takes one step, which zeroes the output.
     """
     t, nh, d = q.shape
     page = k_pages.shape[1]
     nkv = k_pages.shape[2]
     n_rows, max_pages = block_tables.shape
     s = scale if scale is not None else 1.0 / math.sqrt(d)
+    page_bits = _work_item_bits(max_pages)
+    work, n_live = _ragged_work_list(kv_lens, page, max_pages)
+
+    def kv_page(i, bt, work, n_live):
+        r, j, _ = _unpack_work_item(work[i], page_bits)
+        return (bt[r, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, kv_lens
-        grid=(n_rows, max_pages),
+        num_scalar_prefetch=3,  # block_tables, work list, n_live
+        grid=(jnp.maximum(n_live, 1),),
         in_specs=[
-            pl.BlockSpec((t, 1), lambda r, j, bt, kvl: (0, 0)),
-            pl.BlockSpec((t, 1), lambda r, j, bt, kvl: (0, 0)),
-            pl.BlockSpec((t, nh, d), lambda r, j, bt, kvl: (0, 0, 0)),
-            pl.BlockSpec((1, page, nkv, d),
-                         lambda r, j, bt, kvl: (bt[r, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, nkv, d),
-                         lambda r, j, bt, kvl: (bt[r, j], 0, 0, 0)),
+            pl.BlockSpec((t, 1), lambda i, *_: (0, 0)),
+            pl.BlockSpec((t, 1), lambda i, *_: (0, 0)),
+            pl.BlockSpec((t, nh, d), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec((1, page, nkv, d), kv_page),
+            pl.BlockSpec((1, page, nkv, d), kv_page),
         ],
-        out_specs=pl.BlockSpec((t, nh, d), lambda r, j, bt, kvl: (0, 0, 0)),
+        out_specs=pl.BlockSpec((t, nh, d), lambda i, *_: (0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((t * nh, 128), jnp.float32),
             pltpu.VMEM((t * nh, 128), jnp.float32),
@@ -332,15 +392,15 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
         ],
     )
     kernel = functools.partial(
-        _ragged_attention_kernel, page=page, n_pages=max_pages,
-        n_rows=n_rows, scale=s, nh=nh, nkv=nkv, d=d, t=t)
+        _ragged_attention_kernel, page=page, page_bits=page_bits, scale=s,
+        nh=nh, nkv=nkv, d=d, t=t)
     return pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nh, d), v_pages.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), kv_lens.astype(jnp.int32),
+    )(block_tables.astype(jnp.int32), work, n_live.reshape(1),
       token_row.astype(jnp.int32).reshape(t, 1),
       positions.astype(jnp.int32).reshape(t, 1),
       q, k_pages, v_pages)

@@ -172,7 +172,14 @@ def test_record_matches_the_plan_and_the_tokens(run):
     for rec, (token_row, positions, kv_lens) in zip(records, run.plans):
         assert rec["rounds"] == run.chunk and rec["page_size"] == PAGE
         assert rec["token_slots"] == run.chunk * eng._step_tokens
-        assert rec["grid_steps"] == run.chunk * SLOTS * width
+        # the kernel walks each micro-round's live pages, and one step
+        # in a round that has none
+        assert rec["grid_steps"] == sum(
+            max(1, sum(min(-(-int(kv_lens[k, s]) // PAGE), width)
+                       for s in range(SLOTS)))
+            for k in range(run.chunk))
+        assert rec["attended_pages"] <= rec["grid_steps"] <= \
+            rec["attended_pages"] + rec["rounds"]
         assert 1 <= rec["live_rows"] <= SLOTS
         # the kernel's own test, step by step: page j of row s runs in
         # round k iff j * page_size < kv_lens[k, s]
@@ -194,6 +201,27 @@ def test_record_matches_the_plan_and_the_tokens(run):
     else:   # a request that ends inside a dispatch leaves planned rounds
         assert run.delivered <= decoded <= \
             run.delivered + len(run.prompts) * (run.chunk - 1)
+
+
+def test_record_counts_one_grid_step_for_an_empty_round():
+    """A serve never plans a micro-round without a token while a row is
+    live, so the empty round is laid out by hand: it costs the kernel the
+    one step that zeroes its output, a starved row costs none, and a span
+    past the table clamps to the table's width."""
+    _, _, eng, _ = _build(3, False)
+    width = eng._table_width
+    kv_lens = np.zeros((3, SLOTS), np.int32)
+    kv_lens[0] = [5, 0, 9]                      # 2 + 0 + 3 pages
+    kv_lens[2] = [0, PAGE * width + 3, 0]       # past the table: width
+    token_row = np.full((3, eng._step_tokens), -1, np.int32)
+    positions = np.zeros_like(token_row)
+    token_row[0, :2], positions[0, :2] = [0, 2], [4, 8]
+    token_row[2, 0], positions[2, 0] = 1, PAGE * width + 2
+    rec = eng._dispatch_record(token_row, positions, kv_lens,
+                               [1, 1, 1], [0, 0, 0])
+    assert rec["rounds"] == 3
+    assert rec["attended_pages"] == 5 + width
+    assert rec["grid_steps"] == 5 + 1 + width
 
 
 @pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])

@@ -71,25 +71,121 @@ def test_ragged_array_matches_legacy_decode_and_prefill_pair():
         np.testing.assert_allclose(out[sl], ref[0], rtol=1e-5, atol=1e-6)
 
 
-def test_ragged_pallas_interpret_matches_array():
+def _packed_case(kv_lens, spans, shared=()):
+    """A packed batch from row spans: ``spans`` = [(row, first position,
+    tokens)], packed in order and padded to T with pad slots. Each row
+    owns ``width`` private pages; ``shared`` = [(row, table slot, other
+    row)] points a table slot at the other row's page (prefix cache)."""
+    width, PAGE, T, NKV, NH, D = 4, 4, 12, 2, 4, 8
+    rng = np.random.RandomState(0)
+    n_rows = len(kv_lens)
+    pool = 1 + n_rows * width
+    kp = rng.randn(pool, PAGE, NKV, D).astype(np.float32)
+    vp = rng.randn(pool, PAGE, NKV, D).astype(np.float32)
+    bt = (1 + np.arange(n_rows * width, dtype=np.int32)
+          ).reshape(n_rows, width)
+    for row, slot, other in shared:
+        bt[row, slot] = bt[other, slot]
+    token_row = np.full((T,), -1, np.int32)
+    positions = np.zeros((T,), np.int32)
+    at = 0
+    for row, first, n in spans:
+        token_row[at:at + n] = row
+        positions[at:at + n] = first + np.arange(n)
+        at += n
+    q = rng.randn(T, NH, D).astype(np.float32)
+    return (q, kp, vp, bt, token_row, positions,
+            np.asarray(kv_lens, np.int32))
+
+
+_RAGGED_CASES = {
+    # the mixed batch above: decode + cold prefill + warm suffix + pads
+    "mixed_batch": lambda: _mixed_batch(seed=3),
+    # all pad slots: the one grid step only zeroes the output
+    "no_live_row": dict(kv_lens=[0, 0, 0], spans=[]),
+    "starved_rows_between_live": dict(
+        kv_lens=[0, 5, 0, 0, 9, 0], spans=[(1, 4, 1), (4, 8, 1)]),
+    "exact_page_multiple_and_one_past": dict(
+        kv_lens=[8, 9], spans=[(0, 7, 1), (1, 8, 1)]),
+    "row_fills_table_width": dict(
+        kv_lens=[16, 3], spans=[(0, 12, 4), (1, 2, 1)]),
+    # row 1's first two table slots are row 0's pages
+    "shared_prefix_pages": dict(
+        kv_lens=[10, 11], spans=[(0, 9, 1), (1, 8, 3)],
+        shared=[(1, 0, 0), (1, 1, 0)]),
+    # over-decoded row: kv_lens past width * PAGE = 16 clamps to the table
+    "kv_lens_past_table_span": dict(
+        kv_lens=[21, 6], spans=[(0, 15, 1), (1, 5, 1)]),
+    "prefill_chunk_plus_decode_rows": dict(
+        kv_lens=[7, 13, 6, 2],
+        spans=[(0, 6, 1), (1, 12, 1), (2, 0, 6), (3, 0, 2)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RAGGED_CASES))
+def test_ragged_pallas_interpret_matches_array(case):
     """The Pallas ragged kernel (interpret mode on CPU) must match the
-    XLA gather/mask reference elementwise on a mixed batch, pad slots
-    included."""
-    q, kp, vp, bt, token_row, positions, kv_lens = _mixed_batch(seed=3)
-    ref = np.asarray(pa.ragged_paged_attention_array(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-        jnp.asarray(token_row), jnp.asarray(positions),
-        jnp.asarray(kv_lens)))
-    out = np.asarray(pa.ragged_paged_attention_pallas(
-        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
-        jnp.asarray(token_row), jnp.asarray(positions),
-        jnp.asarray(kv_lens), interpret=True))
+    XLA gather/mask reference elementwise, pad slots included, on the
+    mixes that shape its work list: a mixed batch, no work at all, gaps
+    between live rows, page boundaries, a full table row, shared physical
+    pages, a span past the table, prefill beside decode."""
+    spec = _RAGGED_CASES[case]
+    args = spec() if callable(spec) else _packed_case(**spec)
+    token_row = args[4]
+    jargs = [jnp.asarray(a) for a in args]
+    ref = np.asarray(pa.ragged_paged_attention_array(*jargs))
+    out = np.asarray(pa.ragged_paged_attention_pallas(*jargs,
+                                                      interpret=True))
     real = token_row >= 0
     np.testing.assert_allclose(out[real], ref[real], rtol=1e-5, atol=1e-6)
     # pad slots must come out finite (zeros): garbage there would be
     # scattered into the pool and could poison other rows' masked lanes
     assert np.all(np.isfinite(out))
     assert np.all(out[~real] == 0.0)
+
+
+_WORK_LIST_KV_LENS = [
+    [0, 0, 0], [0, 5, 0, 0, 9, 0], [8, 9], [16, 3], [21, 6], [1],
+    [16, 16, 16],
+]
+
+
+def test_ragged_work_list_matches_python_loop():
+    """The in-program work list is the row-major list of live (row,
+    page) pairs, each flagged on its row's last page."""
+    page, width = 4, 4
+    for kv_lens in _WORK_LIST_KV_LENS:
+        want = []
+        for r, n in enumerate(kv_lens):
+            pages = min(-(-n // page), width)
+            want += [(r, j, int(j == pages - 1)) for j in range(pages)]
+        items, n_live = pa._ragged_work_list(
+            jnp.asarray(kv_lens, jnp.int32), page, width)
+        assert items.shape == (len(kv_lens) * width,)
+        bits = pa._work_item_bits(width)
+        items = np.asarray(items)
+        got = [tuple(int(x) for x in pa._unpack_work_item(it, bits))
+               for it in items[:int(n_live)]]
+        assert got == want, kv_lens
+        # entries past the list still index inside the block table
+        rows, js, _ = pa._unpack_work_item(items, bits)
+        assert rows.min() >= 0 and rows.max() < len(kv_lens)
+        assert js.min() >= 0 and js.max() < width
+
+
+def test_ragged_live_pages_helper_matches_in_program_n_live():
+    """The engine's work record counts the kernel's steps with a numpy
+    helper on the host: it must equal the in-program ``n_live``, call by
+    call."""
+    page, width = 4, 4
+    for kv_lens in _WORK_LIST_KV_LENS:
+        _, n_live = pa._ragged_work_list(
+            jnp.asarray(kv_lens, jnp.int32), page, width)
+        assert pa.ragged_live_pages(kv_lens, page, width) == int(n_live), \
+            kv_lens
+    # a dispatch's (rounds, rows) plan: one count per micro-round
+    rounds = np.asarray([[0, 0, 0], [5, 0, 17], [16, 16, 16]], np.int32)
+    assert pa.ragged_live_pages(rounds, page, width).tolist() == [0, 6, 12]
 
 
 # ---------------------------------------------------------------------------
