@@ -64,6 +64,14 @@ class ReferenceWeights:
         return {ref: self._p[own][i] for ref, own in self._NAMES.items()}
 
 
+def fold_seed(seed: int) -> int:
+    """``--seed`` may be larger than int32 holds, which is what a Python int
+    becomes as the argument of a jitted call (no x64). A seed below 2**31 is
+    itself, so it draws the weights it always drew; a larger one is folded
+    into that range."""
+    return seed if seed < 2 ** 31 else seed % (2 ** 31 - 1)
+
+
 def enable_cache() -> str:
     from paddle_tpu.compile_cache import enable_compile_cache
     return enable_compile_cache()
@@ -97,13 +105,13 @@ class Server:
         cfg = self.cfg
         self.params = jax.jit(
             lambda s: L.init_stacked_params(cfg, seed=s),
-            out_shardings=shardings)(seed)
+            out_shardings=shardings)(fold_seed(seed))
         jax.block_until_ready(self.params)
         t1 = time.perf_counter()
         page = inspect.signature(
             ContinuousBatchingEngine.__init__).parameters["page_size"].default
         self.engine = ContinuousBatchingEngine(
-            self.cfg, GenerationConfig(seed=seed),
+            self.cfg, GenerationConfig(seed=fold_seed(seed)),
             num_slots=int(serving["num_slots"]),
             max_seq_len=int(serving["max_seq_len"]),
             num_pages=int(serving["kv_pool_tokens"]) // page + 1,

@@ -14,7 +14,8 @@ from typing import Dict
 
 import numpy as np
 
-from perfbench.adapters.serve_llama import ReferenceWeights, enable_cache, llama_config  # noqa: F401
+from perfbench.adapters.serve_llama import (  # noqa: F401
+    ReferenceWeights, enable_cache, fold_seed, llama_config)
 
 LOOPS = ("train",)
 
@@ -41,7 +42,7 @@ class Trainer:
             self.cfg, mesh, learning_rate=float(train["learning_rate"]))
         # one jitted call: weights and optimizer state made on the device;
         # the seed is an argument, so that all seeds share one program
-        self.params, self.opt_state = jax.jit(init_fn)(seed)
+        self.params, self.opt_state = jax.jit(init_fn)(fold_seed(seed))
         jax.block_until_ready(self.params)
         self.load_seconds = {"weights_and_state": time.perf_counter() - t0}
 

@@ -16,6 +16,44 @@ def _reference(config: Dict):
     return harness.load_module(config["reference"])
 
 
+def _sample(done: List[harness.RequestRecord], spec: Dict) -> List:
+    """The requests held against the reference: the first ``requests`` (by
+    index) that fit the reference's room of ``max_positions``; then, where
+    the configuration asks for ``longest``, that many more of the completed
+    ones that fit, longest first (a mechanism that acts on long contexts
+    only, as a sliding window does, is otherwise rarely in the sample)."""
+    limit = int(spec["max_positions"])
+    fit = sorted((r for r in done if r.n_prompt + r.n_out <= limit),
+                 key=lambda r: r.index)
+    sample = fit[:int(spec["requests"])]
+    rest = sorted(fit[len(sample):],
+                  key=lambda r: (-(r.n_prompt + r.n_out), r.index))
+    return sample + rest[:int(spec.get("longest", 0))]
+
+
+def _reference_logits(weights, sample, generated, config: Dict) -> List:
+    """For each sampled request the reference's float32 logits, one
+    ``(len(generated), vocab)`` array, at the positions that predict its
+    generated tokens (the logits at position p predict token p + 1). A
+    reference that offers ``logits_at`` is asked for those positions alone;
+    one that has only ``forward`` gives every position of a batch padded to
+    ``max_positions``, which fits while vocabulary x positions is small."""
+    reference = _reference(config)
+    rows = [np.concatenate([r.prompt, gen]).astype(np.int32)
+            for r, gen in zip(sample, generated)]
+    spans = [(r.n_prompt - 1, r.n_prompt - 1 + len(gen))
+             for r, gen in zip(sample, generated)]
+    if hasattr(reference, "logits_at"):
+        return [np.asarray(x) for x in
+                reference.logits_at(weights, rows, spans, config)]
+    ids = np.zeros((len(rows), int(config["correct"]["max_positions"])),
+                   np.int32)
+    for i, row in enumerate(rows):
+        ids[i, :len(row)] = row
+    logits = reference.forward(weights, ids, config)
+    return [np.asarray(logits[i, a:b]) for i, (a, b) in enumerate(spans)]
+
+
 def check_serving(server, records: List[harness.RequestRecord], config: Dict,
                   on_chip: bool) -> Tuple[bool, Dict]:
     """Health of the server, every completed request's token count and range,
@@ -38,30 +76,18 @@ def check_serving(server, records: List[harness.RequestRecord], config: Dict,
         if missing:
             problems.append(f"kernels {missing} are not in the lowered step "
                             f"(found {kernels})")
-    # the sample: the first requests (by index) that fit the reference's room
-    limit = int(spec["max_positions"])
-    sample = sorted((r for r in done if r.n_prompt + r.n_out <= limit),
-                    key=lambda r: r.index)[:int(spec["requests"])]
+    sample = _sample(done, spec)
     if not sample:
-        problems.append(f"no completed request of <= {limit} positions to "
-                        "hold against the reference")
+        problems.append(f"no completed request of <= {spec['max_positions']} "
+                        "positions to hold against the reference")
     else:
-        ids = np.zeros((len(sample), limit), np.int32)
-        spans = []
-        for row, r in enumerate(sample):
-            prompt = r.prompt
-            gen = np.asarray(server.tokens(r.handle), np.int32)
-            ids[row, :len(prompt)] = prompt
-            ids[row, len(prompt):len(prompt) + len(gen)] = gen
-            spans.append((len(prompt), gen))
+        generated = [np.asarray(server.tokens(r.handle), np.int32)
+                     for r in sample]
         weights = server.reference_weights()
         server.release_engine()
-        logits = _reference(config).forward(weights, ids, config)
         deficits = []
-        for row, (n_prompt, gen) in enumerate(spans):
-            # logits at position p predict token p+1
-            rows = np.asarray(
-                logits[row, n_prompt - 1:n_prompt - 1 + len(gen)])
+        for rows, gen in zip(_reference_logits(
+                weights, sample, generated, config), generated):
             deficits.extend(rows.max(axis=-1)
                             - rows[np.arange(len(gen)), gen])
         deficits = np.asarray(deficits, np.float64)
@@ -71,6 +97,9 @@ def check_serving(server, records: List[harness.RequestRecord], config: Dict,
             "max_deficit": float(deficits.max()),
             "mean_deficit": float(deficits.mean()),
             "argmax_agree": float((deficits == 0).mean())}
+        facts["compared"] = {
+            "max_deficit": [float(deficits.max()), spec["max_deficit"]],
+            "mean_deficit": [float(deficits.mean()), spec["mean_deficit"]]}
         if deficits.max() > spec["max_deficit"]:
             problems.append(
                 f"a generated token lies {deficits.max():.3f} below the "
@@ -121,6 +150,9 @@ def check_training(trainer, steps: List[harness.StepRecord], config: Dict,
                                   ids[0, :1], labels[0, :1], config)
     got = float(jax.block_until_ready(trainer.step(ids, labels)))
     facts["loss"] = {"program": got, "reference": ref}
+    facts["compared"] = {
+        "loss_gap": [abs(got - ref) / abs(ref), spec["loss_tolerance"]],
+        "loss_fall_min": [memo[0] / memo[-1], spec["loss_fall"]]}
     if not abs(got - ref) <= spec["loss_tolerance"] * abs(ref):
         problems.append(f"program loss {got:.5f} vs reference {ref:.5f}: "
                         f"apart by more than {spec['loss_tolerance']:.0%}")

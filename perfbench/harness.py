@@ -130,36 +130,49 @@ class Spans:
 class Tracer:
     """Traces ``seconds`` of the run from ``start_at`` on (a short
     sub-window: traces are large and tracing slows the host). Armed by the
-    loop once it knows its clock."""
+    loop once it knows its clock: the open loop and training by the clock (the
+    window's last ``seconds``), a batch by its progress (``serve_batch``)."""
 
     def __init__(self, out_dir: Optional[str], seconds: float):
         self.dir = out_dir
         self.seconds = seconds
         self.start_at = math.inf
         self.state = "idle" if out_dir else "off"
+        #: the loop ended while the trace still ran (``finish`` stopped it)
+        self.overran = False
 
     def arm(self, start_at: float) -> None:
         self.start_at = start_at
 
+    @property
+    def armed(self) -> bool:
+        return self.start_at < math.inf
+
+    def _start(self) -> None:
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0     # our spans, not every frame
+        jax.profiler.start_trace(self.dir, profiler_options=options)
+
+    def _stop(self) -> None:
+        import jax
+        jax.profiler.stop_trace()
+
     def poll(self, now: float) -> None:
         """Called by the loops between two dispatches."""
-        import jax
         if self.state == "idle" and now >= self.start_at:
-            options = jax.profiler.ProfileOptions()
-            options.python_tracer_level = 0     # our spans, not every frame
-            jax.profiler.start_trace(self.dir, profiler_options=options)
+            self._start()
             self.state = "tracing"
         elif self.state == "tracing" \
                 and now >= self.start_at + self.seconds:
-            jax.profiler.stop_trace()
+            self._stop()
             self.state = "done"
 
     def finish(self) -> Optional[trace_reduce.TraceSummary]:
         """Stop if still tracing, and reduce what was written."""
         if self.state == "tracing":
-            import jax
-            jax.profiler.stop_trace()
-            self.state = "done"
+            self._stop()
+            self.state, self.overran = "done", True
         path = (trace_reduce.find_xplane(self.dir)
                 if self.state == "done" else None)
         if path is None:
@@ -247,19 +260,24 @@ class _Serving:
         self._live.append(rec)
 
     def drain(self, requests: List[loadgen.Request], clients: int,
-              tracer: Optional[Tracer] = None) -> None:
+              tracer: Optional[Tracer] = None, trace_after: int = 0) -> None:
         """``clients`` callers take the requests one after another, each
         sending its next when its previous one completed, until all are
-        done."""
+        done. The tracer is armed once ``trace_after`` of them have ended."""
         todo = list(requests)
         for _ in range(min(clients, len(todo))):
             self.submit(todo.pop(0), None)
+        done = 0
         while self.server.busy():
             if tracer is not None:
-                tracer.poll(self.clock())
+                now = self.clock()
+                if done >= trace_after and not tracer.armed:
+                    tracer.arm(now)
+                tracer.poll(now)
             with self.spans.span("bench.sched_step"):
                 self.server.step()
             ended = self.sweep()
+            done += ended
             if ended and todo:
                 with self.spans.span("bench.submit"):
                     for _ in range(min(ended, len(todo))):
@@ -323,14 +341,20 @@ def serve_batch(server, traffic: loadgen.Traffic, spans: Spans,
     previous one completed; the window runs from the first submit to the last
     completion, on a server that is empty at both ends — so every token of
     the batch was worked inside the window, and none of another request's.
-    A few warm-up requests, drained, come first. Returns (records,
+    A few warm-up requests, drained, come first. A traced run is traced from
+    the moment a quarter of the batch's requests have completed, whatever the
+    clock says: every caller is busy then (they run dry once all but
+    ``clients`` requests have completed), and a batch that a faster program
+    finishes sooner is still traced while it is loaded (by the clock, PR 25
+    left the four-chip cell's trace 1.5 s of drain tail). Returns (records,
     window)."""
     s = _Serving(server, spans, clock)
     s.drain(traffic.warmup(), traffic.clients)
     server.begin_window()
     t0 = clock()
-    tracer.arm(t0 + traffic.seconds / 3)
-    s.drain(traffic.batch(), traffic.clients, tracer)
+    batch = traffic.batch()
+    s.drain(batch, traffic.clients, tracer,
+            trace_after=math.ceil(len(batch) / 4))
     return s.records, (t0, clock())
 
 

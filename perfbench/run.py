@@ -7,7 +7,8 @@ One process: loads, warms up every shape the cell's traffic uses (set-up),
 measures for ``--seconds``, checks the outputs against the plain reference,
 prints. The LAST line of stdout is one JSON object with the keys ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` with
-``--trace 1``); everything else goes on earlier lines or into
+``--trace 1``) and, last, ``compared``: each number that ``correct`` held
+against a limit, beside that limit; everything else goes on earlier lines or into
 ``perfbench_out/<cell>/`` inside the checkout. ``--trace 0`` reports the
 cell's end-to-end metrics, ``--trace 1`` its per-layer metrics.
 
@@ -114,6 +115,10 @@ def main(argv=None) -> int:
         requests, window = serve(server, traffic, spans, tracer, clock)
         counters = server.counters()
     trace = tracer.finish()
+    if loop == "batch" and tracer.overran:
+        # the trace was still running at the batch's last completion: what
+        # it reads includes the drain, or nothing but the drain
+        say(trace_overran=True)
     device["memory_peak_bytes"] = max(
         int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
         for d in devices[:cell.chips])       # before the reference's room
@@ -134,6 +139,7 @@ def main(argv=None) -> int:
         failed = sum(1 for r in requests
                      if r.outcome not in (None, "ok"))
         correct = correct and failed == 0
+    compared = facts.pop("compared", {})
     say(phase="checked", correct=correct, s=clock() - T_START, **facts)
     say(counters=counters, spans={
         k: {"n": len(v), "total_s": sum(b - a for a, b in v)}
@@ -170,6 +176,13 @@ def main(argv=None) -> int:
         print("run.py: the traced window holds no device operation",
               file=sys.stderr)
         return 1
+    # each number the comparison held against a limit, beside that limit:
+    # last in the result line, and the last lines of standard error
+    result["compared"] = {k: {"value": v, "limit": limit}
+                          for k, (v, limit) in compared.items()}
+    for k, (v, limit) in compared.items():
+        print(f"run.py: compared {k} = {v!r} (limit {limit!r})",
+              file=sys.stderr, flush=True)
     print(json.dumps(result), flush=True)
     return 0
 

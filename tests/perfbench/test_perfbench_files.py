@@ -1,6 +1,7 @@
 """BENCHMARK.json against the benchmark's contract, and every name in it
 resolved to the data file or reader it stands for."""
 
+import copy
 import json
 import os
 import re
@@ -14,6 +15,10 @@ NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 PLAIN_PATH = re.compile(r"^[A-Za-z0-9_./-]+$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: a key that matches is a width: never under ``reduced``, and stated a second
+#: time in the configuration's ``testdata/published`` file
+WIDTH = re.compile(r"(hidden_size|intermediate|_dim$|_rank$|head_dim|per_tok|"
+                   r"window|state)")
 #: what only the program may decide: a configuration that pins one of these
 #: hides a changed default from the benchmark
 ENGINE_DEFAULTS = {"chunk", "step_tokens", "page_size", "num_pages",
@@ -22,10 +27,13 @@ ENGINE_DEFAULTS = {"chunk", "step_tokens", "page_size", "num_pages",
                    "zero_gather", "pipeline_schedule", "virtual_pp"}
 
 
+with open(os.path.join(ROOT, "BENCHMARK.json")) as _f:
+    BENCH = json.load(_f)       # read at collection: it names the cases
+
+
 @pytest.fixture(scope="module")
 def bench():
-    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        return json.load(f)
+    return copy.deepcopy(BENCH)
 
 
 def cells_of(metric, bench):
@@ -76,32 +84,75 @@ def test_files_under_paths_have_plain_names(bench):
                 assert PLAIN_PATH.match(rel), rel
 
 
-def test_configs_hold_what_is_run_and_pin_no_engine_default(bench):
+def check_configuration(c, config, published, bench):
+    """One entry ``c`` of ``configs``, the file it names (``config``) and the
+    second statement of its published widths (``published``:
+    ``perfbench/testdata/published/<name>.json``)."""
+    assert c["name"] in {w["config"] for w in bench["workloads"]}
+    assert c["source"].startswith("https://")
+    assert any(c["file"].startswith(p + "/") for p in bench["paths"])
+    assert config["name"] == c["name"] and config["source"] == c["source"]
+    assert sorted(config["reduced"]) == sorted(c["reduced"])
+    for key in c["reduced"]:
+        assert not WIDTH.search(key), f"{key} is a width: never reduced"
+        assert config["reduced"][key]["to"] == config[key]
+    pinned = ENGINE_DEFAULTS & (set(config) | set(config.get("serving", {}))
+                                | set(config.get("train", {})))
+    assert not pinned, f"{c['name']} pins {pinned}"
+    assert os.path.exists(os.path.join(ROOT, config["reference"]))
+    assert os.path.exists(os.path.join(
+        ROOT, "perfbench", "adapters", config["adapter"] + ".py"))
+    # the widths are the published ones, stated a second time beside the
+    # tests: a width edited in the configuration's file alone shows here
+    assert published["source"] == c["source"]
+    widths = published["widths"]
+    must = {"hidden_size", "num_attention_heads", "num_key_value_heads"} \
+        | {k for k in config if WIDTH.search(k)}
+    assert must <= set(widths), f"no published {sorted(must - set(widths))}"
+    for key, value in widths.items():
+        assert key in config, f"{key} is not at the file's top level"
+        assert config[key] == value, \
+            f"{key}: the file runs {config[key]!r}, published {value!r}"
+        assert key not in c["reduced"], f"{key} is published AND reduced"
+
+
+def configuration(name, bench):
+    c = {c["name"]: c for c in bench["configs"]}[name]
+    return c, harness.read_json(c["file"]), harness.read_json(
+        "perfbench", "testdata", "published", name + ".json")
+
+
+def test_each_configuration_has_a_file_of_its_own(bench):
     files = [c["file"] for c in bench["configs"]]
     assert len(files) == len(set(files))
-    used = {w["config"] for w in bench["workloads"]}
-    for c in bench["configs"]:
-        assert c["name"] in used
-        assert c["source"].startswith("https://")
-        assert any(c["file"].startswith(p + "/") for p in bench["paths"])
-        config = harness.read_json(c["file"])
-        assert config["name"] == c["name"] and config["source"] == c["source"]
-        assert sorted(config["reduced"]) == sorted(c["reduced"])
-        for key in c["reduced"]:
-            assert not re.search(r"(hidden_size|intermediate|_dim$|_rank$|"
-                                 r"head_dim|per_tok)", key), key
-            assert config["reduced"][key]["to"] == config[key]
-        pinned = ENGINE_DEFAULTS & (set(config) | set(config.get("serving", {}))
-                                    | set(config.get("train", {})))
-        assert not pinned, f"{c['name']} pins {pinned}"
-        assert os.path.exists(os.path.join(ROOT, config["reference"]))
-        assert os.path.exists(os.path.join(
-            ROOT, "perfbench", "adapters", config["adapter"] + ".py"))
-        # the widths are the published ones (Mistral-7B-v0.3 config.json)
-        assert (config["hidden_size"], config["intermediate_size"],
-                config["num_attention_heads"], config["num_key_value_heads"],
-                config["rope_theta"], config["rms_norm_eps"]) == \
-            (4096, 14336, 32, 8, 1e6, 1e-5)
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in BENCH["configs"]])
+def test_configs_hold_what_is_run_and_pin_no_engine_default(bench, name):
+    check_configuration(*configuration(name, bench), bench)
+
+
+def test_a_window_or_state_size_under_reduced_is_refused(bench):
+    c, config, published = configuration(bench["configs"][0]["name"], bench)
+    for key, to in (("sliding_window", 1024), ("ssm_state_size", 8)):
+        one, cfg = copy.deepcopy((c, config))
+        one["reduced"] = c["reduced"] + [key]
+        cfg[key] = to
+        cfg["reduced"][key] = {"from": 4096, "to": to, "why": "to fit"}
+        with pytest.raises(AssertionError, match=f"{key} is a width"):
+            check_configuration(one, cfg, published, bench)
+
+
+def test_a_width_that_differs_from_the_published_one_is_refused(bench):
+    c, config, published = configuration(bench["configs"][0]["name"], bench)
+    check_configuration(c, config, published, bench)
+    for key, value in (("hidden_size", 2048), ("num_key_value_heads", 4),
+                       ("rope_theta", 10000.0), ("sliding_window", 4096)):
+        with pytest.raises(AssertionError, match=f"{key}: the file runs"):
+            check_configuration(c, config | {key: value}, published, bench)
+    # a width the file has and the published statement leaves out
+    with pytest.raises(AssertionError, match="no published .*head_dim"):
+        check_configuration(c, config | {"head_dim": 64}, published, bench)
 
 
 def test_every_cell_resolves_and_reports_enough(bench):

@@ -12,7 +12,8 @@ import pytest
 from perfbench import harness
 
 ROOT = harness.ROOT
-RESULT_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+RESULT_KEYS = ["correct", "attempted", "failed", "metrics", "device",
+               "compared"]
 
 
 def run(args, cwd=ROOT, devices=1, **env):
@@ -27,24 +28,30 @@ def run(args, cwd=ROOT, devices=1, **env):
         env=full, capture_output=True, text=True, timeout=240)
 
 
-def cell_args(name, trace=0, seconds="3"):
-    return ["--workload", name, "--seed", "5", "--seconds", seconds,
+def cell_args(name, trace=0, seconds="3", seed=5):
+    return ["--workload", name, "--seed", str(seed), "--seconds", seconds,
             "--trace", str(trace)]
 
 
-@pytest.mark.parametrize("name,devices,trace", [
-    ("m7b-1chip.chat-poisson", 1, 0),
-    ("m7b-1chip.longprompt-batch", 1, 1),
-    ("m7b-tp4.chat-batch", 4, 0),
-    ("m7b-train.pretrain-4k", 1, 1),
-])
+#: every cell of BENCHMARK.json, on as many virtual devices as it asks chips
+#: for, every second one (in the file's order) traced: a cell a later PR adds
+#: is rehearsed without a test file of its own
+CELLS = [(w["name"], w["chips"], i % 2) for i, w in
+         enumerate(harness.read_json("BENCHMARK.json")["workloads"])]
+
+
+@pytest.mark.parametrize("name,devices,trace", CELLS)
 def test_rehearsal_runs_the_control_flow_and_prints_no_metric(
         name, devices, trace):
     p = run(cell_args(name, trace) + ["--rehearse"], devices=devices)
     assert p.returncode == 0, p.stderr[-2000:]
     lines = [json.loads(x) for x in p.stdout.splitlines() if x.startswith("{")]
     last = lines[-1]
-    assert set(last) == RESULT_KEYS
+    assert list(last) == RESULT_KEYS             # ``compared`` comes last
+    assert last["compared"] and all(
+        set(x) == {"value", "limit"} for x in last["compared"].values())
+    assert [x.split()[2] for x in p.stderr.splitlines()[-len(last["compared"]):]
+            ] == list(last["compared"])
     assert last["correct"] is True and last["failed"] == 0
     assert last["attempted"] > 0
     assert last["metrics"] == {}                 # never a CPU number
@@ -63,6 +70,17 @@ def test_rehearsal_runs_the_control_flow_and_prints_no_metric(
     out = os.path.join(ROOT, "perfbench_out", name, f"seed5-trace{trace}",
                        "records.json")
     assert os.path.exists(out)
+
+
+@pytest.mark.parametrize("name", ["m7b-1chip.longprompt-batch",
+                                  "m7b-train.pretrain-4k"])
+def test_a_seed_beyond_int32_draws_weights_and_runs(name):
+    """The driver's seeds are large; as the argument of the jitted weight
+    initialisation 2**31 and above used to overflow."""
+    p = run(cell_args(name, seed=2 ** 31 + 5) + ["--rehearse"])
+    assert p.returncode == 0, p.stderr[-2000:]
+    last = json.loads(p.stdout.splitlines()[-1])
+    assert last["correct"] is True and last["attempted"] > 0
 
 
 def test_without_a_tpu_it_refuses_and_prints_no_result():
