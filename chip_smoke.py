@@ -316,6 +316,9 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
     # decode row, a prefill span starting mid-page, idle rows between, one
     # pad slot. sparse: two decode rows far apart, every other row starved.
     # empty: a round of pad slots only (no page to read; zeros out).
+    # windowed: the dense mix again under a sliding window of three pages
+    # less one key (a second program: the mask's lower bound and each row's
+    # first listed page; the long row and the prefill span lose pages).
     t, rows, width = sz.slots, sz.slots, sz.max_seq // sz.page
     n_pages = rows * width + 1
     kq, kk, kv = jax.random.split(key, 3)
@@ -335,7 +338,15 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
         *a, scale=scale, interpret=interpret))
     ragged_ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
         *a, scale=scale))
-    for name, spans in mixes.items():
+    window = jnp.int32(3 * sz.page - 1)
+    windowed = jax.jit(lambda *a: pa.ragged_paged_attention_pallas(
+        *a, scale=scale, interpret=interpret, window=window))
+    windowed_ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
+        *a, scale=scale, window=window))
+    cases = [(name, spans, ragged, ragged_ref)
+             for name, spans in mixes.items()]
+    cases.append(("windowed", mixes["dense"], windowed, windowed_ref))
+    for name, spans, ragged, ragged_ref in cases:
         token_row = np.full((t,), -1, np.int32)
         positions = np.zeros((t,), np.int32)
         kv_lens = np.zeros((rows,), np.int32)

@@ -13,7 +13,9 @@ replaces symbolic shapes).
 
 from __future__ import annotations
 
+import collections
 import functools
+import importlib
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, Optional, Tuple
@@ -184,10 +186,28 @@ class GenerationEngine:
         return np.asarray(out)
 
 
+_LLAMA = "paddle_tpu.models.llama"
+
+
+def _serving_module(model_config) -> str:
+    """The module that holds the model's serving step: named by the
+    config's class (``serving_module``), Llama's where it names none."""
+    return getattr(model_config, "serving_module", _LLAMA)
+
+
+def _llama_only(model_config, who: str) -> None:
+    if _serving_module(model_config) != _LLAMA:
+        raise ValueError(
+            f"{who} runs the Llama family's programs only; serve a "
+            f"{type(model_config).__name__} through "
+            "ContinuousBatchingEngine (the unified ragged step)")
+
+
 def llama_engine(config, generation_config: Optional[GenerationConfig] = None
                  ) -> GenerationEngine:
     """GenerationEngine wired to the stacked-param Llama family."""
     from ..models import llama as L
+    _llama_only(config, "llama_engine")
 
     return GenerationEngine(
         prefill=functools.partial(_llama_prefill, config=config),
@@ -222,6 +242,7 @@ class PagedGenerationEngine:
     def __init__(self, model_config, generation_config: Optional[GenerationConfig] = None,
                  page_size: int = 16, num_pages: Optional[int] = None):
         from ..models import llama as L
+        _llama_only(model_config, "PagedGenerationEngine")
         self._L = L
         self.model_config = model_config
         self.config = generation_config or GenerationConfig()
@@ -379,9 +400,10 @@ class ContinuousBatchingEngine:
                  drafter=None, fused_tail: bool = False,
                  mesh=None, mp_axis: str = "mp",
                  grammar_states: int = 0):
-        from ..models import llama as L
         from ..ops.paged_attention import PagedKVCacheManager
-        self._L = L
+        # the ONE place the engine learns which model it serves: the
+        # config's class names the module that holds its step
+        self._L = importlib.import_module(_serving_module(model_config))
         self.model_config = model_config
         self.config = generation_config or GenerationConfig()
         self.num_slots = num_slots
@@ -413,11 +435,23 @@ class ContinuousBatchingEngine:
         # chip must live on ITS surviving chip, not the process default
         # device another replica's mesh occupies
         self._mesh = mesh
+        if not unified and not hasattr(self._L, "prefill_paged"):
+            raise ValueError(
+                f"{self._L.__name__} serves through the unified ragged "
+                "step only (the legacy bucketed programs are Llama's); "
+                "construct with unified=True")
         if self._mesh is not None:
             if chips > 1 and not unified:
                 raise ValueError(
                     "multi-chip serving shards the unified ragged step; "
                     "construct with unified=True")
+            if chips > 1 and not any(
+                    mp_axis in jax.tree_util.tree_leaves(tuple(spec))
+                    for spec in self._L.serving_param_specs(mcfg).values()):
+                raise ValueError(
+                    f"{self._L.__name__} replicates every weight: it has "
+                    f"no tensor-parallel layout for a mesh of degree "
+                    f"{chips} over {mp_axis!r}; serve it on one chip")
             if (mcfg.num_key_value_heads % chips
                     or mcfg.num_attention_heads % chips):
                 raise ValueError(
@@ -567,6 +601,15 @@ class ContinuousBatchingEngine:
         #: unified dispatches since the engine was built: the ``n`` of the
         #: work record that rides on each ``cbe.dispatch`` span
         self._dispatches = 0
+        #: per layer the attention's sliding window, None where it is full
+        #: (what the work record counts the ragged kernel's pages by), and
+        #: how many layers have each
+        windows = getattr(self._L, "attention_windows", None)
+        self._layer_windows = (
+            tuple(windows(mcfg)) if windows is not None
+            else (None,) * mcfg.num_hidden_layers)
+        self._window_layers = tuple(
+            collections.Counter(self._layer_windows).items())
         # HBM memory ledger (observability/memory.py): when armed, every
         # step feeds the pool's byte split + per-request holdings and
         # runs the byte conservation audit alongside check_conservation.
@@ -1302,7 +1345,7 @@ class ContinuousBatchingEngine:
                 return L.ragged_step(
                     params, ids, token_row, positions, kv_lens, last_idx,
                     k_pages, v_pages, bt, mcfg, mesh=mesh,
-                    mp_axis=mp_axis, logits_epilogue=hook)
+                    mp_axis=mp_axis, logits_epilogue=hook)[:3]
 
             return _fusion.build_fused_unified_step(
                 model_step, tail, n_rows)
@@ -1325,7 +1368,11 @@ class ContinuousBatchingEngine:
                 hook = (lambda lg: _constrain.mask_logits(
                     lg.astype(jnp.float32), gst, gtable)) \
                     if epilogue else None
-                logits, kp, vp = L.ragged_step(
+                # a model with experts returns a fourth value, its small
+                # int32 routing record: stacked over the micro-rounds and
+                # handed out untouched (Llama returns three, and its
+                # program is what it was)
+                logits, kp, vp, *aux = L.ragged_step(
                     params, ids_eff, tr_k, pos_k, kvl_k, li_k, kp, vp,
                     bt, mcfg, mesh=mesh, mp_axis=mp_axis,
                     logits_epilogue=hook)
@@ -1342,13 +1389,14 @@ class ContinuousBatchingEngine:
                 emit = tok
                 tok = jnp.where(sm_k, nxt, tok)
                 gst = jnp.where(sm_k, ngst, gst)
-                return (tok, gst, kp, vp), emit
+                return (tok, gst, kp, vp), (emit, *aux)
 
-            (tok, gstate, k_pages, v_pages), toks = jax.lax.scan(
+            (tok, gstate, k_pages, v_pages), (toks, *aux) = jax.lax.scan(
                 micro, (tok, gstate, k_pages, v_pages),
                 (ids, use_carry, token_row, positions, kv_lens, last_idx,
                  sample_mask))
-            return toks, tok, gstate, k_pages, v_pages     # toks (K, R)
+            # toks (K, R); aux, if any, (K, ...)
+            return (toks, tok, gstate, k_pages, v_pages, *aux)
 
         return jax.jit(run, donate_argnums=(12, 13))
 
@@ -1511,33 +1559,70 @@ class ContinuousBatchingEngine:
 
     def _dispatch_record(self, token_row, positions, kv_lens, emit_counts,
                          fed) -> Dict[str, int]:
-        """What one unified dispatch asks of the device, as ten integers
-        from the plan arrays — they ride on the ``cbe.dispatch`` span into
-        any profiler trace, where ``perfbench/program_trace.py`` reads the
+        """What one unified dispatch asks of the device, as integers from
+        the plan arrays — they ride on the ``cbe.dispatch`` span into any
+        profiler trace, where ``perfbench/program_trace.py`` reads the
         ragged kernel's live grid share and required bytes/FLOPs off them.
-        Per layer: the kernel's grid walks each micro-round's live pages
-        (``ops.paged_attention.ragged_live_pages``; a starved row's
+        ``attended_pages``, ``grid_steps`` and ``causal_pairs`` are the
+        per-layer MEAN of what the dispatch's kernel calls walk (sum over
+        layers / layers, rounded), so ``layers x`` them is the dispatch's
+        total; where all layers are alike, as Llama's, it is one layer's
+        count. Per layer: the kernel's grid walks each micro-round's live
+        pages (``ops.paged_attention.ragged_live_pages``; a starved row's
         ``kv_lens`` is 0) and takes one step in a round that has none, so
-        ``attended_pages`` of its ``grid_steps`` steps have a page to
-        read; ``causal_pairs`` query-key pairs pass the mask.
+        ``attended_pages`` of its ``grid_steps`` steps have a page to read;
+        ``causal_pairs`` query-key pairs pass the mask. A model with
+        sliding-window layers (its serving module's ``attention_windows``)
+        adds ``window_skipped_pages``: live pages a full mask would have
+        listed and the window did not, the same mean.
         Computed on every dispatch (a few vectorised numpy lines)."""
-        from ..ops.paged_attention import ragged_live_pages
+        from ..ops.paged_attention import (ragged_first_pages,
+                                           ragged_live_pages)
         ps = self.page_size
-        live_pages = ragged_live_pages(kv_lens, ps, self._table_width)
+        full_pages = ragged_live_pages(kv_lens, ps, self._table_width)
+        seen = (positions + 1)[token_row >= 0]
+        # per kind of layer (a window, or None): live pages of each
+        # micro-round and the pairs the mask lets through, weighted by how
+        # many layers are of that kind
+        attended = steps = pairs = 0
+        for window, share in self._window_layers:
+            pages = full_pages if window is None else ragged_live_pages(
+                kv_lens, ps, self._table_width, ragged_first_pages(
+                    token_row, positions, self.num_slots, ps, window))
+            attended += share * int(pages.sum())
+            steps += share * int(np.maximum(pages, 1).sum())
+            pairs += share * int((seen if window is None
+                                  else np.minimum(seen, window)).sum())
+        layers = len(self._layer_windows)
         n = self._dispatches
         self._dispatches = n + 1
-        return {
+        record = {
             "n": n,
             "rounds": self.chunk,
             "token_slots": self.chunk * self._step_tokens,
             "prefill_tokens": sum(fed),
             "decode_tokens": sum(emit_counts),
             "live_rows": self.num_slots - self._slot_rid.count(None),
-            "attended_pages": int(live_pages.sum()),
-            "grid_steps": int(np.maximum(live_pages, 1).sum()),
-            "causal_pairs": int((positions + 1)[token_row >= 0].sum()),
+            "attended_pages": round(attended / layers),
+            "grid_steps": round(steps / layers),
+            "causal_pairs": round(pairs / layers),
             "page_size": ps,
         }
+        if len(self._window_layers) > 1 or self._layer_windows[0] is not None:
+            record["window_skipped_pages"] = round(
+                int(full_pages.sum()) - attended / layers)
+        return record
+
+    @staticmethod
+    def _expert_stats(aux) -> Dict[str, int]:
+        """The ``cbe.unpack`` span's integer stats from a dispatch's routing
+        record ``aux`` (rounds, expert layers, 3: experts hit, largest
+        expert load, assignments; ``ops.moe_ops.grouped_expert_ffn``): sums
+        over the dispatch's ``expert_calls`` = rounds x expert layers."""
+        return {"experts_hit": int(aux[..., 0].sum()),
+                "expert_calls": int(aux[..., 0].size),
+                "expert_assignments": int(aux[..., 2].sum()),
+                "max_expert_load": int(aux[..., 1].sum())}
 
     def _step_unified(self, params) -> int:
         """One ragged round: host-only admission, ONE dispatch serving
@@ -1614,7 +1699,7 @@ class ContinuousBatchingEngine:
             bt = jnp.asarray(self._bt)
         with phase("cbe.dispatch", **record):       # enqueue only
             (toks, self._tok_dev, self._gstate_dev, self.mgr.k_pages,
-             self.mgr.v_pages) = self._unified_step(
+             self.mgr.v_pages, *aux) = self._unified_step(
                 params, *plan_dev,
                 self._tok_dev, self._gstate_dev, self._samp_dev,
                 gtable, self.mgr.k_pages, self.mgr.v_pages, bt)
@@ -1624,6 +1709,9 @@ class ContinuousBatchingEngine:
                 recompiles.observe_compile("cbe.unified_step",
                                            time.perf_counter() - c0)
             toks = np.asarray(toks)                    # the one fence
+            # a model with experts: its routing record comes with the
+            # tokens, and its sums ride on the unpack span
+            routed = self._expert_stats(np.asarray(aux[0])) if aux else {}
         if armed_chain:
             tc1 = time.perf_counter_ns()
             if self._fused_tail:
@@ -1637,7 +1725,7 @@ class ContinuousBatchingEngine:
                 # pass's chain mining (REGIONS["sampling_epilogue"])
                 _note_chain(op_name="cbe.sample_epilogue", dur_ns=0)
             tc0 = tc1
-        with phase("cbe.unpack"):
+        with phase("cbe.unpack", **routed):
             if t0_ns:
                 # per-request phase bookkeeping over the dispatch window:
                 # the trace keeps its prefill/decode lanes even though
@@ -1730,7 +1818,8 @@ class ContinuousBatchingEngine:
                            cand_idx, k_pages, v_pages, bt):
                 return L.ragged_step(params, ids, token_row, positions,
                                      kv_lens, cand_idx, k_pages, v_pages,
-                                     bt, mcfg, mesh=mesh, mp_axis=mp_axis)
+                                     bt, mcfg, mesh=mesh,
+                                     mp_axis=mp_axis)[:3]
 
             return _fusion.build_fused_spec_step(
                 model_step, tail, self.spec_k, n_rows)
@@ -1740,7 +1829,7 @@ class ContinuousBatchingEngine:
                 k_pages, v_pages, bt):
             logits, kp, vp = L.ragged_step(
                 params, ids, token_row, positions, kv_lens, cand_idx,
-                k_pages, v_pages, bt, mcfg, mesh=mesh, mp_axis=mp_axis)
+                k_pages, v_pages, bt, mcfg, mesh=mesh, mp_axis=mp_axis)[:3]
             # the speculative sampling epilogue (spec_sample_rows):
             # greedy rows keep the per-candidate argmax + prefix-match
             # verify (byte-identical to the pre-sampling program),

@@ -12,11 +12,14 @@ alltoall: GSPMD lowers the (N,E,C)×(N,d) contraction to an ICI all_to_all.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 from ..core.compat import axis_size
 
 
@@ -386,3 +389,207 @@ def moe_combine_indices(expert_out, routes, gate_prob):
         term = vals * w.astype(vals.dtype)
         out = term if out is None else out + term
     return out
+
+
+# ---------------------------------------------------------------------------
+# Dropless expert layer (serving): assignments sorted by expert, grouped
+# matrix products over the rows each expert received — no capacity, no drop
+# ---------------------------------------------------------------------------
+
+#: rows of a grouped product's row tile. An expert's rows are contiguous
+#: after the sort, so a tile meets few experts; 32 keeps bf16's (16, 128)
+#: tiling whole and the masked-out share of a step's MXU work small
+_GMM_TILE_ROWS = 32
+#: bytes of one expert's weight block a grid step streams (double-buffered
+#: in VMEM): large enough that the DMA, not the step's fixed cost, sets the
+#: pace; small enough for the default scoped VMEM beside lhs and out
+_GMM_RHS_BLOCK_BYTES = 2 * 1024 * 1024
+
+
+def _gmm_row_tile(m: int) -> int:
+    """Rows of a row tile: ``_GMM_TILE_ROWS``, or all ``m`` where that does
+    not divide them (small test sizes)."""
+    return _GMM_TILE_ROWS if m % _GMM_TILE_ROWS == 0 else m
+
+
+def _gmm_col_tile(k: int, n: int, itemsize: int) -> int:
+    """Output columns a grid step computes: all ``n`` if one expert's (k, n)
+    block fits ``_GMM_RHS_BLOCK_BYTES``, else the largest multiple of 128
+    that divides ``n`` and fits."""
+    if k * n * itemsize <= _GMM_RHS_BLOCK_BYTES or n % 128:
+        return n
+    best = 128
+    for tn in range(128, n + 1, 128):
+        if n % tn == 0 and k * tn * itemsize <= _GMM_RHS_BLOCK_BYTES:
+            best = tn
+    return best
+
+
+def _gmm_work_list(group_sizes, m: int, tm: int):
+    """The grouped product's grid as data: the (row tile, expert) pairs in
+    which the expert owns a row of the tile, tile-major, one packed int32
+    each ``(tile << 17) | (expert << 1) | is the tile's first item``.
+    Returns (items (m // tm + E - 1,), n_items, starts (E,), ends (E,)).
+    An expert nobody chose is in no pair: it costs no step and no weight
+    read. A tile no expert reaches (rows past the last group: pad slots)
+    takes one step, which zeroes its rows; it names the last expert hit, so
+    that step re-reads no weights."""
+    n_exp = group_sizes.shape[0]
+    n_tiles = m // tm
+    sizes = group_sizes.astype(jnp.int32)
+    ends = jnp.cumsum(sizes)
+    starts = ends - sizes
+    lo = jnp.arange(n_tiles, dtype=jnp.int32)[:, None] * tm
+    hit = ((sizes > 0)[None, :] & (starts[None, :] < lo + tm)
+           & (ends[None, :] > lo))                          # (tiles, E)
+    experts = jnp.arange(n_exp, dtype=jnp.int32)
+    last_hit = jnp.max(jnp.where(sizes > 0, experts, 0))
+    empty = ~jnp.any(hit, axis=1, keepdims=True)
+    hit = hit | (empty & (experts == last_hit)[None, :])
+    flat = hit.reshape(-1)
+    # every tile's experts are contiguous and neighbouring tiles share at
+    # most one, so tiles + E - 1 items always suffice
+    idx = jnp.nonzero(flat, size=n_tiles + n_exp - 1, fill_value=0)[0]
+    idx = idx.astype(jnp.int32)
+    tile, expert = idx // n_exp, idx % n_exp
+    first = jnp.concatenate([jnp.ones((1,), bool), tile[1:] != tile[:-1]])
+    items = (tile << 17) | (expert << 1) | first.astype(jnp.int32)
+    return items, jnp.sum(flat, dtype=jnp.int32), starts, ends
+
+
+def _gmm_kernel(work_ref, starts_ref, ends_ref, base_ref, lhs_ref, rhs_ref,
+                out_ref, *, tm: int):
+    del base_ref                            # read by rhs's index map
+    item = work_ref[pl.program_id(1)]
+    tile, expert, first = item >> 17, (item >> 1) & 0xFFFF, (item & 1) == 1
+    rows = tile * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
+    mine = (rows >= starts_ref[expert]) & (rows < ends_ref[expert])
+    prod = jnp.dot(lhs_ref[...], rhs_ref[0],
+                   preferred_element_type=jnp.float32).astype(out_ref.dtype)
+
+    # a row belongs to one expert: "accumulating" is a select, exact in
+    # any dtype; the tile's first step also zeroes the rows of no expert
+    @pl.when(first)
+    def _start():
+        out_ref[...] = jnp.where(mine, prod, jnp.zeros_like(prod))
+
+    @pl.when(jnp.logical_not(first))
+    def _merge():
+        out_ref[...] = jnp.where(mine, prod, out_ref[...])
+
+
+def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, work=None,
+                              interpret: bool = False, layer=None):
+    """Pallas grouped matrix product, same contract as
+    :func:`moe_grouped_matmul_array`. The grid is (column tiles, work list
+    of (row tile, expert) pairs), the second extent a traced scalar: each
+    step streams one expert's (k, tn) weight block and multiplies it with
+    one tile of rows, keeping the rows that expert owns — so the call reads
+    the weights of the experts hit, once (twice for an expert whose rows
+    straddle two tiles), and nothing of the others. ``work``: the list from
+    :func:`_gmm_work_list`, for callers that make several products over one
+    grouping. ``layer`` (a traced index): ``rhs`` is (layers, E, k, n) and
+    the blocks are read IN PLACE from that layer — sliced out first, a
+    layer's experts would be copied whole on every call (XLA cannot fuse a
+    slice into a kernel's operand)."""
+    m, k = lhs.shape
+    n_exp, n = rhs.shape[-3], rhs.shape[-1]
+    tm = _gmm_row_tile(m)
+    tn = _gmm_col_tile(k, n, rhs.dtype.itemsize)
+    items, n_items, starts, ends = (
+        work if work is not None else _gmm_work_list(group_sizes, m, tm))
+    # the stack of layers as one run of experts (a free reshape); the
+    # layer's first expert rides in as a scalar
+    base = jnp.asarray(0 if layer is None else layer * n_exp,
+                       jnp.int32).reshape(1)
+    rhs = rhs.reshape((-1, k, n))
+
+    def tile_of(i, work):
+        return work[i] >> 17
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,      # work list, group starts, ends, base
+        grid=(n // tn, n_items),
+        in_specs=[
+            pl.BlockSpec((tm, k),
+                         lambda c, i, w, s, e, b: (tile_of(i, w), 0)),
+            pl.BlockSpec((1, k, tn), lambda c, i, w, s, e, b: (
+                b[0] + ((w[i] >> 1) & 0xFFFF), 0, c)),
+        ],
+        out_specs=pl.BlockSpec((tm, tn),
+                               lambda c, i, w, s, e, b: (tile_of(i, w), c)),
+    )
+    return pl.pallas_call(
+        functools.partial(_gmm_kernel, tm=tm),
+        name="moe_grouped_matmul",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        interpret=interpret,
+    )(items, starts, ends, base, lhs, rhs)
+
+
+def moe_grouped_matmul_array(lhs, rhs, group_sizes):
+    """Plain ``jax.numpy`` grouped product, the kernel's parity reference
+    and the path off the chip: row i of ``lhs`` (m, k), which lies in group
+    g (``group_sizes`` (E,), the rows sorted by group), times ``rhs[g]``
+    (E, k, n); rows past the last group give zeros. It gathers a weight
+    matrix a row, which only small sizes afford."""
+    ends = jnp.cumsum(group_sizes.astype(jnp.int32))
+    rows = jnp.arange(lhs.shape[0], dtype=jnp.int32)
+    group = jnp.sum(ends[None, :] <= rows[:, None], axis=1, dtype=jnp.int32)
+    owned = group < rhs.shape[0]
+    w = jnp.take(rhs, jnp.minimum(group, rhs.shape[0] - 1), axis=0)
+    out = jnp.einsum("mk,mkn->mn", lhs, w,
+                     preferred_element_type=jnp.float32).astype(lhs.dtype)
+    return jnp.where(owned[:, None], out, jnp.zeros_like(out))
+
+
+def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
+                       w_gate, w_up, w_down, first_expert: int = 0,
+                       layer=None):
+    """Dropless routed-expert SwiGLU: ``sum_j expert_weight[t, j] *
+    expert_{expert_idx[t, j]}(x[t])`` over the experts HELD here.
+
+    x: (T, h); expert_idx / expert_weight: (T, k), the router's choice over
+    ALL its experts and the weights it gave them; token_valid: (T,) bool, a
+    pad slot routes nowhere and counts nowhere. w_gate / w_up: (E, h, m),
+    w_down: (E, m, h): the weights of experts ``first_expert ..
+    first_expert + E - 1`` (a chip's share is a slice; an assignment to an
+    expert outside it adds nothing here). Static shapes, T * k rows: every
+    assignment is computed whatever the skew, none is dropped, and there is
+    no capacity. On the chip the three products are the Pallas kernel
+    ``moe_grouped_matmul``, whose cost follows the experts hit. ``layer``
+    (a traced index, inside a scan over layers): the weights are a whole
+    stack, (layers, E, ...), and that layer's are used where they lie.
+
+    Returns (out (T, h), stats int32 (3,): experts hit, the largest number
+    of assignments one expert received, assignments made)."""
+    from ._common import use_pallas
+    t, k = expert_idx.shape
+    held = w_gate.shape[-3]
+    local = expert_idx.astype(jnp.int32) - first_expert
+    routed = token_valid[:, None] & (local >= 0) & (local < held)
+    # ``held`` sorts after every expert: the rows of no expert come last
+    group = jnp.where(routed, local, held).reshape(-1)
+    order = jnp.argsort(group)                      # stable
+    token_of = order // k
+    sizes = jnp.sum(group[:, None] == jnp.arange(held, dtype=jnp.int32),
+                    axis=0, dtype=jnp.int32)
+    rows = jnp.take(x, token_of, axis=0)            # (T * k, h)
+    if use_pallas():
+        work = _gmm_work_list(sizes, t * k, _gmm_row_tile(t * k))
+        gmm = functools.partial(moe_grouped_matmul_pallas, work=work,
+                                layer=layer)
+    else:
+        def gmm(lhs, rhs, group_sizes):
+            return moe_grouped_matmul_array(
+                lhs, rhs if layer is None else rhs[layer], group_sizes)
+    act = (jax.nn.silu(gmm(rows, w_gate, sizes))
+           * gmm(rows, w_up, sizes))
+    y = gmm(act.astype(x.dtype), w_down, sizes)     # (T * k, h), sorted
+    y = jnp.take(y, jnp.argsort(order), axis=0).reshape(t, k, -1)
+    weight = jnp.where(routed, expert_weight, 0).astype(jnp.float32)
+    out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32), weight)
+    stats = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes),
+                       jnp.sum(sizes, dtype=jnp.int32)])
+    return out.astype(x.dtype), stats

@@ -154,7 +154,7 @@ def paged_prefill_attention_array(q, k_pages, v_pages, block_tables, q_start,
 
 def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
                                  positions, kv_lens=None,
-                                 scale: Optional[float] = None):
+                                 scale: Optional[float] = None, window=None):
     """XLA reference of the unified ragged kernel (gather/mask composition).
 
     The serving engine's single-dispatch step packs every live row's
@@ -177,6 +177,10 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
     positions:    (T,) int32 — absolute KV position per token
     kv_lens:      (R,) int32 — per-row attendable span (page-skip hint for
                   the Pallas kernel; unused by this reference)
+    window:       None, or an int32 scalar (may be traced: a sliding-window
+                  layer's span inside a layer scan). A token then also
+                  needs ``key_pos > positions[t] - window``: it sees the
+                  last ``window`` keys, itself included.
     Returns (T, nh, d).
     """
     t, nh, d = q.shape
@@ -195,6 +199,8 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
 
     key_pos = jnp.arange(max_pages * page)[None, :]     # (1, S)
     mask = (key_pos <= positions[:, None]) & (token_row >= 0)[:, None]
+    if window is not None:
+        mask = mask & (key_pos > positions[:, None] - window)
     if rep > 1:
         # grouped attention without materializing repeated KV (same
         # bandwidth argument as paged_attention_array)
@@ -212,15 +218,39 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
     return jnp.einsum("ths,tshd->thd", probs.astype(v.dtype), v)
 
 
-def ragged_live_pages(kv_lens, page: int, max_pages: int) -> np.ndarray:
+def ragged_first_pages(token_row, positions, n_rows: int, page: int,
+                       window: int) -> np.ndarray:
+    """First page each row's work list holds under a sliding ``window``, on
+    the host in numpy (the engine's work record): the page of the oldest
+    key the row's EARLIEST token of the call still sees, ``max(0, min
+    position - window + 1) // page``; 0 for a row with no token.
+    ``token_row`` / ``positions``: (..., T); returns (..., n_rows)."""
+    token_row = np.asarray(token_row)
+    lead = token_row.shape[:-1]
+    tr = token_row.reshape(-1, token_row.shape[-1])
+    pos = np.asarray(positions, np.int64).reshape(tr.shape)
+    big = np.iinfo(np.int64).max
+    first = np.full((tr.shape[0], n_rows), big, np.int64)
+    call, slot = np.nonzero(tr >= 0)
+    np.minimum.at(first, (call, tr[call, slot]), pos[call, slot])
+    first = np.where(first == big, 0, first)
+    return (np.maximum(first - window + 1, 0) // page).reshape(
+        lead + (n_rows,))
+
+
+def ragged_live_pages(kv_lens, page: int, max_pages: int,
+                      first_pages=None) -> np.ndarray:
     """Live (row, page) pairs of each ragged kernel call, on the host in
     numpy: the work list's ``n_live`` (:func:`_ragged_work_list`), for the
     engine's work record. ``kv_lens``: (..., R) attendable spans, one
-    call per row of the leading axes. A call's grid walks its live pairs,
-    and takes one step when it has none (the step that zeroes the
-    output)."""
-    return np.minimum(-(-np.asarray(kv_lens, np.int64) // page),
-                      max_pages).sum(axis=-1)
+    call per row of the leading axes; ``first_pages`` (same shape, from
+    :func:`ragged_first_pages`) where a sliding window lets each row's list
+    start past page 0. A call's grid walks its live pairs, and takes one
+    step when it has none (the step that zeroes the output)."""
+    pages = np.minimum(-(-np.asarray(kv_lens, np.int64) // page), max_pages)
+    if first_pages is not None:
+        pages = pages - np.minimum(first_pages, pages)
+    return pages.sum(axis=-1)
 
 
 def _work_item_bits(max_pages: int) -> int:
@@ -230,47 +260,75 @@ def _work_item_bits(max_pages: int) -> int:
 
 
 def _unpack_work_item(item, page_bits: int):
-    """(row, page index within the row, is the row's last page)."""
-    return (item >> (page_bits + 1), (item >> 1) & ((1 << page_bits) - 1),
-            (item & 1) == 1)
+    """(row, page index within the row's table, is the row's first listed
+    page, is the row's last page)."""
+    return (item >> (page_bits + 2), (item >> 2) & ((1 << page_bits) - 1),
+            (item & 2) == 2, (item & 1) == 1)
 
 
-def _ragged_work_list(kv_lens, page: int, max_pages: int):
+def _row_first_pages(token_row, positions, n_rows: int, page: int, window):
+    """In-program twin of :func:`ragged_first_pages` for one call:
+    (n_rows,) int32."""
+    big = jnp.iinfo(jnp.int32).max
+    mine = token_row[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
+    first = jnp.min(jnp.where(mine, positions[None, :].astype(jnp.int32),
+                              big), axis=1)
+    first = jnp.where(first == big, 0, first)
+    return jnp.maximum(first - window + 1, 0) // page
+
+
+def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
     """The kernel's grid as data: the live (row, page) pairs of one call,
     row-major, one packed int32 each (see :func:`_unpack_work_item`).
     Returns (items (n_rows * max_pages,), n_live); only the first
-    ``n_live`` items are work, the rest hold in-range indices. Depends on
-    ``kv_lens`` alone, so under a scan over layers it is loop-invariant."""
+    ``n_live`` items are work, the rest hold in-range indices. Without
+    ``first_pages`` it depends on ``kv_lens`` alone, so under a scan over
+    layers it is loop-invariant. ``first_pages`` (n_rows,): a row lists
+    only pages ``first_pages[r] .. ceil(kv_lens[r] / page) - 1``, what a
+    sliding window can reach (:func:`_row_first_pages`)."""
     n_rows = kv_lens.shape[0]
     page_bits = _work_item_bits(max_pages)
     # the min keeps over-decoded rows (kv_lens past the table span) inside
     # their table
     pages_r = jnp.minimum((kv_lens.astype(jnp.int32) + (page - 1)) // page,
                           max_pages)[:, None]               # (R, 1)
+    if first_pages is not None:
+        first_r = jnp.minimum(first_pages.astype(jnp.int32)[:, None], pages_r)
+        pages_r = pages_r - first_r                         # pages LISTED
     ends = jnp.cumsum(pages_r, axis=0)
     i = jnp.arange(n_rows * max_pages, dtype=jnp.int32)[None, :]
-    # done[r, i]: row r's pages all come before item i. Three reductions
-    # of it (no gather): the item's row, the row's first item, and
-    # whether the next item belongs to a later row
+    # done[r, i]: row r's pages all come before item i. Reductions of it
+    # (no gather): the item's row, the row's first item, and whether the
+    # next item belongs to a later row
     done = ends <= i
     row = jnp.sum(done, axis=0, dtype=jnp.int32)
     first = jnp.sum(jnp.where(done, pages_r, 0), axis=0, dtype=jnp.int32)
     last = jnp.sum(ends <= i + 1, axis=0, dtype=jnp.int32) > row
     # items past the list: keep their indices inside the block table
     row = jnp.minimum(row, n_rows - 1)
-    j = jnp.minimum(i[0] - first, max_pages - 1)
-    return ((row << (page_bits + 1)) | (j << 1) | last.astype(jnp.int32),
-            ends[-1, 0])
+    j = i[0] - first
+    is_first = (j == 0).astype(jnp.int32)
+    if first_pages is not None:
+        # the item's row's first page, again as a reduction of ``done``:
+        # first_r[0] plus the steps first_r takes over the rows done
+        step_r = jnp.concatenate([first_r[1:] - first_r[:-1],
+                                  jnp.zeros((1, 1), jnp.int32)], axis=0)
+        j = j + first_r[0, 0] + jnp.sum(jnp.where(done, step_r, 0), axis=0,
+                                        dtype=jnp.int32)
+    j = jnp.clip(j, 0, max_pages - 1)
+    return ((row << (page_bits + 2)) | (j << 2) | (is_first << 1)
+            | last.astype(jnp.int32), ends[-1, 0])
 
 
 def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
-                             token_row_ref, positions_ref, q_ref, k_ref,
-                             v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                             window_ref, token_row_ref, positions_ref, q_ref,
+                             k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
                              page: int, page_bits: int, scale: float,
-                             nh: int, nkv: int, d: int, t: int):
+                             nh: int, nkv: int, d: int, t: int,
+                             windowed: bool):
     del block_tables_ref                    # read by the K/V index maps
     i = pl.program_id(0)
-    r, j, last = _unpack_work_item(work_ref[i], page_bits)
+    r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
     # false only in the one step of a call that has no live page
     live = i < n_live_ref[0]
 
@@ -283,7 +341,7 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
         # lanes of OTHER rows via 0 * NaN)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    @pl.when(live & (j == 0))
+    @pl.when(live & first)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -300,6 +358,11 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
         key_pos = j * page + jax.lax.broadcasted_iota(
             jnp.int32, (t, page), 1)                # (T, page)
         mask = (tr == r) & (key_pos <= pos)         # (T, page)
+        if windowed:
+            # a page wholly below a token's window leaves that token's
+            # state at its init (m = _NEG_INF, p = 1): the first page with
+            # a key it sees rescales that by exp(_NEG_INF - m) = 0
+            mask = mask & (key_pos > pos - window_ref[0])
         # batched matmul wants the batch (kv-head) dim leading on both
         # operands (Mosaic "batch dims must be equal" — round-2 finding)
         qg = q.reshape(t, nkv, rep, d).swapaxes(0, 1).reshape(
@@ -346,7 +409,7 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
 def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                   token_row, positions, kv_lens,
                                   scale: Optional[float] = None,
-                                  interpret: bool = False):
+                                  interpret: bool = False, window=None):
     """Pallas ragged kernel: same contract as
     :func:`ragged_paged_attention_array`.
 
@@ -361,6 +424,12 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     tokens alike, so a mixed batch is one dispatch whose shape is
     invariant to the request mix (PAPERS.md ragged paged attention). A
     call with no live page takes one step, which zeroes the output.
+
+    ``window`` (None, or an int32 scalar that may be traced): the mask also
+    asks ``key_pos > position - window``, and each row's list starts at the
+    first page its earliest token of the call can still see, so a window
+    row costs the pages its window reaches. None traces neither: the
+    program and its outputs are what they were without the argument.
     """
     t, nh, d = q.shape
     page = k_pages.shape[1]
@@ -368,14 +437,19 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     n_rows, max_pages = block_tables.shape
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     page_bits = _work_item_bits(max_pages)
-    work, n_live = _ragged_work_list(kv_lens, page, max_pages)
+    windowed = window is not None
+    window = jnp.asarray(window if windowed else 0, jnp.int32).reshape(1)
+    work, n_live = _ragged_work_list(
+        kv_lens, page, max_pages,
+        _row_first_pages(token_row, positions, n_rows, page, window[0])
+        if windowed else None)
 
-    def kv_page(i, bt, work, n_live):
-        r, j, _ = _unpack_work_item(work[i], page_bits)
+    def kv_page(i, bt, work, n_live, window):
+        r, j, _, _ = _unpack_work_item(work[i], page_bits)
         return (bt[r, j], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,  # block_tables, work list, n_live
+        num_scalar_prefetch=4,  # block_tables, work list, n_live, window
         grid=(jnp.maximum(n_live, 1),),
         in_specs=[
             pl.BlockSpec((t, 1), lambda i, *_: (0, 0)),
@@ -393,14 +467,14 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     )
     kernel = functools.partial(
         _ragged_attention_kernel, page=page, page_bits=page_bits, scale=s,
-        nh=nh, nkv=nkv, d=d, t=t)
+        nh=nh, nkv=nkv, d=d, t=t, windowed=windowed)
     return pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nh, d), v_pages.dtype),
         interpret=interpret,
-    )(block_tables.astype(jnp.int32), work, n_live.reshape(1),
+    )(block_tables.astype(jnp.int32), work, n_live.reshape(1), window,
       token_row.astype(jnp.int32).reshape(t, 1),
       positions.astype(jnp.int32).reshape(t, 1),
       q, k_pages, v_pages)
@@ -408,7 +482,7 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
                            positions, kv_lens, scale: Optional[float] = None,
-                           mesh=None, mp_axis: str = "mp"):
+                           mesh=None, mp_axis: str = "mp", window=None):
     """Dispatcher: Pallas ragged kernel on TPU (FLAGS_use_pallas_kernels),
     XLA gather/mask fallback elsewhere — selected automatically, same
     contract either way (see ragged_paged_attention_array).
@@ -432,19 +506,20 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
                 and mesh.shape[mp_axis] > 1:
             return _ragged_paged_attention_shard_mapped(
                 q, k_pages, v_pages, block_tables, token_row, positions,
-                kv_lens, scale, mesh, mp_axis)
+                kv_lens, scale, mesh, mp_axis, window=window)
         return ragged_paged_attention_pallas(
             q, k_pages, v_pages, block_tables, token_row, positions,
-            kv_lens, scale)
+            kv_lens, scale, window=window)
     return ragged_paged_attention_array(
         q, k_pages, v_pages, block_tables, token_row, positions, kv_lens,
-        scale)
+        scale, window=window)
 
 
 def _ragged_paged_attention_shard_mapped(q, k_pages, v_pages, block_tables,
                                          token_row, positions, kv_lens,
                                          scale, mesh, mp_axis: str,
-                                         interpret: bool = False):
+                                         interpret: bool = False,
+                                         window=None):
     """The Pallas ragged kernel over a head-sharded pool: shard_map over
     ``mp_axis`` with whole GQA groups per chip. q: (T, nh, d) sharded on
     heads; pools: (LP, page, nkv, d) sharded on kv heads; metadata
@@ -454,19 +529,23 @@ def _ragged_paged_attention_shard_mapped(q, k_pages, v_pages, block_tables,
     from jax.sharding import PartitionSpec as P
     from ..core.compat import shard_map
 
-    def local(q_l, kp_l, vp_l, bt, tr, pos, kvl):
+    def local(q_l, kp_l, vp_l, bt, tr, pos, kvl, *win):
         return ragged_paged_attention_pallas(
-            q_l, kp_l, vp_l, bt, tr, pos, kvl, scale, interpret=interpret)
+            q_l, kp_l, vp_l, bt, tr, pos, kvl, scale, interpret=interpret,
+            window=win[0] if win else None)
 
+    # a window is one more replicated scalar operand
+    win = () if window is None else (jnp.asarray(window, jnp.int32),)
     return shard_map(
         local, mesh=mesh,
         in_specs=(P(None, mp_axis, None),
                   P(None, None, mp_axis, None),
                   P(None, None, mp_axis, None),
-                  P(None, None), P(None), P(None), P(None)),
+                  P(None, None), P(None), P(None), P(None))
+        + (P(),) * len(win),
         out_specs=P(None, mp_axis, None),
         check_vma=False,
-    )(q, k_pages, v_pages, block_tables, token_row, positions, kv_lens)
+    )(q, k_pages, v_pages, block_tables, token_row, positions, kv_lens, *win)
 
 
 # ---------------------------------------------------------------------------

@@ -12,8 +12,10 @@ meets GSPMD outside ``shard_map``, a program that does not fit HBM.
 
 Checked at Llama-2-7B width (h=4096, 32x128 heads, ff=11008, bf16) and
 2 layers: the engine's unified step on one chip and on
-``serving_mesh(4)``, and the one-chip hybrid train step — each must
-compile and carry its Pallas kernels, by name, in the lowering. What it
+``serving_mesh(4)``, the one-chip hybrid train step, and the unified step
+of an AFMoE engine at Trinity-Mini's widths (a window in the ragged kernel,
+the grouped expert product) — each must compile and carry its Pallas
+kernels, by name, in the lowering. What it
 cannot show is whether the programs RUN correctly; that is
 ``chip_smoke.py``'s job, on the chip.
 
@@ -87,13 +89,30 @@ def serving_engine():
     return eng
 
 
-def check_unified_step(eng, devices, chips):
+def check_unified_step(eng, devices, chips,
+                       expect=("ragged_paged_attention", "rms_norm_fwd")):
     """The engine's unified ragged step on ``chips`` described chips: the
-    ragged paged-attention kernel + rms_norm."""
+    ragged paged-attention kernel + rms_norm (+ what ``expect`` adds)."""
     from paddle_tpu.parallel.mesh import serving_mesh
     return _compile(f"unified_step/mp{chips}", eng.lower_unified_step(
-        mesh=serving_mesh(chips, devices)),
-        expect=("ragged_paged_attention", "rms_norm_fwd"))
+        mesh=serving_mesh(chips, devices)), expect=expect)
+
+
+def afmoe_engine():
+    """An engine at Trinity-Mini's widths (all 128 experts, one dense and
+    one expert layer, a sliding and a full one, a quarter of the vocabulary):
+    the unified step with the windowed ragged kernel and the grouped expert
+    product."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    from paddle_tpu.models import afmoe as A
+
+    cfg = A.AfmoeConfig(vocab_size=50048, num_hidden_layers=2,
+                        num_dense_layers=1,
+                        layer_types=(A.SLIDING, A.FULL), dtype=jnp.bfloat16)
+    return ContinuousBatchingEngine(cfg, num_slots=32, page_size=16,
+                                    max_seq_len=8192, num_pages=1025,
+                                    prefix_cache=True)
 
 
 def check_train_step(devices):
@@ -127,6 +146,10 @@ def run_checks():
             "device_kind": devices[0].device_kind,
             "unified_step_mp1": check_unified_step(eng, devices, 1),
             "unified_step_mp4": check_unified_step(eng, devices, 4),
+            "afmoe_unified_step_mp1": check_unified_step(
+                afmoe_engine(), devices, 1,
+                expect=("ragged_paged_attention", "rms_norm_fwd",
+                        "moe_grouped_matmul")),
             "train_step": check_train_step(devices),
         }
 

@@ -269,3 +269,102 @@ def test_phase_is_inert_outside_a_session():
         with light:
             assert light._start_ns is None      # no HostSpan off-capture
         assert light._jax_ann is None
+
+
+# ---------------------------------------------------------------------------
+# a model with window layers and experts (models.afmoe): what its records add
+# ---------------------------------------------------------------------------
+def _afmoe_engine(chunk=3):
+    from paddle_tpu.models import afmoe as A
+    cfg = A.afmoe_tiny()                # window 8: sliding, sliding, full
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
+        num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=chunk,
+        prefix_cache=True)
+    return A, cfg, eng
+
+
+def test_afmoe_record_counts_the_windows_pages_by_hand():
+    """Two of three layers slide over 8 positions: the record's page counts
+    are the per-layer MEAN, and ``window_skipped_pages`` the mean of what
+    the window left out, for a plan laid out by hand."""
+    _, _, eng = _afmoe_engine()
+    kv_lens = np.zeros((3, SLOTS), np.int32)
+    token_row = np.full((3, 8), -1, np.int32)   # a wider packed axis
+    positions = np.zeros_like(token_row)
+    # round 0: row 0 decodes at position 20 (6 pages; the window reaches
+    # positions 13..20 = pages 3..5), row 2 prefills positions 2..6 (2
+    # pages, all inside every token's window)
+    kv_lens[0] = [21, 0, 7]
+    token_row[0, :6] = [0, 2, 2, 2, 2, 2]
+    positions[0, :6] = [20, 2, 3, 4, 5, 6]
+    # round 1: nothing; round 2: row 1 prefills positions 9..13 (4 pages;
+    # its earliest token sees 2..9, so the list starts at page 0)
+    kv_lens[2] = [0, 14, 0]
+    token_row[2, :5] = 1
+    positions[2, :5] = [9, 10, 11, 12, 13]
+    rec = eng._dispatch_record(token_row, positions, kv_lens,
+                               [1, 0, 0], [0, 5, 5])
+    full, windowed = 6 + 2 + 4, 3 + 2 + 4
+    assert set(rec) == RECORD_KEYS | {"window_skipped_pages"}
+    assert rec["attended_pages"] == round((full + 2 * windowed) / 3) == 10
+    assert rec["window_skipped_pages"] == round(
+        full - (full + 2 * windowed) / 3) == 2
+    # one empty round costs each layer's kernel one step
+    assert rec["grid_steps"] == rec["attended_pages"] + 1
+    pairs_full = 21 + (3 + 4 + 5 + 6 + 7) + (10 + 11 + 12 + 13 + 14)
+    pairs_win = 8 + (3 + 4 + 5 + 6 + 7) + 5 * 8
+    assert rec["causal_pairs"] == round((pairs_full + 2 * pairs_win) / 3)
+    # a Llama engine's record of the same plan has no such key
+    _, _, llama_eng, _ = _build(3, False)
+    assert set(llama_eng._dispatch_record(
+        token_row, positions, kv_lens, [1, 0, 0], [0, 5, 5])) == RECORD_KEYS
+
+
+def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
+    """``cbe.unpack`` of a model with experts: four integer sums over the
+    dispatch's rounds x expert layers; Llama's span carries none."""
+    A, cfg, eng = _afmoe_engine()
+    # laid out by hand: 2 rounds x 2 expert layers of (hit, max, made)
+    aux = np.array([[[5, 3, 12], [4, 6, 12]], [[0, 0, 0], [1, 2, 2]]])
+    assert eng._expert_stats(aux) == {
+        "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
+        "max_expert_load": 11}
+    params = A.init_stacked_params(cfg, seed=3)
+    sched = ServingScheduler(eng, SchedulerConfig(max_queue_depth=64))
+    _warm(cfg, params, sched)
+    plans, plain = [], eng._plan_step
+
+    def spy():
+        out = plain()
+        plans.append(out[0][2].copy())
+        return out
+    eng._plan_step = spy
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for p in _prompts(cfg, 4, seed=1):
+            sched.submit(p, max_new_tokens=MAX_NEW)
+        _drain(sched, params)
+    finally:
+        jax.profiler.stop_trace()
+    events = _host_events(str(tmp_path))
+    unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
+    records = [e[3] for e in events if e[0] == "cbe.dispatch"]
+    assert len(unpacks) == len(plans) == len(records) > 0
+    for stats, token_row, rec in zip(unpacks, plans, records):
+        assert set(stats) == {"experts_hit", "expert_calls",
+                              "expert_assignments", "max_expert_load"}
+        assert stats["expert_calls"] == 3 * cfg.num_expert_layers
+        # dropless: every packed token's every choice, in every layer
+        assert stats["expert_assignments"] == int((token_row >= 0).sum()) \
+            * cfg.num_experts_per_tok * cfg.num_expert_layers
+        assert stats["max_expert_load"] <= stats["expert_assignments"]
+        assert 0 < stats["experts_hit"] <= \
+            stats["expert_calls"] * cfg.num_experts
+        assert "window_skipped_pages" in rec
+
+
+def test_llama_unpack_span_carries_no_stats(run):
+    assert all(e[3] == {} for e in run.events if e[0] == "cbe.unpack")

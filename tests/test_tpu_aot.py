@@ -25,4 +25,9 @@ def test_serving_and_train_steps_compile_for_v5e_with_their_kernels():
     for name in ("unified_step_mp1", "unified_step_mp4"):
         assert out[name]["kernels"]["ragged_paged_attention"] == 1
         assert out[name]["kernels"]["rms_norm_fwd"] == 3
+    # one call site of the ragged kernel a scanned stack (dense, expert);
+    # gate, up and down of the expert layer
+    afmoe = out["afmoe_unified_step_mp1"]["kernels"]
+    assert afmoe["ragged_paged_attention"] == 2
+    assert afmoe["moe_grouped_matmul"] == 3
     assert out["train_step"]["kernels"]["flash_attention_bwd_dkv"] >= 1

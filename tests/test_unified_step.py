@@ -152,13 +152,15 @@ _WORK_LIST_KV_LENS = [
 
 def test_ragged_work_list_matches_python_loop():
     """The in-program work list is the row-major list of live (row,
-    page) pairs, each flagged on its row's last page."""
+    page) pairs, each flagged on its row's first listed page (page 0 where
+    no window cuts the list) and on its last."""
     page, width = 4, 4
     for kv_lens in _WORK_LIST_KV_LENS:
         want = []
         for r, n in enumerate(kv_lens):
             pages = min(-(-n // page), width)
-            want += [(r, j, int(j == pages - 1)) for j in range(pages)]
+            want += [(r, j, int(j == 0), int(j == pages - 1))
+                     for j in range(pages)]
         items, n_live = pa._ragged_work_list(
             jnp.asarray(kv_lens, jnp.int32), page, width)
         assert items.shape == (len(kv_lens) * width,)
@@ -168,7 +170,7 @@ def test_ragged_work_list_matches_python_loop():
                for it in items[:int(n_live)]]
         assert got == want, kv_lens
         # entries past the list still index inside the block table
-        rows, js, _ = pa._unpack_work_item(items, bits)
+        rows, js, _, _ = pa._unpack_work_item(items, bits)
         assert rows.min() >= 0 and rows.max() < len(kv_lens)
         assert js.min() >= 0 and js.max() < width
 
