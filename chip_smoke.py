@@ -311,14 +311,18 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
     errs = {}
 
     # -- ragged paged attention at the unified step's shapes: T = slots
-    # packed tokens, one pool layer, three mixes through ONE compiled
+    # packed tokens, one pool layer, four mixes through ONE compiled
     # kernel (its grid's extent is data). dense: a long decode row, a short
     # decode row, a prefill span starting mid-page, idle rows between, one
     # pad slot. sparse: two decode rows far apart, every other row starved.
-    # empty: a round of pad slots only (no page to read; zeros out).
-    # windowed: the dense mix again under a sliding window of three pages
-    # less one key (a second program: the mask's lower bound and each row's
-    # first listed page; the long row and the prefill span lose pages).
+    # empty: a round of pad slots only (no block to read; zeros out).
+    # blocks: the kernel folds G pages of a row a step; decode rows of 1,
+    # exactly G and G + 5 pages and a prefill span that crosses a block
+    # boundary into page 2G + 1, so last blocks hold 1 to G of their slots.
+    # windowed / blocks_windowed: the dense and the blocks mix again under
+    # a sliding window (a second program: the mask's lower bound and each
+    # row's first listed page, from which its blocks count; the long rows
+    # and the prefill spans lose pages, and a list starts mid-block).
     t, rows, width = sz.slots, sz.slots, sz.max_seq // sz.page
     n_pages = rows * width + 1
     kq, kk, kv = jax.random.split(key, 3)
@@ -327,39 +331,48 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
     v_pages = jax.random.normal(kv, (n_pages, sz.page, nkv, d), jnp.bfloat16)
     tables = (1 + rng.permutation(n_pages - 1)).reshape(rows, width)
     span = t - 3                    # prefill tokens; one slot stays a pad
+    group = pa.ragged_block_pages(sz.page, width)
     mixes = {   # name -> [(row, first position, tokens)], packed in order
         "dense": [(0, sz.max_seq - 2, 1), (1, sz.page + 2, 1),
                   (rows - 1, 3 * sz.page + 1, span)],
         "sparse": [(1, 2 * sz.page - 1, 1), (rows - 2, sz.page, 1)],
         "empty": [],
+        "blocks": [(0, sz.page - 2, 1), (1, group * sz.page - 1, 1),
+                   (2, (group + 5) * sz.page - 3, 1),
+                   (rows - 1, 2 * group * sz.page - 5, span)],
     }
     scale = 1.0 / d ** 0.5
     ragged = jax.jit(lambda *a: pa.ragged_paged_attention_pallas(
         *a, scale=scale, interpret=interpret))
     ragged_ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
         *a, scale=scale))
-    window = jnp.int32(3 * sz.page - 1)
+    # the window is an operand: one windowed program for both spans
     windowed = jax.jit(lambda *a: pa.ragged_paged_attention_pallas(
-        *a, scale=scale, interpret=interpret, window=window))
+        *a[:-1], scale=scale, interpret=interpret, window=a[-1]))
     windowed_ref = jax.jit(lambda *a: pa.ragged_paged_attention_array(
-        *a, scale=scale, window=window))
-    cases = [(name, spans, ragged, ragged_ref)
+        *a[:-1], scale=scale, window=a[-1]))
+    cases = [(name, spans, ragged, ragged_ref, ())
              for name, spans in mixes.items()]
-    cases.append(("windowed", mixes["dense"], windowed, windowed_ref))
-    for name, spans, ragged, ragged_ref in cases:
+    cases += [("windowed", mixes["dense"], windowed, windowed_ref,
+               (jnp.int32(3 * sz.page - 1),)),
+              ("blocks_windowed", mixes["blocks"], windowed, windowed_ref,
+               (jnp.int32((group + 2) * sz.page + 5),))]
+    for name, spans, ragged, ragged_ref, window in cases:
         token_row = np.full((t,), -1, np.int32)
         positions = np.zeros((t,), np.int32)
         kv_lens = np.zeros((rows,), np.int32)
         at = 0
         for row, first, n in spans:
             token_row[at:at + n] = row
-            positions[at:at + n] = first + np.arange(n)
-            kv_lens[row] = first + n
+            # a toy table is narrower than the mix: stay inside it
+            positions[at:at + n] = np.minimum(first + np.arange(n),
+                                              sz.max_seq - 1)
+            kv_lens[row] = positions[at + n - 1] + 1
             at += n
         args = (q, k_pages, v_pages, jnp.asarray(tables, jnp.int32),
                 jnp.asarray(token_row), jnp.asarray(positions),
-                jnp.asarray(kv_lens))
-        if not interpret and name == "dense":   # one program for all three
+                jnp.asarray(kv_lens)) + window
+        if not interpret and name == "dense":   # one program for all four
             require_kernels(ragged.lower(*args),
                             ("ragged_paged_attention",), "ragged parity")
         got = ragged(*args)

@@ -1562,35 +1562,45 @@ class ContinuousBatchingEngine:
         """What one unified dispatch asks of the device, as integers from
         the plan arrays — they ride on the ``cbe.dispatch`` span into any
         profiler trace, where ``perfbench/program_trace.py`` reads the
-        ragged kernel's live grid share and required bytes/FLOPs off them.
+        ragged kernel's block fill share and required bytes/FLOPs off them.
         ``attended_pages``, ``grid_steps`` and ``causal_pairs`` are the
         per-layer MEAN of what the dispatch's kernel calls walk (sum over
         layers / layers, rounded), so ``layers x`` them is the dispatch's
         total; where all layers are alike, as Llama's, it is one layer's
         count. Per layer: the kernel's grid walks each micro-round's live
-        pages (``ops.paged_attention.ragged_live_pages``; a starved row's
-        ``kv_lens`` is 0) and takes one step in a round that has none, so
-        ``attended_pages`` of its ``grid_steps`` steps have a page to read;
+        blocks (``ops.paged_attention.ragged_live_blocks``: up to G =
+        ``ragged_block_pages`` consecutive pages of one row a step; a
+        starved row's ``kv_lens`` is 0) and takes one step in a round that
+        has none. ``grid_steps`` counts the PAGE SLOTS those steps hold, G a
+        block, and ``attended_pages`` of them have a live page
+        (``ragged_live_pages``): their ratio is how full the blocks run,
+        100% only where every row's page count is a multiple of G.
         ``causal_pairs`` query-key pairs pass the mask. A model with
         sliding-window layers (its serving module's ``attention_windows``)
         adds ``window_skipped_pages``: live pages a full mask would have
         listed and the window did not, the same mean.
         Computed on every dispatch (a few vectorised numpy lines)."""
-        from ..ops.paged_attention import (ragged_first_pages,
+        from ..ops.paged_attention import (ragged_block_pages,
+                                           ragged_first_pages,
+                                           ragged_live_blocks,
                                            ragged_live_pages)
         ps = self.page_size
-        full_pages = ragged_live_pages(kv_lens, ps, self._table_width)
+        width = self._table_width
+        group = ragged_block_pages(ps, width)
+        full_pages = ragged_live_pages(kv_lens, ps, width)
         seen = (positions + 1)[token_row >= 0]
-        # per kind of layer (a window, or None): live pages of each
-        # micro-round and the pairs the mask lets through, weighted by how
-        # many layers are of that kind
+        # per kind of layer (a window, or None): live pages and blocks of
+        # each micro-round and the pairs the mask lets through, weighted by
+        # how many layers are of that kind
         attended = steps = pairs = 0
         for window, share in self._window_layers:
+            first = None if window is None else ragged_first_pages(
+                token_row, positions, self.num_slots, ps, window)
             pages = full_pages if window is None else ragged_live_pages(
-                kv_lens, ps, self._table_width, ragged_first_pages(
-                    token_row, positions, self.num_slots, ps, window))
+                kv_lens, ps, width, first)
+            blocks = ragged_live_blocks(kv_lens, ps, width, first)
             attended += share * int(pages.sum())
-            steps += share * int(np.maximum(pages, 1).sum())
+            steps += share * group * int(np.maximum(blocks, 1).sum())
             pairs += share * int((seen if window is None
                                   else np.minimum(seen, window)).sum())
         layers = len(self._layer_windows)

@@ -16,7 +16,9 @@ Two compute paths behind one dispatcher (:func:`paged_attention`):
   scalar prefetch, each grid step streams exactly ONE physical page
   HBM→VMEM (Mosaic double-buffers consecutive steps), online-softmax
   accumulation in VMEM scratch. HBM traffic is precisely the pages each
-  sequence owns — the point of paging on a bandwidth-bound decode.
+  sequence owns — the point of paging on a bandwidth-bound decode. (The
+  serving engine's kernel, :func:`ragged_paged_attention_pallas`, folds a
+  block of a row's pages a step.)
 """
 
 from __future__ import annotations
@@ -238,19 +240,43 @@ def ragged_first_pages(token_row, positions, n_rows: int, page: int,
         lead + (n_rows,))
 
 
-def ragged_live_pages(kv_lens, page: int, max_pages: int,
-                      first_pages=None) -> np.ndarray:
-    """Live (row, page) pairs of each ragged kernel call, on the host in
-    numpy: the work list's ``n_live`` (:func:`_ragged_work_list`), for the
-    engine's work record. ``kv_lens``: (..., R) attendable spans, one
-    call per row of the leading axes; ``first_pages`` (same shape, from
-    :func:`ragged_first_pages`) where a sliding window lets each row's list
-    start past page 0. A call's grid walks its live pairs, and takes one
-    step when it has none (the step that zeroes the output)."""
+def ragged_block_pages(page: int, max_pages: int) -> int:
+    """G: the pages of one row that one step of the ragged kernel folds
+    together. Static, from the shapes a call sees: as many as fill the 128
+    lanes with keys, never more than the table is wide."""
+    return max(1, min(128 // page, max_pages))
+
+
+def _listed_pages(kv_lens, page: int, max_pages: int, first_pages):
+    """Pages each row's work list covers, on the host in numpy: (..., R)."""
     pages = np.minimum(-(-np.asarray(kv_lens, np.int64) // page), max_pages)
     if first_pages is not None:
         pages = pages - np.minimum(first_pages, pages)
-    return pages.sum(axis=-1)
+    return pages
+
+
+def ragged_live_pages(kv_lens, page: int, max_pages: int,
+                      first_pages=None) -> np.ndarray:
+    """Live (row, page) pairs of each ragged kernel call, on the host in
+    numpy, for the engine's work record: the pages the call's blocks hold
+    (:func:`ragged_live_blocks` counts the blocks). ``kv_lens``: (..., R)
+    attendable spans, one call per row of the leading axes; ``first_pages``
+    (same shape, from :func:`ragged_first_pages`) where a sliding window
+    lets each row's list start past page 0."""
+    return _listed_pages(kv_lens, page, max_pages, first_pages).sum(axis=-1)
+
+
+def ragged_live_blocks(kv_lens, page: int, max_pages: int,
+                       first_pages=None) -> np.ndarray:
+    """Blocks of each ragged kernel call, on the host in numpy: the work
+    list's ``n_live`` (:func:`_ragged_work_list`), every row's listed pages
+    in blocks of :func:`ragged_block_pages`, a row's last block as short as
+    its pages leave it. Arguments as :func:`ragged_live_pages`. A call's
+    grid walks its blocks, and takes one step when it has none (the step
+    that zeroes the output)."""
+    g = ragged_block_pages(page, max_pages)
+    pages = _listed_pages(kv_lens, page, max_pages, first_pages)
+    return (-(-pages // g)).sum(axis=-1)
 
 
 def _work_item_bits(max_pages: int) -> int:
@@ -260,8 +286,8 @@ def _work_item_bits(max_pages: int) -> int:
 
 
 def _unpack_work_item(item, page_bits: int):
-    """(row, page index within the row's table, is the row's first listed
-    page, is the row's last page)."""
+    """(row, index within the row's table of the block's first page, is the
+    row's first listed block, is the row's last block)."""
     return (item >> (page_bits + 2), (item >> 2) & ((1 << page_bits) - 1),
             (item & 2) == 2, (item & 1) == 1)
 
@@ -278,16 +304,20 @@ def _row_first_pages(token_row, positions, n_rows: int, page: int, window):
 
 
 def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
-    """The kernel's grid as data: the live (row, page) pairs of one call,
-    row-major, one packed int32 each (see :func:`_unpack_work_item`).
-    Returns (items (n_rows * max_pages,), n_live); only the first
-    ``n_live`` items are work, the rest hold in-range indices. Without
-    ``first_pages`` it depends on ``kv_lens`` alone, so under a scan over
-    layers it is loop-invariant. ``first_pages`` (n_rows,): a row lists
-    only pages ``first_pages[r] .. ceil(kv_lens[r] / page) - 1``, what a
-    sliding window can reach (:func:`_row_first_pages`)."""
+    """The kernel's grid as data: the live blocks of one call, row-major,
+    one packed int32 each (see :func:`_unpack_work_item`). A block is up to
+    G = :func:`ragged_block_pages` consecutive pages of one row, counted
+    from the row's first listed page; only a row's last block can hold
+    fewer. Returns (items (n_rows * ceil(max_pages / G),), n_live); only
+    the first ``n_live`` items are work, the rest hold in-range indices.
+    Without ``first_pages`` it depends on ``kv_lens`` alone, so under a
+    scan over layers it is loop-invariant. ``first_pages`` (n_rows,): a row
+    lists only pages ``first_pages[r] .. ceil(kv_lens[r] / page) - 1``,
+    what a sliding window can reach (:func:`_row_first_pages`), and its
+    blocks count from ``first_pages[r]``, not from a multiple of G."""
     n_rows = kv_lens.shape[0]
     page_bits = _work_item_bits(max_pages)
+    group = ragged_block_pages(page, max_pages)
     # the min keeps over-decoded rows (kv_lens past the table span) inside
     # their table
     pages_r = jnp.minimum((kv_lens.astype(jnp.int32) + (page - 1)) // page,
@@ -295,19 +325,21 @@ def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
     if first_pages is not None:
         first_r = jnp.minimum(first_pages.astype(jnp.int32)[:, None], pages_r)
         pages_r = pages_r - first_r                         # pages LISTED
-    ends = jnp.cumsum(pages_r, axis=0)
-    i = jnp.arange(n_rows * max_pages, dtype=jnp.int32)[None, :]
-    # done[r, i]: row r's pages all come before item i. Reductions of it
+    blocks_r = (pages_r + (group - 1)) // group
+    ends = jnp.cumsum(blocks_r, axis=0)
+    i = jnp.arange(n_rows * (-(-max_pages // group)), dtype=jnp.int32)[None, :]
+    # done[r, i]: row r's blocks all come before item i. Reductions of it
     # (no gather): the item's row, the row's first item, and whether the
     # next item belongs to a later row
     done = ends <= i
     row = jnp.sum(done, axis=0, dtype=jnp.int32)
-    first = jnp.sum(jnp.where(done, pages_r, 0), axis=0, dtype=jnp.int32)
+    first = jnp.sum(jnp.where(done, blocks_r, 0), axis=0, dtype=jnp.int32)
     last = jnp.sum(ends <= i + 1, axis=0, dtype=jnp.int32) > row
     # items past the list: keep their indices inside the block table
     row = jnp.minimum(row, n_rows - 1)
-    j = i[0] - first
-    is_first = (j == 0).astype(jnp.int32)
+    b = i[0] - first                        # the block's number in its row
+    is_first = (b == 0).astype(jnp.int32)
+    j = b * group
     if first_pages is not None:
         # the item's row's first page, again as a reduction of ``done``:
         # first_r[0] plus the steps first_r takes over the rows done
@@ -322,15 +354,43 @@ def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
 
 def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
                              window_ref, token_row_ref, positions_ref, q_ref,
-                             k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
-                             page: int, page_bits: int, scale: float,
-                             nh: int, nkv: int, d: int, t: int,
-                             windowed: bool):
-    del block_tables_ref                    # read by the K/V index maps
+                             k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref,
+                             k_buf, v_buf, sems, *, page: int, group: int,
+                             page_bits: int, scale: float, windowed: bool):
+    keys = group * page
+    max_pages = block_tables_ref.shape[1]
     i = pl.program_id(0)
     r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
-    # false only in the one step of a call that has no live page
+    # false only in the one step of a call that has no live block
     live = i < n_live_ref[0]
+    half = i % 2
+
+    def page_copies(p, side, slot):
+        """Physical page ``p`` of K and of V into slot ``slot`` of buffer
+        half ``side``; the G copies of a pool's block share one semaphore."""
+        return (pltpu.make_async_copy(k_hbm.at[p], k_buf.at[side, slot],
+                                      sems.at[0, side]),
+                pltpu.make_async_copy(v_hbm.at[p], v_buf.at[side, slot],
+                                      sems.at[1, side]))
+
+    def fetch(item, side):
+        # a slot past the row's last page reads a page clipped into the
+        # table, under key positions no token of the row has reached
+        row, first_page, _, _ = _unpack_work_item(item, page_bits)
+        for slot in range(group):
+            p = block_tables_ref[row, jnp.minimum(first_page + slot,
+                                                  max_pages - 1)]
+            for copy in page_copies(p, side, slot):
+                copy.start()
+
+    @pl.when(live & (i == 0))
+    def _fetch_first():
+        fetch(work_ref[0], 0)
+
+    @pl.when(i + 1 < n_live_ref[0])
+    def _fetch_next():
+        # the next block streams in while this one is folded
+        fetch(work_ref[i + 1], 1 - half)
 
     @pl.when(i == 0)
     def _zero_out():
@@ -349,61 +409,54 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
 
     @pl.when(live)
     def _compute():
-        rep = nh // nkv
-        q = q_ref[...].astype(jnp.float32)          # (T, nh, d)
-        k = k_ref[0].astype(jnp.float32)            # (page, nkv, d)
-        v = v_ref[0].astype(jnp.float32)
-        tr = token_row_ref[...]                     # (T, 1) int32
-        pos = positions_ref[...]                    # (T, 1) int32
+        q = q_ref[...].astype(jnp.float32)          # (nkv, T*rep, d)
+        for slot in range(group):
+            # a wait reads only the copy's size and semaphore, not its page
+            for copy in page_copies(0, half, slot):
+                copy.wait()
+
+        def block(buf):
+            # the block's pages as one (nkv, G*page, d) operand: batched
+            # matmul wants the batch (kv-head) dim leading on both
+            # operands (Mosaic "batch dims must be equal" — round-2
+            # finding)
+            pages = buf[half].reshape((keys,) + buf.shape[3:])
+            return pages.astype(jnp.float32).swapaxes(0, 1)
+
+        tr = token_row_ref[...]                     # (T*rep, 1) int32
+        pos = positions_ref[...]                    # (T*rep, 1) int32
         key_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (t, page), 1)                # (T, page)
-        mask = (tr == r) & (key_pos <= pos)         # (T, page)
+            jnp.int32, (tr.shape[0], keys), 1)      # (T*rep, G*page)
+        mask = (tr == r) & (key_pos <= pos)
         if windowed:
-            # a page wholly below a token's window leaves that token's
-            # state at its init (m = _NEG_INF, p = 1): the first page with
+            # a block wholly below a token's window leaves that token's
+            # state at its init (m = _NEG_INF, p = 1): the first block with
             # a key it sees rescales that by exp(_NEG_INF - m) = 0
             mask = mask & (key_pos > pos - window_ref[0])
-        # batched matmul wants the batch (kv-head) dim leading on both
-        # operands (Mosaic "batch dims must be equal" — round-2 finding)
-        qg = q.reshape(t, nkv, rep, d).swapaxes(0, 1).reshape(
-            nkv, t * rep, d)
-        kt = k.swapaxes(0, 1)                       # (nkv, page, d)
-        vt = v.swapaxes(0, 1)
         s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        mg = jnp.broadcast_to(mask[None, :, None, :], (nkv, t, rep, page)
-                              ).reshape(nkv, t * rep, page)
-        s = jnp.where(mg, s, _NEG_INF)
-        # flatten to (T*nh, page) rows for the online-softmax state
-        s2 = s.reshape(nkv, t, rep, page).swapaxes(0, 1).reshape(
-            t * nh, page)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s2, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
+            q, block(k_buf), (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * scale   # (nkv, T*rep, keys)
+        s = jnp.where(mask[None], s, _NEG_INF)
+        m_prev = m_ref[:, :, :1]
+        l_prev = l_ref[:, :, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s2 - m_new)                     # (T*nh, page)
+        p = jnp.exp(s - m_new)
         l_ref[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True),
-            l_ref.shape)
-        pg = p.reshape(t, nkv, rep, page).swapaxes(0, 1).reshape(
-            nkv, t * rep, page)
+            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
         pv = jax.lax.dot_general(
-            pg, vt, (((2,), (1,)), ((0,), (0,))),
+            p, block(v_buf), (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)     # (nkv, T*rep, d)
-        pv2 = pv.reshape(nkv, t, rep, d).swapaxes(0, 1).reshape(t * nh, d)
-        acc_ref[...] = acc_ref[...] * alpha + pv2
+        acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
     @pl.when(live & last)
     def _finalize():
-        l = l_ref[:, :1]
+        l = l_ref[:, :, :1]
         safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = (acc_ref[...] / safe_l).reshape(t, nh, d)
-        mine = (token_row_ref[...] == r)            # (T, 1)
-        o_ref[...] = jnp.where(mine[:, :, None], out.astype(o_ref.dtype),
-                               o_ref[...])
+        out = acc_ref[...] / safe_l
+        mine = (token_row_ref[...] == r)[None]      # (1, T*rep, 1)
+        o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
 
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
@@ -413,30 +466,39 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     """Pallas ragged kernel: same contract as
     :func:`ragged_paged_attention_array`.
 
-    The grid is a work list of the call's live (row, page) pairs
+    The grid is a work list of the call's live blocks
     (:func:`_ragged_work_list`, built here from ``kv_lens``), its extent
     their number — a traced scalar, so a call costs what its rows attend
-    to, not rows x table width. Each step streams exactly ONE physical
-    page of one row HBM→VMEM via the scalar-prefetched work list and
-    block table (consecutive steps walk consecutive pages of a row, so
-    Mosaic double-buffers them) and folds it into the online softmax of
-    every packed token that belongs to the row — decode and prefill
-    tokens alike, so a mixed batch is one dispatch whose shape is
-    invariant to the request mix (PAPERS.md ragged paged attention). A
-    call with no live page takes one step, which zeroes the output.
+    to, not rows x table width. A block is up to G consecutive pages of
+    one row, G = :func:`ragged_block_pages` (128 keys: one lane width, one
+    MXU tile). The pools stay in HBM: each step waits for its block's G
+    physical pages, which the step before started copying into the other
+    half of a VMEM double buffer through the scalar-prefetched work list
+    and block table (one copy a page slot: 2 DMAs a page cost a third of
+    what 2G auto-pipelined operands did; a slot past the row's last page is
+    clipped into the table and masked by position), and folds them in ONE
+    online-softmax merge into every packed token that belongs to the row
+    — decode and prefill tokens alike, so a mixed batch is one dispatch
+    whose shape is invariant to the request mix (PAPERS.md ragged paged
+    attention). Inside, heads lead: queries, softmax state and output are
+    (nkv, T x rep, ·), laid out here before and after the call, so a step
+    relays out nothing but the block's K and V. A call with no live block
+    takes one step, which copies nothing and zeroes the output.
 
     ``window`` (None, or an int32 scalar that may be traced): the mask also
     asks ``key_pos > position - window``, and each row's list starts at the
     first page its earliest token of the call can still see, so a window
-    row costs the pages its window reaches. None traces neither: the
+    row costs the blocks its window reaches. None traces neither: the
     program and its outputs are what they were without the argument.
     """
     t, nh, d = q.shape
     page = k_pages.shape[1]
     nkv = k_pages.shape[2]
     n_rows, max_pages = block_tables.shape
+    rep = nh // nkv
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     page_bits = _work_item_bits(max_pages)
+    group = ragged_block_pages(page, max_pages)
     windowed = window is not None
     window = jnp.asarray(window if windowed else 0, jnp.int32).reshape(1)
     work, n_live = _ragged_work_list(
@@ -444,40 +506,43 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
         _row_first_pages(token_row, positions, n_rows, page, window[0])
         if windowed else None)
 
-    def kv_page(i, bt, work, n_live, window):
-        r, j, _, _ = _unpack_work_item(work[i], page_bits)
-        return (bt[r, j], 0, 0, 0)
+    def per_head_row(x):                # (T,) -> (T*rep, 1), as q's rows
+        return jnp.repeat(x.astype(jnp.int32), rep).reshape(t * rep, 1)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # block_tables, work list, n_live, window
         grid=(jnp.maximum(n_live, 1),),
         in_specs=[
-            pl.BlockSpec((t, 1), lambda i, *_: (0, 0)),
-            pl.BlockSpec((t, 1), lambda i, *_: (0, 0)),
-            pl.BlockSpec((t, nh, d), lambda i, *_: (0, 0, 0)),
-            pl.BlockSpec((1, page, nkv, d), kv_page),
-            pl.BlockSpec((1, page, nkv, d), kv_page),
+            pl.BlockSpec((t * rep, 1), lambda i, *_: (0, 0)),
+            pl.BlockSpec((t * rep, 1), lambda i, *_: (0, 0)),
+            pl.BlockSpec((nkv, t * rep, d), lambda i, *_: (0, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+            pl.BlockSpec(memory_space=pl.ANY),
         ],
-        out_specs=pl.BlockSpec((t, nh, d), lambda i, *_: (0, 0, 0)),
+        out_specs=pl.BlockSpec((nkv, t * rep, d), lambda i, *_: (0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((t * nh, 128), jnp.float32),
-            pltpu.VMEM((t * nh, 128), jnp.float32),
-            pltpu.VMEM((t * nh, d), jnp.float32),
+            pltpu.VMEM((nkv, t * rep, 128), jnp.float32),
+            pltpu.VMEM((nkv, t * rep, 128), jnp.float32),
+            pltpu.VMEM((nkv, t * rep, d), jnp.float32),
+            pltpu.VMEM((2, group, page, nkv, d), k_pages.dtype),
+            pltpu.VMEM((2, group, page, nkv, d), v_pages.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),
         ],
     )
     kernel = functools.partial(
-        _ragged_attention_kernel, page=page, page_bits=page_bits, scale=s,
-        nh=nh, nkv=nkv, d=d, t=t, windowed=windowed)
-    return pl.pallas_call(
+        _ragged_attention_kernel, page=page, group=group,
+        page_bits=page_bits, scale=s, windowed=windowed)
+    out = pl.pallas_call(
         kernel,
         name="ragged_paged_attention",
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((t, nh, d), v_pages.dtype),
+        out_shape=jax.ShapeDtypeStruct((nkv, t * rep, d), v_pages.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), work, n_live.reshape(1), window,
-      token_row.astype(jnp.int32).reshape(t, 1),
-      positions.astype(jnp.int32).reshape(t, 1),
-      q, k_pages, v_pages)
+      per_head_row(token_row), per_head_row(positions),
+      q.reshape(t, nkv, rep, d).swapaxes(0, 1).reshape(nkv, t * rep, d),
+      k_pages, v_pages)
+    return out.reshape(nkv, t, rep, d).swapaxes(0, 1).reshape(t, nh, d)
 
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,

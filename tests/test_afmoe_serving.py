@@ -21,7 +21,7 @@ from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.serving import ServingScheduler
 from perfbench import harness
 
-from test_unified_step import _RAGGED_CASES, _packed_case
+from test_unified_step import _BLOCKS, _RAGGED_CASES, _packed_case
 
 adapter = harness.load_module("perfbench/adapters/serve_afmoe.py")
 reference = harness.load_module("perfbench/reference/afmoe.py")
@@ -30,7 +30,8 @@ reference = harness.load_module("perfbench/reference/afmoe.py")
 # ---------------------------------------------------------------------------
 # the window in the ragged kernel
 # ---------------------------------------------------------------------------
-# page 4, table width 4 (16 positions), window 6 unless a case says otherwise
+# page 4, table width 4 (16 positions: one block of G = 4 pages a row), window
+# 6 unless a case says otherwise; ``**_BLOCKS``: page 16, width 20, G = 8
 _WINDOW_CASES = {
     "row_shorter_than_window": dict(
         kv_lens=[4, 3], spans=[(0, 3, 1), (1, 2, 1)]),
@@ -47,6 +48,27 @@ _WINDOW_CASES = {
         kv_lens=[16, 9], spans=[(0, 15, 1), (1, 6, 3)], window=4),
     "window_of_one_key": dict(
         kv_lens=[11, 5], spans=[(0, 8, 3), (1, 4, 1)], window=1),
+    # row 0's earliest token (280) sees keys from 81: its list starts at page
+    # 5, in the middle of what would be block 0, and holds 14 pages = blocks
+    # at pages 5 and 13; every token's window begins after page 5's first key
+    "blocks_start_at_the_windows_first_page": dict(
+        kv_lens=[292, 60], spans=[(0, 280, 11), (1, 59, 1)], window=200,
+        **_BLOCKS),
+    # a window of exactly one block of keys, not aligned to one: 9 pages
+    "blocks_window_of_128_keys_over_9_pages": dict(
+        kv_lens=[300, 129], spans=[(0, 299, 1), (1, 128, 1)], window=128,
+        **_BLOCKS),
+    # a 140-token chunk under a window of 16: the list runs from page 5 (of
+    # token 100) in blocks at 5 and 13, and the chunk's last tokens see
+    # nothing of the first block: their state stays at its init through it
+    "blocks_a_token_whose_window_begins_in_a_later_block": dict(
+        kv_lens=[240, 33], spans=[(0, 100, 140), (1, 32, 1)], window=16,
+        t=144, **_BLOCKS),
+    # a decode row whose window reaches back over three blocks' worth of
+    # pages from a start that is no multiple of G, starved rows around it
+    "blocks_starved_rows_and_a_long_window": dict(
+        kv_lens=[0, 310, 0, 75], spans=[(1, 309, 1), (3, 70, 5)], window=290,
+        **_BLOCKS),
 }
 
 
@@ -95,7 +117,7 @@ def test_without_a_window_the_kernel_is_bit_equal(case):
 
 
 _WORK_LIST_PLANS = [
-    # (kv_lens, [(row, first position, tokens)], window)
+    # (kv_lens, [(row, first position, tokens)], window[, geometry])
     ([16, 15], [(0, 15, 1), (1, 14, 1)], 6),
     ([10, 2], [(0, 3, 7), (1, 1, 1)], 6),
     ([13, 0, 0, 16], [(0, 12, 1), (3, 12, 4)], 6),
@@ -103,51 +125,64 @@ _WORK_LIST_PLANS = [
     ([0, 0, 0], [], 5),
     ([21, 6], [(0, 15, 1), (1, 5, 1)], 3),          # past the table span
     ([16, 16], [(0, 15, 1), (1, 15, 1)], 1 << 30),  # nothing skipped
+    # rows of several blocks (G = 8): lists that start mid-table
+    ([292, 60], [(0, 280, 11), (1, 59, 1)], 200, _BLOCKS),
+    ([300, 129, 0, 320], [(0, 299, 1), (1, 128, 1), (3, 316, 4)], 128,
+     _BLOCKS),
+    ([240, 33], [(0, 100, 12), (1, 32, 1)], 16, _BLOCKS),
+    ([333, 310], [(0, 319, 1), (1, 309, 1)], 290, _BLOCKS),  # past the table
+    ([300, 200], [(0, 299, 1), (1, 190, 10)], 1 << 30, _BLOCKS),
 ]
 
 
-def test_windowed_work_list_matches_python_loop():
+@pytest.mark.parametrize("plan", range(len(_WORK_LIST_PLANS)))
+def test_windowed_work_list_matches_python_loop(plan):
     """Under a window a row's list starts at the page of the oldest key its
-    earliest token of the call still sees, and ``_init`` fires on that first
-    LISTED page; the numpy helpers of the engine's work record count the
-    same pages."""
-    page, width, t = 4, 4, 12
-    for kv_lens, spans, window in _WORK_LIST_PLANS:
-        token_row = np.full((t,), -1, np.int32)
-        positions = np.zeros((t,), np.int32)
-        at, want, firsts = 0, [], []
-        for row, first, n in spans:
-            token_row[at:at + n] = row
-            positions[at:at + n] = np.minimum(first + np.arange(n),
-                                              page * width - 1)
-            at += n
-        for r, n in enumerate(kv_lens):
-            pages = min(-(-n // page), width)
-            mine = positions[token_row == r]
-            lo = (max(0, int(mine.min()) - window + 1) // page
-                  if mine.size else 0)
-            lo = min(lo, pages)
-            firsts.append(lo)
-            want += [(r, j, int(j == lo), int(j == pages - 1))
-                     for j in range(lo, pages)]
-        first_pages = pa._row_first_pages(
-            jnp.asarray(token_row), jnp.asarray(positions), len(kv_lens),
-            page, jnp.int32(window))
-        items, n_live = pa._ragged_work_list(
-            jnp.asarray(kv_lens, jnp.int32), page, width, first_pages)
-        bits = pa._work_item_bits(width)
-        got = [tuple(int(x) for x in pa._unpack_work_item(it, bits))
-               for it in np.asarray(items)[:int(n_live)]]
-        assert got == want, (kv_lens, spans, window)
-        rows, js, _, _ = pa._unpack_work_item(np.asarray(items), bits)
-        assert rows.min() >= 0 and rows.max() < len(kv_lens)
-        assert js.min() >= 0 and js.max() < width
-        host_first = pa.ragged_first_pages(token_row, positions,
-                                           len(kv_lens), page, window)
-        assert np.minimum(host_first, [min(-(-n // page), width)
-                                       for n in kv_lens]).tolist() == firsts
-        assert pa.ragged_live_pages(kv_lens, page, width,
-                                    host_first) == int(n_live) == len(want)
+    earliest token of the call still sees, its blocks of G pages count from
+    THERE, and ``_init`` fires on that first listed block; the numpy helpers
+    of the engine's work record count the same blocks and pages."""
+    kv_lens, spans, window, *geometry = _WORK_LIST_PLANS[plan]
+    geometry = {"page": 4, "width": 4, **(geometry[0] if geometry else {})}
+    page, width, t = geometry["page"], geometry["width"], 12
+    group = pa.ragged_block_pages(page, width)
+    token_row = np.full((t,), -1, np.int32)
+    positions = np.zeros((t,), np.int32)
+    at, want, firsts, listed = 0, [], [], 0
+    for row, first, n in spans:
+        token_row[at:at + n] = row
+        positions[at:at + n] = np.minimum(first + np.arange(n),
+                                          page * width - 1)
+        at += n
+    for r, n in enumerate(kv_lens):
+        pages = min(-(-n // page), width)
+        mine = positions[token_row == r]
+        lo = (max(0, int(mine.min()) - window + 1) // page
+              if mine.size else 0)
+        lo = min(lo, pages)
+        firsts.append(lo)
+        listed += pages - lo
+        blocks = -(-(pages - lo) // group)
+        want += [(r, lo + b * group, int(b == 0), int(b == blocks - 1))
+                 for b in range(blocks)]
+    first_pages = pa._row_first_pages(
+        jnp.asarray(token_row), jnp.asarray(positions), len(kv_lens),
+        page, jnp.int32(window))
+    items, n_live = pa._ragged_work_list(
+        jnp.asarray(kv_lens, jnp.int32), page, width, first_pages)
+    bits = pa._work_item_bits(width)
+    got = [tuple(int(x) for x in pa._unpack_work_item(it, bits))
+           for it in np.asarray(items)[:int(n_live)]]
+    assert got == want, (kv_lens, spans, window)
+    rows, js, _, _ = pa._unpack_work_item(np.asarray(items), bits)
+    assert rows.min() >= 0 and rows.max() < len(kv_lens)
+    assert js.min() >= 0 and js.max() < width
+    host_first = pa.ragged_first_pages(token_row, positions,
+                                       len(kv_lens), page, window)
+    assert np.minimum(host_first, [min(-(-n // page), width)
+                                   for n in kv_lens]).tolist() == firsts
+    assert pa.ragged_live_blocks(kv_lens, page, width,
+                                 host_first) == int(n_live) == len(want)
+    assert pa.ragged_live_pages(kv_lens, page, width, host_first) == listed
 
 
 # ---------------------------------------------------------------------------

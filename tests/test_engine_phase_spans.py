@@ -17,6 +17,7 @@ import pytest
 from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                            GenerationConfig)
 from paddle_tpu.models import llama as L
+from paddle_tpu.ops.paged_attention import ragged_block_pages
 from paddle_tpu.profiler import Profiler, ProfilerTarget
 from paddle_tpu.serving import SchedulerConfig, ServingScheduler
 
@@ -172,14 +173,15 @@ def test_record_matches_the_plan_and_the_tokens(run):
     for rec, (token_row, positions, kv_lens) in zip(records, run.plans):
         assert rec["rounds"] == run.chunk and rec["page_size"] == PAGE
         assert rec["token_slots"] == run.chunk * eng._step_tokens
-        # the kernel walks each micro-round's live pages, and one step
-        # in a round that has none
-        assert rec["grid_steps"] == sum(
-            max(1, sum(min(-(-int(kv_lens[k, s]) // PAGE), width)
+        # the kernel walks each micro-round's live blocks of G pages, and
+        # one step in a round that has none: G page slots a step
+        group = ragged_block_pages(PAGE, width)
+        assert rec["grid_steps"] == group * sum(
+            max(1, sum(-(-min(-(-int(kv_lens[k, s]) // PAGE), width) // group)
                        for s in range(SLOTS)))
             for k in range(run.chunk))
         assert rec["attended_pages"] <= rec["grid_steps"] <= \
-            rec["attended_pages"] + rec["rounds"]
+            group * (rec["attended_pages"] + rec["rounds"])
         assert 1 <= rec["live_rows"] <= SLOTS
         # the kernel's own test, step by step: page j of row s runs in
         # round k iff j * page_size < kv_lens[k, s]
@@ -207,9 +209,11 @@ def test_record_counts_one_grid_step_for_an_empty_round():
     """A serve never plans a micro-round without a token while a row is
     live, so the empty round is laid out by hand: it costs the kernel the
     one step that zeroes its output, a starved row costs none, and a span
-    past the table clamps to the table's width."""
+    past the table clamps to the table's width. The table here is one
+    block wide (G = its 8 pages), so every live row is one step."""
     _, _, eng, _ = _build(3, False)
     width = eng._table_width
+    assert ragged_block_pages(PAGE, width) == width
     kv_lens = np.zeros((3, SLOTS), np.int32)
     kv_lens[0] = [5, 0, 9]                      # 2 + 0 + 3 pages
     kv_lens[2] = [0, PAGE * width + 3, 0]       # past the table: width
@@ -221,7 +225,34 @@ def test_record_counts_one_grid_step_for_an_empty_round():
                                [1, 1, 1], [0, 0, 0])
     assert rec["rounds"] == 3
     assert rec["attended_pages"] == 5 + width
-    assert rec["grid_steps"] == 5 + 1 + width
+    # two live rows, the empty round's one step, one live row: G slots each
+    assert rec["grid_steps"] == (2 + 1 + 1) * width
+
+
+def test_record_counts_the_page_slots_of_rows_of_several_blocks():
+    """``grid_steps`` is the page slots the kernel's steps hold, G a block,
+    a row's last block as short as its pages leave it: a table of 64 pages
+    of 4 keys folds G = 32 pages a step."""
+    cfg = L.llama_tiny(num_hidden_layers=2)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
+        num_slots=SLOTS, page_size=PAGE, max_seq_len=256, chunk=2,
+        prefix_cache=True)
+    width = eng._table_width
+    assert (width, ragged_block_pages(PAGE, width)) == (64, 32)
+    kv_lens = np.zeros((2, SLOTS), np.int32)
+    # pages 1, 32 and 33: blocks 1, 1 and 2; then 45 pages, past the table
+    # (64 pages: 2 blocks) and a starved row
+    kv_lens[0] = [3, 128, 129]
+    kv_lens[1] = [180, PAGE * width + 9, 0]
+    token_row = np.full((2, eng._step_tokens), -1, np.int32)
+    positions = np.zeros_like(token_row)
+    token_row[0, :3], positions[0, :3] = [0, 1, 2], [2, 127, 128]
+    token_row[1, :2], positions[1, :2] = [0, 1], [179, PAGE * width - 1]
+    rec = eng._dispatch_record(token_row, positions, kv_lens,
+                               [1, 1, 1], [0, 0, 0])
+    assert rec["attended_pages"] == 1 + 32 + 33 + 45 + 64
+    assert rec["grid_steps"] == 32 * (1 + 1 + 2 + 2 + 2)
 
 
 @pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
@@ -310,8 +341,9 @@ def test_afmoe_record_counts_the_windows_pages_by_hand():
     assert rec["attended_pages"] == round((full + 2 * windowed) / 3) == 10
     assert rec["window_skipped_pages"] == round(
         full - (full + 2 * windowed) / 3) == 2
-    # one empty round costs each layer's kernel one step
-    assert rec["grid_steps"] == rec["attended_pages"] + 1
+    # every layer's kernel takes one step a live row (a table of 8 pages
+    # is one block) and one in the empty round: 4 steps of G = 8 slots
+    assert rec["grid_steps"] == 8 * (2 + 1 + 1)
     pairs_full = 21 + (3 + 4 + 5 + 6 + 7) + (10 + 11 + 12 + 13 + 14)
     pairs_win = 8 + (3 + 4 + 5 + 6 + 7) + 5 * 8
     assert rec["causal_pairs"] == round((pairs_full + 2 * pairs_win) / 3)

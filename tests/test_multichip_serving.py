@@ -176,13 +176,20 @@ def test_mesh_resize_helpers():
         shrink_serving_mesh(m4, 4, 4)
 
 
-def test_sharded_pallas_wrapper_interpret_parity():
+@pytest.mark.parametrize("page,width,window", [
+    (4, 4, None),           # the table is one block
+    (16, 20, None),         # G = 8: rows of 1, 9 and 13 pages
+    (16, 20, 100),          # and a window whose first page is mid-block
+], ids=["one_block_table", "several_blocks", "several_blocks_windowed"])
+def test_sharded_pallas_wrapper_interpret_parity(page, width, window):
     """The TPU path's shard_map wrapper around the Pallas ragged kernel
-    (per-chip GQA slices, replicated metadata) matches the XLA reference
-    elementwise — run in Pallas interpret mode on the CPU mesh."""
+    (per-chip GQA slices, replicated metadata, the window one more
+    replicated scalar) matches the XLA reference elementwise — run in
+    Pallas interpret mode on the CPU mesh, rows of one block and of
+    several."""
     from paddle_tpu.ops import paged_attention as pa
     rng = np.random.RandomState(0)
-    n_rows, width, page, nkv, nh, d, T = 3, 4, 4, 4, 4, 8, 10
+    n_rows, nkv, nh, d, T = 3, 4, 4, 8, 10
     pool = n_rows * width + 1
     kp = jnp.asarray(rng.randn(pool, page, nkv, d).astype(np.float32))
     vp = jnp.asarray(rng.randn(pool, page, nkv, d).astype(np.float32))
@@ -191,15 +198,19 @@ def test_sharded_pallas_wrapper_interpret_parity():
     for r in range(n_rows):
         bt[r] = 1 + r * width + np.arange(width)
     token_row = np.array([0, 0, 0, 1, 1, 2, -1, -1, -1, -1], np.int32)
-    positions = np.array([0, 1, 2, 5, 6, 3, 0, 0, 0, 0], np.int32)
-    kv_lens = np.array([3, 7, 4], np.int32)
+    if page == 4:
+        positions = np.array([0, 1, 2, 5, 6, 3, 0, 0, 0, 0], np.int32)
+        kv_lens = np.array([3, 7, 4], np.int32)
+    else:
+        positions = np.array([0, 1, 2, 138, 139, 199, 0, 0, 0, 0], np.int32)
+        kv_lens = np.array([3, 140, 200], np.int32)
     ref = pa.ragged_paged_attention_array(
         q, kp, vp, jnp.asarray(bt), jnp.asarray(token_row),
-        jnp.asarray(positions), jnp.asarray(kv_lens))
+        jnp.asarray(positions), jnp.asarray(kv_lens), window=window)
     got = pa._ragged_paged_attention_shard_mapped(
         q, kp, vp, jnp.asarray(bt), jnp.asarray(token_row),
         jnp.asarray(positions), jnp.asarray(kv_lens), None,
-        serving_mesh(2), "mp", interpret=True)
+        serving_mesh(2), "mp", interpret=True, window=window)
     real = np.asarray(token_row) >= 0
     np.testing.assert_allclose(np.asarray(got)[real],
                                np.asarray(ref)[real], rtol=2e-5,

@@ -71,12 +71,15 @@ def test_ragged_array_matches_legacy_decode_and_prefill_pair():
         np.testing.assert_allclose(out[sl], ref[0], rtol=1e-5, atol=1e-6)
 
 
-def _packed_case(kv_lens, spans, shared=()):
+def _packed_case(kv_lens, spans, shared=(), page=4, width=4, t=12):
     """A packed batch from row spans: ``spans`` = [(row, first position,
-    tokens)], packed in order and padded to T with pad slots. Each row
-    owns ``width`` private pages; ``shared`` = [(row, table slot, other
-    row)] points a table slot at the other row's page (prefix cache)."""
-    width, PAGE, T, NKV, NH, D = 4, 4, 12, 2, 4, 8
+    tokens)], packed in order and padded to ``t`` with pad slots. Each row
+    owns ``width`` private pages of ``page`` keys; ``shared`` = [(row,
+    table slot, other row)] points a table slot at the other row's page
+    (prefix cache). The kernel folds G = ``pa.ragged_block_pages(page,
+    width)`` pages a step: 4 (the whole table) at the default geometry, 8
+    at ``**_BLOCKS`` (page 16, width 20: rows of several blocks)."""
+    PAGE, T, NKV, NH, D = page, t, 2, 4, 8
     rng = np.random.RandomState(0)
     n_rows = len(kv_lens)
     pool = 1 + n_rows * width
@@ -97,6 +100,8 @@ def _packed_case(kv_lens, spans, shared=()):
     return (q, kp, vp, bt, token_row, positions,
             np.asarray(kv_lens, np.int32))
 
+
+_BLOCKS = dict(page=16, width=20)      # G = 8 pages a step, 2.5 blocks a table
 
 _RAGGED_CASES = {
     # the mixed batch above: decode + cold prefill + warm suffix + pads
@@ -119,6 +124,33 @@ _RAGGED_CASES = {
     "prefill_chunk_plus_decode_rows": dict(
         kv_lens=[7, 13, 6, 2],
         spans=[(0, 6, 1), (1, 12, 1), (2, 0, 6), (3, 0, 2)]),
+    # the blocked walk, G = 8: rows of 1 page, exactly G, G + 1 and 13 pages
+    "blocks_of_1_G_G_plus_1_and_13_pages": dict(
+        kv_lens=[5, 128, 130, 200],
+        spans=[(0, 4, 1), (1, 127, 1), (2, 126, 4), (3, 194, 6)], **_BLOCKS),
+    # 2.5 blocks with starved rows around them; a row whose last block is
+    # one page, beside a one-page row
+    "blocks_with_starved_rows_between": dict(
+        kv_lens=[0, 150, 0, 0, 300, 0, 17],
+        spans=[(1, 149, 1), (4, 296, 4), (6, 16, 1)], **_BLOCKS),
+    # over-decoded: kv_lens past width * page = 320 clamps to the table,
+    # whose last block holds 4 of its 8 slots
+    "blocks_kv_lens_past_table_span": dict(
+        kv_lens=[333, 40], spans=[(0, 319, 1), (1, 38, 2)], **_BLOCKS),
+    # a prefill chunk that crosses a block boundary beside decode rows, and
+    # row 2 reading row 0's first block through the prefix cache
+    "blocks_prefill_across_a_boundary_shared_prefix": dict(
+        kv_lens=[131, 260, 140],
+        spans=[(0, 124, 7), (1, 259, 1), (2, 137, 3)],
+        shared=[(2, slot, 0) for slot in range(8)], **_BLOCKS),
+    # a table narrower than 128 keys: G is the table's width, 3
+    "table_narrower_than_a_block": dict(
+        kv_lens=[48, 17, 33], spans=[(0, 47, 1), (1, 14, 3), (2, 32, 1)],
+        page=16, width=3),
+    # a page of 128 keys: G = 1, a step is a page again
+    "one_page_blocks": dict(
+        kv_lens=[300, 128], spans=[(0, 297, 3), (1, 127, 1)],
+        page=128, width=3),
 }
 
 
@@ -128,7 +160,9 @@ def test_ragged_pallas_interpret_matches_array(case):
     XLA gather/mask reference elementwise, pad slots included, on the
     mixes that shape its work list: a mixed batch, no work at all, gaps
     between live rows, page boundaries, a full table row, shared physical
-    pages, a span past the table, prefill beside decode."""
+    pages, a span past the table, prefill beside decode; and, where a row
+    takes several blocks of G pages, page counts on both sides of a
+    multiple of G, a table narrower than a block and blocks of one page."""
     spec = _RAGGED_CASES[case]
     args = spec() if callable(spec) else _packed_case(**spec)
     token_row = args[4]
@@ -146,24 +180,35 @@ def test_ragged_pallas_interpret_matches_array(case):
 
 _WORK_LIST_KV_LENS = [
     [0, 0, 0], [0, 5, 0, 0, 9, 0], [8, 9], [16, 3], [21, 6], [1],
-    [16, 16, 16],
+    [16, 16, 16], [5, 128, 130, 200], [0, 150, 0, 0, 300, 0, 17], [333, 40],
 ]
+# (page, table width): G = 4 (the table), 8, 8 past a table of 9, 2, 1, 1
+_WORK_LIST_GEOMETRY = [(4, 4), (16, 20), (16, 9), (64, 5), (128, 3), (256, 3)]
 
 
-def test_ragged_work_list_matches_python_loop():
-    """The in-program work list is the row-major list of live (row,
-    page) pairs, each flagged on its row's first listed page (page 0 where
-    no window cuts the list) and on its last."""
-    page, width = 4, 4
+def test_ragged_block_pages_fills_the_lanes():
+    assert [pa.ragged_block_pages(p, w) for p, w in _WORK_LIST_GEOMETRY] == \
+        [4, 8, 8, 2, 1, 1]
+    assert pa.ragged_block_pages(16, 256) == 8      # the serving default
+    assert pa.ragged_block_pages(8, 64) == 16
+
+
+@pytest.mark.parametrize("page,width", _WORK_LIST_GEOMETRY)
+def test_ragged_work_list_matches_python_loop(page, width):
+    """The in-program work list is the row-major list of live blocks: (row,
+    first page of the block), a block every G pages of the row, each
+    flagged on its row's first block (page 0 where no window cuts the
+    list) and on its last."""
+    group = pa.ragged_block_pages(page, width)
     for kv_lens in _WORK_LIST_KV_LENS:
         want = []
         for r, n in enumerate(kv_lens):
-            pages = min(-(-n // page), width)
-            want += [(r, j, int(j == 0), int(j == pages - 1))
-                     for j in range(pages)]
+            blocks = -(-min(-(-n // page), width) // group)
+            want += [(r, b * group, int(b == 0), int(b == blocks - 1))
+                     for b in range(blocks)]
         items, n_live = pa._ragged_work_list(
             jnp.asarray(kv_lens, jnp.int32), page, width)
-        assert items.shape == (len(kv_lens) * width,)
+        assert items.shape == (len(kv_lens) * -(-width // group),)
         bits = pa._work_item_bits(width)
         items = np.asarray(items)
         got = [tuple(int(x) for x in pa._unpack_work_item(it, bits))
@@ -175,19 +220,26 @@ def test_ragged_work_list_matches_python_loop():
         assert js.min() >= 0 and js.max() < width
 
 
-def test_ragged_live_pages_helper_matches_in_program_n_live():
-    """The engine's work record counts the kernel's steps with a numpy
-    helper on the host: it must equal the in-program ``n_live``, call by
-    call."""
-    page, width = 4, 4
+@pytest.mark.parametrize("page,width", _WORK_LIST_GEOMETRY)
+def test_ragged_host_helpers_match_in_program_n_live(page, width):
+    """The engine's work record counts the kernel's steps and the pages
+    they hold with numpy helpers on the host: the blocks must equal the
+    in-program ``n_live``, call by call, and the pages fit in them."""
+    group = pa.ragged_block_pages(page, width)
     for kv_lens in _WORK_LIST_KV_LENS:
         _, n_live = pa._ragged_work_list(
             jnp.asarray(kv_lens, jnp.int32), page, width)
-        assert pa.ragged_live_pages(kv_lens, page, width) == int(n_live), \
-            kv_lens
+        blocks = pa.ragged_live_blocks(kv_lens, page, width)
+        assert blocks == int(n_live), kv_lens
+        pages = pa.ragged_live_pages(kv_lens, page, width)
+        assert pages == sum(min(-(-n // page), width) for n in kv_lens)
+        assert blocks <= pages <= group * blocks
     # a dispatch's (rounds, rows) plan: one count per micro-round
     rounds = np.asarray([[0, 0, 0], [5, 0, 17], [16, 16, 16]], np.int32)
-    assert pa.ragged_live_pages(rounds, page, width).tolist() == [0, 6, 12]
+    assert pa.ragged_live_pages(rounds, 4, 4).tolist() == [0, 6, 12]
+    assert pa.ragged_live_blocks(rounds, 4, 4).tolist() == [0, 2, 3]
+    assert pa.ragged_live_blocks(rounds, 4, 3).tolist() == [0, 2, 3]
+    assert pa.ragged_live_blocks(rounds * 40, 16, 64).tolist() == [0, 8, 15]
 
 
 # ---------------------------------------------------------------------------
