@@ -43,8 +43,7 @@ def _decode_tail_ab(cfg, params, *, n_req, max_new, num_slots, chunk,
         return ContinuousBatchingEngine(
             cfg, GenerationConfig(max_new_tokens=max_new),
             num_slots=num_slots, page_size=16, max_seq_len=max_seq_len,
-            chunk=chunk, unified=True, fused_tail=fused,
-            check_invariants=False)
+            chunk=chunk, fused_tail=fused, check_invariants=False)
 
     rng = np.random.RandomState(1)
     lens = rng.randint(prompt_lens[0], prompt_lens[1] + 1, n_req)

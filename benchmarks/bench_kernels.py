@@ -86,27 +86,6 @@ def main():
         ms = with_pallas(flag, lambda: timeit(lambda: rfn(x, w)[0], iters=20))
         rows.append((f"rms_norm fwd+bwd 8192x4096 [{label}]", ms))
 
-    # paged attention decode: 64 seqs, 128 pages x 16 tokens, 8 heads x 128
-    try:
-        from paddle_tpu.ops import paged_attention as PA
-        B, H, D, PAGES, PSZ = 64, 8, 128, 128, 16
-        kp = jnp.asarray(rng.randn(PAGES, PSZ, H, D), jnp.bfloat16)
-        vp = jnp.asarray(rng.randn(PAGES, PSZ, H, D), jnp.bfloat16)
-        qd = jnp.asarray(rng.randn(B, H, D), jnp.bfloat16)
-        bt = jnp.asarray(rng.randint(0, PAGES, (B, 16)), jnp.int32)
-        sl = jnp.full((B,), 200, jnp.int32)
-
-        pfn = jax.jit(lambda q: PA.paged_attention(q, kp, vp, bt, sl))
-        for label, flag in (("pallas", True), ("xla", False)):
-            if flag and not on_tpu:
-                continue
-            jax.clear_caches()
-            ms = with_pallas(flag, lambda: timeit(lambda: pfn(qd), iters=20))
-            rows.append((f"paged_attn decode 64seq 8x128 [{label}]", ms))
-    except Exception as e:
-        print(f"# paged_attention skipped: {type(e).__name__}: {e}",
-              file=sys.stderr)
-
     # fused rope: (8, 2048, 32, 128)
     try:
         qr = jnp.asarray(rng.randn(8, 2048, 32, 128), jnp.bfloat16)

@@ -111,8 +111,7 @@ def main():
     out["kvcache"] = eng.cache.snapshot()
     assert all(h.done for h in handles)
 
-    # length-diverse "storm": the recompile cliff study. Unified ragged
-    # step vs the legacy bucketed pipeline on the same cold engine +
+    # length-diverse "storm": the recompile cliff study. A cold engine +
     # prompt-length spread + mid-decode admissions; recompile counts and
     # compile seconds come straight from the RecompileDetector.
     if on_tpu:
@@ -124,8 +123,7 @@ def main():
     out["storm"] = {
         "prompt_lens": list(storm_kw["prompt_lens"]),
         "requests": storm_kw["n_req"],
-        "unified": _storm(cfg, params, True, **storm_kw),
-        "legacy": _storm(cfg, params, False, **storm_kw),
+        "unified": _storm(cfg, params, **storm_kw),
     }
 
     # speculative decoding A/B: the same mid-decode-admission storm with
@@ -147,9 +145,9 @@ def main():
         spec_params = L.init_stacked_params(spec_cfg, seed=0)
         spec_kw = dict(n_req=12, max_new=32, num_slots=4, chunk=2,
                        prompt_lens=(4, 24), max_seq_len=64)
-    spec_on = _storm(spec_cfg, spec_params, True, speculative=True,
-                     warm=True, **spec_kw)
-    spec_off = _storm(spec_cfg, spec_params, True, warm=True, **spec_kw)
+    spec_on = _storm(spec_cfg, spec_params, speculative=True, warm=True,
+                     **spec_kw)
+    spec_off = _storm(spec_cfg, spec_params, warm=True, **spec_kw)
     # O(1) recompiles asserted ACROSS the speculative storm: one program
     # (+ at most the sanctioned flag-flip retrace)
     assert spec_on["recompiles"] <= 2, spec_on
@@ -295,7 +293,7 @@ def _hot_chains():
     params = L.init_stacked_params(cfg, seed=0)
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=8), num_slots=2,
-        page_size=4, max_seq_len=64, chunk=3, unified=True)
+        page_size=4, max_seq_len=64, chunk=3)
     rng = _np.random.RandomState(5)
     prompts = [rng.randint(1, cfg.vocab_size, (int(n),)).astype(_np.int32)
                for n in (5, 9, 13, 7)]
@@ -323,12 +321,11 @@ def _hot_chains():
                             for s in plan.skipped]}}
 
 
-def _storm(cfg, params, unified, *, n_req, max_new, num_slots, chunk,
+def _storm(cfg, params, *, n_req, max_new, num_slots, chunk,
            prompt_lens, max_seq_len, speculative=False, warm=False):
     """One cold engine through a length-diverse storm with mid-decode
     admissions; reports recompiles, compile wall time, TTFT/ITL p50/p95
-    and tok/s so the unified-vs-legacy (and spec-on-vs-off) delta is a
-    one-line diff."""
+    and tok/s so the spec-on-vs-off delta is a one-line diff."""
     from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                                GenerationConfig)
     from paddle_tpu.observability.runtime import recompiles
@@ -337,14 +334,13 @@ def _storm(cfg, params, unified, *, n_req, max_new, num_slots, chunk,
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=max_new),
         num_slots=num_slots, page_size=16, max_seq_len=max_seq_len,
-        chunk=chunk, unified=unified, speculative=speculative,
+        chunk=chunk, speculative=speculative,
         spec_k=4, check_invariants=False)
     rng = np.random.RandomState(1)
     lens = rng.randint(prompt_lens[0], prompt_lens[1] + 1, n_req)
     prompts = [rng.randint(1, cfg.vocab_size, (int(n),)).astype(np.int32)
                for n in lens]
-    fns = ("cbe.unified_step", "cbe.prefill", "cbe.decode_chunk",
-           "cbe.spec_step")
+    fns = ("cbe.unified_step", "cbe.spec_step")
     rc0 = {f: recompiles.count(f) for f in fns}
     cs0 = {f: recompiles.compile_seconds_total(f) for f in fns}
 
@@ -352,7 +348,7 @@ def _storm(cfg, params, unified, *, n_req, max_new, num_slots, chunk,
         # A/B mode: compile outside the timing window (the recompile
         # counters above still span the warmup, so the O(1) assertion
         # covers the whole run); the cold-compile study is the
-        # unified-vs-legacy storm. The warmup rides a THROWAWAY
+        # recompile storm. The warmup rides a THROWAWAY
         # scheduler (main()'s idiom) so the measured scheduler's
         # token counters and TTFT/ITL histograms hold only the timed
         # requests — not the warmup's compile-inclusive TTFT.
@@ -363,8 +359,7 @@ def _storm(cfg, params, unified, *, n_req, max_new, num_slots, chunk,
 
     t0 = time.perf_counter()
     # a third lands up front; the rest trickle in MID-DECODE, so every
-    # admission joins live traffic (the legacy path pays a fresh
-    # (bucket, batch) prefill compile whenever the mix shifts)
+    # admission joins live traffic
     upfront = max(1, n_req // 3)
     handles = [sched.submit(p) for p in prompts[:upfront]]
     i = upfront
@@ -457,8 +452,7 @@ def _sampling_scenario(cfg, params, on_tpu):
             num_slots=num_slots, page_size=16, max_seq_len=max_seq_len,
             chunk=chunk, speculative=speculative, spec_k=4,
             grammar_states=gram.n_states, check_invariants=False)
-        fns = ("cbe.unified_step", "cbe.prefill", "cbe.decode_chunk",
-               "cbe.spec_step")
+        fns = ("cbe.unified_step", "cbe.spec_step")
         rc0 = {f: recompiles.count(f) for f in fns}
         w = ServingScheduler(eng, SchedulerConfig(max_queue_depth=1))
         # representative warmup: mixed rotates greedy first, but the

@@ -6,10 +6,10 @@ round-2 item 2):
 * ``moe``    — workload #4: GPT-MoE causal-LM train step, dense single-chip
                expert path (the all_to_all path needs a mesh; its dryrun is
                driver config 3).
-* ``decode`` — serving: GenerationEngine prefill + KV-cache decode split
-               (the AnalysisPredictor-replacement path).
+* ``decode_cb`` — serving: ``ContinuousBatchingEngine`` over the paged KV
+               pool (the AnalysisPredictor-replacement path).
 
-Run on the real chip:  python benchmarks/bench_workloads.py [bert|moe|decode]
+Run on the real chip:  python benchmarks/bench_workloads.py [bert|moe|decode_cb]
 CPU smoke:             JAX_PLATFORMS=cpu BENCH_WORKLOADS_SMOKE=1 python ...
 Timing fences through a device->host transfer (float(...)).
 """
@@ -309,78 +309,6 @@ def bench_moe():
             "params_m": round(n_params / 1e6, 1), "loss": float(loss)}
 
 
-def bench_decode():
-    jax, smoke = _setup()
-    import jax.numpy as jnp
-    import paddle_tpu as paddle
-    from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import (GenerationConfig,
-                                               llama_engine)
-
-    gqa = os.environ.get("BENCH_DECODE_GQA") == "1"
-    if smoke:
-        cfg = L.llama_tiny(num_hidden_layers=2)
-        B, T, new = 2, 16, 8
-    else:
-        # the 876M serving config (wide3072) in bf16 — decode is
-        # HBM-bandwidth-bound, so tokens/s tracks bytes-of-weights/step.
-        # BENCH_DECODE_GQA=1: nkv = nh/4 (VERDICT r4 missing #4) — smaller
-        # KV projections AND a 4x smaller KV cache to stream per step,
-        # exactly where serving bandwidth wins live
-        cfg = L.LlamaConfig(
-            vocab_size=32000, hidden_size=3072, intermediate_size=8192,
-            num_hidden_layers=6, num_attention_heads=24,
-            num_key_value_heads=6 if gqa else 24,
-            max_position_embeddings=2048,
-            dtype=jnp.bfloat16)
-        B, T, new = 8, 512, 128
-
-    params = L.init_stacked_params(cfg, seed=0)
-    if os.environ.get("BENCH_DECODE_INT8") == "1":
-        # weight-only int8 serving: halves the bytes each decode step
-        # streams (models/llama._dense dequantizes inside the layer scan)
-        from paddle_tpu.quantization import quantize_stacked_params
-        params = quantize_stacked_params(params)
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, cfg.vocab_size, (B, T)).astype(np.int32)
-
-    def run(max_new):
-        eng = llama_engine(cfg, GenerationConfig(max_new_tokens=max_new))
-        out = eng.generate(params, ids)          # compile
-        t0 = time.perf_counter()
-        out = eng.generate(params, ids)
-        _ = int(np.asarray(out)[0, -1])          # host fence
-        return time.perf_counter() - t0
-
-    t_prefill = run(1)                            # ≈ prefill + 1 token
-    t_full = run(new)
-    decode_s = max(t_full - t_prefill, 1e-9)
-    decode_tok_s = B * (new - 1) / decode_s
-    # bandwidth ceiling note: every decode step streams the full weight set
-    def leaf_bytes(v):
-        # int8-quantized leaves stream 1 byte + their f32 scales; dense
-        # leaves (embed, norms — NOT quantized) stream their own itemsize
-        if isinstance(v, dict):
-            return (int(np.prod(v["q"].shape))
-                    + 4 * int(np.prod(v["scale"].shape)))
-        return int(np.prod(v.shape)) * v.dtype.itemsize
-
-    int8_mode = os.environ.get("BENCH_DECODE_INT8") == "1"
-    n_params = sum(
-        int(np.prod(v["q"].shape)) if isinstance(v, dict)
-        else int(np.prod(v.shape)) for v in params.values())
-    total_bytes = sum(leaf_bytes(v) for v in params.values())
-    bytes_per_tok = total_bytes / B               # amortised over batch
-    return {"metric": "llama_876M_serving_decode"
-            + ("_int8" if int8_mode else "") + ("_gqa" if gqa else ""),
-            "prefill_ms": round(t_prefill * 1e3, 1),
-            "decode_tokens_per_sec": round(decode_tok_s, 1),
-            "per_seq_tokens_per_sec": round(decode_tok_s / B, 1),
-            "hbm_gbps_implied": round(decode_tok_s * bytes_per_tok / 1e9, 1),
-            "num_kv_heads": cfg.num_key_value_heads,
-            "batch": B, "prompt": T, "new_tokens": new}
-
-
 def bench_encoder_int8():
     """A8W8 fused encoder inference vs the bf16 float stack (reference
     fused_multi_transformer_int8 path) at BERT-large geometry."""
@@ -471,16 +399,12 @@ def bench_decode_cb():
             cfg, GenerationConfig(max_new_tokens=new), num_slots=slots,
             page_size=page, max_seq_len=max_len, chunk=chunk)
 
-    # warm: compile prefill bucket + decode chunk on a small serve
+    # warm: compile the step program on a small serve
     eng = make_engine()
     eng.serve(params, prompts[:slots])
-    compiled_prefill = eng._compiled_prefill
-    compiled_chunk = eng._decode_chunk
     compiled_unified = eng._unified_step      # the (one) unified program
 
     eng = make_engine()
-    eng._compiled_prefill = compiled_prefill
-    eng._decode_chunk = compiled_chunk
     eng._unified_step = compiled_unified
     # carry the host state the program baked in, or the fresh engine
     # treats the transplant as stale and recompiles (decoding._prefill_flags)
@@ -644,8 +568,7 @@ def bench_ppyoloe():
 def main():
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     benches = {"bert": bench_bert, "bert_packed": bench_bert_packed,
-               "moe": bench_moe, "decode": bench_decode,
-               "decode_cb": bench_decode_cb,
+               "moe": bench_moe, "decode_cb": bench_decode_cb,
                "encoder_int8": bench_encoder_int8, "vit": bench_vit,
                "ppyoloe": bench_ppyoloe}
     if which != "all" and which not in benches:

@@ -12,7 +12,8 @@ layers:
 * **traced reachability** — the call graph walked from *jit roots*:
   functions handed to ``jax.jit`` / ``pl.pallas_call`` (positionally or
   via ``functools.partial(jax.jit, ...)`` decorators), ``@jit``-style
-  decorated functions, and lambdas jitted inline. Resolution is
+  decorated functions, lambdas jitted inline, and each model module's
+  serving step (``_MODEL_STEP``). Resolution is
   deliberately conservative (same-scope names, same-class ``self.``
   methods, explicitly imported module attributes) so the purity rules
   over-approximate reachable code only through edges that are certainly
@@ -31,6 +32,12 @@ from .engine import Project, SourceModule
 
 #: call targets that mark their function argument as traced
 _JIT_NAMES = {"jit", "pallas_call"}
+
+#: traced roots no call edge reaches: the serving engine jits the step of
+#: whichever module the model's config names
+#: (``inference/decoding._serving_module``), a lookup by string the
+#: conservative resolution cannot follow
+_MODEL_STEP = ("paddle_tpu/models/", "ragged_step")
 
 
 def dotted(node: ast.AST) -> Optional[str]:
@@ -258,6 +265,10 @@ class ProjectIndex:
                             fi = self._info_for_def(mi, node)
                             if fi is not None:
                                 roots.append(fi)
+            if mi.module.rel.startswith(_MODEL_STEP[0]):
+                step = mi.top_level.get(_MODEL_STEP[1])
+                if step is not None:
+                    roots.append(step)
         self._roots = roots
         seen: Set[int] = set()
         queue = list(roots)

@@ -15,8 +15,7 @@ from .config import Config  # noqa: F401
 from .predictor import Predictor, create_predictor  # noqa: F401
 from . import decoding  # noqa: F401
 from .decoding import (  # noqa: F401
-    ContinuousBatchingEngine, GenerationConfig, GenerationEngine,
-    PagedGenerationEngine, KVCache,
+    ContinuousBatchingEngine, GenerationConfig,
 )
 from .speculative import (  # noqa: F401
     Drafter, DraftModel, NgramDrafter, SpeculationTelemetry,

@@ -1,20 +1,21 @@
-"""Serving decode loop: compiled prefill + KV-cache token generation.
+"""Serving decode loop: the continuous-batching engine over the paged KV
+cache.
 
 This is the TPU replacement for the reference's inference hot path
 (AnalysisPredictor decode loop over fused_multi_transformer with its CUDA
-KV cache — SURVEY.md §2.2/§3.5): one jitted prefill over the padded prompt
-bucket, then a jitted ``lax.scan`` over decode steps, KV cache donated
-between steps so generation runs without host round-trips.
+KV cache — SURVEY.md §2.2/§3.5): ONE jitted program, the model module's
+``ragged_step`` under a ``lax.scan`` of micro-rounds, serves mixed
+prefill and decode rows from a host-planned packed layout, the paged
+pools donated between steps so a round costs one host round-trip.
 
-Prompt lengths are padded to buckets (powers of two by default) — the
-dynamic-shape story on XLA (SURVEY §2.5 CINN row: bucketing/padding
-replaces symbolic shapes).
+Shapes never follow the request mix — the dynamic-shape story on XLA
+(SURVEY §2.5 CINN row) is row metadata padded to the fixed slot count,
+not a compile per prompt bucket.
 """
 
 from __future__ import annotations
 
 import collections
-import functools
 import importlib
 import time
 from dataclasses import dataclass, field
@@ -31,8 +32,7 @@ from ..observability.memory import memory_armed, memory_ledger
 from ..observability.profiling import chain_armed as _chain_armed
 from ..observability.profiling import note_chain as _note_chain
 from ..observability.runtime import recompiles
-from ..profiler.record import (emit_span, emit_spans, make_span, phase,
-                               spans_armed)
+from ..profiler.record import emit_spans, make_span, phase, spans_armed
 from . import constrain as _constrain
 from . import sampling as _sampling
 from .sampling import SamplerConfig
@@ -67,253 +67,32 @@ class GenerationConfig:
     seed: int = 0
 
 
-class KVCache:
-    """Thin named wrapper over the model's cache pytree (parity surface for
-    the reference's CacheKV tensors)."""
-
-    def __init__(self, tree: Any):
-        self.tree = tree
-
-    @property
-    def seq_capacity(self) -> int:
-        leaves = jax.tree_util.tree_leaves(self.tree)
-        return leaves[0].shape[2] if leaves else 0
-
-
-def _bucket(n: int, minimum: int = 16) -> int:
-    b = minimum
-    while b < n:
-        b *= 2
-    return b
-
-
-def _sample(logits, key, cfg: GenerationConfig):
-    logits = logits.astype(jnp.float32)
-    if not cfg.do_sample:
-        return jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    logits = logits / jnp.maximum(cfg.temperature, 1e-6)
-    if cfg.top_k > 0:
-        kth = jnp.sort(logits, axis=-1)[..., -cfg.top_k][..., None]
-        logits = jnp.where(logits < kth, -jnp.inf, logits)
-    if cfg.top_p < 1.0:
-        sorted_l = jnp.sort(logits, axis=-1)[..., ::-1]
-        probs = jax.nn.softmax(sorted_l, axis=-1)
-        cum = jnp.cumsum(probs, axis=-1)
-        # keep the smallest set with cumulative prob >= top_p
-        cutoff_idx = jnp.sum(cum < cfg.top_p, axis=-1, keepdims=True)
-        cutoff = jnp.take_along_axis(sorted_l, cutoff_idx, axis=-1)
-        logits = jnp.where(logits < cutoff, -jnp.inf, logits)
-    return jax.random.categorical(key, logits, axis=-1).astype(jnp.int32)
-
-
-class GenerationEngine:
-    """Compiled generation over a model's (prefill, decode_step, init_cache)
-    triple.
-
-    ``prefill(params, ids, cache) -> (logits, cache)``
-    ``decode_step(params, tok, pos, cache) -> (logits, cache)``
-    ``init_cache(batch, max_len) -> cache pytree``
-    """
-
-    def __init__(self, prefill: Callable, decode_step: Callable,
-                 init_cache: Callable, config: GenerationConfig = None):
-        self._prefill = prefill
-        self._decode = decode_step
-        self._init_cache = init_cache
-        self.config = config or GenerationConfig()
-        self._compiled: Dict[Tuple, Callable] = {}
-
-    # -- compiled program per (bucket, max_new) shape ------------------------
-
-    def _build(self, prompt_bucket: int, max_new: int):
-        cfg = self.config
-        prefill = self._prefill
-        decode = self._decode
-
-        def run(params, ids, prompt_len, cache, key):
-            # ids: (B, prompt_bucket) right-padded; prompt_len: (B,) uniform
-            # (ragged serving batches belong to the paged-attention path,
-            # ops/paged_attention.py)
-            logits, cache = prefill(params, ids, cache)       # (B, T, V)
-            last = jax.lax.dynamic_index_in_dim(
-                logits, prompt_len[0] - 1, axis=1, keepdims=False)
-            key, sub = jax.random.split(key)
-            tok = _sample(last, sub, cfg)
-
-            def step(carry, i):
-                tok, cache, key = carry
-                pos = prompt_len[0] + i  # uniform-length batch
-                lg, cache = decode(params, tok, pos, cache)
-                key, sub = jax.random.split(key)
-                nxt = _sample(lg, sub, cfg)
-                return (nxt, cache, key), tok
-
-            (last, cache, _), toks = jax.lax.scan(
-                step, (tok, cache, key), jnp.arange(max_new - 1))
-            toks = jnp.concatenate([toks, last[None]], axis=0)  # (max_new, B)
-            # Return the final cache so the donated input cache buffers are
-            # actually aliasable (donating without returning produced
-            # "donated buffers were not usable" warnings and saved nothing).
-            return jnp.swapaxes(toks, 0, 1), cache              # (B, max_new)
-
-        return jax.jit(run, donate_argnums=(3,))
-
-    def generate(self, params, input_ids,
-                 generation_config: Optional[GenerationConfig] = None):
-        """input_ids: (B, T) numpy/jax int array → (B, max_new_tokens)."""
-        if generation_config is not None:
-            self.config = generation_config
-            self._compiled.clear()
-        cfg = self.config
-        ids = np.asarray(input_ids)
-        b, t = ids.shape
-        bucket = _bucket(t)
-        padded = np.full((b, bucket), cfg.pad_token_id, ids.dtype)
-        padded[:, :t] = ids
-        # right-padding is safe: pad rows in the cache sit beyond kv_len
-        # until decode overwrites each position before first attending to it
-        key = (bucket, cfg.max_new_tokens, b) + _prefill_flags()
-        if key not in self._compiled:
-            recompiles.record_miss("generation_engine.run", key)
-            self._compiled[key] = self._build(bucket, cfg.max_new_tokens)
-        cache = self._init_cache(b, bucket + cfg.max_new_tokens)
-        if isinstance(cache, KVCache):
-            cache = cache.tree
-        prompt_len = jnp.full((b,), t, jnp.int32)
-        rng = jax.random.key(cfg.seed)
-        out, _ = self._compiled[key](params, jnp.asarray(padded), prompt_len,
-                                     cache, rng)
-        return np.asarray(out)
-
-
 _LLAMA = "paddle_tpu.models.llama"
+_MODEL_PROTOCOL = ("ragged_step", "init_stacked_params",
+                   "serving_param_specs", "shard_params_tp")
 
 
-def _serving_module(model_config) -> str:
+def _serving_module(model_config):
     """The module that holds the model's serving step: named by the
-    config's class (``serving_module``), Llama's where it names none."""
-    return getattr(model_config, "serving_module", _LLAMA)
+    config's class (``serving_module``), Llama's where it names none.
 
-
-def _llama_only(model_config, who: str) -> None:
-    if _serving_module(model_config) != _LLAMA:
+    The model protocol — every name of a model module the engine looks
+    up. REQUIRED: ``ragged_step(params, ids, token_row, positions,
+    kv_lens, last_idx, k_pages, v_pages, block_tables, config, mesh=,
+    mp_axis=, logits_epilogue=) -> (logits, k_pages, v_pages[, aux])``,
+    ``init_stacked_params(config)``, ``serving_param_specs(config)``,
+    ``shard_params_tp(params, mesh, config)``. OPTIONAL:
+    ``attention_windows(config)`` (per layer the sliding window, None
+    where attention is full) and the step's fourth return value (a small
+    int32 routing record, handed out with the tokens)."""
+    name = getattr(model_config, "serving_module", _LLAMA)
+    module = importlib.import_module(name)
+    missing = [n for n in _MODEL_PROTOCOL if not hasattr(module, n)]
+    if missing:
         raise ValueError(
-            f"{who} runs the Llama family's programs only; serve a "
-            f"{type(model_config).__name__} through "
-            "ContinuousBatchingEngine (the unified ragged step)")
-
-
-def llama_engine(config, generation_config: Optional[GenerationConfig] = None
-                 ) -> GenerationEngine:
-    """GenerationEngine wired to the stacked-param Llama family."""
-    from ..models import llama as L
-    _llama_only(config, "llama_engine")
-
-    return GenerationEngine(
-        prefill=functools.partial(_llama_prefill, config=config),
-        decode_step=functools.partial(_llama_decode, config=config),
-        init_cache=lambda b, s: L.init_kv_cache(config, b, s),
-        config=generation_config,
-    )
-
-
-def _llama_prefill(params, ids, cache, config):
-    from ..models import llama as L
-    return L.prefill_stacked(params, ids, cache, config)
-
-
-def _llama_decode(params, tok, pos, cache, config):
-    from ..models import llama as L
-    return L.decode_step_stacked(params, tok, pos, cache, config)
-
-
-# ---------------------------------------------------------------------------
-# Ragged (paged) serving engine
-# ---------------------------------------------------------------------------
-class PagedGenerationEngine:
-    """Ragged-batch generation over the paged KV cache.
-
-    Unlike GenerationEngine (uniform prompt lengths, contiguous cache),
-    prompts may have different lengths: each sequence owns pages via a
-    block table (ops/paged_attention.py), decode positions advance per row,
-    and sampling starts from each row's own last prompt token.
-    """
-
-    def __init__(self, model_config, generation_config: Optional[GenerationConfig] = None,
-                 page_size: int = 16, num_pages: Optional[int] = None):
-        from ..models import llama as L
-        _llama_only(model_config, "PagedGenerationEngine")
-        self._L = L
-        self.model_config = model_config
-        self.config = generation_config or GenerationConfig()
-        self.page_size = page_size
-        self._num_pages = num_pages
-        self._compiled: Dict[Tuple, Callable] = {}
-
-    def _build(self, max_new: int):
-        L = self._L
-        cfg = self.config
-        mcfg = self.model_config
-
-        def run(params, ids, seq_lens, k_pages, v_pages, block_tables, key):
-            logits, k_pages, v_pages = L.prefill_paged(
-                params, ids, seq_lens, k_pages, v_pages, block_tables, mcfg)
-            last = jnp.take_along_axis(
-                logits, (seq_lens - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]                       # (B, V) per-row last token
-            key, sub = jax.random.split(key)
-            tok = _sample(last, sub, cfg)
-
-            def step(carry, i):
-                tok, kp, vp, key = carry
-                positions = seq_lens + i            # (B,) per-row position
-                lg, kp, vp = L.decode_step_paged(
-                    params, tok, positions, kp, vp, block_tables, mcfg)
-                key, sub = jax.random.split(key)
-                nxt = _sample(lg, sub, cfg)
-                return (nxt, kp, vp, key), tok
-
-            (last_tok, k_pages, v_pages, _), toks = jax.lax.scan(
-                step, (tok, k_pages, v_pages, key), jnp.arange(max_new - 1))
-            toks = jnp.concatenate([toks, last_tok[None]], axis=0)
-            return jnp.swapaxes(toks, 0, 1), k_pages, v_pages
-
-        return jax.jit(run, donate_argnums=(3, 4))
-
-    def generate(self, params, prompts):
-        """prompts: list of 1-D int arrays (ragged) → (B, max_new_tokens)."""
-        from ..ops.paged_attention import PagedKVCacheManager
-        cfg = self.config
-        mcfg = self.model_config
-        lens = [len(p) for p in prompts]
-        b = len(prompts)
-        t_bucket = _bucket(max(lens))
-        ids = np.full((b, t_bucket), cfg.pad_token_id, np.int32)
-        for i, p in enumerate(prompts):
-            ids[i, :len(p)] = np.asarray(p, np.int32)
-
-        total = [l + cfg.max_new_tokens for l in lens]
-        pages_per_seq = [PagedKVCacheManager.pages_needed(n, self.page_size)
-                         for n in total]
-        num_pages = self._num_pages or (sum(pages_per_seq) + 1)
-        mgr = PagedKVCacheManager(
-            mcfg.num_hidden_layers, num_pages, self.page_size,
-            mcfg.num_key_value_heads, mcfg.head_dim, dtype=mcfg.dtype)
-        for i in range(b):
-            mgr.allocate(i, total[i])
-            mgr._lens[i] = lens[i]  # prompt length is the live length
-        bt, seq_lens = mgr.block_tables(list(range(b)))
-
-        key = (t_bucket, cfg.max_new_tokens, b,
-               bt.shape[1]) + _prefill_flags()
-        if key not in self._compiled:
-            recompiles.record_miss("paged_engine.run", key)
-            self._compiled[key] = self._build(cfg.max_new_tokens)
-        rng = jax.random.key(cfg.seed)
-        toks, _, _ = self._compiled[key](
-            params, jnp.asarray(ids), jnp.asarray(seq_lens, jnp.int32),
-            mgr.k_pages, mgr.v_pages, jnp.asarray(bt), rng)
-        return np.asarray(toks)
+            f"{name} cannot be served: the engine's model protocol "
+            f"requires {', '.join(missing)}")
+    return module
 
 
 # ---------------------------------------------------------------------------
@@ -347,20 +126,15 @@ class ContinuousBatchingEngine:
     shapes (slots, page pool, block-table width) never change, so nothing
     recompiles at runtime.
 
-    Unified ragged step (default, ``unified=True``): the WHOLE round —
-    prefill chunks of newly admitted prompts, warm-prefix/COW suffixes
-    and every decoding row — is ONE dispatch of one compiled program
-    (``models.llama.ragged_step`` over
+    The unified ragged step: the WHOLE round — prefill chunks of newly
+    admitted prompts, warm-prefix/COW suffixes and every decoding row —
+    is ONE dispatch of one compiled program (the model module's
+    ``ragged_step``, see ``_serving_module``, over
     ``ops.paged_attention.ragged_paged_attention``). Rows are metadata
     arrays padded to the fixed slot count, so the program's shape is
     invariant to the request mix: exactly one compile-cache entry ever
     (O(1) recompiles across a length-diverse storm), and a prompt
     submitted mid-decode joins the current step's batch immediately.
-    ``unified=False`` keeps the legacy pipeline — bucketed prefill waves
-    (``_build_prefill``), the warm-suffix variant
-    (``_build_prefill_suffix``) and the per-shape decode chunk
-    (``_build_decode_chunk``) — for A/B benches; both paths emit
-    byte-identical greedy tokens.
 
     Speculative decoding (``speculative=True``, default off): each
     decode row's round becomes ``[carry] + up to spec_k drafted
@@ -375,17 +149,17 @@ class ContinuousBatchingEngine:
     speculative step.
 
     Host-fence discipline (every device->host value dependency stalls
-    the dispatch pipeline): the ONLY transfer per round is the
-    decode chunk's emitted tokens. Slot tokens live on device (admission
-    writes the prefill's sampled token with a lazy ``.at[s].set``), the
-    decode scan emits each step's INPUT token — so chunk outputs chain
-    across chunks without overlap and the prefill token arrives with the
-    slot's first chunk — and positions are mirrored host-side
-    analytically instead of being read back.
+    the dispatch pipeline): the ONLY transfer per round is the step's
+    emitted tokens. Slot tokens live on device (the carry of the step's
+    ``lax.scan``), each micro-round emits its INPUT token — so step
+    outputs chain across steps without overlap and a finished prefill's
+    first sample arrives with the row's first decode round — and
+    positions are mirrored host-side analytically instead of being read
+    back.
 
     Service API:
-      ``submit(prompt) -> rid``; ``step(params)`` runs one admit+decode
-      chunk; ``collect()`` drains finished requests; ``serve(params,
+      ``submit(prompt) -> rid``; ``step(params)`` runs one admit+ragged
+      round; ``collect()`` drains finished requests; ``serve(params,
       prompts)`` streams a whole list through the engine.
     """
 
@@ -394,7 +168,7 @@ class ContinuousBatchingEngine:
                  num_slots: int = 8, page_size: int = 16,
                  max_seq_len: int = 2048, num_pages: Optional[int] = None,
                  chunk: int = 16, prefix_cache: bool = False,
-                 check_invariants: bool = True, unified: bool = True,
+                 check_invariants: bool = True,
                  step_tokens: Optional[int] = None,
                  speculative: bool = False, spec_k: int = 4,
                  drafter=None, fused_tail: bool = False,
@@ -403,7 +177,7 @@ class ContinuousBatchingEngine:
         from ..ops.paged_attention import PagedKVCacheManager
         # the ONE place the engine learns which model it serves: the
         # config's class names the module that holds its step
-        self._L = importlib.import_module(_serving_module(model_config))
+        self._L = _serving_module(model_config)
         self.model_config = model_config
         self.config = generation_config or GenerationConfig()
         self.num_slots = num_slots
@@ -435,16 +209,7 @@ class ContinuousBatchingEngine:
         # chip must live on ITS surviving chip, not the process default
         # device another replica's mesh occupies
         self._mesh = mesh
-        if not unified and not hasattr(self._L, "prefill_paged"):
-            raise ValueError(
-                f"{self._L.__name__} serves through the unified ragged "
-                "step only (the legacy bucketed programs are Llama's); "
-                "construct with unified=True")
         if self._mesh is not None:
-            if chips > 1 and not unified:
-                raise ValueError(
-                    "multi-chip serving shards the unified ragged step; "
-                    "construct with unified=True")
             if chips > 1 and not any(
                     mp_axis in jax.tree_util.tree_leaves(tuple(spec))
                     for spec in self._L.serving_param_specs(mcfg).values()):
@@ -501,7 +266,6 @@ class ContinuousBatchingEngine:
         self._tok_dev = jnp.zeros((num_slots,), jnp.int32)
         self._pos = np.zeros((num_slots,), np.int32)
         self._bt = np.zeros((num_slots, self._table_width), np.int32)
-        self._rng = jax.random.key(self.config.seed)
         # per-row sampling epilogue state (inference/sampling.py): the
         # (seeds, temps, top_k, top_p) device arrays admission writes
         # lazily, like the token carry. Defaults are greedy — a slot
@@ -526,14 +290,10 @@ class ContinuousBatchingEngine:
         # the recompile key), after which mixed greedy/sampled/
         # constrained storms still run O(1) programs.
         self._epilogue_on = False
-        # legacy (unified=False) per-shape compile caches; the unified
-        # path needs exactly ONE compiled step function
-        self._compiled_prefill: Dict[Tuple, Callable] = {}
-        self._decode_chunk = None
-        # unified ragged step: one program serving mixed prefill+decode
-        # rows; its shape depends only on (slots, chunk, step_tokens,
-        # table width) fixed at construction — O(1) recompiles by design
-        self._unified = unified
+        # unified ragged step: ONE compiled program serving mixed
+        # prefill+decode rows; its shape depends only on (slots, chunk,
+        # step_tokens, table width) fixed at construction — O(1)
+        # recompiles by design
         self._step_tokens = max(step_tokens or
                                 max(num_slots, chunk, page_size), num_slots)
         self._unified_step = None
@@ -545,10 +305,6 @@ class ContinuousBatchingEngine:
         # all-decode rounds plan through a vectorized fast path. Tokens
         # are byte-identical fused on/off; the admission gate lives in
         # benchmarks/bench_fusion.py.
-        if fused_tail and not unified:
-            raise ValueError(
-                "fused_tail megakernel-izes the unified ragged step; "
-                "construct with unified=True")
         self._fused_tail = bool(fused_tail)
         self._pend = [None] * num_slots   # per-slot unfed prompt suffix
         # coalesced per-slot span windows ([kind, t0_ns, t1_ns, units]):
@@ -564,7 +320,7 @@ class ContinuousBatchingEngine:
         # row's round becomes [carry + up to spec_k drafted tokens] — a
         # short prefill the same ragged program verifies in ONE dispatch
         # whose per-candidate argmax IS the accept/reject oracle.
-        # Default OFF: the non-speculative paths above are byte-for-byte
+        # Default OFF: the non-speculative step is byte-for-byte
         # untouched.
         self._speculative = bool(speculative)
         self.spec_k = int(spec_k)
@@ -573,10 +329,6 @@ class ContinuousBatchingEngine:
         self._spec_step = None
         self._spec_flags = None
         if speculative:
-            if not unified:
-                raise ValueError(
-                    "speculative decoding rides the unified ragged step; "
-                    "construct with unified=True")
             # sampling composes with speculation since the rejection-
             # sampling verifier (sampling.spec_sample_rows) landed:
             # greedy rows keep verify-by-argmax byte-identity, sampled
@@ -620,70 +372,6 @@ class ContinuousBatchingEngine:
         # unpacks a chunk; finish_callback(rid, tokens) fires at _retire.
         self.token_callback: Optional[Callable[[int, int], None]] = None
         self.finish_callback: Optional[Callable[[int, list], None]] = None
-
-    # -- compiled programs --------------------------------------------------
-
-    def _build_prefill(self, bucket: int):
-        L = self._L
-        mcfg = self.model_config
-        cfg = self.config
-
-        def run(params, ids, seq_len, k_pages, v_pages, bt, key):
-            logits, k_pages, v_pages = L.prefill_paged(
-                params, ids, seq_len, k_pages, v_pages, bt, mcfg)
-            last = jnp.take_along_axis(
-                logits, (seq_len - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-            tok = _sample(last, key, cfg)
-            return tok, k_pages, v_pages
-
-        return jax.jit(run, donate_argnums=(3, 4))
-
-    def _build_prefill_suffix(self, bucket: int):
-        """Prefill of the UNCACHED SUFFIX only (prefix-cache hits): the
-        rows' leading ``start`` tokens are already resident in shared
-        pages, so the model runs over the suffix at offset positions and
-        attends through the page gather (models.llama.prefill_paged_suffix).
-        Cold rows (start 0) riding in the same batch are exact full
-        prefills."""
-        L = self._L
-        mcfg = self.model_config
-        cfg = self.config
-
-        def run(params, ids, seq_len, start, k_pages, v_pages, bt, key):
-            logits, k_pages, v_pages = L.prefill_paged_suffix(
-                params, ids, seq_len, start, k_pages, v_pages, bt, mcfg)
-            last = jnp.take_along_axis(
-                logits, (seq_len - 1)[:, None, None].astype(jnp.int32),
-                axis=1)[:, 0]
-            tok = _sample(last, key, cfg)
-            return tok, k_pages, v_pages
-
-        return jax.jit(run, donate_argnums=(4, 5))
-
-    def _build_decode_chunk(self):
-        L = self._L
-        mcfg = self.model_config
-        cfg = self.config
-        K = self.chunk
-
-        def run(params, tok, pos, k_pages, v_pages, bt, key):
-            def step(carry, _):
-                tok, pos, kp, vp, key = carry
-                lg, kp, vp = L.decode_step_paged(params, tok, pos, kp, vp,
-                                                 bt, mcfg)
-                key, sub = jax.random.split(key)
-                nxt = _sample(lg, sub, cfg)
-                # emit the INPUT token: chunk outputs then chain across
-                # chunks (and deliver each admission's prefill token)
-                return (nxt, pos + 1, kp, vp, key), tok
-
-            (tok, pos, k_pages, v_pages, _), toks = jax.lax.scan(
-                step, (tok, pos, k_pages, v_pages, key), None, length=K)
-            return (jnp.swapaxes(toks, 0, 1),       # (S, K)
-                    tok, k_pages, v_pages)
-
-        return jax.jit(run, donate_argnums=(3, 4))
 
     # -- service API --------------------------------------------------------
 
@@ -750,22 +438,9 @@ class ContinuousBatchingEngine:
                 f"max_seq_len={self.max_seq_len}; raise max_seq_len or "
                 "truncate the prompt (silent page clamping would corrupt "
                 "the sequence's KV)")
-        if (sampler is not None or grammar is not None) \
-                and not self._unified:
-            # the legacy pipeline's epilogue is the engine-wide
-            # GenerationConfig sampler baked into three programs; the
-            # per-row runtime-parameter epilogue exists only in the
-            # unified step. A genuinely unsupported combo, so it stays
-            # a construction-time contract (README "Sampling &
-            # constrained decoding").
-            raise ValueError(
-                "per-request sampling / constrained decoding ride the "
-                "unified ragged step's in-program epilogue; construct "
-                "the engine with unified=True (legacy unified=False "
-                "supports only the engine-wide GenerationConfig sampler)")
         rid = self._next_rid
         self._next_rid += 1
-        if sampler is None and self.config.do_sample and self._unified:
+        if sampler is None and self.config.do_sample:
             # engine-wide do_sample maps onto the same per-request
             # epilogue: one derived SamplerConfig per request, seeded
             # from (config seed, rid) so streams are replayable
@@ -829,10 +504,8 @@ class ContinuousBatchingEngine:
         """Shared admission bookkeeping (host metadata only): pop queued
         requests into free slots, resolve the prefix cache (shared pages,
         COW copy), allocate pages. Returns the picked
-        ``(slot, req, pages_row, prompt_len, n_cached)`` list; the legacy
-        path then runs bucketed prefill dispatches over it while the
-        unified path just queues the suffix tokens into the next ragged
-        step."""
+        ``(slot, req, pages_row, prompt_len, n_cached)`` list; the step
+        queues each pick's suffix tokens into the next ragged round."""
         picked = []                # (slot, req, pages_row, lp, n_cached)
         recorded = []              # deferred stats-only cache accounting
         try:
@@ -937,101 +610,6 @@ class ContinuousBatchingEngine:
                 recorded.append((req, lp, n_cached, len(shared), cow_src))
         return picked
 
-    def _admit(self, params):
-        """Legacy (unified=False) admission: allocate pages, prefill into
-        the slots, record the first generated tokens.
-
-        Round-5: admissions are BATCHED — every free slot fillable this
-        round goes through ONE prefill call per prompt bucket (B padded to
-        the next power of two so the compile cache stays small; pad rows
-        write into the reserved garbage page 0 and their sampled tokens
-        are discarded). A one-at-a-time B=1 prefill wave was ~1/3 of the
-        mixed-workload serve wall time at 16 slots — batch-1 prefills
-        leave the MXU almost idle."""
-        cfg = self.config
-        picked = self._admit_pick()
-        if not picked:
-            return
-        # group by (SUFFIX bucket, warm): cold rows NEVER share a group
-        # with warm rows, so they always run the original full-prefill
-        # program and cache-enabled cold traffic stays byte-identical
-        # with the cache-disabled engine (the suffix program is a
-        # numerically different attention — fine for warm rows, whose
-        # reuse is cross-program by construction, but not imposed on
-        # cold ones). Without the cache every row is cold and grouping /
-        # compile keys match the pre-cache engine exactly.
-        groups: Dict[Tuple, list] = {}
-        for item in picked:
-            groups.setdefault((_bucket(item[3] - item[4]), item[4] > 0),
-                              []).append(item)
-        for (bucket, warm), items in groups.items():
-            real = len(items)
-            b_pad = 1
-            while b_pad < real:
-                b_pad *= 2
-            # real <= num_slots by construction; clamp keeps b_pad within
-            # one slot-wave (for non-power-of-two num_slots the final
-            # bucket is num_slots itself)
-            b_pad = min(b_pad, self.num_slots)
-            ids = np.full((b_pad, bucket), cfg.pad_token_id, np.int32)
-            rows = np.zeros((b_pad, self._table_width), np.int32)
-            lens = np.ones((b_pad,), np.int32)   # pad rows: 1 garbage tok
-            starts = np.zeros((b_pad,), np.int32)
-            for i, (s, req, pages, lp, nc) in enumerate(items):
-                ids[i, :lp - nc] = req.prompt[nc:]
-                rows[i, :len(pages)] = pages
-                lens[i] = lp - nc
-                starts[i] = nc
-            key = (("sfx", bucket, b_pad) if warm
-                   else (bucket, b_pad)) + _prefill_flags()
-            fresh = key not in self._compiled_prefill
-            if fresh:
-                recompiles.record_miss("cbe.prefill", key)
-                self._compiled_prefill[key] = (
-                    self._build_prefill_suffix(bucket) if warm
-                    else self._build_prefill(bucket))
-            self._rng, sub = jax.random.split(self._rng)
-            c0 = time.perf_counter() if fresh else 0.0
-            t0_ns = time.perf_counter_ns() if spans_armed() else 0
-            if warm:
-                tok, self.mgr.k_pages, self.mgr.v_pages = \
-                    self._compiled_prefill[key](
-                        params, jnp.asarray(ids), jnp.asarray(lens),
-                        jnp.asarray(starts), self.mgr.k_pages,
-                        self.mgr.v_pages, jnp.asarray(rows), sub)
-            else:
-                tok, self.mgr.k_pages, self.mgr.v_pages = \
-                    self._compiled_prefill[key](
-                        params, jnp.asarray(ids), jnp.asarray(lens),
-                        self.mgr.k_pages, self.mgr.v_pages,
-                        jnp.asarray(rows), sub)
-            if fresh:
-                # first call of a new shape = trace+compile; surface the
-                # warmup cost in paddle_runtime_compile_seconds{fn}
-                jax.block_until_ready(tok)
-                recompiles.observe_compile("cbe.prefill",
-                                           time.perf_counter() - c0)
-            self._prefill_tokens += int(sum(it[3] - it[4] for it in items))
-            if t0_ns:
-                # one batched prefill serves several requests: emit one
-                # span per admitted request so each trace-id lane shows
-                # its own prefill segment
-                t1_ns = time.perf_counter_ns()
-                for s, req, pages, lp, nc in items:
-                    emit_span("engine.prefill", t0_ns, t1_ns,
-                              event_type="Operator", trace_id=req.trace_id,
-                              args={"request_id": req.rid, "bucket": bucket,
-                                    "prompt_len": lp, "cached_tokens": nc})
-            # NO host readback: prefill tokens are written into the slots
-            # lazily and reach the host with the next chunk's emissions
-            slot_idx = jnp.asarray([s for s, *_ in items], jnp.int32)
-            self._tok_dev = self._tok_dev.at[slot_idx].set(tok[:real])
-            for i, (s, req, pages, lp, nc) in enumerate(items):
-                self._slot_rid[s] = req.rid
-                self._live[req.rid] = req
-                self._pos[s] = lp
-                self._bt[s] = rows[i]
-
     def _complete(self, req) -> bool:
         cfg = self.config
         if len(req.tokens) >= self._budget(req):
@@ -1121,7 +699,7 @@ class ContinuousBatchingEngine:
         """Unpack one slot's emitted tokens: append to the request, fire
         ``token_callback`` per token (surviving a reentrant in-place
         cancel from inside the callback), retire on completion. Shared
-        verbatim by the legacy and unified steps — the reentrancy
+        verbatim by the unified and speculative steps — the reentrancy
         contract must never fork. Returns True while the slot's request
         keeps decoding (caller may advance its position mirror)."""
         rid = self._slot_rid[s]
@@ -1165,11 +743,9 @@ class ContinuousBatchingEngine:
         """One admit + decode round (ONE device->host transfer: the
         step's emitted tokens). Returns the live count after the round.
 
-        Unified mode (default): admission is host bookkeeping only and
-        the round is ONE ragged dispatch — newly admitted prompts join
-        the current step's packed batch immediately, alongside every
-        decoding row. Legacy mode replays the pre-unified pipeline
-        (bucketed prefill waves + per-shape decode chunk). Speculative
+        Admission is host bookkeeping only and the round is ONE ragged
+        dispatch — newly admitted prompts join the current step's packed
+        batch immediately, alongside every decoding row. Speculative
         mode folds draft verification into the same single dispatch
         (``_step_spec``)."""
         with phase("cbe.step"):
@@ -1177,10 +753,8 @@ class ContinuousBatchingEngine:
                 params = self._place_params(params)
             if self._speculative:
                 n = self._step_spec(params)
-            elif self._unified:
-                n = self._step_unified(params)
             else:
-                n = self._step_legacy(params)
+                n = self._step_unified(params)
             if memory_armed[0]:
                 # the memory half of the per-step audit: byte split by
                 # class + per-request holdings + byte conservation, run
@@ -1218,56 +792,7 @@ class ContinuousBatchingEngine:
             else None,
             audit=self._check_invariants)
 
-    def _step_legacy(self, params) -> int:
-        self._admit(params)
-        if not self._live:
-            if self._check_invariants:
-                self.mgr.check_conservation()
-            return 0
-        fresh_chunk = self._decode_chunk is None
-        if fresh_chunk:
-            recompiles.record_miss("cbe.decode_chunk",
-                                   (self.num_slots, self.chunk))
-            self._decode_chunk = self._build_decode_chunk()
-            c0 = time.perf_counter()
-        self._rng, sub = jax.random.split(self._rng)
-        t0_ns = time.perf_counter_ns() if spans_armed() else 0
-        toks, self._tok_dev, self.mgr.k_pages, self.mgr.v_pages = \
-            self._decode_chunk(params, self._tok_dev,
-                               jnp.asarray(self._pos), self.mgr.k_pages,
-                               self.mgr.v_pages, jnp.asarray(self._bt), sub)
-        if fresh_chunk:
-            jax.block_until_ready(toks)
-            recompiles.observe_compile("cbe.decode_chunk",
-                                       time.perf_counter() - c0)
-        toks = np.asarray(toks)                    # the one fence
-        if t0_ns:
-            t1_ns = time.perf_counter_ns()
-            for s in range(self.num_slots):
-                rid = self._slot_rid[s]
-                if rid is None:
-                    continue
-                req = self._live[rid]
-                emit_span("engine.decode_chunk", t0_ns, t1_ns,
-                          event_type="Operator", trace_id=req.trace_id,
-                          args={"request_id": rid, "slot": s,
-                                "chunk": self.chunk})
-        for s in range(self.num_slots):
-            if self._slot_rid[s] is None:
-                continue
-            if self._deliver_tokens(s, toks[s]):
-                self._pos[s] += self.chunk
-        # idle slots decode into the garbage page; their host positions
-        # stay pinned at 0 so they never run past the rope cache
-        if self.cache is not None:
-            if self._check_invariants:
-                # the ownership-model anchor: every page is free, live
-                # (refcounted) or cached — checked after EVERY step
-                self.mgr.check_conservation()
-            self.cache.update_gauges()
-        return len(self._live)
-
-    # -- unified ragged step (the default serving path) ----------------------
+    # -- unified ragged step (the serving path) ------------------------------
 
     def _set_row_sampler(self, s: int, req: "_Request") -> None:
         """Write one admitted request's sampler/grammar parameters into
@@ -1297,10 +822,6 @@ class ContinuousBatchingEngine:
         miss; flipping mid-serve drops the compiled program and rebuilds
         on the next step — a counted miss, same contract as a baked-in
         flags flip."""
-        if not self._unified:
-            raise ValueError(
-                "fused_tail megakernel-izes the unified ragged step; "
-                "construct with unified=True")
         if not self._fused_tail:
             self._fused_tail = True
             self._unified_step = None
@@ -1384,8 +905,7 @@ class ContinuousBatchingEngine:
                 nxt, ngst = tail(logits, kvl_k, samp, gst, gtable)
                 # emit the INPUT carry: step outputs chain across steps
                 # and a finished prefill's first sample arrives with the
-                # row's first decode round (same contract as the legacy
-                # decode chunk)
+                # row's first decode round
                 emit = tok
                 tok = jnp.where(sm_k, nxt, tok)
                 gst = jnp.where(sm_k, ngst, gst)
@@ -1637,8 +1157,7 @@ class ContinuousBatchingEngine:
     def _step_unified(self, params) -> int:
         """One ragged round: host-only admission, ONE dispatch serving
         the mixed prefill+decode batch, unpack. The single device→host
-        transfer is the step's emitted tokens — identical host-fence
-        discipline to the legacy path, minus its prefill dispatches.
+        transfer is the step's emitted tokens.
 
         Every stretch of host work sits in a ``phase`` (``cbe.admit`` ..
         ``cbe.audit``), so a profiler trace says what the host did in each
@@ -1652,8 +1171,7 @@ class ContinuousBatchingEngine:
                 self._bt[s] = 0
                 self._bt[s, :len(pages)] = pages
                 # a warm/COW suffix row IS "a row whose first position >
-                # 0"; cold rows just start at 0 — one code path for all
-                # three legacy programs
+                # 0"; cold rows just start at 0 — one code path
                 self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
                 self._set_row_sampler(s, req)
         if not self._live:
@@ -1701,7 +1219,7 @@ class ContinuousBatchingEngine:
         # mid-prefill requests never inflate the skip-ratio math)
         self._prefill_tokens += sum(fed)
         if fresh:
-            c0 = time.perf_counter()   # dispatch-only window, like legacy
+            c0 = time.perf_counter()   # dispatch-only window
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
         with phase("cbe.upload"):
             plan_dev = [jnp.asarray(a) for a in plan]

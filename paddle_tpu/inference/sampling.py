@@ -164,8 +164,9 @@ def _keys(seeds, pos, salt):
 
 def process_logits(logits, temps, top_k, top_p):
     """Temperature scale -> top-k -> top-p, all with PER-ROW runtime
-    parameters — the vectorized twin of the legacy ``_sample`` filters
-    (same kth-value rule, same keep-ties-at-cutoff top-p rule), with
+    parameters — the vectorized form of the scalar-parameter filter
+    chain tests/test_sampling.py holds it to (same kth-value rule, same
+    keep-ties-at-cutoff top-p rule), with
     ``top_k == 0`` / ``top_p == 1`` rows passing through untouched.
     ``logits`` must already be f32 (and grammar-masked for constrained
     rows)."""
@@ -213,7 +214,7 @@ def greedy_rows(logits, pos_next, samp, gstate, gtable):
     compile cost at the pre-sampling baseline — on single-core CI
     boxes compile time is the tier-1 budget. The f32 cast is
     value-exact for bf16/f16 logits, so the argmax is bit-identical
-    both to the legacy tail and to ``sample_rows``'s greedy path."""
+    to ``sample_rows``'s greedy path."""
     del pos_next, samp, gtable
     tok = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
     return tok, gstate

@@ -233,8 +233,8 @@ class FusionPlan:
             except Exception as exc:
                 # the degradation contract covers installation too: a
                 # target without the surface (AttributeError) or one
-                # that rejects it (e.g. a non-unified engine's
-                # ValueError) becomes a structured skip, never a raise
+                # that rejects it (ValueError) becomes a structured
+                # skip, never a raise
                 _note_skip(cand.ops, "target-unsupported", region=name,
                            error=f"{type(exc).__name__}: {exc}")
                 continue

@@ -921,286 +921,8 @@ def microbatch(ids: np.ndarray, labels: np.ndarray, num_micro: int):
 
 
 # ===========================================================================
-# KV-cache inference path (serving: prefill + single-token decode)
+# Serving: the ragged step over the paged KV pool (ops/paged_attention.py)
 # ===========================================================================
-def init_kv_cache(config: LlamaConfig, batch: int, max_len: int, dtype=None):
-    """Contiguous per-layer KV cache (L, B, S_max, n_kv, d). The paged
-    variant for ragged serving batches lives in ops/paged_attention.py."""
-    L = config.num_hidden_layers
-    d = config.head_dim
-    nkv = config.num_key_value_heads
-    dt = dtype or config.dtype
-    shape = (L, batch, max_len, nkv, d)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-
-
-def _cached_attention(q, k_cache, v_cache, kv_len, config: LlamaConfig):
-    """q: (B, T, nh, d); caches: (B, S_max, nkv, d); attend over [0, kv_len)
-    with causality inside the current T block (query i sits at absolute
-    position kv_len - T + i)."""
-    b, t, nh, d = q.shape
-    s_max = k_cache.shape[1]
-    nkv = k_cache.shape[2]
-    rep = nh // nkv
-    q_pos = kv_len - t + jnp.arange(t)                      # (T,)
-    mask = jnp.arange(s_max)[None, :] <= q_pos[:, None]     # (T, S_max)
-    if rep > 1:
-        # grouped attention WITHOUT materializing repeated KV: a
-        # jnp.repeat here would stream rep x the cache bytes every decode
-        # step — exactly the bandwidth GQA exists to save. Group the
-        # query heads instead: (B, T, nkv, rep, d) against (B, S, nkv, d).
-        qg = q.reshape(b, t, nkv, rep, d)
-        scores = jnp.einsum("btgrd,bsgd->bgrts", qg.astype(jnp.float32),
-                            k_cache.astype(jnp.float32)) / math.sqrt(d)
-        scores = jnp.where(mask[None, None, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bgrts,bsgd->btgrd",
-                         probs.astype(v_cache.dtype), v_cache)
-        return out.reshape(b, t, nh, d)
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        k_cache.astype(jnp.float32)) / math.sqrt(d)
-    scores = jnp.where(mask[None, None], scores, -1e30)
-    probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhts,bshd->bthd", probs.astype(v_cache.dtype), v_cache)
-    return out
-
-
-def _decoder_layer_cached_full(lp, l, x, cos, sin, kf, vf, kv_len,
-                               config: LlamaConfig):
-    """One cached decoder layer operating on the FULL stacked cache
-    (L, B, S_max, nkv, d): the new tokens write a (1, B, T, nkv, d) slab at
-    layer ``l`` and attention reads the layer slice (the slice read fuses
-    into the attention matmuls). This keeps the caches in the scan CARRY —
-    scanning them as xs/ys (the old structure) made XLA write fresh ys
-    cache buffers, a full cache copy per decode step."""
-    b, t, h = x.shape
-    d = config.head_dim
-    xn = _rms(x, lp["ln1"], config.rms_norm_eps)
-    q = _mm_prefill(xn, lp["wq"]).reshape(b, t, -1, d)
-    k = _mm_prefill(xn, lp["wk"]).reshape(b, t, -1, d)
-    v = _mm_prefill(xn, lp["wv"]).reshape(b, t, -1, d)
-    q, k = rope_ops.apply_rope_array(q, k, cos, sin)
-    start = kv_len - t
-    kf = lax.dynamic_update_slice(kf, k.astype(kf.dtype)[None],
-                                  (l, 0, start, 0, 0))
-    vf = lax.dynamic_update_slice(vf, v.astype(vf.dtype)[None],
-                                  (l, 0, start, 0, 0))
-    kc = lax.dynamic_index_in_dim(kf, l, 0, keepdims=False)
-    vc = lax.dynamic_index_in_dim(vf, l, 0, keepdims=False)
-    attn = _cached_attention(q, kc, vc, kv_len, config)
-    x = x + _mm_prefill(attn.reshape(b, t, -1), lp["wo"]).astype(x.dtype)
-    xn = _rms(x, lp["ln2"], config.rms_norm_eps)
-    g = _mm_prefill(xn, lp["w_gate"])
-    u = _mm_prefill(xn, lp["w_up"])
-    x = x + _mm_prefill(jax.nn.silu(g) * u, lp["w_down"]).astype(x.dtype)
-    return x, kf, vf
-
-
-def prefill_stacked(params, ids, cache, config: LlamaConfig):
-    """Process the whole prompt, filling the cache.
-    ids: (B, T) int32 (pad to a bucket length for shape stability).
-    Returns (per-position logits (B, T, V), cache') — the caller picks the
-    last *real* prompt position (right-padding makes position T-1 a pad)."""
-    t = ids.shape[1]
-    s_max = cache["k"].shape[2]
-    cos_full, sin_full = rope_ops.build_rope_cache(s_max, config.head_dim,
-                                                   config.rope_theta)
-    x = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
-    kv_len = jnp.asarray(t, jnp.int32)
-
-    def body(carry, lp_l):
-        xc, kf, vf = carry
-        lp, l = lp_l
-        xo, kf, vf = _decoder_layer_cached_full(
-            lp, l, xc, cos_full[:t], sin_full[:t], kf, vf, kv_len, config)
-        # int8-quantized weights dequantize to f32; keep the carry dtype
-        return (xo.astype(xc.dtype), kf, vf), None
-
-    layer_params = {k: params[k] for k in LAYER_KEYS}
-    (x, k_new, v_new), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (layer_params, jnp.arange(config.num_hidden_layers)))
-    x = _rms(x, params["ln_f"], config.rms_norm_eps)
-    logits = jnp.einsum("bth,hv->btv", x, _dense(params["lm_head"]))
-    return logits, {"k": k_new, "v": v_new}
-
-
-def decode_step_stacked(params, tok, pos, cache, config: LlamaConfig):
-    """One generated token. tok: (B,) int32; pos: scalar int32 — absolute
-    position of ``tok`` (so kv_len becomes pos+1). Returns (logits, cache')."""
-    s_max = cache["k"].shape[2]
-    cos_full, sin_full = rope_ops.build_rope_cache(s_max, config.head_dim,
-                                                   config.rope_theta)
-    x = jnp.take(params["embed"], tok.astype(jnp.int32), axis=0)[:, None, :]
-    cos = lax.dynamic_slice_in_dim(cos_full, pos, 1, 0)
-    sin = lax.dynamic_slice_in_dim(sin_full, pos, 1, 0)
-    kv_len = pos + 1
-
-    def body(carry, lp_l):
-        xc, kf, vf = carry
-        lp, l = lp_l
-        xo, kf, vf = _decoder_layer_cached_full(lp, l, xc, cos, sin, kf, vf,
-                                                kv_len, config)
-        return (xo.astype(xc.dtype), kf, vf), None
-
-    layer_params = {k: params[k] for k in LAYER_KEYS}
-    (x, k_new, v_new), _ = lax.scan(
-        body, (x, cache["k"], cache["v"]),
-        (layer_params, jnp.arange(config.num_hidden_layers)))
-    x = _rms(x, params["ln_f"], config.rms_norm_eps)
-    logits = jnp.einsum("bh,hv->bv", x[:, 0], _dense(params["lm_head"]))
-    return logits, {"k": k_new, "v": v_new}
-
-
-# ===========================================================================
-# Paged KV-cache path (ragged serving batches; ops/paged_attention.py)
-# ===========================================================================
-def _paged_prefill_layer(carry, lp_l, *, config, b, t, cos, sin, phys,
-                         page_off, pool_p, attn_fn, scatter_first):
-    """One transformer layer of a paged prefill — the single body shared
-    by the full path (:func:`prefill_paged`) and the prefix-cache suffix
-    path (:func:`prefill_paged_suffix`). The two differ ONLY in attention
-    (in-prompt causal vs page-gather at offset positions — ``attn_fn``)
-    and in whether the K/V scatter must precede it (the suffix attends
-    THROUGH the pool, so its keys must land there first)."""
-    xc, kp, vp = carry
-    lp, l = lp_l
-    d = config.head_dim
-    xn = _rms(xc, lp["ln1"], config.rms_norm_eps)
-    q = _mm_prefill(xn, lp["wq"]).reshape(b, t, -1, d)
-    k = _mm_prefill(xn, lp["wk"]).reshape(b, t, -1, d)
-    v = _mm_prefill(xn, lp["wv"]).reshape(b, t, -1, d)
-    q, k = rope_ops.apply_rope_array(q, k, cos, sin)
-    if scatter_first:
-        kp = kp.at[phys + l * pool_p, page_off].set(k.astype(kp.dtype))
-        vp = vp.at[phys + l * pool_p, page_off].set(v.astype(vp.dtype))
-    attn = attn_fn(q, k, v, kp, vp, l)
-    xo = xc + _mm_prefill(attn.reshape(b, t, -1), lp["wo"]).astype(xc.dtype)
-    xn2 = _rms(xo, lp["ln2"], config.rms_norm_eps)
-    g = _mm_prefill(xn2, lp["w_gate"])
-    u = _mm_prefill(xn2, lp["w_up"])
-    xo = xo + jnp.einsum("btm,mh->bth", jax.nn.silu(g) * u,
-                         _dense(lp["w_down"]))
-    if not scatter_first:
-        # scatter this layer's K/V into its slab of the flat pool
-        kp = kp.at[phys + l * pool_p, page_off].set(k.astype(kp.dtype))
-        vp = vp.at[phys + l * pool_p, page_off].set(v.astype(vp.dtype))
-    # int8-quantized weights dequantize to f32; keep the carry dtype
-    return (xo.astype(xc.dtype), kp, vp), None
-
-
-def prefill_paged(params, ids, seq_lens, k_pages, v_pages, block_tables,
-                  config: LlamaConfig):
-    """Prefill a ragged batch into paged KV.
-
-    ids: (B, T) right-padded prompts; seq_lens: (B,) true lengths;
-    k_pages/v_pages: (L, P, page, nkv, d); block_tables: (B, max_pages),
-    padded slots pointing at reserved page 0.
-    Returns (logits (B, T, V), k_pages', v_pages').
-    """
-    b, t = ids.shape
-    page = k_pages.shape[2]
-    cos, sin = rope_ops.build_rope_cache(t, config.head_dim, config.rope_theta)
-    x = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
-
-    # scatter indices for every (b, t) slot: pad tokens land in page 0
-    tpos = jnp.arange(t)
-    page_idx = tpos[None, :] // page                      # (B, T)
-    page_off = tpos[None, :] % page
-    phys = jnp.take_along_axis(block_tables, page_idx, axis=1)  # (B, T)
-    valid = tpos[None, :] < seq_lens[:, None]
-    phys = jnp.where(valid, phys, 0)
-
-    # Pools travel FLAT (L*P, page, nkv, d) in the scan CARRY with
-    # per-layer page-id offsets l*P. Scanning them as xs->ys (the old
-    # structure) forced XLA to write fresh ys pool buffers — a full copy
-    # of both pools per call; carried scatters update in place. The
-    # manager reserves page 0, so every layer slab's page l*P+0 is the
-    # garbage page and padded block-table slots stay safe after offset.
-    n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
-    kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
-    vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
-
-    body = functools.partial(
-        _paged_prefill_layer, config=config, b=b, t=t, cos=cos, sin=sin,
-        phys=phys, page_off=page_off, pool_p=pool_p,
-        # causal attention within the (padded) prompt
-        attn_fn=lambda q, k, v, kp, vp, l: fa._sdpa_array(
-            q, k, v, scale=1.0 / math.sqrt(config.head_dim), causal=True),
-        scatter_first=False)
-    layer_params = {k: params[k] for k in LAYER_KEYS}
-    (x, kp_flat, vp_flat), _ = lax.scan(
-        body, (x, kp_flat, vp_flat),
-        (layer_params, jnp.arange(n_layers)))
-    x = _rms(x, params["ln_f"], config.rms_norm_eps)
-    logits = jnp.einsum("bth,hv->btv", x, _dense(params["lm_head"]))
-    return (logits, kp_flat.reshape(k_pages.shape),
-            vp_flat.reshape(v_pages.shape))
-
-
-def prefill_paged_suffix(params, ids, seq_lens, start_pos, k_pages, v_pages,
-                         block_tables, config: LlamaConfig):
-    """Prefill only the UNCACHED SUFFIX of a ragged batch into paged KV.
-
-    The prefix-cache path (paddle_tpu.kvcache): each row's leading
-    ``start_pos[b]`` tokens are already resident in shared pages reachable
-    through ``block_tables``, so only the suffix runs through the model.
-    Suffix queries sit at absolute positions ``start_pos + t`` — rope is
-    taken at those positions and attention runs over the gathered page
-    span (cached prefix + just-scattered suffix) with the
-    ``key_pos <= query_pos`` mask (ops.paged_attention.
-    paged_prefill_attention_array), not the in-prompt causal mask.
-
-    ids: (B, T) right-padded suffix tokens; seq_lens: (B,) true suffix
-    lengths; start_pos: (B,) cached-prefix lengths (0 = cold row);
-    k_pages/v_pages: (L, P, page, nkv, d); block_tables: (B, max_pages).
-    Returns (logits (B, T, V), k_pages', v_pages').
-    """
-    from ..ops import paged_attention as pa
-    b, t = ids.shape
-    page = k_pages.shape[2]
-    s_max = block_tables.shape[1] * page
-    cos_full, sin_full = rope_ops.build_rope_cache(s_max, config.head_dim,
-                                                   config.rope_theta)
-    x = jnp.take(params["embed"], ids.astype(jnp.int32), axis=0)
-
-    tpos = jnp.arange(t)
-    start_pos = start_pos.astype(jnp.int32)
-    # clamp: a padded suffix bucket may poke past the table span; those
-    # slots are invalid (masked below) but the gathers must stay in range
-    abs_pos = jnp.minimum(start_pos[:, None] + tpos[None, :], s_max - 1)
-    cos = jnp.take(cos_full, abs_pos, axis=0)             # (B, T, d)
-    sin = jnp.take(sin_full, abs_pos, axis=0)
-    page_idx = abs_pos // page                            # (B, T)
-    page_off = abs_pos % page
-    phys = jnp.take_along_axis(block_tables, page_idx, axis=1)
-    valid = tpos[None, :] < seq_lens[:, None]
-    phys = jnp.where(valid, phys, 0)                      # pads -> page 0
-
-    # flat-pool carry with per-layer page offsets — see prefill_paged
-    n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
-    kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
-    vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
-
-    body = functools.partial(
-        _paged_prefill_layer, config=config, b=b, t=t, cos=cos, sin=sin,
-        phys=phys, page_off=page_off, pool_p=pool_p,
-        # scatter the suffix K/V FIRST (scatter_first) so attention sees
-        # cached prefix + suffix through one page gather
-        attn_fn=lambda q, k, v, kp, vp, l: pa.paged_prefill_attention_array(
-            q, kp, vp, block_tables + l * pool_p, start_pos,
-            scale=1.0 / math.sqrt(config.head_dim)),
-        scatter_first=True)
-    layer_params = {k: params[k] for k in LAYER_KEYS}
-    (x, kp_flat, vp_flat), _ = lax.scan(
-        body, (x, kp_flat, vp_flat),
-        (layer_params, jnp.arange(n_layers)))
-    x = _rms(x, params["ln_f"], config.rms_norm_eps)
-    logits = jnp.einsum("bth,hv->btv", x, _dense(params["lm_head"]))
-    return (logits, kp_flat.reshape(k_pages.shape),
-            vp_flat.reshape(v_pages.shape))
-
-
 def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
                 k_pages, v_pages, block_tables, config: LlamaConfig,
                 mesh: Optional[Mesh] = None, mp_axis: str = "mp",
@@ -1257,8 +979,7 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
     cos_full, sin_full = rope_ops.build_rope_cache(s_max, config.head_dim,
                                                    config.rope_theta)
     # clamp: over-decoded tokens past the table span land in the last
-    # slot (their outputs are trimmed by the host, same as the legacy
-    # decode path's clipped take_along_axis)
+    # slot (their outputs are trimmed by the host)
     pos_c = jnp.minimum(positions.astype(jnp.int32), s_max - 1)
     cos = jnp.take(cos_full, pos_c, axis=0)[None]          # (1, T, d)
     sin = jnp.take(sin_full, pos_c, axis=0)[None]
@@ -1271,8 +992,12 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
     phys = jnp.take(block_tables.reshape(-1), row_c * width + page_idx)
     phys = jnp.where(valid, phys, 0)                       # pads -> page 0
 
-    # flat-pool carry with per-layer page offsets — see prefill_paged's
-    # structure note (pools as scan xs/ys would copy both pools per step)
+    # Pools travel FLAT (L*P, page, nkv, d) in the scan CARRY with
+    # per-layer page-id offsets l*P: as scan xs/ys XLA would write fresh
+    # pool buffers, a full copy of both pools per step; carried scatters
+    # update in place. The manager reserves page 0, so every layer slab's
+    # page l*P+0 is the garbage page and padded block-table slots stay
+    # safe after the offset.
     n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
     kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
     vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
@@ -1309,7 +1034,7 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
         (layer_params, jnp.arange(n_layers)))
     x = rms(x, params["ln_f"])
     # lm_head over ONLY each row's last token: (R, h) @ (h, V), not the
-    # full (T, V) logits the bucketed prefill paid for
+    # full (T, V) logits
     h_last = jnp.take(x[0], last_idx.astype(jnp.int32), axis=0)
     logits = jnp.einsum("rh,hv->rv", h_last, _dense(params["lm_head"]))
     if logits_epilogue is not None:
@@ -1317,60 +1042,5 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
         # mask of inference.constrain — applied BEFORE any sampling
         # epilogue so constrained rows renormalize over legal tokens)
         logits = logits_epilogue(logits)
-    return (logits, kp_flat.reshape(k_pages.shape),
-            vp_flat.reshape(v_pages.shape))
-
-
-def decode_step_paged(params, tok, positions, k_pages, v_pages, block_tables,
-                      config: LlamaConfig):
-    """One ragged decode step. tok: (B,); positions: (B,) absolute position
-    of each row's new token (may differ per row). Returns
-    (logits (B, V), k_pages', v_pages')."""
-    from ..ops import paged_attention as pa
-    b = tok.shape[0]
-    d = config.head_dim
-    s_max = block_tables.shape[1] * k_pages.shape[2]
-    cos_full, sin_full = rope_ops.build_rope_cache(s_max, config.head_dim,
-                                                   config.rope_theta)
-    x = jnp.take(params["embed"], tok.astype(jnp.int32), axis=0)[:, None, :]
-    cos = jnp.take(cos_full, positions, axis=0)[:, None, :]  # (B, 1, d)
-    sin = jnp.take(sin_full, positions, axis=0)[:, None, :]
-    kv_lens = positions + 1
-
-    # flat-pool carry with per-layer page offsets — see prefill_paged's
-    # structure note (pools as scan xs/ys would copy both pools per STEP,
-    # ~1.5 GB at serving scale; carried scatters are in place)
-    n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
-    kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
-    vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
-
-    def body(carry, lp_l):
-        xc, kp, vp = carry
-        lp, l = lp_l
-        bt_l = block_tables + l * pool_p
-        xn = _rms(xc, lp["ln1"], config.rms_norm_eps)
-        q = jnp.einsum("bth,hd->btd", xn, _dense(lp["wq"])).reshape(b, 1, -1, d)
-        k = jnp.einsum("bth,hd->btd", xn, _dense(lp["wk"])).reshape(b, 1, -1, d)
-        v = jnp.einsum("bth,hd->btd", xn, _dense(lp["wv"])).reshape(b, 1, -1, d)
-        q2, k2 = rope_ops.apply_rope_array(q, k, cos, sin)  # (B,1,d) 3-D form
-        kp, vp = pa.paged_write_array(kp, vp, k2[:, 0], v[:, 0],
-                                      bt_l, positions)
-        attn = pa.paged_attention(q2[:, 0], kp, vp, bt_l,
-                                  kv_lens, scale=1.0 / math.sqrt(d))
-        xo = xc + jnp.einsum("bd,dh->bh", attn.reshape(b, -1),
-                             _dense(lp["wo"]))[:, None, :]
-        xn2 = _rms(xo, lp["ln2"], config.rms_norm_eps)
-        g = _mm_prefill(xn2, lp["w_gate"])
-        u = _mm_prefill(xn2, lp["w_up"])
-        xo = xo + jnp.einsum("btm,mh->bth", jax.nn.silu(g) * u, _dense(lp["w_down"]))
-        # int8-quantized weights dequantize to f32; keep the carry dtype
-        return (xo.astype(xc.dtype), kp, vp), None
-
-    layer_params = {k: params[k] for k in LAYER_KEYS}
-    (x, kp_flat, vp_flat), _ = lax.scan(
-        body, (x, kp_flat, vp_flat),
-        (layer_params, jnp.arange(n_layers)))
-    x = _rms(x, params["ln_f"], config.rms_norm_eps)
-    logits = jnp.einsum("bh,hv->bv", x[:, 0], _dense(params["lm_head"]))
     return (logits, kp_flat.reshape(k_pages.shape),
             vp_flat.reshape(v_pages.shape))

@@ -171,6 +171,14 @@ def rebuild_fleet(head: Dict[str, Any], clock: ReplayClock, injector):
     replicas = []
     for spec in specs:
         e = spec.get("engine") or {}
+        if not e.get("unified", True):
+            # journals from before the bucketed pipeline went: it is
+            # another program, so its window cannot be re-executed
+            raise ReplayRefused(
+                "legacy_engine",
+                f"replica {spec.get('replica_id')} served through the "
+                "bucketed prefill/decode pipeline (\"unified\": false), "
+                "which this tree no longer has")
         eng = ContinuousBatchingEngine(
             cfg, GenerationConfig(**(spec.get("generation") or {})),
             num_slots=int(e["num_slots"]), page_size=int(e["page_size"]),
@@ -178,8 +186,7 @@ def rebuild_fleet(head: Dict[str, Any], clock: ReplayClock, injector):
             num_pages=int(e["num_pages"]), chunk=int(e["chunk"]),
             prefix_cache=bool(e.get("prefix_cache", False)),
             speculative=bool(e.get("speculative", False)),
-            spec_k=int(e.get("spec_k") or 4),
-            unified=bool(e.get("unified", True)))
+            spec_k=int(e.get("spec_k") or 4))
         replicas.append(ReplicaHandle(
             int(spec["replica_id"]), eng,
             config=SchedulerConfig(**(spec.get("scheduler") or {})),

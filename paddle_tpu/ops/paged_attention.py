@@ -8,17 +8,15 @@ so ragged batches don't reserve max_len × batch HBM and finished sequences
 return pages to the pool immediately (vLLM-style, and the layout of the
 TPU ragged-paged-attention kernels referenced in PAPERS.md).
 
-Two compute paths behind one dispatcher (:func:`paged_attention`):
-
-* XLA fallback — gather of the sequence's pages + masked softmax, fused by
-  XLA; runs everywhere (CPU tests included).
-* Pallas kernel (:func:`paged_attention_pallas`) — the block table rides
-  scalar prefetch, each grid step streams exactly ONE physical page
-  HBM→VMEM (Mosaic double-buffers consecutive steps), online-softmax
-  accumulation in VMEM scratch. HBM traffic is precisely the pages each
-  sequence owns — the point of paging on a bandwidth-bound decode. (The
-  serving engine's kernel, :func:`ragged_paged_attention_pallas`, folds a
-  block of a row's pages a step.)
+One kernel serves every row, :func:`ragged_paged_attention` (a decode
+row is a row of one token): the Pallas kernel
+(:func:`ragged_paged_attention_pallas`) on TPU — block tables ride scalar
+prefetch, each grid step folds a block of one row's pages HBM→VMEM with
+online-softmax accumulation in VMEM scratch, so HBM traffic is the pages
+each sequence owns — and its XLA reference
+(:func:`ragged_paged_attention_array`: page gather + masked softmax)
+everywhere else, CPU tests included. :func:`paged_attention_array` is the
+decode-shaped reference the tests hold the ragged composition to.
 """
 
 from __future__ import annotations
@@ -68,8 +66,7 @@ def paged_attention_array(q, k_pages, v_pages, block_tables, seq_lens,
     if rep > 1:
         # grouped attention without materializing repeated KV (a
         # jnp.repeat here streamed rep x the gathered cache bytes — the
-        # exact bandwidth GQA exists to save; same fix as
-        # models/llama._cached_attention, round 5)
+        # exact bandwidth GQA exists to save)
         qg = q.reshape(b, nkv, rep, d)
         scores = jnp.einsum("bgrd,bsgd->bgrs", qg.astype(jnp.float32),
                             k.astype(jnp.float32)) * s
@@ -99,57 +96,6 @@ def paged_write_array(k_pages, v_pages, k_new, v_new, block_tables, positions):
     return k_pages, v_pages
 
 
-def paged_prefill_attention_array(q, k_pages, v_pages, block_tables, q_start,
-                                  scale: Optional[float] = None):
-    """Chunked/suffix prefill attention over paged KV.
-
-    The prefix-cache path (paddle_tpu.kvcache): a request whose leading
-    tokens are already resident in shared pages prefills only its suffix.
-    The suffix queries sit at absolute positions ``q_start + t`` and must
-    attend to BOTH the cached prefix pages and the suffix's own (already
-    scattered) K/V — so unlike the in-prompt causal mask of the full
-    prefill, the mask here is ``key_pos <= q_start + t`` over the gathered
-    page span.
-
-    q:            (B, T, nh, d)  — suffix queries (right-padded)
-    k_pages:      (P, page, nkv, d) — page pool (suffix K/V already written)
-    v_pages:      (P, page, nkv, d)
-    block_tables: (B, max_pages) int32 (pad: 0, the reserved garbage page)
-    q_start:      (B,) int32 — absolute position of each row's first query
-    Returns (B, T, nh, d).
-    """
-    b, t, nh, d = q.shape
-    page = k_pages.shape[1]
-    nkv = k_pages.shape[2]
-    max_pages = block_tables.shape[1]
-    rep = nh // nkv
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-
-    k = jnp.take(k_pages, block_tables, axis=0)     # (B, max_pages, page, ..)
-    v = jnp.take(v_pages, block_tables, axis=0)
-    k = k.reshape(b, max_pages * page, nkv, d)
-    v = v.reshape(b, max_pages * page, nkv, d)
-
-    q_pos = q_start[:, None] + jnp.arange(t)[None, :]          # (B, T)
-    mask = (jnp.arange(max_pages * page)[None, None, :]
-            <= q_pos[:, :, None])                              # (B, T, S)
-    if rep > 1:
-        # grouped attention without materializing repeated KV (same
-        # bandwidth argument as paged_attention_array)
-        qg = q.reshape(b, t, nkv, rep, d)
-        scores = jnp.einsum("btgrd,bsgd->bgrts", qg.astype(jnp.float32),
-                            k.astype(jnp.float32)) * s
-        scores = jnp.where(mask[:, None, None], scores, _NEG_INF)
-        probs = jax.nn.softmax(scores, axis=-1)
-        out = jnp.einsum("bgrts,bsgd->btgrd", probs.astype(v.dtype), v)
-        return out.reshape(b, t, nh, d)
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        k.astype(jnp.float32)) * s
-    scores = jnp.where(mask[:, None], scores, _NEG_INF)
-    probs = jax.nn.softmax(scores, axis=-1)
-    return jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v)
-
-
 # ---------------------------------------------------------------------------
 # Ragged paged attention: ONE program for mixed prefill+decode rows
 # ---------------------------------------------------------------------------
@@ -167,9 +113,10 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
         key_pos <= positions[t]            (self-inclusive causality)
 
     A decode token at absolute position p sees keys [0, p] — exactly
-    ``paged_attention``'s ``pos < kv_len`` with ``kv_len = p+1``; a
-    prefill token at p sees the cached/scattered prefix plus itself —
-    exactly ``paged_prefill_attention_array``'s ``key_pos <= q_start+t``.
+    ``paged_attention_array``'s ``pos < kv_len`` with ``kv_len = p+1``;
+    a prefill token at p sees the cached/scattered prefix plus itself
+    (``key_pos <= q_start + t`` for a row whose chunk starts at
+    ``q_start``).
 
     q:            (T, nh, d)   — packed queries (pad slots: token_row -1)
     k_pages:      (P, page, nkv, d)
@@ -851,129 +798,3 @@ class PagedKVCacheManager:
             bt[i, :len(t)] = t
         lens = np.asarray([self._lens[s] for s in seq_ids], np.int32)
         return bt, lens
-
-
-# ---------------------------------------------------------------------------
-# Pallas decode kernel (TPU): double-buffered page fetch via scalar-prefetched
-# block tables — the ragged-paged-attention pattern (PAPERS.md)
-# ---------------------------------------------------------------------------
-def _paged_decode_kernel(block_tables_ref, seq_lens_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, page: int,
-                         n_pages: int, scale: float, nh: int, nkv: int,
-                         d: int):
-    b = pl.program_id(0)
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    seq_len = seq_lens_ref[b]
-    # skip pages entirely beyond this sequence's length
-    run = j * page < seq_len
-
-    @pl.when(run)
-    def _compute():
-        rep = nh // nkv
-        q = q_ref[0].astype(jnp.float32)            # (nh, d)
-        k = k_ref[0].astype(jnp.float32)            # (page, nkv, d)
-        v = v_ref[0].astype(jnp.float32)
-        qg = q.reshape(nkv, rep, d)
-        # Mosaic's batched matmul requires the batch dim LEADING on both
-        # operands ("batch dims must be equal" otherwise — round-2 chip
-        # finding), so bring kv heads to the front first.
-        kt = k.swapaxes(0, 1)                       # (nkv, page, d)
-        vt = v.swapaxes(0, 1)                       # (nkv, page, d)
-        # (nkv, rep, d) x (nkv, page, d) -> (nkv, rep, page)
-        s = jax.lax.dot_general(
-            qg, kt, (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32) * scale
-        pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (nkv, rep, page), 2)
-        s = jnp.where(pos < seq_len, s, _NEG_INF)
-        s2 = s.reshape(nh, page)
-        m_prev = m_ref[:, :1]
-        l_prev = l_ref[:, :1]
-        m_cur = jnp.max(s2, axis=-1, keepdims=True)
-        m_new = jnp.maximum(m_prev, m_cur)
-        alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s2 - m_new)                     # (nh, page)
-        l_ref[...] = jnp.broadcast_to(
-            alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True), l_ref.shape)
-        pg = p.reshape(nkv, rep, page)
-        # (nkv, rep, page) x (nkv, page, d) -> (nkv, rep, d)
-        pv = jax.lax.dot_general(
-            pg, vt, (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)
-        acc_ref[...] = acc_ref[...] * alpha + pv.reshape(nh, d)
-        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
-
-    @pl.when(j == n_pages - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = (acc_ref[...] / safe_l).astype(o_ref.dtype)
-
-
-def paged_attention_pallas(q, k_pages, v_pages, block_tables, seq_lens,
-                           scale: Optional[float] = None,
-                           interpret: bool = False):
-    """Pallas decode kernel: same contract as paged_attention_array.
-
-    Each grid step fetches ONE physical page via the scalar-prefetched
-    block table (Mosaic double-buffers the HBM→VMEM stream), so HBM
-    traffic is exactly the pages each sequence owns — the fused
-    gather+softmax the XLA fallback approximates.
-    """
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    b, nh, d = q.shape
-    page = k_pages.shape[1]
-    nkv = k_pages.shape[2]
-    max_pages = block_tables.shape[1]
-    s = scale if scale is not None else 1.0 / math.sqrt(d)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,  # block_tables, seq_lens
-        grid=(b, max_pages),
-        in_specs=[
-            pl.BlockSpec((1, nh, d), lambda bi, j, bt, sl: (bi, 0, 0)),
-            pl.BlockSpec((1, page, nkv, d),
-                         lambda bi, j, bt, sl: (bt[bi, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, nkv, d),
-                         lambda bi, j, bt, sl: (bt[bi, j], 0, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, nh, d), lambda bi, j, bt, sl: (bi, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, 128), jnp.float32),
-            pltpu.VMEM((nh, d), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _paged_decode_kernel, page=page, n_pages=max_pages, scale=s,
-        nh=nh, nkv=nkv, d=d)
-    return pl.pallas_call(
-        kernel,
-        name="paged_attention",
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, nh, d), v_pages.dtype),
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32), seq_lens.astype(jnp.int32),
-      q, k_pages, v_pages)
-
-
-def paged_attention(q, k_pages, v_pages, block_tables, seq_lens,
-                    scale: Optional[float] = None):
-    """Dispatcher: Pallas kernel on TPU (FLAGS_use_pallas_kernels), XLA
-    gather fallback elsewhere. Same contract as paged_attention_array."""
-    from ._common import use_pallas
-    if use_pallas():
-        return paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                      seq_lens, scale)
-    return paged_attention_array(q, k_pages, v_pages, block_tables,
-                                 seq_lens, scale)

@@ -198,7 +198,6 @@ class ReplicaHandle:
                 "prefix_cache": eng.cache is not None,
                 "speculative": eng._speculative,
                 "spec_k": eng.spec_k,
-                "unified": eng._unified,
             },
             "generation": asdict(eng.config),
             "scheduler": asdict(self._scheduler.config),

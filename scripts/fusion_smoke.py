@@ -51,8 +51,7 @@ def main() -> int:
     def engine(fused=False):
         return ContinuousBatchingEngine(
             cfg, GenerationConfig(max_new_tokens=8), num_slots=2,
-            page_size=4, max_seq_len=64, chunk=3, unified=True,
-            fused_tail=fused)
+            page_size=4, max_seq_len=64, chunk=3, fused_tail=fused)
 
     # 1. profile the CPU smoke ------------------------------------------------
     telemetry.enable()

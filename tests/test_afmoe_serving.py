@@ -12,8 +12,7 @@ import numpy as np
 import pytest
 
 from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
-                                           GenerationConfig,
-                                           PagedGenerationEngine)
+                                           GenerationConfig)
 from paddle_tpu.models import afmoe as A
 from paddle_tpu.models import llama as L
 from paddle_tpu.ops import moe_ops
@@ -21,6 +20,7 @@ from paddle_tpu.ops import paged_attention as pa
 from paddle_tpu.serving import ServingScheduler
 from perfbench import harness
 
+from _oracle import assert_greedy
 from test_unified_step import _BLOCKS, _RAGGED_CASES, _packed_case
 
 adapter = harness.load_module("perfbench/adapters/serve_afmoe.py")
@@ -439,21 +439,42 @@ def test_param_count_and_bytes_match_the_weights():
     assert A.kv_geometry(full, 16)["num_kv_heads"] == 4
 
 
-def test_engine_refuses_what_afmoe_cannot_do():
+def _afmoe_over_a_mesh(monkeypatch):
     from paddle_tpu.parallel.mesh import serving_mesh
+    return A.afmoe_tiny(), dict(mesh=serving_mesh(2, jax.devices()[:2]))
+
+
+def _module_without_its_step(monkeypatch):
+    """A model module that has every name of the engine's protocol but
+    ``ragged_step`` and ``shard_params_tp``, named by its config's class
+    as ``models.afmoe`` is by ``AfmoeConfig``."""
+    import sys
+    import types
+    half = types.ModuleType("half_a_model")
+    half.init_stacked_params = A.init_stacked_params
+    half.serving_param_specs = A.serving_param_specs
+    monkeypatch.setitem(sys.modules, half.__name__, half)
     cfg = A.afmoe_tiny()
-    with pytest.raises(ValueError, match="replicates every weight"):
-        ContinuousBatchingEngine(cfg, num_slots=2, page_size=4,
-                                 max_seq_len=32,
-                                 mesh=serving_mesh(2, jax.devices()[:2]))
-    with pytest.raises(ValueError, match="unified ragged step only"):
-        ContinuousBatchingEngine(cfg, num_slots=2, page_size=4,
-                                 max_seq_len=32, unified=False)
-    with pytest.raises(ValueError, match="Llama family's programs only"):
-        PagedGenerationEngine(cfg)
+    cfg.serving_module = half.__name__
+    return cfg, {}
 
 
-def test_llama_engine_is_what_it_was_after_the_model_lookup():
+@pytest.mark.parametrize("build,message", [
+    (_afmoe_over_a_mesh, "replicates every weight"),
+    (_module_without_its_step,
+     "half_a_model cannot be served.*requires ragged_step, shard_params_tp"),
+], ids=["afmoe_over_a_mesh", "module_lacks_protocol_names"])
+def test_engine_refuses_at_construction(build, message, monkeypatch):
+    """What a model cannot do is refused when the engine is built, by name:
+    afmoe over a mesh (it replicates every weight), and a model module that
+    lacks a REQUIRED name of the protocol ``_serving_module`` states."""
+    cfg, kw = build(monkeypatch)
+    with pytest.raises(ValueError, match=message):
+        ContinuousBatchingEngine(cfg, num_slots=2, page_size=4,
+                                 max_seq_len=32, **kw)
+
+
+def test_llama_is_served_as_it_was_before_the_model_lookup():
     """The lookup hands a LlamaConfig ``models.llama``, the step returns
     Llama's three values, and the tiny engine's greedy output is the greedy
     full re-forward's, token for token."""
@@ -470,9 +491,4 @@ def test_llama_engine_is_what_it_was_after_the_model_lookup():
     while eng.num_queued or eng._live:
         eng.step(params)
     done = eng.collect()
-    for rid, prompt in zip(rids, prompts):
-        ids = list(prompt)
-        for _ in range(6):
-            logits = L.forward_stacked(params, jnp.asarray([ids]), cfg)
-            ids.append(int(jnp.argmax(logits[0, -1])))
-        assert list(done[rid]) == ids[len(prompt):]
+    assert_greedy(params, cfg, prompts, [done[r] for r in rids], n_new=6)

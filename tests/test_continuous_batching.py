@@ -5,11 +5,12 @@ engine (paddle/fluid/inference/api/analysis_predictor.cc:§0)."""
 
 import numpy as np
 import pytest
-import jax.numpy as jnp
 
 from paddle_tpu.models import llama as L
 from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                            GenerationConfig)
+
+from _oracle import greedy_reforward as _greedy_ref
 
 
 def _setup(max_new=6, num_slots=2, eos=None, seed=3):
@@ -19,18 +20,6 @@ def _setup(max_new=6, num_slots=2, eos=None, seed=3):
         cfg, GenerationConfig(max_new_tokens=max_new, eos_token_id=eos),
         num_slots=num_slots, page_size=4, max_seq_len=32, chunk=3)
     return cfg, params, eng
-
-
-def _greedy_ref(params, cfg, prompt, n_new):
-    """Oracle: argmax over full re-forward each step."""
-    seq = np.asarray(prompt, np.int32)[None, :]
-    out = []
-    for _ in range(n_new):
-        logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-        nxt = int(np.asarray(jnp.argmax(logits[0, -1].astype(jnp.float32))))
-        out.append(nxt)
-        seq = np.concatenate([seq, [[nxt]]], axis=1).astype(np.int32)
-    return out
 
 
 def test_streams_3x_slots_with_correct_outputs():

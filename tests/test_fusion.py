@@ -45,7 +45,7 @@ def _engine(fused, prefix_cache=False, speculative=False, max_new=6,
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=max_new),
         num_slots=num_slots, page_size=4, max_seq_len=64, chunk=chunk,
-        prefix_cache=prefix_cache, unified=True, fused_tail=fused,
+        prefix_cache=prefix_cache, fused_tail=fused,
         speculative=speculative, **kw)
     return cfg, eng
 
@@ -156,18 +156,23 @@ def test_apply_installs_on_duck_typed_targets():
 
 
 def test_apply_on_rejecting_target_skips_never_raises():
-    """The degradation contract covers installation: a non-unified
-    engine REJECTS the fused tail (ValueError) — apply() turns that
-    into a target-unsupported skip instead of propagating."""
+    """The degradation contract covers installation: a target that
+    REJECTS the fused tail (ValueError from ``enable_fused_tail``) —
+    apply() turns that into a target-unsupported skip instead of
+    propagating."""
     doc = _artifact([("cbe.unified_step", "cbe.decode_tail")])
     plan = F.FusionPass().plan(doc)
-    cfg = L.llama_tiny(num_hidden_layers=2)
-    legacy = ContinuousBatchingEngine(
-        cfg, GenerationConfig(max_new_tokens=4), num_slots=2,
-        page_size=4, max_seq_len=64, chunk=3, unified=False)
-    installed = plan.apply(engine=legacy)
+
+    class Rejecting:
+        _fused_tail = False
+
+        def enable_fused_tail(self):
+            raise ValueError("this engine has no decode tail to fuse")
+
+    target = Rejecting()
+    installed = plan.apply(engine=target)
     assert installed == {}
-    assert not legacy._fused_tail
+    assert not target._fused_tail
     snap = get_registry().snapshot()
     fam = snap.get("paddle_fusion_skipped_total", {})
     assert any("target-unsupported" in k for k in fam)
@@ -301,8 +306,7 @@ def test_fused_tail_recompile_neutral_across_storm():
         before = recompiles.count("cbe.unified_step")
         eng = ContinuousBatchingEngine(
             cfg, GenerationConfig(max_new_tokens=5), num_slots=2,
-            page_size=4, max_seq_len=64, chunk=3, unified=True,
-            fused_tail=fused)
+            page_size=4, max_seq_len=64, chunk=3, fused_tail=fused)
         prompts = _prompts(cfg, _STORM_LENS)
         rids = [eng.submit(p) for p in prompts[:3]]
         done = {}

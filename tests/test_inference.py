@@ -5,12 +5,13 @@ zero-copy handles, and KV-cache generation parity vs full re-forward
 import numpy as np
 import pytest
 
-import jax
 import jax.numpy as jnp
 
 import paddle_tpu as paddle
 from paddle_tpu import inference, jit, nn
 from paddle_tpu.static import InputSpec
+
+from _oracle import assert_greedy
 
 pytestmark = pytest.mark.slow  # core tier: -m 'not slow'
 
@@ -60,39 +61,34 @@ def test_predictor_direct_run_api(tmp_path):
     assert len(outs) == 1 and outs[0].shape == (2, 3)
 
 
+def _serve_tiny(cfg, params, prompts, gen):
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    eng = ContinuousBatchingEngine(cfg, gen, num_slots=2, page_size=4,
+                                   max_seq_len=32, chunk=3)
+    return eng.serve(params, list(prompts))
+
+
 def test_generation_matches_full_reforward():
-    """Greedy KV-cache generation == argmax over full re-forward each step."""
+    """Greedy paged-KV generation == argmax over full re-forward each step."""
     from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import GenerationConfig, llama_engine
+    from paddle_tpu.inference.decoding import GenerationConfig
 
     cfg = L.llama_tiny(num_hidden_layers=2)
     params = L.init_stacked_params(cfg, seed=3)
     rng = np.random.RandomState(0)
     B, T, NEW = 2, 5, 6
     prompt = rng.randint(1, cfg.vocab_size, (B, T)).astype(np.int32)
-
-    engine = llama_engine(cfg, GenerationConfig(max_new_tokens=NEW))
-    out = engine.generate(params, prompt)
-    assert out.shape == (B, NEW)
-
-    # oracle: recompute the full forward over the growing sequence
-    seq = prompt.copy()
-    ref_tokens = []
-    for _ in range(NEW):
-        logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-        nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32), -1))
-        ref_tokens.append(nxt)
-        seq = np.concatenate([seq, nxt[:, None].astype(np.int32)], axis=1)
-    ref = np.stack(ref_tokens, axis=1)
-    np.testing.assert_array_equal(out, ref)
+    out = _serve_tiny(cfg, params, prompt,
+                      GenerationConfig(max_new_tokens=NEW))
+    assert_greedy(params, cfg, prompt, out, n_new=NEW)
 
 
 def test_generation_gqa_matches_full_reforward():
     """VERDICT r4 missing #4b: the serving path with GQA (nkv = nh/2) —
-    cached generation == full re-forward argmax, so the grouped KV cache
-    and head-repeat attention are token-exact."""
+    cached generation == full re-forward argmax, so the grouped KV pool
+    and grouped-query attention are token-exact."""
     from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import GenerationConfig, llama_engine
+    from paddle_tpu.inference.decoding import GenerationConfig
 
     cfg = L.llama_tiny(num_hidden_layers=2, num_key_value_heads=2)
     assert cfg.num_attention_heads == 4
@@ -100,23 +96,16 @@ def test_generation_gqa_matches_full_reforward():
     rng = np.random.RandomState(1)
     B, T, NEW = 2, 5, 6
     prompt = rng.randint(1, cfg.vocab_size, (B, T)).astype(np.int32)
-    engine = llama_engine(cfg, GenerationConfig(max_new_tokens=NEW))
-    out = engine.generate(params, prompt)
-
-    seq = prompt.copy()
-    ref_tokens = []
-    for _ in range(NEW):
-        logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-        nxt = np.asarray(jnp.argmax(logits[:, -1].astype(jnp.float32), -1))
-        ref_tokens.append(nxt)
-        seq = np.concatenate([seq, nxt[:, None].astype(np.int32)], axis=1)
-    np.testing.assert_array_equal(out, np.stack(ref_tokens, axis=1))
+    out = _serve_tiny(cfg, params, prompt,
+                      GenerationConfig(max_new_tokens=NEW))
+    assert_greedy(params, cfg, prompt, out, n_new=NEW)
 
 
 def test_a8w8_prefill_close_to_weight_only():
     """VERDICT r4 missing #4a: int8 A8W8 prefill (int8xint8->int32 with
     per-token activation scales) tracks the weight-only dequant prefill
-    closely; decode (t=1) stays on the weight-only path by construction."""
+    closely: every position's logits of ``ragged_step`` on one prefill
+    row, ``FLAGS_serving_a8w8_prefill`` off against on."""
     import paddle_tpu as paddle
     from paddle_tpu.models import llama as L
     from paddle_tpu.quantization import quantize_stacked_params
@@ -125,35 +114,44 @@ def test_a8w8_prefill_close_to_weight_only():
     params = L.init_stacked_params(cfg, seed=7)
     qparams = quantize_stacked_params(params)
     rng = np.random.RandomState(2)
-    ids = rng.randint(1, cfg.vocab_size, (2, 12)).astype(np.int32)
-    cache = L.init_kv_cache(cfg, 2, 32)
+    T, PAGE = 12, 4
+    ids = rng.randint(1, cfg.vocab_size, (T,)).astype(np.int32)
+    pool = (cfg.num_hidden_layers, 1 + T // PAGE, PAGE,
+            cfg.num_key_value_heads, cfg.head_dim)
+    at = jnp.arange(T, dtype=jnp.int32)
+
+    def prefill_logits():
+        # row 0 owns pages 1..3; logits taken at EVERY position
+        return np.asarray(L.ragged_step(
+            qparams, jnp.asarray(ids), jnp.zeros((T,), jnp.int32), at,
+            jnp.asarray([T], jnp.int32), at, jnp.zeros(pool, jnp.float32),
+            jnp.zeros(pool, jnp.float32),
+            jnp.asarray([[1, 2, 3]], jnp.int32), cfg)[0].astype(jnp.float32))
 
     paddle.set_flags({"FLAGS_serving_a8w8_prefill": 0})
     try:
-        lo, _ = L.prefill_stacked(qparams, jnp.asarray(ids), cache, cfg)
+        lo = prefill_logits()
     finally:
         paddle.set_flags({"FLAGS_serving_a8w8_prefill": 1})
-    cache2 = L.init_kv_cache(cfg, 2, 32)
-    hi, _ = L.prefill_stacked(qparams, jnp.asarray(ids), cache2, cfg)
-    lo = np.asarray(lo.astype(jnp.float32))
-    hi = np.asarray(hi.astype(jnp.float32))
+    hi = prefill_logits()
+    assert lo.shape == hi.shape == (T, cfg.vocab_size)
+    assert np.abs(hi - lo).max() > 0            # the flag picks a program
     rel = np.abs(hi - lo).max() / (np.abs(lo).max() + 1e-9)
     assert rel < 0.05, rel
     # greedy last-token picks agree on the tiny model
-    np.testing.assert_array_equal(lo[:, -1].argmax(-1), hi[:, -1].argmax(-1))
+    assert lo[-1].argmax() == hi[-1].argmax()
 
 
 def test_generation_sampling_shapes():
     from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import GenerationConfig, llama_engine
+    from paddle_tpu.inference.decoding import GenerationConfig
 
     cfg = L.llama_tiny(num_hidden_layers=1)
     params = L.init_stacked_params(cfg, seed=0)
-    engine = llama_engine(cfg, GenerationConfig(
-        max_new_tokens=4, do_sample=True, temperature=0.8, top_k=8,
-        top_p=0.9, seed=11))
-    prompt = np.array([[5, 6, 7]], np.int32)
-    out = engine.generate(params, prompt)
+    out = np.asarray(_serve_tiny(
+        cfg, params, np.array([[5, 6, 7]], np.int32),
+        GenerationConfig(max_new_tokens=4, do_sample=True, temperature=0.8,
+                         top_k=8, top_p=0.9, seed=11)))
     assert out.shape == (1, 4)
     assert (out >= 0).all() and (out < cfg.vocab_size).all()
 

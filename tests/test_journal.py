@@ -247,17 +247,35 @@ def test_first_divergence_ignores_transport_fields_and_localizes():
 # replay refusals for structurally un-replayable windows
 # ---------------------------------------------------------------------------
 
-def test_replay_refuses_scale_and_handoff_windows():
-    scale = {"t": "scale", "seq": 1, "step": 2, "scale_seq": 1,
-             "action": "scale_up", "reason": "queue", "replica": None,
-             "role": None}
-    rep = replay_journal(decode_journal(_journal_bytes([scale])))
-    assert rep.refused["code"] == "topology_changed"
+_LEGACY_HEAD = {
+    "model": model_spec(CFG, SEED),
+    "fleet": {"router_kind": "FleetRouter", "replicas": [{
+        "replica_id": 0,
+        "engine": {"num_slots": 2, "page_size": 4, "chunk": 3,
+                   "max_seq_len": 32, "num_pages": 17, "unified": False}}]}}
 
-    handoff = {"t": "handoff", "seq": 1, "step": 2, "rid": 0, "src": 0,
-               "dst": 1, "pages": 3, "outcome": "ok"}
-    rep = replay_journal(decode_journal(_journal_bytes([handoff])))
-    assert rep.refused["code"] == "disagg"
+_UNREPLAYABLE = {
+    "scale": ([{"t": "scale", "seq": 1, "step": 2, "scale_seq": 1,
+                "action": "scale_up", "reason": "queue", "replica": None,
+                "role": None}], None, "topology_changed", "scale_up"),
+    "handoff": ([{"t": "handoff", "seq": 1, "step": 2, "rid": 0, "src": 0,
+                  "dst": 1, "pages": 3, "outcome": "ok"}], None, "disagg",
+                "handoffs"),
+    # a journal written when the engine still had its bucketed pipeline
+    # is recovery data for ANOTHER program: refused, never replayed
+    # through the ragged step
+    "legacy_engine": ([_step_frame(1, 1)], _LEGACY_HEAD, "legacy_engine",
+                      '"unified": false'),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_UNREPLAYABLE))
+def test_replay_refuses_unreplayable_windows(case):
+    frames, head, code, reason = _UNREPLAYABLE[case]
+    rep = replay_journal(decode_journal(_journal_bytes(frames, head)))
+    assert not rep.ok
+    assert rep.refused["code"] == code
+    assert reason in rep.refused["detail"]
 
 
 def test_replay_refuses_bundle_without_journal(tmp_path):

@@ -147,12 +147,6 @@ def test_pool_head_sharding_and_validation():
             CFG, GenerationConfig(max_new_tokens=4), num_slots=2,
             page_size=4, max_seq_len=32,
             mesh=serving_mesh(3))
-    # multi-chip requires the unified step
-    with pytest.raises(ValueError, match="unified"):
-        ContinuousBatchingEngine(
-            CFG, GenerationConfig(max_new_tokens=4), num_slots=2,
-            page_size=4, max_seq_len=32, unified=False,
-            mesh=serving_mesh(2))
 
 
 def test_mesh_resize_helpers():

@@ -104,10 +104,10 @@ def test_manager_exhaustion():
 
 def test_ragged_paged_generation_matches_reforward():
     """Paged ragged generation == per-row full re-forward greedy decode."""
-    import jax
     from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import (GenerationConfig,
-                                               PagedGenerationEngine)
+    from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
+                                               GenerationConfig)
+    from _oracle import assert_greedy
 
     cfg = L.llama_tiny(num_hidden_layers=2)
     params = L.init_stacked_params(cfg, seed=9)
@@ -115,43 +115,8 @@ def test_ragged_paged_generation_matches_reforward():
     prompts = [rng.randint(1, cfg.vocab_size, n).astype(np.int32)
                for n in (3, 6, 5)]
     NEW = 4
-    eng = PagedGenerationEngine(cfg, GenerationConfig(max_new_tokens=NEW),
-                                page_size=4)
-    out = eng.generate(params, prompts)
-    assert out.shape == (3, NEW)
-
-    for b, p in enumerate(prompts):
-        seq = p[None, :].copy()
-        for j in range(NEW):
-            logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-            nxt = int(np.asarray(jnp.argmax(
-                logits[0, -1].astype(jnp.float32))))
-            assert nxt == out[b, j], (b, j, nxt, out[b].tolist())
-            seq = np.concatenate(
-                [seq, np.array([[nxt]], np.int32)], axis=1)
-
-
-def test_pallas_kernel_matches_fallback_interpret():
-    """The Pallas paged decode kernel (interpret mode on CPU) must match
-    the XLA gather fallback bit-for-bit-ish on a ragged batch with GQA."""
-    rng = np.random.RandomState(11)
-    PAGE, NPAGES, NKV, NH, D = 4, 16, 2, 4, 8
-    lens = [7, 13, 1, 16]
-    B = len(lens)
-    mgr = pa.PagedKVCacheManager(1, NPAGES, PAGE, NKV, D, dtype=jnp.float32)
-    k_pool = np.zeros((NPAGES, PAGE, NKV, D), np.float32)
-    v_pool = np.zeros((NPAGES, PAGE, NKV, D), np.float32)
-    for sid, L in enumerate(lens):
-        pages = mgr.allocate(sid, L)
-        for t in range(L):
-            k_pool[pages[t // PAGE], t % PAGE] = rng.randn(NKV, D)
-            v_pool[pages[t // PAGE], t % PAGE] = rng.randn(NKV, D)
-    bt, seq_lens = mgr.block_tables(list(range(B)))
-    q = rng.randn(B, NH, D).astype(np.float32)
-    ref = np.asarray(pa.paged_attention_array(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(bt), jnp.asarray(seq_lens)))
-    out = np.asarray(pa.paged_attention_pallas(
-        jnp.asarray(q), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(bt), jnp.asarray(seq_lens), interpret=True))
-    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=NEW), num_slots=3, page_size=4,
+        max_seq_len=16)
+    out = eng.serve(params, prompts)
+    assert_greedy(params, cfg, prompts, out, n_new=NEW)

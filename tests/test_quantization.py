@@ -81,18 +81,21 @@ def test_quantize_stacked_params():
 
 
 def test_quantized_params_drive_generation():
-    """The serving paths consume the {"q","scale"} format directly: greedy
-    generation from int8-stored weights matches fp32 (weight error <1%)."""
+    """The serving path consumes the {"q","scale"} format directly: greedy
+    generation from int8-stored weights matches the fp32 oracle's (weight
+    error <1%)."""
     from paddle_tpu.models import llama as L
-    from paddle_tpu.inference.decoding import GenerationConfig, llama_engine
+    from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
+                                               GenerationConfig)
+    from _oracle import greedy_reforward
     cfg = L.llama_tiny(num_hidden_layers=2)
     params = L.init_stacked_params(cfg, seed=4)
     qp = quantize_stacked_params(params)
-    prompt = np.array([[3, 1, 4, 1, 5]], np.int32)
-    t_fp = llama_engine(cfg, GenerationConfig(max_new_tokens=6)) \
-        .generate(params, prompt)
-    t_q = llama_engine(cfg, GenerationConfig(max_new_tokens=6)) \
-        .generate(qp, prompt)
+    prompt = np.array([3, 1, 4, 1, 5], np.int32)
+    t_fp = np.asarray(greedy_reforward(params, cfg, prompt, 6))
+    eng = ContinuousBatchingEngine(cfg, GenerationConfig(max_new_tokens=6),
+                                   num_slots=1, page_size=4, max_seq_len=16)
+    t_q = np.asarray(eng.serve(qp, [prompt])[0])
     assert (t_fp == t_q).mean() >= 0.5, (t_fp, t_q)
 
 

@@ -22,6 +22,8 @@ from paddle_tpu.serving import (FleetRouter, HealthConfig, HealthTracker,
                                 ReplicaHandle, ReplicaState, RequestState,
                                 RouterConfig, SchedulerConfig, ServingError)
 
+from _oracle import greedy_reforward as _greedy_ref
+
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -75,18 +77,6 @@ def _drive(router, clock, params, dt=0.05, max_steps=400):
         steps += 1
         assert steps < max_steps, router.statusz()
     return steps
-
-
-def _greedy_ref(params, cfg, prompt, n_new):
-    import jax.numpy as jnp
-    seq = np.asarray(prompt, np.int32)[None, :]
-    out = []
-    for _ in range(n_new):
-        logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-        nxt = int(np.asarray(jnp.argmax(logits[0, -1].astype(jnp.float32))))
-        out.append(nxt)
-        seq = np.concatenate([seq, [[nxt]]], axis=1).astype(np.int32)
-    return out
 
 
 def _counter_total(name):

@@ -2,7 +2,7 @@
 epilogue, lossless rejection-sampling speculation, and grammar-
 constrained decoding.
 
-The acceptance bar: greedy stays byte-identical to the legacy argmax
+The acceptance bar: greedy stays byte-identical to the argmax-only
 epilogue; a seeded sampled request replays its exact stream across
 engine rebuilds, speculation on/off, the fused tail, TP sharding, and
 router failovers; speculation under sampling is DISTRIBUTION-identical
@@ -108,16 +108,17 @@ def test_sampler_config_resolved():
     (1.0, 0, 1.0), (0.7, 0, 1.0), (1.3, 5, 1.0), (1.0, 0, 0.8),
     (0.9, 7, 0.6), (1.0, 1, 1.0),
 ])
-def test_process_logits_matches_legacy_filters(temp, top_k, top_p):
-    """Per-row ``process_logits`` is a bit-exact port of the legacy
-    batch ``_sample`` filter chain (same kth-value tie semantics, same
-    smallest-set top-p cutoff on the post-top-k logits)."""
+def test_process_logits_matches_scalar_filter_chain(temp, top_k, top_p):
+    """Per-row ``process_logits`` is bit-exact with the plain filter chain
+    under ONE scalar (temperature, top-k, top-p) for the batch (same
+    kth-value tie semantics, same smallest-set top-p cutoff on the
+    post-top-k logits)."""
     rng = np.random.RandomState(0)
     lg = rng.randn(6, 32).astype(np.float32)
     lg[2, :16] = lg[2, 16:]                       # planted ties
     R = lg.shape[0]
 
-    # the legacy chain, verbatim (decoding._sample minus the draw)
+    # the chain with scalar parameters, written plainly
     ref = jnp.asarray(lg) / jnp.maximum(temp, 1e-6)
     if top_k > 0:
         kth = jnp.sort(ref, axis=-1)[..., -top_k][..., None]
@@ -296,12 +297,6 @@ def test_seeded_replay_sharded(mp):
     a = _run(_engine(mp=mp), prompts, sampler=sc)
     b = _run(_engine(mp=mp), prompts, sampler=sc)
     assert a == b and len(a[0]) == 8
-
-
-def test_sampler_requires_unified():
-    eng = _engine(unified=False)
-    with pytest.raises(ValueError, match="unified"):
-        eng.submit(_prompts(1)[0], sampler=SamplerConfig(seed=1))
 
 
 # ---------------------------------------------------------------------------

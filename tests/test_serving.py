@@ -19,6 +19,8 @@ from paddle_tpu.profiler.record import host_recorder
 from paddle_tpu.serving import (RequestState, SchedulerConfig, ServingError,
                                 ServingMetrics, ServingScheduler)
 
+from _oracle import greedy_reforward as _greedy_ref
+
 REPO = Path(__file__).resolve().parent.parent
 
 
@@ -58,18 +60,6 @@ def _prompts(cfg, n, rng_seed=0, lens=(3, 8)):
     return [rng.randint(1, cfg.vocab_size,
                         (int(rng.randint(lens[0], lens[1] + 1)),)
                         ).astype(np.int32) for _ in range(n)]
-
-
-def _greedy_ref(params, cfg, prompt, n_new):
-    import jax.numpy as jnp
-    seq = np.asarray(prompt, np.int32)[None, :]
-    out = []
-    for _ in range(n_new):
-        logits = L.forward_stacked(params, jnp.asarray(seq), cfg)
-        nxt = int(np.asarray(jnp.argmax(logits[0, -1].astype(jnp.float32))))
-        out.append(nxt)
-        seq = np.concatenate([seq, [[nxt]]], axis=1).astype(np.int32)
-    return out
 
 
 # ---------------------------------------------------------------------------

@@ -310,11 +310,6 @@ def test_spec_storm_recompiles_o1():
 # config surface + telemetry
 # ---------------------------------------------------------------------------
 
-def test_speculative_requires_unified():
-    with pytest.raises(ValueError, match="unified"):
-        _engine(speculative=True, unified=False)
-
-
 def test_speculative_accepts_do_sample():
     """The old hard rejection of do_sample+speculative is gone: the
     rejection-sampling verifier makes sampled speculation lossless, so

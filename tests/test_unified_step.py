@@ -1,11 +1,8 @@
 """Unified ragged paged-attention step (ROADMAP item 1, per PAPERS.md
 "Ragged Paged Attention"): ONE Pallas/XLA kernel and ONE compiled engine
-step serve mixed prefill+decode rows of arbitrary lengths — byte-identical
-greedy output to the legacy three-program pipeline, O(1) recompiles across
+step serve mixed prefill+decode rows of arbitrary lengths — greedy output
+equal to the full re-forward oracle (``_oracle``), O(1) recompiles across
 a length-diverse storm, conservation after every ragged step."""
-
-import os
-import re
 
 import numpy as np
 import pytest
@@ -17,9 +14,11 @@ from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
 from paddle_tpu.observability.runtime import recompiles
 from paddle_tpu.ops import paged_attention as pa
 
+from _oracle import assert_greedy
+
 
 # ---------------------------------------------------------------------------
-# kernel parity: the ragged composition vs the pair it replaces
+# kernel parity: the ragged composition vs plainer references
 # ---------------------------------------------------------------------------
 
 def _mixed_batch(seed=0, PAGE=4, NPAGES=32, NKV=2, NH=4, D=8):
@@ -45,30 +44,44 @@ def _mixed_batch(seed=0, PAGE=4, NPAGES=32, NKV=2, NH=4, D=8):
             np.asarray(kv_lens, np.int32))
 
 
-def test_ragged_array_matches_legacy_decode_and_prefill_pair():
-    """Elementwise parity of the unified XLA reference against BOTH
-    programs it replaces: paged_attention_array for the decode token and
-    paged_prefill_attention_array for the prefill/suffix rows."""
+def _dense_causal(q, k, v, positions):
+    """Dense reference in numpy: token i of ``q`` (n, nh, d) sits at
+    ``positions[i]`` and attends keys [0, positions[i]] of ONE row's
+    contiguous ``k`` / ``v`` (S, nkv, d); query heads share KV heads in
+    groups (GQA)."""
+    rep = q.shape[1] // k.shape[1]
+    k, v = np.repeat(k, rep, axis=1), np.repeat(v, rep, axis=1)
+    scores = np.einsum("thd,shd->ths", q, k) / np.sqrt(q.shape[-1])
+    seen = np.arange(k.shape[0])[None, :] <= np.asarray(positions)[:, None]
+    scores = np.where(seen[:, None, :], scores, -np.inf)
+    p = np.exp(scores - scores.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("ths,shd->thd", p, v)
+
+
+def test_ragged_array_matches_decode_reference_and_dense_causal():
+    """Elementwise parity of the ragged XLA reference against plainer
+    ones: ``paged_attention_array`` for the decode token, and for the
+    prefill and warm-suffix rows dense causal attention over the row's
+    keys gathered out of the pool."""
     q, kp, vp, bt, token_row, positions, kv_lens = _mixed_batch()
     out = np.asarray(pa.ragged_paged_attention_array(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(bt),
         jnp.asarray(token_row), jnp.asarray(positions),
         jnp.asarray(kv_lens)))
 
-    # decode token (row 0): legacy decode op with kv_len = pos + 1
+    # decode token (row 0): the decode op with kv_len = pos + 1
     dec = np.asarray(pa.paged_attention_array(
         jnp.asarray(q[:1]), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(bt[:1]), jnp.asarray([9], np.int32)))
     np.testing.assert_allclose(out[0], dec[0], rtol=1e-5, atol=1e-6)
 
-    # prefill rows: legacy suffix op at each row's q_start
-    for row, sl, q_start in ((1, slice(1, 7), 0), (2, slice(7, 10), 5)):
-        t = sl.stop - sl.start
-        ref = np.asarray(pa.paged_prefill_attention_array(
-            jnp.asarray(q[sl][None]), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(bt[row:row + 1]),
-            jnp.asarray([q_start], np.int32)))
-        np.testing.assert_allclose(out[sl], ref[0], rtol=1e-5, atol=1e-6)
+    # prefill rows: a cold one (positions 0..5) and a suffix at offset 5
+    for row, sl in ((1, slice(1, 7)), (2, slice(7, 10))):
+        k_row = kp[bt[row]].reshape(-1, *kp.shape[2:])
+        v_row = vp[bt[row]].reshape(-1, *vp.shape[2:])
+        ref = _dense_causal(q[sl], k_row, v_row, positions[sl])
+        np.testing.assert_allclose(out[sl], ref, rtol=1e-5, atol=1e-5)
 
 
 def _packed_case(kv_lens, spans, shared=(), page=4, width=4, t=12):
@@ -243,16 +256,16 @@ def test_ragged_host_helpers_match_in_program_n_live(page, width):
 
 
 # ---------------------------------------------------------------------------
-# engine: byte-identical greedy output vs the legacy pipeline
+# engine: greedy output equal to the full re-forward oracle
 # ---------------------------------------------------------------------------
 
-def _engine(unified, prefix_cache=False, max_new=6, num_slots=2, chunk=3,
-            seed=3, **kw):
-    cfg = L.llama_tiny(num_hidden_layers=2)
+def _engine(prefix_cache=False, max_new=6, num_slots=2, chunk=3, kv_heads=4,
+            **kw):
+    cfg = L.llama_tiny(num_hidden_layers=2, num_key_value_heads=kv_heads)
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=max_new),
         num_slots=num_slots, page_size=4, max_seq_len=64, chunk=chunk,
-        prefix_cache=prefix_cache, unified=unified, **kw)
+        prefix_cache=prefix_cache, **kw)
     return cfg, eng
 
 
@@ -263,13 +276,14 @@ def _ragged_prompts(cfg, n, lens, seed=0):
             for i in range(n)]
 
 
+@pytest.mark.parametrize("kv_heads", [4, 2])
 @pytest.mark.parametrize("prefix_cache", [False, True])
-def test_unified_byte_identical_to_legacy(prefix_cache):
+def test_engine_matches_full_reforward(prefix_cache, kv_heads):
     """The whole acceptance surface in one sweep: ragged lengths, slot
-    reuse, and (with the cache) warm suffix + COW rows — the unified
-    single-dispatch engine must emit exactly the legacy pipeline's greedy
-    tokens."""
-    cfg, leg = _engine(False, prefix_cache=prefix_cache)
+    reuse, (with the cache) warm suffix + COW rows, and grouped-query
+    attention (2 KV heads under 4 query heads) beside multi-head — the
+    single-dispatch engine must emit exactly the oracle's greedy tokens."""
+    cfg, eng = _engine(prefix_cache=prefix_cache, kv_heads=kv_heads)
     params = L.init_stacked_params(cfg, seed=3)
     prompts = _ragged_prompts(cfg, 8, (5, 12, 3, 9, 17, 2, 7, 30), seed=1)
     if prefix_cache:
@@ -277,18 +291,20 @@ def test_unified_byte_identical_to_legacy(prefix_cache):
         # match forces a copy-on-write of the final page)
         prompts[3] = np.concatenate([prompts[1], prompts[2]])
         prompts[5] = prompts[1].copy()
-    legacy = leg.serve(params, prompts)
-    cfg2, uni = _engine(True, prefix_cache=prefix_cache)
-    unified = uni.serve(params, prompts)
-    assert unified == legacy
+    outs = eng.serve(params, prompts)
+    assert_greedy(params, cfg, prompts, outs, n_new=6)
+    if prefix_cache:
+        snap = eng.cache.snapshot()
+        # the warm rows were warm, the exact repeat copied its last page
+        assert snap["hits"] >= 2 and snap["cow_copies"] >= 1, snap
 
 
-def test_mid_decode_admission_byte_identical_and_conserved():
+def test_mid_decode_admission_matches_oracle_and_conserved():
     """A request admitted while others are mid-decode joins the current
-    ragged step immediately and still produces byte-identical greedy
-    output to running it against a fresh engine; page conservation holds
-    after every ragged step (engine-internal check + explicit audits)."""
-    cfg, eng = _engine(True, prefix_cache=True, max_new=6, num_slots=2)
+    ragged step immediately and still produces the greedy tokens of a
+    fresh engine and of the oracle; page conservation holds after every
+    ragged step (engine-internal check + explicit audits)."""
+    cfg, eng = _engine(prefix_cache=True, max_new=6, num_slots=2)
     params = L.init_stacked_params(cfg, seed=3)
     early = _ragged_prompts(cfg, 2, (11, 4), seed=5)
     late = _ragged_prompts(cfg, 1, (7,), seed=9)[0]
@@ -308,40 +324,29 @@ def test_mid_decode_admission_byte_identical_and_conserved():
         if len(results) == 3:
             break
     assert set(results) == set(r_early) | {r_late}
-
-    cfg3, fresh = _engine(True, prefix_cache=True, max_new=6, num_slots=2)
-    assert fresh.serve(params, [late]) == [results[r_late]]
-    # and the storm's early rows match a legacy engine end to end
-    cfg4, leg = _engine(False, prefix_cache=True, max_new=6, num_slots=2)
-    assert leg.serve(params, early) == [results[r] for r in r_early]
+    # early and late rows alike are what the oracle generates alone
+    assert_greedy(params, cfg, early + [late],
+                  [results[r] for r in r_early + [r_late]], n_new=6)
 
 
 # ---------------------------------------------------------------------------
 # O(1) recompiles across a length-diverse storm
 # ---------------------------------------------------------------------------
 
-def test_storm_recompiles_o1_where_legacy_recompiles_per_bucket():
-    """A length-diverse request storm (the recompile cliff): the unified
-    engine's step cache misses at most twice (one compile, one optional
-    remat) while the legacy engine recompiles per (bucket, batch) shape."""
-    cfg, uni = _engine(True, max_new=4, num_slots=4)
+def test_storm_recompiles_o1():
+    """A length-diverse request storm (the recompile cliff of a compile
+    per prompt bucket): the engine's step cache misses at most twice (one
+    compile, one optional remat) and every output is the oracle's."""
+    cfg, eng = _engine(max_new=4, num_slots=4)
     params = L.init_stacked_params(cfg, seed=3)
     lens = (2, 3, 5, 7, 9, 12, 17, 23, 31, 44)
     prompts = _ragged_prompts(cfg, 12, lens, seed=7)
 
     u0 = recompiles.count("cbe.unified_step")
-    out_u = uni.serve(params, prompts)
+    outs = eng.serve(params, prompts)
     u_misses = recompiles.count("cbe.unified_step") - u0
     assert u_misses <= 2, u_misses          # O(1): the acceptance bound
-
-    l0 = (recompiles.count("cbe.prefill")
-          + recompiles.count("cbe.decode_chunk"))
-    cfg2, leg = _engine(False, max_new=4, num_slots=4)
-    out_l = leg.serve(params, prompts)
-    l_misses = (recompiles.count("cbe.prefill")
-                + recompiles.count("cbe.decode_chunk")) - l0
-    assert l_misses > u_misses              # the cliff the kernel removes
-    assert out_u == out_l                   # and identical output
+    assert_greedy(params, cfg, prompts, outs, n_new=4)
 
     # compile wall time surfaced for warmup visibility (/metrics + bench)
     assert recompiles.compile_seconds_total("cbe.unified_step") > 0
@@ -350,7 +355,7 @@ def test_storm_recompiles_o1_where_legacy_recompiles_per_bucket():
 def test_unified_single_program_reused_across_admission_mixes():
     """Every step — pure prefill, mixed, pure decode, re-admission into
     freed slots — runs the SAME compiled program object."""
-    cfg, eng = _engine(True, max_new=4, num_slots=2)
+    cfg, eng = _engine(max_new=4, num_slots=2)
     params = L.init_stacked_params(cfg, seed=3)
     [eng.submit(p) for p in _ragged_prompts(cfg, 5, (3, 13, 6, 21, 2),
                                             seed=11)]
@@ -360,30 +365,3 @@ def test_unified_single_program_reused_across_admission_mixes():
     while eng.step(params) or eng._queue:
         assert eng._unified_step is prog
     assert eng._unified_step is prog
-
-
-# ---------------------------------------------------------------------------
-# dead-path guard: the legacy trio stays an inference/-internal detail
-# ---------------------------------------------------------------------------
-
-def test_no_legacy_prefill_trio_callers_outside_inference():
-    """`_build_prefill` / `_build_prefill_suffix` / `_build_decode_chunk`
-    remain only as the engine's opt-in legacy path (unified=False, kept
-    for A/B benches): nothing outside paddle_tpu/inference/ may reach
-    for them."""
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    pat = re.compile(
-        r"_build_prefill_suffix|_build_prefill|_build_decode_chunk")
-    offenders = []
-    for top in ("paddle_tpu", "benchmarks"):
-        for dirpath, _dirs, files in os.walk(os.path.join(repo, top)):
-            if os.path.join("paddle_tpu", "inference") in dirpath:
-                continue
-            for f in files:
-                if not f.endswith(".py"):
-                    continue
-                path = os.path.join(dirpath, f)
-                src = open(path, encoding="utf-8").read()
-                if pat.search(src):
-                    offenders.append(os.path.relpath(path, repo))
-    assert not offenders, offenders
