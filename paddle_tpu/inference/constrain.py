@@ -40,6 +40,9 @@ import numpy as np
 
 #: transition-table sentinel: token illegal in this state
 ILLEGAL = -1
+#: per-row reset sentinel (:func:`reset_states`): the row keeps the
+#: grammar state the program's carry holds
+KEEP = -2
 
 
 # ---------------------------------------------------------------------------
@@ -496,6 +499,17 @@ class GrammarArena:
 # ---------------------------------------------------------------------------
 # In-program helpers (called from the compiled unified/spec epilogues)
 # ---------------------------------------------------------------------------
+def reset_states(gstate, greset):
+    """Admission's per-row reset of the grammar-state carry, applied at
+    the top of a step program: a row whose ``greset`` is not ``KEEP``
+    starts the step at that state (``-1`` or an arena row), every other
+    row keeps what the previous step left. The carry is advanced by the
+    program itself, so the host can say where a row restarts but cannot
+    overwrite the whole array from a mirror."""
+    import jax.numpy as jnp
+    return jnp.where(greset == KEEP, gstate, greset)
+
+
 def mask_logits(logits, gstate, gtable):
     """Grammar mask gathered in-program: rows with ``gstate >= 0`` get
     ``-inf`` on every token whose arena transition is ``ILLEGAL``;

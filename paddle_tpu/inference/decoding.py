@@ -267,12 +267,26 @@ class ContinuousBatchingEngine:
         self._pos = np.zeros((num_slots,), np.int32)
         self._bt = np.zeros((num_slots, self._table_width), np.int32)
         # per-row sampling epilogue state (inference/sampling.py): the
-        # (seeds, temps, top_k, top_p) device arrays admission writes
-        # lazily, like the token carry. Defaults are greedy — a slot
-        # never inherits a retired request's temperature.
-        self._samp_dev = _sampling.init_row_state(num_slots)
-        # per-row grammar DFA state; -1 = unconstrained (mask is a no-op)
+        # (seeds, temps, top_k, top_p) HOST mirrors admission writes,
+        # like ``_pos`` and ``_bt``; the device copies the step program
+        # takes are refreshed with the plan's uploads, on a step whose
+        # admission changed a value (``_upload_row_state``). Defaults are
+        # greedy — a slot never inherits a retired request's temperature.
+        self._samp = _sampling.init_row_state(num_slots)
+        self._samp_dev = self._upload_copies(self._samp)
+        self._samp_dirty = False
+        # per-row grammar DFA state; -1 = unconstrained (mask is a no-op).
+        # A carry the program advances, so admission cannot overwrite it
+        # from a mirror: it writes the row's start state into ``_greset``
+        # (``KEEP`` elsewhere), uploaded with the plan and applied at the
+        # top of the program (constrain.reset_states). ``_gheld`` marks
+        # the slots whose carry can hold anything but -1, so an
+        # unconstrained request after an unconstrained one writes nothing.
         self._gstate_dev = jnp.full((num_slots,), -1, jnp.int32)
+        self._greset = np.full((num_slots,), _constrain.KEEP, np.int32)
+        self._greset_keep_dev, = self._upload_copies([self._greset])
+        self._greset_dirty = False
+        self._gheld = np.zeros((num_slots,), bool)
         # the grammar arena is ALLOCATED AT CONSTRUCTION with a fixed
         # shape — it is a program input, so sizing it lazily would
         # change the compiled signature and recompile. grammar_states=0
@@ -794,19 +808,70 @@ class ContinuousBatchingEngine:
 
     # -- unified ragged step (the serving path) ------------------------------
 
+    def _admit(self) -> int:
+        """Admission, shared by both step paths: host bookkeeping only
+        (no device computation, no transfer — what it writes rides the
+        step's uploads). Returns the number of requests admitted."""
+        picked = self._admit_pick()
+        for s, req, pages, lp, nc in picked:
+            self._slot_rid[s] = req.rid
+            self._live[req.rid] = req
+            self._pos[s] = nc                 # next position to write
+            self._bt[s] = 0
+            self._bt[s, :len(pages)] = pages
+            # a warm/COW suffix row IS "a row whose first position >
+            # 0"; cold rows just start at 0 — one code path
+            self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
+            if self._speculative:
+                self._reserved[s] = len(pages)
+            self._set_row_sampler(s, req)
+        return len(picked)
+
     def _set_row_sampler(self, s: int, req: "_Request") -> None:
         """Write one admitted request's sampler/grammar parameters into
-        the per-row device arrays (lazy ``.at[s].set``, same discipline
-        as the token carry). ALWAYS runs — a greedy request resets the
-        slot, so reuse never inherits a retired row's temperature or a
-        stale grammar state."""
-        self._samp_dev = _sampling.set_row(self._samp_dev, s, req.sampler)
+        the per-row host mirrors (numpy, like ``_pos`` and ``_bt``).
+        ALWAYS runs — a greedy request resets the slot, so reuse never
+        inherits a retired row's temperature or a stale grammar state —
+        and marks a mirror dirty only where a value changed: greedy
+        after greedy, unconstrained after unconstrained cost the device
+        nothing."""
+        if _sampling.set_row(self._samp, s, req.sampler):
+            self._samp_dirty = True
         g = -1
         if req.grammar is not None:
             # arena rows are the grammar block rebased by its offset:
             # global = (gstart - local start) + local host-mirror state
             g = req.gstart - req.grammar.start + req.gstate_host
-        self._gstate_dev = self._gstate_dev.at[s].set(jnp.int32(g))
+        if g != -1 or self._gheld[s]:
+            self._greset[s] = g
+            self._greset_dirty = True
+            self._gheld[s] = g != -1
+
+    def _upload_row_state(self):
+        """Refresh the device copies of the per-row mirrors admission
+        changed (one transfer an array, with the plan's own uploads) and
+        return the step's grammar reset: the cached all-``KEEP`` array
+        unless a row restarts. A step whose admission changed nothing
+        transfers nothing here."""
+        if self._samp_dirty:
+            self._samp_dev = self._upload_copies(self._samp)
+            self._samp_dirty = False
+        if not self._greset_dirty:
+            return self._greset_keep_dev
+        greset, = self._upload_copies([self._greset])
+        self._greset[:] = _constrain.KEEP
+        self._greset_dirty = False
+        return greset
+
+    @staticmethod
+    def _upload_copies(mirrors) -> Tuple:
+        """Device arrays of PRIVATE copies of host mirrors that later
+        admissions write in place. A transfer may still be reading its
+        source when this returns, or alias it outright (the CPU backend,
+        an aligned buffer), with ``jnp.asarray`` and ``jnp.array`` alike:
+        a mirror handed over itself would leak its next write into the
+        device array."""
+        return tuple(jnp.asarray(a.copy()) for a in mirrors)
 
     def _epilogue_active(self) -> bool:
         """Any live request exercising the sampling epilogue (sampled or
@@ -869,11 +934,13 @@ class ContinuousBatchingEngine:
                     mp_axis=mp_axis, logits_epilogue=hook)[:3]
 
             return _fusion.build_fused_unified_step(
-                model_step, tail, n_rows)
+                model_step, tail, _constrain.reset_states, n_rows)
 
         def run(params, ids, use_carry, token_row, positions, kv_lens,
-                last_idx, sample_mask, tok, gstate, samp, gtable,
+                last_idx, sample_mask, tok, gstate, greset, samp, gtable,
                 k_pages, v_pages, bt):
+            gstate = _constrain.reset_states(gstate, greset)
+
             def micro(carry, xs):
                 tok, gst, kp, vp = carry
                 ids_k, uc_k, tr_k, pos_k, kvl_k, li_k, sm_k = xs
@@ -918,7 +985,7 @@ class ContinuousBatchingEngine:
             # toks (K, R); aux, if any, (K, ...)
             return (toks, tok, gstate, k_pages, v_pages, *aux)
 
-        return jax.jit(run, donate_argnums=(12, 13))
+        return jax.jit(run, donate_argnums=(13, 14))
 
     def lower_unified_step(self, mesh=None):
         """``jax.stages.Lowered`` of the unified step at this engine's
@@ -958,7 +1025,7 @@ class ContinuousBatchingEngine:
         table = self._arena.device_table()
         return self._build_unified_step(mesh).lower(
             params, *plan, abstract((R,), jnp.int32),
-            abstract((R,), jnp.int32),
+            abstract((R,), jnp.int32), abstract((R,), jnp.int32),
             jax.tree_util.tree_map(
                 lambda a: abstract(a.shape, a.dtype), self._samp_dev),
             abstract(table.shape, table.dtype), pool, pool,
@@ -1163,17 +1230,7 @@ class ContinuousBatchingEngine:
         ``cbe.audit``), so a profiler trace says what the host did in each
         gap the device idles through."""
         with phase("cbe.admit"):
-            picked = self._admit_pick()
-            for s, req, pages, lp, nc in picked:
-                self._slot_rid[s] = req.rid
-                self._live[req.rid] = req
-                self._pos[s] = nc                 # next position to write
-                self._bt[s] = 0
-                self._bt[s, :len(pages)] = pages
-                # a warm/COW suffix row IS "a row whose first position >
-                # 0"; cold rows just start at 0 — one code path
-                self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
-                self._set_row_sampler(s, req)
+            admitted = self._admit()
         if not self._live:
             if self._check_invariants:
                 with phase("cbe.audit"):
@@ -1221,15 +1278,22 @@ class ContinuousBatchingEngine:
         if fresh:
             c0 = time.perf_counter()   # dispatch-only window
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
-        with phase("cbe.upload"):
+        # how often admission happens and how often it costs a transfer:
+        # the requests this step admitted, and 1 where one of them changed
+        # a row's sampler parameters or restarts a grammar state (the
+        # mirrors then ride this upload)
+        row_state = int(self._samp_dirty or self._greset_dirty)
+        with phase("cbe.upload", admitted=admitted,
+                   row_state_uploads=row_state):
             plan_dev = [jnp.asarray(a) for a in plan]
+            greset = self._upload_row_state()
             gtable = self._arena.device_table()
             bt = jnp.asarray(self._bt)
         with phase("cbe.dispatch", **record):       # enqueue only
             (toks, self._tok_dev, self._gstate_dev, self.mgr.k_pages,
              self.mgr.v_pages, *aux) = self._unified_step(
                 params, *plan_dev,
-                self._tok_dev, self._gstate_dev, self._samp_dev,
+                self._tok_dev, self._gstate_dev, greset, self._samp_dev,
                 gtable, self.mgr.k_pages, self.mgr.v_pages, bt)
         with phase("cbe.fence"):
             if fresh:
@@ -1350,11 +1414,13 @@ class ContinuousBatchingEngine:
                                      mp_axis=mp_axis)[:3]
 
             return _fusion.build_fused_spec_step(
-                model_step, tail, self.spec_k, n_rows)
+                model_step, tail, _constrain.reset_states, self.spec_k,
+                n_rows)
 
         def run(params, ids, token_row, positions, kv_lens, cand_idx,
-                drafts, draft_len, sampled, gstate, samp, gtable,
+                drafts, draft_len, sampled, gstate, greset, samp, gtable,
                 k_pages, v_pages, bt):
+            gstate = _constrain.reset_states(gstate, greset)
             logits, kp, vp = L.ragged_step(
                 params, ids, token_row, positions, kv_lens, cand_idx,
                 k_pages, v_pages, bt, mcfg, mesh=mesh, mp_axis=mp_axis)[:3]
@@ -1375,7 +1441,7 @@ class ContinuousBatchingEngine:
             gstate = jnp.where(sampled, ngst, gstate)
             return toks, accepted, gstate, kp, vp
 
-        return jax.jit(run, donate_argnums=(12, 13))
+        return jax.jit(run, donate_argnums=(13, 14))
 
     def _plan_spec(self):
         """Host layout of one speculative round. Every decode row claims
@@ -1560,16 +1626,7 @@ class ContinuousBatchingEngine:
         row's draft, host accept/reject + paged rollback. The single
         device→host transfer is the ``(slots*(spec_k+1),)`` candidate
         token vector — smaller than the unified step's emit matrix."""
-        picked = self._admit_pick()
-        for s, req, pages, lp, nc in picked:
-            self._slot_rid[s] = req.rid
-            self._live[req.rid] = req
-            self._pos[s] = nc               # next position to write
-            self._bt[s] = 0
-            self._bt[s, :len(pages)] = pages
-            self._pend[s] = np.asarray(req.prompt[nc:], np.int32)
-            self._reserved[s] = len(pages)
-            self._set_row_sampler(s, req)
+        self._admit()
         if not self._live:
             if self._check_invariants:
                 self.mgr.check_conservation()
@@ -1605,7 +1662,8 @@ class ContinuousBatchingEngine:
          self.mgr.v_pages) = self._spec_step(
             params, *(jnp.asarray(a) for a in plan),
             jnp.asarray(drafts), jnp.asarray(draft_len),
-            jnp.asarray(sampled), self._gstate_dev, self._samp_dev,
+            jnp.asarray(sampled), self._gstate_dev,
+            self._upload_row_state(), self._samp_dev,
             self._arena.device_table(), self.mgr.k_pages,
             self.mgr.v_pages, jnp.asarray(self._bt))
         if fresh:

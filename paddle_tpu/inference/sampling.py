@@ -8,10 +8,12 @@ without forking the program:
 
 * **Per-request runtime parameters.** :class:`SamplerConfig`
   (temperature, top_k, top_p, per-request seed) rides on each request
-  and lands in per-row DEVICE arrays the engine updates at admission
-  (the same lazy ``.at[slot].set`` discipline as the token carry), so a
-  mixed greedy/sampled/constrained batch is one dispatch and the
-  request mix never recompiles anything.
+  and lands in per-row HOST numpy mirrors the engine writes at admission
+  (like its position and block-table mirrors); the device copies the
+  step program takes are refreshed with the plan's uploads, and only on
+  a step whose admission changed a value. A mixed greedy/sampled/
+  constrained batch is one dispatch and the request mix never
+  recompiles anything.
 * **Counter-based PRNG.** The key for the token at sequence position
   ``P`` of a request with seed ``s`` is
   ``fold_in(fold_in(PRNGKey(s), P), salt)`` — derived in-program from
@@ -56,6 +58,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..observability.registry import get_registry
 from . import constrain as _constrain
@@ -126,27 +129,32 @@ def greedy_config() -> SamplerConfig:
     return SamplerConfig(temperature=0.0, seed=0)
 
 
-#: the per-row device arrays one engine slot owns, in tuple order:
+#: the per-row sampler parameters one engine slot owns, as HOST numpy
+#: mirrors in the step program's tuple order:
 #: (seeds uint32, temperatures f32, top_k int32, top_p f32)
 def init_row_state(num_rows: int) -> Tuple:
-    return (jnp.zeros((num_rows,), jnp.uint32),
-            jnp.zeros((num_rows,), jnp.float32),
-            jnp.zeros((num_rows,), jnp.int32),
-            jnp.ones((num_rows,), jnp.float32))
+    return (np.zeros((num_rows,), np.uint32),
+            np.zeros((num_rows,), np.float32),
+            np.zeros((num_rows,), np.int32),
+            np.ones((num_rows,), np.float32))
 
 
-def set_row(samp: Tuple, s: int, cfg: Optional[SamplerConfig]) -> Tuple:
-    """Write one slot's sampler parameters at admission (lazy device
-    updates, mirroring the engine's token-carry discipline). ``None``
-    resets the row to greedy defaults — slot reuse must never inherit a
-    previous request's temperature."""
-    seeds, temps, top_k, top_p = samp
+def set_row(samp: Tuple, s: int, cfg: Optional[SamplerConfig]) -> bool:
+    """Write one slot's sampler parameters into the host mirrors at
+    admission, in place. ``None`` resets the row to greedy defaults —
+    slot reuse must never inherit a previous request's temperature.
+    Returns whether a value changed: only then do the device copies
+    need a refresh (greedy after greedy writes what the row holds)."""
     if cfg is None:
         cfg = greedy_config()
-    return (seeds.at[s].set(jnp.uint32((cfg.seed or 0) & 0xFFFFFFFF)),
-            temps.at[s].set(jnp.float32(cfg.temperature)),
-            top_k.at[s].set(jnp.int32(cfg.top_k)),
-            top_p.at[s].set(jnp.float32(cfg.top_p)))
+    changed = False
+    for rows, value in zip(samp, ((cfg.seed or 0) & 0xFFFFFFFF,
+                                  cfg.temperature, cfg.top_k, cfg.top_p)):
+        value = rows.dtype.type(value)
+        if rows[s] != value:
+            rows[s] = value
+            changed = True
+    return changed
 
 
 # ---------------------------------------------------------------------------

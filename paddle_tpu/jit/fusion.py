@@ -669,7 +669,7 @@ def pack_plan(ids, use_carry, token_row, positions, kv_lens, last_idx,
 
 
 def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
-                             num_rows: int):
+                             reset_fn: Callable, num_rows: int):
     """The fused decode-tail twin of the engine's unified ragged step:
     same compute graph (``model_step`` per micro-round, the sampling
     epilogue, the carry select) — byte-identical tokens by construction
@@ -682,11 +682,14 @@ def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
     ``sample_fn(logits, pos_next, samp, gstate, gtable) ->
     ((rows,) int32 tokens, (rows,) int32 grammar states)`` — the
     counter-based epilogue needs no key input, so no PRNG state
-    threads through the scan carry.
+    threads through the scan carry. ``reset_fn(gstate, greset) ->
+    gstate`` applies admission's per-row restart of the grammar carry
+    once, before the first micro-round (``constrain.reset_states``).
     """
 
-    def run(params, plan_tt, plan_tr, tok, gstate, samp, gtable,
+    def run(params, plan_tt, plan_tr, tok, gstate, greset, samp, gtable,
             k_pages, v_pages, bt):
+        gstate = reset_fn(gstate, greset)
         ids = plan_tt[0]
         use_carry = plan_tt[1].astype(bool)
         token_row = plan_tt[2]
@@ -715,11 +718,11 @@ def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
              sample_mask))
         return toks, tok, gstate, k_pages, v_pages
 
-    return jax.jit(run, donate_argnums=(7, 8))
+    return jax.jit(run, donate_argnums=(8, 9))
 
 
 def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
-                          spec_k: int, num_rows: int):
+                          reset_fn: Callable, spec_k: int, num_rows: int):
     """The fused decode-tail twin of the speculative step: the same
     single ragged dispatch plus the **verify epilogue in-program** — a
     vectorized accepted-prefix count (greedy rows) / rejection-sampling
@@ -733,13 +736,15 @@ def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
     (rows,), gstate')``. ``sampled (rows,) bool`` gates which rows
     really committed a token this round — only those advance their
     grammar state (a mid-prefill constrained row must not advance on a
-    garbage candidate).
+    garbage candidate). ``reset_fn`` as in
+    :func:`build_fused_unified_step`.
     """
     k1 = spec_k + 1
 
     def run(params, ids, token_row, positions, kv_lens, cand_idx,
-            drafts, draft_len, sampled, gstate, samp, gtable,
+            drafts, draft_len, sampled, gstate, greset, samp, gtable,
             k_pages, v_pages, bt):
+        gstate = reset_fn(gstate, greset)
         logits, kp, vp = model_step(params, ids, token_row, positions,
                                     kv_lens, cand_idx, k_pages, v_pages,
                                     bt)
@@ -752,4 +757,4 @@ def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
         gstate = jnp.where(sampled, ngst, gstate)
         return toks, accepted, gstate, kp, vp
 
-    return jax.jit(run, donate_argnums=(12, 13))
+    return jax.jit(run, donate_argnums=(13, 14))

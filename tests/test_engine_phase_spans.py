@@ -1,7 +1,8 @@
 """Engine step phases and the per-dispatch work record in the PROFILER'S own
 trace (ISSUE 24): ``paddle_serving.step`` > ``cbe.step`` > the seven inner
 phases of ``_step_unified``, with the record's ten integers on
-``cbe.dispatch`` — in any ``jax.profiler`` session, no arming.
+``cbe.dispatch`` — in any ``jax.profiler`` session, no arming. Since ISSUE
+30 ``cbe.upload`` carries two more: ``admitted`` and ``row_state_uploads``.
 
 A tiny engine as ``tests/test_serving.py`` builds one, traced on the CPU
 with ``python_tracer_level = 0``."""
@@ -16,6 +17,7 @@ import pytest
 
 from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                            GenerationConfig)
+from paddle_tpu.inference.sampling import SamplerConfig
 from paddle_tpu.models import llama as L
 from paddle_tpu.ops.paged_attention import ragged_block_pages
 from paddle_tpu.profiler import Profiler, ProfilerTarget
@@ -203,6 +205,63 @@ def test_record_matches_the_plan_and_the_tokens(run):
     else:   # a request that ends inside a dispatch leaves planned rounds
         assert run.delivered <= decoded <= \
             run.delivered + len(run.prompts) * (run.chunk - 1)
+
+
+UPLOAD_KEYS = {"admitted", "row_state_uploads"}
+
+
+def test_upload_span_counts_admissions_and_no_row_state_upload(run):
+    """``cbe.upload`` says how often admission happens and how often it
+    costs a transfer: ``admitted`` sums to the requests the traced serve
+    brought in, and all-greedy traffic never uploads a row's state."""
+    uploads = [e[3] for e in run.events if e[0] == "cbe.upload"]
+    assert len(uploads) == len(run.plans)
+    assert all(set(u) == UPLOAD_KEYS for u in uploads)
+    assert all(isinstance(v, int) for u in uploads for v in u.values())
+    assert sum(u["admitted"] for u in uploads) == len(run.prompts)
+    assert all(0 <= u["admitted"] <= SLOTS for u in uploads)
+    assert all(u["row_state_uploads"] == 0 for u in uploads)
+
+
+@pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
+def test_row_state_uploads_once_for_a_changed_row(tmp_path, fused_tail):
+    """A sampled request changes its row's mirrors: the step that admits
+    it uploads them (1), every later step of its decode does not, and a
+    greedy request into the same slot resets the row with one more."""
+    cfg = L.llama_tiny(num_hidden_layers=2)
+    params = L.init_stacked_params(cfg, seed=3)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
+        num_slots=1, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=2,
+        prefix_cache=True, fused_tail=fused_tail)
+    prompts = _prompts(cfg, 3, seed=4)
+    subs = [dict(sampler=SamplerConfig(temperature=0.8, seed=11)), {}, {}]
+    eng.submit(prompts[0], **subs[0])       # compile the full epilogue
+    while not eng.collect():
+        eng.step(params)
+    eng.submit(prompts[0])                  # leave the row greedy again
+    while not eng.collect():
+        eng.step(params)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        for p, sub in zip(prompts, subs):
+            eng.submit(p, **sub)
+        done = 0
+        while done < len(prompts):
+            eng.step(params)
+            done += len(eng.collect())
+    finally:
+        jax.profiler.stop_trace()
+    uploads = [e[3] for e in _host_events(str(tmp_path))
+               if e[0] == "cbe.upload"]
+    admitting = [u for u in uploads if u["admitted"]]
+    # one slot: sampled (dirty), greedy after sampled (the reset: dirty),
+    # greedy after greedy (nothing to send)
+    assert [u["admitted"] for u in admitting] == [1, 1, 1]
+    assert [u["row_state_uploads"] for u in admitting] == [1, 1, 0]
+    assert sum(u["row_state_uploads"] for u in uploads) == 2
 
 
 def test_record_counts_one_grid_step_for_an_empty_round():

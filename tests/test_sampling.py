@@ -18,6 +18,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from _oracle import assert_greedy
 from paddle_tpu.inference import sampling as S
 from paddle_tpu.inference.constrain import (GrammarArena, compile_regex,
                                             json_regex, mask_logits)
@@ -48,8 +49,9 @@ def _engine(max_new=8, num_slots=2, mp=1, **kw):
         mesh=mesh, **kw)
 
 
-def _run(eng, prompts, **sub):
-    rids = [eng.submit(p, **sub) for p in prompts]
+def _run_each(eng, prompts, subs):
+    """Serve ``prompts``, each with its own submit arguments."""
+    rids = [eng.submit(p, **s) for p, s in zip(prompts, subs)]
     out, steps = {}, 0
     while len(out) < len(prompts):
         eng.step(PARAMS)
@@ -57,6 +59,10 @@ def _run(eng, prompts, **sub):
         steps += 1
         assert steps < 3000
     return [out[r] for r in rids]
+
+
+def _run(eng, prompts, **sub):
+    return _run_each(eng, prompts, [sub] * len(prompts))
 
 
 def _abc_vocab():
@@ -140,10 +146,18 @@ def test_process_logits_matches_scalar_filter_chain(temp, top_k, top_p):
 
 
 def test_row_state_defaults_are_greedy():
+    """The row state is host numpy mirrors written in place; ``set_row``
+    says whether a value changed (what the engine's dirty mark reads)."""
     samp = S.init_row_state(3)
-    samp = S.set_row(samp, 1, SamplerConfig(temperature=0.5, seed=7))
-    samp = S.set_row(samp, 1, None)               # slot reuse resets
+    assert all(isinstance(a, np.ndarray) for a in samp)
+    assert not S.set_row(samp, 1, None)           # greedy holds greedy
+    assert S.set_row(samp, 1, SamplerConfig(temperature=0.5, seed=7))
+    assert (int(samp[0][1]), float(samp[1][1])) == (7, 0.5)
+    assert not S.set_row(samp, 1, SamplerConfig(temperature=0.5, seed=7))
+    assert S.set_row(samp, 1, None)               # slot reuse resets
     assert float(samp[1][1]) == 0.0               # temperature 0 = argmax
+    assert [a.tolist() for a in samp] == \
+        [a.tolist() for a in S.init_row_state(3)]
 
 
 # ---------------------------------------------------------------------------
@@ -414,6 +428,195 @@ def test_mixed_storm_o1_recompiles_and_metrics(abc_grammar):
         mode="sampled") > 0
     assert reg.get("paddle_sampling_grammar_states").value() \
         == g.n_states
+
+
+# ---------------------------------------------------------------------------
+# admission writes host mirrors, not the device (ISSUE 30)
+# ---------------------------------------------------------------------------
+TAILS = ["unified", "spec", "fused"]
+
+
+def _tail_engine(tail, **kw):
+    """An engine on one of the three step paths."""
+    eng = _engine(speculative=tail == "spec", **kw)
+    return eng.enable_fused_tail() if tail == "fused" else eng
+
+
+def _guard_admission(eng):
+    """Every admission of ``eng`` from now on runs with host<->device
+    transfers forbidden; returns the list its admitted counts land in."""
+    admit, counts = eng._admit, []
+
+    def guarded():
+        with jax.transfer_guard("disallow_explicit"):
+            counts.append(admit())
+        return counts[-1]
+    eng._admit = guarded
+    return counts
+
+
+@pytest.mark.parametrize("tail", TAILS)
+def test_greedy_admission_does_no_device_work(tail):
+    """Greedy unconstrained requests entering slots (fresh ones, then
+    reused ones) transfer nothing and launch nothing inside admission:
+    the row state's device arrays are the SAME objects afterwards, no
+    mirror is dirty, and the step's grammar reset is the cached all-KEEP
+    array. The parent's ``_set_row_sampler`` ran five eager
+    ``.at[s].set`` programs here (a scalar transfer each)."""
+    eng = _tail_engine(tail)                     # 2 slots, 6 requests
+    counts = _guard_admission(eng)
+    samp_dev, keep = eng._samp_dev, eng._greset_keep_dev
+    prompts = _prompts(6, seed=9)
+    rids = [eng.submit(p) for p in prompts]
+    out, steps = {}, 0
+    while len(out) < len(prompts):
+        eng.step(PARAMS)
+        assert eng._samp_dev is samp_dev
+        assert not (eng._samp_dirty or eng._greset_dirty)
+        assert eng._upload_row_state() is keep   # nothing rides the plan
+        out.update(eng.collect())
+        steps += 1
+        assert steps < 3000
+    assert sum(counts) == len(prompts) and max(counts) <= 2
+    assert_greedy(PARAMS, CFG, prompts, [out[r] for r in rids], n_new=8)
+
+
+def test_changed_row_state_rides_one_upload(abc_grammar):
+    """A sampled or constrained admission does no device work either: it
+    marks a mirror dirty, and the step's upload refreshes the device
+    copies once. The same values again leave the mirrors clean."""
+    g = abc_grammar
+    eng = _engine(num_slots=1, grammar_states=g.n_states)
+    counts = _guard_admission(eng)
+    sc = SamplerConfig(temperature=0.9, seed=5)
+    eng.submit(_prompts(1)[0], sampler=sc)
+    samp_dev = eng._samp_dev
+    with jax.transfer_guard("disallow_explicit"):
+        assert eng._admit() == 1
+    assert eng._samp_dirty and not eng._greset_dirty
+    assert eng._samp_dev is samp_dev             # not yet: with the plan
+    eng.step(PARAMS)
+    assert eng._samp_dev is not samp_dev and not eng._samp_dirty
+    assert float(np.asarray(eng._samp_dev[1])[0]) == np.float32(0.9)
+    while not eng.collect():
+        eng.step(PARAMS)
+    # the same sampler into the same slot: what the row holds already
+    samp_dev = eng._samp_dev
+    eng.submit(_prompts(1)[0], sampler=sc, grammar=g)
+    eng.step(PARAMS)
+    assert eng._samp_dev is samp_dev             # sampler unchanged
+    assert not eng._greset_dirty and eng._gheld[0]     # grammar rode it
+    assert (np.asarray(eng._greset) == -2).all()       # KEEP again
+    assert counts == [1] + [0] * (len(counts) - 2) + [1]
+
+
+def _aligned(a, align=64):
+    """A copy of ``a`` at an address the CPU backend maps zero-copy."""
+    buf = np.empty(a.nbytes + align, np.uint8)
+    off = (-buf.ctypes.data) % align
+    out = buf[off:off + a.nbytes].view(a.dtype)
+    out[:] = a
+    return out
+
+
+def test_row_state_uploads_hand_over_private_copies(abc_grammar):
+    """The mirrors are written in place by later admissions (and the
+    grammar reset is cleared right after its upload), while a transfer
+    may still be reading its source or alias it outright: with other
+    device work in flight (a handoff's page import, a COW copy) an
+    upload of the mirror ITSELF let that next write through, and a
+    constrained row lost its start state. Aligned mirrors and a matmul
+    in flight make the hazard deterministic on the CPU."""
+    g = abc_grammar
+    sc = SamplerConfig(temperature=1.2, seed=11)
+    kw = dict(num_slots=2, grammar_states=g.n_states)
+    want = _run(_engine(**kw), _prompts(4), sampler=sc, grammar=g)
+    eng = _engine(**kw)
+    eng._samp = tuple(_aligned(a) for a in eng._samp)
+    eng._greset = _aligned(eng._greset)
+    big, upload = jnp.ones((1024, 1024)), eng._upload_row_state
+    busy = jax.jit(lambda x: x @ x @ x)
+
+    def upload_behind_other_work():
+        busy(big)                                 # in flight, not awaited
+        return upload()
+    eng._upload_row_state = upload_behind_other_work
+    outs = _run(eng, _prompts(4), sampler=sc, grammar=g)
+    for t in outs:
+        _assert_legal_stream(g, t)
+    assert outs == want
+    held = [np.array(d) for d in eng._samp_dev]
+    for a in eng._samp:
+        a[:] = 7                                  # a later admission
+    assert all((np.asarray(d) == h).all()
+               for d, h in zip(eng._samp_dev, held))
+
+
+def _reuse_kinds(g):
+    return {"greedy": dict(),
+            "sampled": dict(sampler=SamplerConfig(
+                temperature=0.9, top_k=12, top_p=0.95, seed=77)),
+            "constrained": dict(grammar=g)}
+
+
+@pytest.mark.parametrize("tail", TAILS)
+@pytest.mark.parametrize("first,second", [
+    ("sampled", "greedy"), ("greedy", "sampled"),
+    ("constrained", "greedy"), ("greedy", "constrained")])
+def test_slot_reuse_never_inherits_row_state(abc_grammar, tail, first,
+                                             second):
+    """ONE slot, two requests: the second enters the row the first left
+    and decodes as if the row were new — a greedy one equals the full
+    re-forward (no inherited temperature, no stale grammar mask), a
+    sampled or constrained one equals its run on a fresh engine."""
+    g = abc_grammar
+    kinds = _reuse_kinds(g)
+    prompts = _prompts(2, seed=21)
+    kw = dict(num_slots=1, grammar_states=g.n_states)
+    eng = _tail_engine(tail, **kw)
+    _guard_admission(eng)
+    reused = _run_each(eng, prompts, [kinds[first], kinds[second]])
+    assert len(reused[0]) == len(reused[1]) == 8
+    if second == "greedy":
+        assert_greedy(PARAMS, CFG, prompts[1:], reused[1:], n_new=8)
+    else:
+        alone = _run_each(_tail_engine(tail, **kw), prompts[1:],
+                          [kinds[second]])
+        assert reused[1] == alone[0]
+    if second == "constrained":
+        _assert_legal_stream(g, reused[1])
+    if first == "constrained":
+        _assert_legal_stream(g, reused[0])
+
+
+def test_mixed_samplers_over_many_steps_compile_nothing_new(abc_grammar):
+    """Sampler configurations and grammars admitted one at a time over
+    many steps are DATA: after the greedy program and the one sticky
+    epilogue flip, the step program is never rebuilt and never retraced
+    (the uploaded mirrors have the dtypes of the arrays they replace)."""
+    g = abc_grammar
+    eng = _engine(max_new=4, num_slots=2, grammar_states=g.n_states)
+    _guard_admission(eng)
+    rc0 = recompiles.count("cbe.unified_step")
+    _run(eng, _prompts(2))                        # the greedy program
+    assert recompiles.count("cbe.unified_step") - rc0 == 1
+    subs = [dict(sampler=SamplerConfig(temperature=0.5 + 0.1 * i,
+                                       top_k=i % 4, seed=100 + i),
+                 **(dict(grammar=g) if i % 3 == 0 else {}))
+            if i % 2 else dict() for i in range(12)]
+    prompts = _prompts(12, seed=8)
+    out, prog = {}, None
+    for p, sub in zip(prompts, subs):             # one admission a time
+        rid = eng.submit(p, **sub)
+        while rid not in out:
+            eng.step(PARAMS)
+            if eng._epilogue_on:                  # from the flip on: ONE
+                prog = prog or eng._unified_step
+                assert eng._unified_step is prog
+            out.update(eng.collect())
+    assert recompiles.count("cbe.unified_step") - rc0 == 2   # the flip
+    assert prog._cache_size() == 1
+    assert len(out) == 12
 
 
 def test_catalog_declares_sampling_surface():
