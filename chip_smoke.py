@@ -357,7 +357,9 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
                (jnp.int32(3 * sz.page - 1),)),
               ("blocks_windowed", mixes["blocks"], windowed, windowed_ref,
                (jnp.int32((group + 2) * sz.page + 5),))]
-    for name, spans, ragged, ragged_ref, window in cases:
+    def packed(spans):
+        """A mix's (token_row, positions, kv_lens), its spans packed in
+        order into the ``t`` slots."""
         token_row = np.full((t,), -1, np.int32)
         positions = np.zeros((t,), np.int32)
         kv_lens = np.zeros((rows,), np.int32)
@@ -369,6 +371,10 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
                                               sz.max_seq - 1)
             kv_lens[row] = positions[at + n - 1] + 1
             at += n
+        return token_row, positions, kv_lens
+
+    for name, spans, ragged, ragged_ref, window in cases:
+        token_row, positions, kv_lens = packed(spans)
         args = (q, k_pages, v_pages, jnp.asarray(tables, jnp.int32),
                 jnp.asarray(token_row), jnp.asarray(positions),
                 jnp.asarray(kv_lens)) + window
@@ -384,6 +390,35 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
         if spans:
             errs[f"ragged_paged_attention.{name}"] = _err(
                 got[~pad], ragged_ref(*args)[~pad])
+
+    # -- the latent (MLA) ragged kernel on the same mixes (its rows' tokens
+    # are contiguous in them): one pool without a head axis, an entry of 640
+    # lanes (A.X-K1's 576 numbers), values its first 512; heads as A.X-K1's
+    lat_d, lat_v, lat_h = 640, 512, 64
+    kq, kp, key = jax.random.split(key, 3)
+    q_lat = jax.random.normal(kq, (t, lat_h, lat_d), jnp.bfloat16)
+    lat_pool = jax.random.normal(kp, (n_pages, sz.page, lat_d), jnp.bfloat16)
+    mla = jax.jit(lambda *a: pa.mla_paged_attention_pallas(
+        *a, scale=0.13, value_dim=lat_v, interpret=interpret))
+    mla_ref = jax.jit(lambda *a: pa.mla_paged_attention_array(
+        *a, scale=0.13, value_dim=lat_v))
+    for name, spans in mixes.items():
+        token_row, positions, kv_lens = packed(spans)
+        args = (q_lat, lat_pool, jnp.asarray(tables, jnp.int32),
+                jnp.asarray(token_row), jnp.asarray(positions),
+                jnp.asarray(kv_lens))
+        if not interpret and name == "dense":
+            require_kernels(mla.lower(*args), ("mla_paged_attention",),
+                            "latent parity")
+        got = mla(*args)
+        pad = token_row < 0
+        check(bool(jnp.all(got[pad] == 0)),
+              f"latent kernel ({name}): pad slots not 0")
+        check(bool(jnp.all(jnp.isfinite(got.astype(jnp.float32)))),
+              f"latent kernel ({name}): non-finite output")
+        if spans:
+            errs[f"mla_paged_attention.{name}"] = _err(
+                got[~pad], mla_ref(*args)[~pad])
 
     # -- rms_norm fwd+bwd at the serve (T x h) and train (B*S x h) row counts
     for rows_ in (sz.slots, sz.batch * sz.seq):

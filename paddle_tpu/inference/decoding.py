@@ -78,12 +78,16 @@ def _serving_module(model_config):
 
     The model protocol — every name of a model module the engine looks
     up. REQUIRED: ``ragged_step(params, ids, token_row, positions,
-    kv_lens, last_idx, k_pages, v_pages, block_tables, config, mesh=,
-    mp_axis=, logits_epilogue=) -> (logits, k_pages, v_pages[, aux])``,
+    kv_lens, last_idx, *pools, block_tables, config, mesh=, mp_axis=,
+    logits_epilogue=) -> (logits, *pools[, aux])``, ``pools`` the cache's
+    arrays (``k_pages, v_pages`` unless the module says otherwise),
     ``init_stacked_params(config)``, ``serving_param_specs(config)``,
     ``shard_params_tp(params, mesh, config)``. OPTIONAL:
+    ``cache_layout(config)`` (what a token keeps per layer, an
+    ``ops.paged_attention.CacheLayout``; K and V of
+    ``num_key_value_heads`` x ``head_dim`` where it names none),
     ``attention_windows(config)`` (per layer the sliding window, None
-    where attention is full) and the step's fourth return value (a small
+    where attention is full) and the step's last return value (a small
     int32 routing record, handed out with the tokens)."""
     name = getattr(model_config, "serving_module", _LLAMA)
     module = importlib.import_module(name)
@@ -93,6 +97,12 @@ def _serving_module(model_config):
             f"{name} cannot be served: the engine's model protocol "
             f"requires {', '.join(missing)}")
     return module
+
+
+def _split_step(out, n_pools: int):
+    """A ``ragged_step``'s return value as (logits, the cache's arrays, what
+    follows them: the routing record of a model with experts, or nothing)."""
+    return out[0], tuple(out[1:1 + n_pools]), tuple(out[1 + n_pools:])
 
 
 # ---------------------------------------------------------------------------
@@ -174,7 +184,8 @@ class ContinuousBatchingEngine:
                  drafter=None, fused_tail: bool = False,
                  mesh=None, mp_axis: str = "mp",
                  grammar_states: int = 0):
-        from ..ops.paged_attention import PagedKVCacheManager
+        from ..ops.paged_attention import (PagedKVCacheManager,
+                                           kv_cache_layout)
         # the ONE place the engine learns which model it serves: the
         # config's class names the module that holds its step
         self._L = _serving_module(model_config)
@@ -217,18 +228,22 @@ class ContinuousBatchingEngine:
                     f"{self._L.__name__} replicates every weight: it has "
                     f"no tensor-parallel layout for a mesh of degree "
                     f"{chips} over {mp_axis!r}; serve it on one chip")
-            if (mcfg.num_key_value_heads % chips
-                    or mcfg.num_attention_heads % chips):
+            if mcfg.num_attention_heads % chips:
                 raise ValueError(
                     f"TP degree {chips} must divide num_attention_heads="
-                    f"{mcfg.num_attention_heads} and num_key_value_heads="
-                    f"{mcfg.num_key_value_heads} (whole GQA groups per "
+                    f"{mcfg.num_attention_heads} (whole GQA groups per "
                     "chip — pick a degree via mesh.surviving_mp_degree)")
-        # the pool is allocated ON the mesh (head-sharded from its first
-        # byte): a pool sized for the mesh need not fit one chip
-        pool_args = (mcfg.num_hidden_layers, pool, page_size,
-                     mcfg.num_key_value_heads, mcfg.head_dim)
-        pool_kw = dict(dtype=mcfg.dtype, mesh=mesh, mp_axis=mp_axis)
+        # what a token keeps in the cache is the model's: K and V with a
+        # head axis unless its module names another layout (which also
+        # says whether a mesh can split it). The pool is allocated ON the
+        # mesh (head-sharded from its first byte): a pool sized for the
+        # mesh need not fit one chip
+        layout = getattr(self._L, "cache_layout", None)
+        layout = (layout(mcfg) if layout is not None else kv_cache_layout(
+            mcfg.num_key_value_heads, mcfg.head_dim))
+        pool_args = (mcfg.num_hidden_layers, pool, page_size)
+        pool_kw = dict(dtype=mcfg.dtype, mesh=mesh, mp_axis=mp_axis,
+                       layout=layout)
         if prefix_cache:
             # shared-ownership pool + radix prefix index: retired prompts
             # stay resident and later requests prefill only their suffix
@@ -364,6 +379,9 @@ class ContinuousBatchingEngine:
         #: their cached prefix; benchmarks diff this against submitted
         #: prompt lengths for the skip ratio)
         self._prefill_tokens = 0
+        #: prompt tokens the prefix cache already held for the requests the
+        #: last admission brought in (``cbe.upload``'s ``cached_tokens``)
+        self._admitted_cached = 0
         #: unified dispatches since the engine was built: the ``n`` of the
         #: work record that rides on each ``cbe.dispatch`` span
         self._dispatches = 0
@@ -811,7 +829,9 @@ class ContinuousBatchingEngine:
     def _admit(self) -> int:
         """Admission, shared by both step paths: host bookkeeping only
         (no device computation, no transfer — what it writes rides the
-        step's uploads). Returns the number of requests admitted."""
+        step's uploads). Returns the number of requests admitted, and
+        leaves in ``_admitted_cached`` how many of their prompt tokens the
+        prefix cache already held."""
         picked = self._admit_pick()
         for s, req, pages, lp, nc in picked:
             self._slot_rid[s] = req.rid
@@ -825,6 +845,7 @@ class ContinuousBatchingEngine:
             if self._speculative:
                 self._reserved[s] = len(pages)
             self._set_row_sampler(s, req)
+        self._admitted_cached = sum(nc for *_, nc in picked)
         return len(picked)
 
     def _set_row_sampler(self, s: int, req: "_Request") -> None:
@@ -924,25 +945,26 @@ class ContinuousBatchingEngine:
             from ..jit import fusion as _fusion
 
             def model_step(params, ids, token_row, positions, kv_lens,
-                           last_idx, k_pages, v_pages, bt, gst, gtable):
+                           last_idx, pools, bt, gst, gtable):
                 hook = (lambda lg: _constrain.mask_logits(
                     lg.astype(jnp.float32), gst, gtable)) \
                     if epilogue else None
-                return L.ragged_step(
+                return _split_step(L.ragged_step(
                     params, ids, token_row, positions, kv_lens, last_idx,
-                    k_pages, v_pages, bt, mcfg, mesh=mesh,
-                    mp_axis=mp_axis, logits_epilogue=hook)[:3]
+                    *pools, bt, mcfg, mesh=mesh,
+                    mp_axis=mp_axis, logits_epilogue=hook),
+                    len(pools))[:2]
 
             return _fusion.build_fused_unified_step(
                 model_step, tail, _constrain.reset_states, n_rows)
 
         def run(params, ids, use_carry, token_row, positions, kv_lens,
                 last_idx, sample_mask, tok, gstate, greset, samp, gtable,
-                k_pages, v_pages, bt):
+                pools, bt):
             gstate = _constrain.reset_states(gstate, greset)
 
             def micro(carry, xs):
-                tok, gst, kp, vp = carry
+                tok, gst, pools = carry
                 ids_k, uc_k, tr_k, pos_k, kvl_k, li_k, sm_k = xs
                 row_c = jnp.clip(tr_k, 0, n_rows - 1)
                 # decode slots take the row's carry token (last sample);
@@ -956,14 +978,14 @@ class ContinuousBatchingEngine:
                 hook = (lambda lg: _constrain.mask_logits(
                     lg.astype(jnp.float32), gst, gtable)) \
                     if epilogue else None
-                # a model with experts returns a fourth value, its small
+                # a model with experts returns one more value, its small
                 # int32 routing record: stacked over the micro-rounds and
-                # handed out untouched (Llama returns three, and its
+                # handed out untouched (Llama returns none, and its
                 # program is what it was)
-                logits, kp, vp, *aux = L.ragged_step(
-                    params, ids_eff, tr_k, pos_k, kvl_k, li_k, kp, vp,
+                logits, pools, aux = _split_step(L.ragged_step(
+                    params, ids_eff, tr_k, pos_k, kvl_k, li_k, *pools,
                     bt, mcfg, mesh=mesh, mp_axis=mp_axis,
-                    logits_epilogue=hook)
+                    logits_epilogue=hook), len(pools))
                 # the in-program sampling epilogue (sampling.sample_rows):
                 # per-row temperature/top-k/top-p + counter-based PRNG
                 # keyed on the token's sequence position (= this round's
@@ -976,16 +998,16 @@ class ContinuousBatchingEngine:
                 emit = tok
                 tok = jnp.where(sm_k, nxt, tok)
                 gst = jnp.where(sm_k, ngst, gst)
-                return (tok, gst, kp, vp), (emit, *aux)
+                return (tok, gst, pools), (emit, *aux)
 
-            (tok, gstate, k_pages, v_pages), (toks, *aux) = jax.lax.scan(
-                micro, (tok, gstate, k_pages, v_pages),
+            (tok, gstate, pools), (toks, *aux) = jax.lax.scan(
+                micro, (tok, gstate, pools),
                 (ids, use_carry, token_row, positions, kv_lens, last_idx,
                  sample_mask))
             # toks (K, R); aux, if any, (K, ...)
-            return (toks, tok, gstate, k_pages, v_pages, *aux)
+            return (toks, tok, gstate, pools, *aux)
 
-        return jax.jit(run, donate_argnums=(13, 14))
+        return jax.jit(run, donate_argnums=(13,))
 
     def lower_unified_step(self, mesh=None):
         """``jax.stages.Lowered`` of the unified step at this engine's
@@ -1012,8 +1034,9 @@ class ContinuousBatchingEngine:
             k: abstract(v.shape, v.dtype, specs[k]) for k, v in
             jax.eval_shape(
                 lambda: self._L.init_stacked_params(mcfg)).items()}
-        pool = abstract(self.mgr.k_pages.shape, self.mgr.k_pages.dtype,
-                        self.mgr.pool_spec(self._mp_axis))
+        pools = tuple(
+            abstract(p.shape, p.dtype, spec) for p, spec in zip(
+                self.mgr.pools, self.mgr.layout.pool_specs(self._mp_axis)))
         if self._fused_tail:        # jit.fusion.pack_plan's two uploads
             plan = [abstract((4, K, tb), jnp.int32),
                     abstract((3, K, R), jnp.int32)]
@@ -1028,7 +1051,7 @@ class ContinuousBatchingEngine:
             abstract((R,), jnp.int32), abstract((R,), jnp.int32),
             jax.tree_util.tree_map(
                 lambda a: abstract(a.shape, a.dtype), self._samp_dev),
-            abstract(table.shape, table.dtype), pool, pool,
+            abstract(table.shape, table.dtype), pools,
             abstract((R, self._table_width), jnp.int32))
 
     def _plan_step(self):
@@ -1279,22 +1302,25 @@ class ContinuousBatchingEngine:
             c0 = time.perf_counter()   # dispatch-only window
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
         # how often admission happens and how often it costs a transfer:
-        # the requests this step admitted, and 1 where one of them changed
-        # a row's sampler parameters or restarts a grammar state (the
-        # mirrors then ride this upload)
+        # the requests this step admitted, the prompt tokens of theirs the
+        # prefix cache already held (so a trace shows whether a dispatch's
+        # new rows were hits), and 1 where one of them changed a row's
+        # sampler parameters or restarts a grammar state (the mirrors then
+        # ride this upload)
         row_state = int(self._samp_dirty or self._greset_dirty)
         with phase("cbe.upload", admitted=admitted,
-                   row_state_uploads=row_state):
+                   row_state_uploads=row_state,
+                   cached_tokens=self._admitted_cached):
             plan_dev = [jnp.asarray(a) for a in plan]
             greset = self._upload_row_state()
             gtable = self._arena.device_table()
             bt = jnp.asarray(self._bt)
         with phase("cbe.dispatch", **record):       # enqueue only
-            (toks, self._tok_dev, self._gstate_dev, self.mgr.k_pages,
-             self.mgr.v_pages, *aux) = self._unified_step(
+            (toks, self._tok_dev, self._gstate_dev, self.mgr.pools,
+             *aux) = self._unified_step(
                 params, *plan_dev,
                 self._tok_dev, self._gstate_dev, greset, self._samp_dev,
-                gtable, self.mgr.k_pages, self.mgr.v_pages, bt)
+                gtable, self.mgr.pools, bt)
         with phase("cbe.fence"):
             if fresh:
                 jax.block_until_ready(toks)
@@ -1407,11 +1433,11 @@ class ContinuousBatchingEngine:
             from ..jit import fusion as _fusion
 
             def model_step(params, ids, token_row, positions, kv_lens,
-                           cand_idx, k_pages, v_pages, bt):
-                return L.ragged_step(params, ids, token_row, positions,
-                                     kv_lens, cand_idx, k_pages, v_pages,
-                                     bt, mcfg, mesh=mesh,
-                                     mp_axis=mp_axis)[:3]
+                           cand_idx, pools, bt):
+                return _split_step(L.ragged_step(
+                    params, ids, token_row, positions, kv_lens, cand_idx,
+                    *pools, bt, mcfg, mesh=mesh, mp_axis=mp_axis),
+                    len(pools))[:2]
 
             return _fusion.build_fused_spec_step(
                 model_step, tail, _constrain.reset_states, self.spec_k,
@@ -1419,11 +1445,11 @@ class ContinuousBatchingEngine:
 
         def run(params, ids, token_row, positions, kv_lens, cand_idx,
                 drafts, draft_len, sampled, gstate, greset, samp, gtable,
-                k_pages, v_pages, bt):
+                pools, bt):
             gstate = _constrain.reset_states(gstate, greset)
-            logits, kp, vp = L.ragged_step(
+            logits, pools, _ = _split_step(L.ragged_step(
                 params, ids, token_row, positions, kv_lens, cand_idx,
-                k_pages, v_pages, bt, mcfg, mesh=mesh, mp_axis=mp_axis)[:3]
+                *pools, bt, mcfg, mesh=mesh, mp_axis=mp_axis), len(pools))
             # the speculative sampling epilogue (spec_sample_rows):
             # greedy rows keep the per-candidate argmax + prefix-match
             # verify (byte-identical to the pre-sampling program),
@@ -1439,9 +1465,9 @@ class ContinuousBatchingEngine:
             # grammar state (a mid-prefill constrained row's candidate
             # slot holds garbage)
             gstate = jnp.where(sampled, ngst, gstate)
-            return toks, accepted, gstate, kp, vp
+            return toks, accepted, gstate, pools
 
-        return jax.jit(run, donate_argnums=(13, 14))
+        return jax.jit(run, donate_argnums=(13,))
 
     def _plan_spec(self):
         """Host layout of one speculative round. Every decode row claims
@@ -1658,14 +1684,13 @@ class ContinuousBatchingEngine:
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
         # fused and unfused spec programs share one signature since the
         # verify/sampling epilogue moved in-program for both
-        (toks, accepted, self._gstate_dev, self.mgr.k_pages,
-         self.mgr.v_pages) = self._spec_step(
+        toks, accepted, self._gstate_dev, self.mgr.pools = self._spec_step(
             params, *(jnp.asarray(a) for a in plan),
             jnp.asarray(drafts), jnp.asarray(draft_len),
             jnp.asarray(sampled), self._gstate_dev,
             self._upload_row_state(), self._samp_dev,
-            self._arena.device_table(), self.mgr.k_pages,
-            self.mgr.v_pages, jnp.asarray(self._bt))
+            self._arena.device_table(), self.mgr.pools,
+            jnp.asarray(self._bt))
         if fresh:
             jax.block_until_ready(toks)
             recompiles.observe_compile("cbe.spec_step",

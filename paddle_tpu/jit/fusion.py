@@ -676,8 +676,8 @@ def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
     — fed from the packed plan of :func:`pack_plan`.
 
     ``model_step(params, ids, token_row, positions, kv_lens, last_idx,
-    k_pages, v_pages, bt, gstate, gtable) -> (logits, k_pages,
-    v_pages)`` (the grammar state rides into the model's logits
+    pools, bt, gstate, gtable) -> (logits, pools)``, ``pools`` the tuple
+    of the cache's arrays (the grammar state rides into the model's logits
     epilogue hook so masking happens before the sampler);
     ``sample_fn(logits, pos_next, samp, gstate, gtable) ->
     ((rows,) int32 tokens, (rows,) int32 grammar states)`` — the
@@ -688,7 +688,7 @@ def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
     """
 
     def run(params, plan_tt, plan_tr, tok, gstate, greset, samp, gtable,
-            k_pages, v_pages, bt):
+            pools, bt):
         gstate = reset_fn(gstate, greset)
         ids = plan_tt[0]
         use_carry = plan_tt[1].astype(bool)
@@ -699,26 +699,26 @@ def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
         sample_mask = plan_tr[2].astype(bool)
 
         def micro(carry, xs):
-            tok, gst, kp, vp = carry
+            tok, gst, pools = carry
             ids_k, uc_k, tr_k, pos_k, kvl_k, li_k, sm_k = xs
             row_c = jnp.clip(tr_k, 0, num_rows - 1)
             ids_eff = jnp.where(uc_k, jnp.take(tok, row_c), ids_k)
-            logits, kp, vp = model_step(params, ids_eff, tr_k, pos_k,
-                                        kvl_k, li_k, kp, vp, bt,
-                                        gst, gtable)
+            logits, pools = model_step(params, ids_eff, tr_k, pos_k,
+                                       kvl_k, li_k, pools, bt,
+                                       gst, gtable)
             nxt, ngst = sample_fn(logits, kvl_k, samp, gst, gtable)
             emit = tok
             tok = jnp.where(sm_k, nxt, tok)
             gst = jnp.where(sm_k, ngst, gst)
-            return (tok, gst, kp, vp), emit
+            return (tok, gst, pools), emit
 
-        (tok, gstate, k_pages, v_pages), toks = jax.lax.scan(
-            micro, (tok, gstate, k_pages, v_pages),
+        (tok, gstate, pools), toks = jax.lax.scan(
+            micro, (tok, gstate, pools),
             (ids, use_carry, token_row, positions, kv_lens, last_idx,
              sample_mask))
-        return toks, tok, gstate, k_pages, v_pages
+        return toks, tok, gstate, pools
 
-    return jax.jit(run, donate_argnums=(8, 9))
+    return jax.jit(run, donate_argnums=(8,))
 
 
 def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
@@ -743,11 +743,10 @@ def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
 
     def run(params, ids, token_row, positions, kv_lens, cand_idx,
             drafts, draft_len, sampled, gstate, greset, samp, gtable,
-            k_pages, v_pages, bt):
+            pools, bt):
         gstate = reset_fn(gstate, greset)
-        logits, kp, vp = model_step(params, ids, token_row, positions,
-                                    kv_lens, cand_idx, k_pages, v_pages,
-                                    bt)
+        logits, pools = model_step(params, ids, token_row, positions,
+                                   kv_lens, cand_idx, pools, bt)
         lg = logits.reshape(num_rows, k1, -1)
         pos_base = jnp.take(positions,
                             cand_idx.reshape(num_rows, k1)[:, 0])
@@ -755,6 +754,6 @@ def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
                                               pos_base, samp, gstate,
                                               gtable)
         gstate = jnp.where(sampled, ngst, gstate)
-        return toks, accepted, gstate, kp, vp
+        return toks, accepted, gstate, pools
 
-    return jax.jit(run, donate_argnums=(13, 14))
+    return jax.jit(run, donate_argnums=(13,))

@@ -39,21 +39,19 @@ import jax.numpy as jnp
 from ..ops.paged_attention import PagedKVCacheManager
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _copy_page_slab(k_pages, v_pages, src, dst):
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _copy_page_slab(pools, src, dst):
     # donated buffers update in place: only the copied page's slab moves,
-    # not the whole pool (an eager .at[].set would copy both pool arrays)
-    return (k_pages.at[:, dst].set(k_pages[:, src]),
-            v_pages.at[:, dst].set(v_pages[:, src]))
+    # not the whole pool (an eager .at[].set would copy every pool array)
+    return tuple(p.at[:, dst].set(p[:, src]) for p in pools)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1))
-def _write_page_slab(k_pages, v_pages, k_slab, v_slab, dst):
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _write_page_slab(pools, slabs, dst):
     # migration import: scatter one host-provided page slab (every layer)
     # into the donated pool arrays; the page id rides as a traced scalar
     # so N imported pages reuse one compiled program
-    return (k_pages.at[:, dst].set(k_slab),
-            v_pages.at[:, dst].set(v_slab))
+    return tuple(p.at[:, dst].set(s) for p, s in zip(pools, slabs))
 
 
 class RefcountedKVCacheManager(PagedKVCacheManager):
@@ -181,8 +179,8 @@ class RefcountedKVCacheManager(PagedKVCacheManager):
         ``dst``. One jitted, donated gather-scatter on the pool arrays —
         the same update machinery as ``paged_write_array``, page-granular
         (page ids ride as traced scalars, so this compiles once)."""
-        self.k_pages, self.v_pages = _copy_page_slab(
-            self.k_pages, self.v_pages, jnp.int32(src), jnp.int32(dst))
+        self.pools = _copy_page_slab(self.pools, jnp.int32(src),
+                                     jnp.int32(dst))
 
     # -- page-granular export/import (DCN migration) -------------------------
 
@@ -212,20 +210,23 @@ class RefcountedKVCacheManager(PagedKVCacheManager):
         self._free.extend(pages)
 
     def export_page(self, page: int):
-        """Read one page's K and V slabs (every layer) off the device as
-        a ``(k_slab, v_slab)`` pair of host arrays — the wire format's
-        payload unit."""
+        """Read one page's slabs (every layer), one per pool array, off
+        the device as host arrays — the wire format's payload unit, a
+        ``(k_slab, v_slab)`` pair under the default layout."""
         import numpy as np
-        return (np.asarray(self.k_pages[:, page]),
-                np.asarray(self.v_pages[:, page]))
+        return tuple(np.asarray(p[:, page]) for p in self.pools)
 
-    def write_page(self, page: int, k_slab, v_slab) -> None:
-        """Scatter a host-provided slab pair into ``page`` device-side
-        (jitted, donated; compiles once — page ids are traced)."""
-        self.k_pages, self.v_pages = _write_page_slab(
-            self.k_pages, self.v_pages,
-            jnp.asarray(k_slab, self.k_pages.dtype),
-            jnp.asarray(v_slab, self.v_pages.dtype),
+    def write_page(self, page: int, *slabs) -> None:
+        """Scatter host-provided slabs, one per pool array, into ``page``
+        device-side (jitted, donated; compiles once — page ids are
+        traced)."""
+        if len(slabs) != len(self.pools):
+            raise ValueError(f"{len(slabs)} slabs for a pool of "
+                             f"{len(self.pools)} array(s)")
+        self.pools = _write_page_slab(
+            self.pools,
+            tuple(jnp.asarray(s, p.dtype)
+                  for s, p in zip(slabs, self.pools)),
             jnp.int32(page))
 
     # -- accounting ----------------------------------------------------------

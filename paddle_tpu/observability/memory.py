@@ -97,13 +97,18 @@ MAX_MIGRATIONS = 64
 # pure helpers (the ONE place these derivations live)
 # ---------------------------------------------------------------------------
 
-def page_nbytes(num_layers: int, page_size: int, num_kv_heads: int,
-                head_dim: int, dtype_bytes: int) -> int:
-    """Device bytes of ONE page across every layer: K and V slabs (the
-    factor 2) × layers × page_size tokens × kv_heads × head_dim ×
-    element size. Derived from geometry — an int8 page pool
+def page_nbytes(num_layers: int, page_size: int,
+                num_kv_heads: Optional[int], head_dim: Optional[int],
+                dtype_bytes: int, token_elems: Optional[int] = None) -> int:
+    """Device bytes of ONE page across every layer: layers × page_size
+    tokens × the numbers a token keeps per layer × element size. A token
+    keeps K and V slabs of kv_heads × head_dim (the factor 2), or, for a
+    cache of another layout (``ops.paged_attention.CacheLayout``),
+    ``token_elems``. Derived from geometry — an int8 page pool
     (``dtype_bytes=1``) halves it with no ledger change."""
-    return 2 * num_layers * page_size * num_kv_heads * head_dim * dtype_bytes
+    if token_elems is None:
+        token_elems = 2 * num_kv_heads * head_dim
+    return num_layers * page_size * token_elems * dtype_bytes
 
 
 def pytree_nbytes(tree: Any) -> int:
@@ -146,8 +151,7 @@ def _mgr_page_nbytes(mgr) -> int:
     pb = getattr(mgr, "page_nbytes", None)
     if pb is not None:
         return int(pb)
-    return (int(mgr.k_pages.nbytes) + int(mgr.v_pages.nbytes)) \
-        // int(mgr.num_pages)
+    return sum(int(p.nbytes) for p in mgr.pools) // int(mgr.num_pages)
 
 
 # ---------------------------------------------------------------------------
@@ -183,10 +187,12 @@ class CapacityPlan:
         }
 
 
-def plan_capacity(*, num_layers: int, num_kv_heads: int, head_dim: int,
+def plan_capacity(*, num_layers: int, num_kv_heads: Optional[int] = None,
+                  head_dim: Optional[int] = None,
                   page_size: int, dtype_bytes: int, hbm_bytes: int,
                   weight_bytes: int = 0,
-                  max_seq_len: Optional[int] = None) -> CapacityPlan:
+                  max_seq_len: Optional[int] = None,
+                  token_elems: Optional[int] = None) -> CapacityPlan:
     """Model geometry + page size + dtype + HBM budget → pool capacity.
 
     ``hbm_bytes`` is the device budget; ``weight_bytes`` (resident model
@@ -197,7 +203,7 @@ def plan_capacity(*, num_layers: int, num_kv_heads: int, head_dim: int,
     if page_size <= 0 or num_layers <= 0:
         raise ValueError("geometry must be positive")
     pb = page_nbytes(num_layers, page_size, num_kv_heads, head_dim,
-                     dtype_bytes)
+                     dtype_bytes, token_elems)
     kv_budget = max(0, int(hbm_bytes) - int(weight_bytes))
     total = kv_budget // pb
     usable = max(0, total - 1)            # page 0 is the reserved pad page
@@ -479,15 +485,14 @@ class MemoryLedger:
             pool.page_size = int(mgr.page_size)
             pool.usable_pages = int(mgr.usable_pages)
             pool.page_bytes = _mgr_page_nbytes(mgr)
-            pool.pool_bytes = (int(mgr.k_pages.nbytes)
-                               + int(mgr.v_pages.nbytes))
+            pool.pool_bytes = sum(int(p.nbytes) for p in mgr.pools)
             # planner verdict: re-derive the plan from the pool's own
             # geometry + byte size; it must predict capacity exactly
-            shape = mgr.k_pages.shape      # (L, P, page, kv_heads, dim)
+            shape = mgr.pools[0].shape     # (L, P, page) + a token's entry
             plan = plan_capacity(
-                num_layers=int(shape[0]), num_kv_heads=int(shape[3]),
-                head_dim=int(shape[4]), page_size=int(shape[2]),
-                dtype_bytes=int(mgr.k_pages.dtype.itemsize),
+                num_layers=int(shape[0]), page_size=int(shape[2]),
+                token_elems=int(mgr.layout.token_elems),
+                dtype_bytes=int(mgr.pools[0].dtype.itemsize),
                 hbm_bytes=pool.pool_bytes)
             pool.verdict = plan_verdict(plan, mgr)
             self._pools[key] = pool

@@ -21,6 +21,7 @@ decode-shaped reference the tests hold the ragged composition to.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import List, Optional, Tuple
@@ -299,45 +300,66 @@ def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
             | last.astype(jnp.int32), ends[-1, 0])
 
 
+def _block_dma(block_tables_ref, page_bits: int, group: int, pools):
+    """The page copies of one work item, for both ragged kernels. ``pools``:
+    per pool array its (HBM ref, VMEM double buffer (2, G, page, ...), DMA
+    semaphores (2,)). Returns ``fetch(item, side)``, which starts the copies
+    of the item's G pages into buffer half ``side`` (a slot past the row's
+    last page reads a page clipped into the table, under key positions no
+    token of the row has reached; the G copies of a pool's block share one
+    semaphore), and ``wait(side)`` (a wait reads only the copy's size and
+    semaphore, not its page)."""
+    max_pages = block_tables_ref.shape[1]
+
+    def copies(p, side, slot):
+        return [pltpu.make_async_copy(hbm.at[p], buf.at[side, slot],
+                                      sem.at[side])
+                for hbm, buf, sem in pools]
+
+    def fetch(item, side):
+        row, first_page, _, _ = _unpack_work_item(item, page_bits)
+        for slot in range(group):
+            p = block_tables_ref[row, jnp.minimum(first_page + slot,
+                                                  max_pages - 1)]
+            for copy in copies(p, side, slot):
+                copy.start()
+
+    def wait(side):
+        for slot in range(group):
+            for copy in copies(0, side, slot):
+                copy.wait()
+
+    return fetch, wait
+
+
+def _stream_blocks(i, n_live, work_ref, fetch):
+    """The double buffer's schedule, for both ragged kernels: step 0 starts
+    its own block's copies, and every step starts the next block's into the
+    other half, so that it streams in while this one is folded."""
+    @pl.when((i == 0) & (n_live > 0))
+    def _fetch_first():
+        fetch(work_ref[0], 0)
+
+    @pl.when(i + 1 < n_live)
+    def _fetch_next():
+        fetch(work_ref[i + 1], 1 - i % 2)
+
+
 def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
                              window_ref, token_row_ref, positions_ref, q_ref,
                              k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref,
                              k_buf, v_buf, sems, *, page: int, group: int,
                              page_bits: int, scale: float, windowed: bool):
     keys = group * page
-    max_pages = block_tables_ref.shape[1]
     i = pl.program_id(0)
     r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
     # false only in the one step of a call that has no live block
     live = i < n_live_ref[0]
     half = i % 2
-
-    def page_copies(p, side, slot):
-        """Physical page ``p`` of K and of V into slot ``slot`` of buffer
-        half ``side``; the G copies of a pool's block share one semaphore."""
-        return (pltpu.make_async_copy(k_hbm.at[p], k_buf.at[side, slot],
-                                      sems.at[0, side]),
-                pltpu.make_async_copy(v_hbm.at[p], v_buf.at[side, slot],
-                                      sems.at[1, side]))
-
-    def fetch(item, side):
-        # a slot past the row's last page reads a page clipped into the
-        # table, under key positions no token of the row has reached
-        row, first_page, _, _ = _unpack_work_item(item, page_bits)
-        for slot in range(group):
-            p = block_tables_ref[row, jnp.minimum(first_page + slot,
-                                                  max_pages - 1)]
-            for copy in page_copies(p, side, slot):
-                copy.start()
-
-    @pl.when(live & (i == 0))
-    def _fetch_first():
-        fetch(work_ref[0], 0)
-
-    @pl.when(i + 1 < n_live_ref[0])
-    def _fetch_next():
-        # the next block streams in while this one is folded
-        fetch(work_ref[i + 1], 1 - half)
+    fetch, wait = _block_dma(
+        block_tables_ref, page_bits, group,
+        [(k_hbm, k_buf, sems.at[0]), (v_hbm, v_buf, sems.at[1])])
+    _stream_blocks(i, n_live_ref[0], work_ref, fetch)
 
     @pl.when(i == 0)
     def _zero_out():
@@ -357,10 +379,7 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
     @pl.when(live)
     def _compute():
         q = q_ref[...].astype(jnp.float32)          # (nkv, T*rep, d)
-        for slot in range(group):
-            # a wait reads only the copy's size and semaphore, not its page
-            for copy in page_copies(0, half, slot):
-                copy.wait()
+        wait(half)
 
         def block(buf):
             # the block's pages as one (nkv, G*page, d) operand: batched
@@ -561,6 +580,285 @@ def _ragged_paged_attention_shard_mapped(q, k_pages, v_pages, block_tables,
 
 
 # ---------------------------------------------------------------------------
+# Latent (MLA) ragged paged attention: one pool, no head axis
+# ---------------------------------------------------------------------------
+
+#: tokens of one row the latent kernel folds a block into at a time: 4 tokens
+#: x 64 heads fill the MXU's rows on a prefill row (scratch timing on the
+#: chip, PERF.md section 6, PR 31: 10.9 us a block against 18.7 one token at
+#: a time, 10.2 at 8); a decode row is one token whatever this says
+_MLA_TOKEN_TILE = 4
+
+def mla_paged_attention_array(q, pool, block_tables, token_row, positions,
+                              kv_lens=None, scale: Optional[float] = None,
+                              value_dim: Optional[int] = None):
+    """XLA reference of the latent ragged kernel (gather/mask composition).
+
+    Multi-head latent attention in its absorbed form: every head attends to
+    the SAME entry of a token, the pool has no head axis, and the values are
+    the first ``value_dim`` numbers of the keys (the normed latent; the rest
+    is the shared roped key).
+
+    q:            (T, nh, d)    — packed queries ``[q~ | q_rope]``
+    pool:         (P, page, d)  — a token's entry ``[c_kv | k_rope]``
+    block_tables, token_row, positions, kv_lens: as
+                  :func:`ragged_paged_attention_array`; the mask is
+                  ``key_pos <= positions[t]``
+    Returns (T, nh, value_dim)."""
+    t, nh, d = q.shape
+    page = pool.shape[1]
+    n_rows, max_pages = block_tables.shape
+    value_dim = d if value_dim is None else value_dim
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    row_c = jnp.clip(token_row, 0, n_rows - 1)
+    bt_tok = jnp.take(block_tables, row_c, axis=0)          # (T, W)
+    kv = jnp.take(pool, bt_tok, axis=0).reshape(t, max_pages * page, d)
+    key_pos = jnp.arange(max_pages * page)[None, :]
+    mask = (key_pos <= positions[:, None]) & (token_row >= 0)[:, None]
+    scores = jnp.einsum("thd,tsd->ths", q.astype(jnp.float32),
+                        kv.astype(jnp.float32)) * s
+    scores = jnp.where(mask[:, None, :], scores, _NEG_INF)
+    probs = jax.nn.softmax(scores, axis=-1)
+    return jnp.einsum("ths,tsv->thv", probs.astype(pool.dtype),
+                      kv[..., :value_dim])
+
+
+def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
+                          tok_start_ref, tok_count_ref, positions_ref, q_ref,
+                          pool_hbm, o_ref, m_ref, l_ref, acc_ref, buf, sems,
+                          *, page: int, group: int, page_bits: int,
+                          scale: float, value_dim: int):
+    keys = group * page
+    tile = _MLA_TOKEN_TILE
+    heads, d = q_ref.shape[1:]
+    i = pl.program_id(0)
+    r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
+    # false only in the one step of a call that has no live block
+    live = i < n_live_ref[0]
+    half = i % 2
+    fetch, wait = _block_dma(block_tables_ref, page_bits, group,
+                             [(pool_hbm, buf, sems)])
+    _stream_blocks(i, n_live_ref[0], work_ref, fetch)
+
+    @pl.when(i == 0)
+    def _zero_out():
+        # pad slots and tokens of rows with no page belong to no work item
+        # (see the GQA kernel)
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live)
+    def _compute():
+        wait(half)
+        # the block, read once: keys are its rows as they lie (no head
+        # axis, nothing to transpose), values their first lanes
+        block = buf[half].reshape(keys, d)
+        values = block[:, :value_dim]
+        key_pos = j * page + jax.lax.broadcasted_iota(
+            jnp.int32, (1, keys), 1)
+        t0, n = tok_start_ref[r], tok_count_ref[r]
+
+        def fold(t, tt: int):
+            """Fold the block into tokens ``t .. t + tt - 1`` of the row:
+            ``tt x heads`` query rows, and no other token's."""
+            rows = tt * heads
+            q = q_ref[pl.ds(t, tt)].reshape(rows, d)
+            pos = positions_ref[t]
+            if tt > 1:
+                token_of = jax.lax.broadcasted_iota(
+                    jnp.int32, (rows, 1), 0) // heads
+                for k in range(1, tt):
+                    pos = jnp.where(token_of == k, positions_ref[t + k], pos)
+            sc = jax.lax.dot_general(
+                q, block, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale  # (rows, keys)
+            sc = jnp.where(key_pos <= pos, sc, _NEG_INF)
+            at = pl.ds(t, tt)
+            # a row's first block starts its tokens' state
+            m_prev = jnp.where(
+                first, _NEG_INF, m_ref[at].reshape(rows, 128)[:, :1])
+            l_prev = jnp.where(
+                first, 0.0, l_ref[at].reshape(rows, 128)[:, :1])
+            acc_prev = jnp.where(
+                first, 0.0, acc_ref[at].reshape(rows, value_dim))
+            m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            p = jnp.exp(sc - m_new)
+            l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
+            acc = acc_prev * alpha + jax.lax.dot_general(
+                p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)     # (rows, value_dim)
+            m_ref[at] = jnp.broadcast_to(m_new, (rows, 128)).reshape(
+                tt, heads, 128)
+            l_ref[at] = jnp.broadcast_to(l_new, (rows, 128)).reshape(
+                tt, heads, 128)
+            acc_ref[at] = acc.reshape(tt, heads, value_dim)
+
+            @pl.when(last)
+            def _finalize():
+                out = acc / jnp.where(l_new == 0.0, 1.0, l_new)
+                o_ref[at] = out.reshape(tt, heads, value_dim).astype(
+                    o_ref.dtype)
+
+        # the row's tokens are contiguous in the packed axis: whole tiles
+        # of ``_MLA_TOKEN_TILE`` tokens (a prefill row fills the MXU's
+        # rows), then the rest one token at a time (a decode row is one
+        # token: its heads)
+        n_tiles = n // tile
+
+        def tile_body(k, carry):
+            fold(t0 + k * tile, tile)
+            return carry
+
+        def token_body(k, carry):
+            fold(t0 + n_tiles * tile + k, 1)
+            return carry
+
+        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
+        jax.lax.fori_loop(0, n - n_tiles * tile, token_body, 0)
+
+
+def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
+                               kv_lens, scale: Optional[float] = None,
+                               value_dim: Optional[int] = None,
+                               interpret: bool = False):
+    """Pallas latent ragged kernel: same contract as
+    :func:`mla_paged_attention_array`, with each row's tokens CONTIGUOUS in
+    the packed axis (the engine's plan packs them so).
+
+    The grid, the work list of live blocks, the scalar-prefetched block
+    table and the double-buffered page copies are the GQA kernel's
+    (:func:`ragged_paged_attention_pallas`; ``_ragged_work_list``,
+    ``_block_dma``, ``_stream_blocks``). What differs is what a step folds:
+    the pool has no head axis, so a block of G pages is ONE (G x page, d)
+    operand that is read once, its rows the keys of every head and its
+    first ``value_dim`` lanes the values; and a work item folds its block
+    into the query rows of ITS row only (the row's tokens x heads, found by
+    the row's first token and token count, both scalar-prefetched), in
+    tiles of ``_MLA_TOKEN_TILE`` tokens and then single tokens, where the
+    GQA kernel folds every block into all T x rep query rows under a mask. Queries,
+    softmax state and output stay (tokens, heads, ·) as they come: nothing
+    is laid out before or after the call."""
+    t, nh, d = q.shape
+    page = pool.shape[1]
+    n_rows, max_pages = block_tables.shape
+    value_dim = d if value_dim is None else value_dim
+    s = scale if scale is not None else 1.0 / math.sqrt(d)
+    page_bits = _work_item_bits(max_pages)
+    group = ragged_block_pages(page, max_pages)
+    work, n_live = _ragged_work_list(kv_lens, page, max_pages)
+    mine = token_row[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
+    tok_count = jnp.sum(mine, axis=1, dtype=jnp.int32)
+    tok_start = jnp.argmax(mine, axis=1).astype(jnp.int32)
+
+    whole = lambda i, *_: (0, 0, 0)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # block_tables, work list, n_live, each row's first token and token
+        # count, positions
+        num_scalar_prefetch=6,
+        grid=(jnp.maximum(n_live, 1),),
+        in_specs=[pl.BlockSpec((t, nh, d), whole),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((t, nh, value_dim), whole),
+        scratch_shapes=[
+            pltpu.VMEM((t, nh, 128), jnp.float32),
+            pltpu.VMEM((t, nh, 128), jnp.float32),
+            pltpu.VMEM((t, nh, value_dim), jnp.float32),
+            pltpu.VMEM((2, group, page, d), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+    )
+    kernel = functools.partial(
+        _mla_attention_kernel, page=page, group=group, page_bits=page_bits,
+        scale=s, value_dim=value_dim)
+    # queries and output are whole-array blocks (double-buffered by the
+    # pipeline) beside the float32 state: past the default scoped limit at
+    # the serving shapes (32 tokens x 64 heads: ~16 MB)
+    item = jnp.dtype(pool.dtype).itemsize
+    lanes = lambda n: -(-n // 128) * 128
+    vmem = (2 * t * nh * (lanes(d) + lanes(value_dim)) * item
+            + t * nh * (256 + lanes(value_dim)) * 4
+            + 2 * group * page * lanes(d) * item)
+    return pl.pallas_call(
+        kernel,
+        name="mla_paged_attention",
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((t, nh, value_dim), pool.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=int(vmem) + (16 << 20)),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32), work, n_live.reshape(1), tok_start,
+      tok_count, positions.astype(jnp.int32), q, pool)
+
+
+def mla_paged_attention(q, pool, block_tables, token_row, positions, kv_lens,
+                        scale: Optional[float] = None,
+                        value_dim: Optional[int] = None):
+    """Dispatcher: the Pallas latent kernel on TPU
+    (FLAGS_use_pallas_kernels), its XLA twin elsewhere; same contract
+    (:func:`mla_paged_attention_array`). One chip: a pool without a head
+    axis has nothing a tensor-parallel mesh could split."""
+    from ._common import use_pallas
+    impl = mla_paged_attention_pallas if use_pallas() \
+        else mla_paged_attention_array
+    return impl(q, pool, block_tables, token_row, positions, kv_lens,
+                scale, value_dim)
+
+
+# ---------------------------------------------------------------------------
+# What a token keeps in the cache: the model's choice
+# ---------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """What ONE token keeps per layer in the paged pool: a pool array per
+    entry, ``(layers, pages, page) + entry``. Pages, block tables,
+    refcounts and the prefix index never look inside an entry: a page is a
+    page. ``head_axis``: the axis of every entry that a tensor-parallel
+    mesh splits (whole heads a chip), None where an entry has no head axis
+    and the pool lives on one chip."""
+    entries: Tuple[Tuple[int, ...], ...]
+    head_axis: Optional[int] = None
+
+    @property
+    def token_elems(self) -> int:
+        """Numbers a token keeps per layer, all entries together."""
+        return sum(math.prod(e) for e in self.entries)
+
+    def check_degree(self, chips: int) -> None:
+        """Refuse a tensor-parallel degree the entries cannot be split by."""
+        if chips <= 1:
+            return
+        if self.head_axis is None:
+            raise ValueError(
+                f"a cache entry of {self.entries} has no head axis: it "
+                f"cannot be split over a TP degree of {chips}")
+        heads = self.entries[0][self.head_axis]
+        if heads % chips:
+            raise ValueError(
+                f"num_kv_heads={heads} must divide by the TP "
+                f"degree {chips} (whole GQA groups per chip; "
+                "a split group would split single heads across chips)")
+
+    def pool_specs(self, mp_axis: str = "mp") -> List:
+        """PartitionSpec of each pool array on a TP mesh."""
+        from jax.sharding import PartitionSpec as P
+        specs = []
+        for entry in self.entries:
+            axes = [None] * (3 + len(entry))    # (layers, pages, page) first
+            if self.head_axis is not None:
+                axes[3 + self.head_axis] = mp_axis
+            specs.append(P(*axes))
+        return specs
+
+
+def kv_cache_layout(num_kv_heads: int, head_dim: int) -> CacheLayout:
+    """The default: K and V, each (kv heads, head_dim) a token, split by
+    kv head over a TP mesh."""
+    entry = (num_kv_heads, head_dim)
+    return CacheLayout((entry, entry), head_axis=0)
+
+
+# ---------------------------------------------------------------------------
 # Host-side page pool (the allocator metadata; device arrays hold the data)
 # ---------------------------------------------------------------------------
 
@@ -576,46 +874,70 @@ class PagedKVCacheManager:
     """
 
     def __init__(self, num_layers: int, num_pages: int, page_size: int,
-                 num_kv_heads: int, head_dim: int, dtype=jnp.bfloat16,
-                 mesh=None, mp_axis: str = "mp"):
-        """``mesh`` head-shards both page pools over its ``mp_axis``:
-        whole GQA (kv-head) groups per chip, so every page's bytes split
-        evenly across the TP mesh and attention stays head-local. Pure
-        LAYOUT — the allocator metadata (free list, tables, lens) is
-        host-side and chip-agnostic, which is what makes an elastic
-        resize a rebuild-and-replay rather than a data migration. The
-        pools are ALLOCATED sharded (never whole on one chip first): a
-        full-depth pool sized for the mesh does not fit a single chip."""
+                 num_kv_heads: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype=jnp.bfloat16,
+                 mesh=None, mp_axis: str = "mp",
+                 layout: Optional[CacheLayout] = None):
+        """``layout`` says what a token keeps per layer (:class:`CacheLayout`;
+        a model's ``cache_layout(config)``); without one it is K and V with
+        a head axis, ``kv_cache_layout(num_kv_heads, head_dim)``. One pool
+        array per entry of the layout, ``(layers, pages, page) + entry``.
+
+        ``mesh`` shards every pool over its ``mp_axis`` along the layout's
+        head axis: whole GQA (kv-head) groups per chip, so every page's
+        bytes split evenly across the TP mesh and attention stays
+        head-local. Pure LAYOUT — the allocator metadata (free list,
+        tables, lens) is host-side and chip-agnostic, which is what makes
+        an elastic resize a rebuild-and-replay rather than a data
+        migration. The pools are ALLOCATED sharded (never whole on one
+        chip first): a full-depth pool sized for the mesh does not fit a
+        single chip."""
         self.page_size = page_size
         self.num_pages = num_pages
-        shape = (num_layers, num_pages, page_size, num_kv_heads, head_dim)
+        self.layout = layout if layout is not None else kv_cache_layout(
+            num_kv_heads, head_dim)
         #: TP chips the pool is head-sharded over (1 = single-chip) — the
         #: memory ledger splits per-chip bytes off it and the engine
         #: stamps it into its compile keys
         self.mesh_chips: int = 1
-        sharding = None
+        shardings = [None] * len(self.layout.entries)
         if mesh is not None:
             from jax.sharding import NamedSharding
             self.mesh_chips = int(mesh.shape[mp_axis])
-            if num_kv_heads % self.mesh_chips:
-                raise ValueError(
-                    f"num_kv_heads={num_kv_heads} must divide by the TP "
-                    f"degree {self.mesh_chips} (whole GQA groups per chip; "
-                    "a split group would split single heads across chips)")
-            sharding = NamedSharding(mesh, self.pool_spec(mp_axis))
-        self.k_pages = jnp.zeros(shape, dtype, device=sharding)
-        self.v_pages = jnp.zeros(shape, dtype, device=sharding)
+            self.layout.check_degree(self.mesh_chips)
+            shardings = [NamedSharding(mesh, spec)
+                         for spec in self.layout.pool_specs(mp_axis)]
+        #: the device arrays, one per entry of the layout (K and V by
+        #: default, also ``k_pages`` / ``v_pages``)
+        self.pools: Tuple = tuple(
+            jnp.zeros((num_layers, num_pages, page_size) + tuple(entry),
+                      dtype, device=sharding)
+            for entry, sharding in zip(self.layout.entries, shardings))
         self._free: List[int] = list(range(num_pages - 1, 0, -1))  # 0 reserved
         self._tables: dict = {}   # seq_id -> List[int]
         self._lens: dict = {}     # seq_id -> int
         self._page_nb: int = 0    # page_nbytes memo (geometry is fixed)
 
-    @staticmethod
-    def pool_spec(mp_axis: str = "mp"):
-        """PartitionSpec of a (L, P, page, nkv, d) pool on a TP mesh: the
-        kv-head axis over ``mp_axis``."""
-        from jax.sharding import PartitionSpec as P
-        return P(None, None, None, mp_axis, None)
+    # the default layout's two arrays under their old names; a layout
+    # without a V pool has no ``v_pages``
+    @property
+    def k_pages(self):
+        return self.pools[0]
+
+    @k_pages.setter
+    def k_pages(self, value):
+        self.pools = (value,) + self.pools[1:]
+
+    @property
+    def v_pages(self):
+        if len(self.pools) != 2:
+            raise AttributeError(
+                f"a pool of {len(self.pools)} array(s) has no v_pages")
+        return self.pools[1]
+
+    @v_pages.setter
+    def v_pages(self, value):
+        self.pools = (self.pools[0], value)
 
     # -- allocation ---------------------------------------------------------
 
@@ -642,16 +964,15 @@ class PagedKVCacheManager:
 
     @property
     def page_nbytes(self) -> int:
-        """Measured device bytes of ONE page (K + V slabs across every
-        layer) — the memory ledger's byte unit; an int8 pool halves it
-        automatically because it is read off the actual arrays.
+        """Measured device bytes of ONE page (every pool array's slab
+        across every layer) — the memory ledger's byte unit; an int8 pool
+        halves it automatically because it is read off the actual arrays.
         Memoized: the pool's geometry and dtype never change after
         construction."""
         pb = self._page_nb
         if not pb:
-            pb = self._page_nb = (
-                int(self.k_pages.nbytes)
-                + int(self.v_pages.nbytes)) // self.num_pages
+            pb = self._page_nb = sum(
+                int(p.nbytes) for p in self.pools) // self.num_pages
         return pb
 
     def _oom(self, source: str, need: int) -> None:

@@ -14,7 +14,8 @@ Checked at Llama-2-7B width (h=4096, 32x128 heads, ff=11008, bf16) and
 2 layers: the engine's unified step on one chip and on
 ``serving_mesh(4)``, the one-chip hybrid train step, and the unified step
 of an AFMoE engine at Trinity-Mini's widths (a window in the ragged kernel,
-the grouped expert product) — each must compile and carry its Pallas
+the grouped expert product) and of an A.X-K1 engine (the latent ragged
+kernel over a pool without a head axis) — each must compile and carry its Pallas
 kernels, by name, in the lowering. What it
 cannot show is whether the programs RUN correctly; that is
 ``chip_smoke.py``'s job, on the chip.
@@ -115,6 +116,26 @@ def afmoe_engine():
                                     prefix_cache=True)
 
 
+def axk1_engine():
+    """An engine at A.X-K1's widths (12 of the 192 experts held, one dense
+    and one expert layer, a sixteenth of the vocabulary): the unified step
+    with the latent ragged kernel over a one-array pool and the grouped
+    expert product."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    from paddle_tpu.models import axk1 as X
+
+    cfg = X.Axk1Config(
+        vocab_size=10240, num_hidden_layers=2, experts_held=12,
+        rope_scaling=dict(type="yarn", factor=32, beta_fast=32, beta_slow=1,
+                          mscale=1, mscale_all_dim=1,
+                          original_max_position_embeddings=4096),
+        dtype=jnp.bfloat16)
+    return ContinuousBatchingEngine(cfg, num_slots=32, page_size=16,
+                                    max_seq_len=17408, num_pages=1089,
+                                    prefix_cache=True)
+
+
 def check_train_step(devices):
     """bench.py's llama7b_layer geometry (B=8, S=2048, full remat) on one
     chip: flash attention fwd+bwd and rms_norm fwd+bwd."""
@@ -149,6 +170,10 @@ def run_checks():
             "afmoe_unified_step_mp1": check_unified_step(
                 afmoe_engine(), devices, 1,
                 expect=("ragged_paged_attention", "rms_norm_fwd",
+                        "moe_grouped_matmul")),
+            "axk1_unified_step_mp1": check_unified_step(
+                axk1_engine(), devices, 1,
+                expect=("mla_paged_attention", "rms_norm_fwd",
                         "moe_grouped_matmul")),
             "train_step": check_train_step(devices),
         }

@@ -207,7 +207,7 @@ def test_record_matches_the_plan_and_the_tokens(run):
             run.delivered + len(run.prompts) * (run.chunk - 1)
 
 
-UPLOAD_KEYS = {"admitted", "row_state_uploads"}
+UPLOAD_KEYS = {"admitted", "row_state_uploads", "cached_tokens"}
 
 
 def test_upload_span_counts_admissions_and_no_row_state_upload(run):
@@ -221,6 +221,11 @@ def test_upload_span_counts_admissions_and_no_row_state_upload(run):
     assert sum(u["admitted"] for u in uploads) == len(run.prompts)
     assert all(0 <= u["admitted"] <= SLOTS for u in uploads)
     assert all(u["row_state_uploads"] == 0 for u in uploads)
+    # ``cached_tokens``: prompt tokens the prefix cache already held for the
+    # requests a step admitted; none where it admitted nobody
+    assert all(u["cached_tokens"] >= 0 for u in uploads)
+    assert all(u["cached_tokens"] == 0 for u in uploads
+               if u["admitted"] == 0)
 
 
 @pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])

@@ -30,4 +30,9 @@ def test_serving_and_train_steps_compile_for_v5e_with_their_kernels():
     afmoe = out["afmoe_unified_step_mp1"]["kernels"]
     assert afmoe["ragged_paged_attention"] == 2
     assert afmoe["moe_grouped_matmul"] == 3
+    # A.X-K1: the latent kernel in both stacks, no GQA kernel anywhere
+    axk1 = out["axk1_unified_step_mp1"]["kernels"]
+    assert axk1["mla_paged_attention"] == 2
+    assert axk1["moe_grouped_matmul"] == 3
+    assert "ragged_paged_attention" not in axk1
     assert out["train_step"]["kernels"]["flash_attention_bwd_dkv"] >= 1
