@@ -130,16 +130,14 @@ def main():
     # chip (374M, B=8 S=2048): remat OFF out-of-memories; the "dots" policy
     # (save matmul outputs) reached only 34.3% MFU vs full remat's 37.6% —
     # the saved activations raise HBM pressure more than the skipped
-    # recompute saves. Round 3 also tried BENCH_REMAT=attn (save only the
-    # flash-attention outputs): 51.4% vs full remat's 52.0% at the 7B
-    # geometry — same verdict. Full remat stays default;
-    # BENCH_REMAT=full|dots|attn|off.
+    # recompute saves. "full" recomputes everything but the flash forward
+    # kernel, whose out and lse are kept. BENCH_REMAT=full|dots|offload|off.
     remat_mode = os.environ.get("BENCH_REMAT", "full")
     # legacy knob values from earlier rounds: 1 = full remat, 0 = off
     remat_mode = {"1": "full", "0": "off"}.get(remat_mode, remat_mode)
-    if remat_mode not in ("full", "dots", "attn", "offload", "off"):
+    if remat_mode not in ("full", "dots", "offload", "off"):
         sys.exit(f"unknown BENCH_REMAT={remat_mode!r}; "
-                 "pick from full|dots|attn|offload|off")
+                 "pick from full|dots|offload|off")
     # BENCH_KSTEP: k training steps per dispatch (lax.scan over a leading
     # k axis, params/opt-state carry donated) — amortizes the per-dispatch
     # host cost. k=1 is the single-step program. Default 8 from the

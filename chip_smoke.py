@@ -14,7 +14,8 @@ this process finds, and checks what comes out by the repo's own means:
           rms_norm fwd+bwd, flash attention fwd+bwd — against their XLA
           references, on the chip, at the phases' shapes.
   train   bench.py's llama7b_layer geometry (L=4, vocab 8192, B=8, S=2048,
-          full remat) through build_hybrid_train_step: loss finite + falling.
+          default remat) through build_hybrid_train_step: loss finite +
+          falling, one call site of each flash kernel (no replayed forward).
   fence   one steady train step timed to jax.block_until_ready and to a
           float(loss) read — the two must agree.
   4chips  only when the process holds >= 4 chips: the serve phase again on
@@ -139,14 +140,18 @@ def release() -> int:
     return bytes_in_use(1)[0]
 
 
-def require_kernels(lowered, names, where: str) -> dict:
+def require_kernels(lowered, names, where: str, sites=None) -> dict:
     """No kernel gave way to its reference: every named Pallas kernel is a
-    Mosaic custom call in the lowering."""
+    Mosaic custom call in the lowering; ``sites`` holds kernels to an exact
+    number of call sites."""
     from paddle_tpu.ops._common import mosaic_kernels
     found = mosaic_kernels(lowered)
     missing = sorted(set(names) - set(found))
     check(not missing, f"{where}: Pallas kernels {missing} are not in the "
                        f"lowered program (found {found})")
+    wrong = {k: found.get(k, 0) for k, n in (sites or {}).items()
+             if found.get(k, 0) != n}
+    check(not wrong, f"{where}: call sites {wrong}, expected {sites}")
     return found
 
 
@@ -497,9 +502,11 @@ def train_phase(sz: Sizes, name: str = "train", degrees=None,
     labels = np.roll(ids, -1, axis=-1)
     kernels = require_kernels(
         step.lower(params, opt_state, ids, labels),
-        ("flash_attention_fwd", "flash_attention_bwd_dq",
-         "flash_attention_bwd_dkv", "rms_norm_fwd", "rms_norm_bwd"),
-        "train step")
+        ("rms_norm_fwd", "rms_norm_bwd"), "train step",
+        # the backward reads the forward's saved out and lse: a second
+        # forward site is the S^2 kernel replayed under remat
+        sites={"flash_attention_fwd": 1, "flash_attention_bwd_dq": 1,
+               "flash_attention_bwd_dkv": 1})
 
     t0 = time.perf_counter()
     loss, params, opt_state = step(params, opt_state, ids, labels)

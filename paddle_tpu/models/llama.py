@@ -439,11 +439,7 @@ def _decoder_layer_manual(p, x, cos, sin, config: LlamaConfig, mp_axis,
         if sep_axis is not None:
             attn = lax.all_to_all(attn, sep_axis, split_axis=1, concat_axis=2,
                                   tiled=True)
-    # named for the selective remat policy (remat_policy="attn"). NOTE the
-    # measured verdict (BASELINE.md): the flash custom_vjp still replays
-    # its forward to rematerialize the unsaved LSE, so saving these
-    # outputs buys little and the extra live memory made it SLOWER than
-    # full remat (51.4% vs 52.0% at the 7B geometry) — kept as a knob
+    # named for remat_policy="offload", which streams this copy to host
     from jax.ad_checkpoint import checkpoint_name as _ckpt_name
     attn = _ckpt_name(attn, "attn_out")
     attn = attn.reshape(b, s, -1)
@@ -499,6 +495,13 @@ def build_hybrid_train_step(config: LlamaConfig, mesh: Mesh,
     repartition around attention).
     Optimizer: fused AdamW (state sharded like the weights).
 
+    ``remat_policy`` (with ``remat``): "full" (default) keeps a layer's
+    input and the flash forward kernel's ``out`` and ``lse`` (one more
+    (B, S, hidden) array a layer a microbatch) and recomputes the rest, so
+    the backward never runs the S^2 kernel a second time; "dots" also
+    keeps the matmul outputs; "offload" streams the attention output to
+    pinned host memory.
+
     ``pipeline_schedule``: "fill_drain" (default; becomes the interleaved
     virtual-pipeline schedule when virtual_pp > 1) or "1f1b" — the
     memory-scheduled one-forward-one-backward program
@@ -522,9 +525,9 @@ def build_hybrid_train_step(config: LlamaConfig, mesh: Mesh,
     if zero_gather == "per_step" and pipeline_schedule == "1f1b":
         raise ValueError("zero_gather='per_step' is a fill-drain-family "
                          "option (1f1b gathers per layer)")
-    if remat_policy not in ("full", "dots", "attn", "offload"):
+    if remat_policy not in ("full", "dots", "offload"):
         raise ValueError(f"unknown remat_policy {remat_policy!r} "
-                         "(expected 'full', 'dots', 'attn' or 'offload')")
+                         "(expected 'full', 'dots' or 'offload')")
     if pipeline_schedule == "1f1b":
         if mesh.shape.get("pp", 1) <= 1:
             raise ValueError("pipeline_schedule='1f1b' needs a pp axis > 1")
@@ -618,12 +621,6 @@ def build_hybrid_train_step(config: LlamaConfig, mesh: Mesh,
                         # remat at a modest activation-memory cost
                         fn = jax.checkpoint(
                             fn, policy=jax.checkpoint_policies.dots_saveable)
-                    elif remat_policy == "attn":
-                        # save only the flash-attention outputs: the one
-                        # recompute with superlinear (S^2) cost
-                        fn = jax.checkpoint(
-                            fn, policy=jax.checkpoint_policies
-                            .save_only_these_names("attn_out"))
                     elif remat_policy == "offload":
                         # VERDICT r3 item 9: stream the attention outputs
                         # to pinned HOST memory during forward and fetch
@@ -637,7 +634,12 @@ def build_hybrid_train_step(config: LlamaConfig, mesh: Mesh,
                                 offload_src="device",
                                 offload_dst="pinned_host"))
                     else:
-                        fn = jax.checkpoint(fn)
+                        # recompute everything but the flash forward: its
+                        # out and lse are kept, so the backward re-derives
+                        # q, k, v and does not run the S^2 kernel again
+                        fn = jax.checkpoint(
+                            fn, policy=jax.checkpoint_policies
+                            .save_only_these_names(*fa.SAVED_RESIDUALS))
                 return fn(lp, carry, cos, sin), None
 
             layer_params = {k: sparams[k] for k in LAYER_KEYS}

@@ -20,6 +20,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -394,6 +395,10 @@ def _pad_to(s: int, mult: int = 128) -> int:
     return -(-s // mult) * mult
 
 
+# checkpoint names of the Pallas forward's ``out`` and ``lse`` residuals
+SAVED_RESIDUALS = ("flash_attention_out", "flash_attention_lse")
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _flash_bhsd_inner(q, k, v, scale, causal, kv_valid, causal_offset):
     out, _ = _fa_fwd(q, k, v, scale, causal, kv_valid, causal_offset)
@@ -422,6 +427,12 @@ def _fa_fwd(q, k, v, scale, causal, kv_valid, causal_offset):
         out, lse = _flash_fwd_pallas(q, k, v, scale, causal, bq, bk,
                                      kv_valid=kv_valid,
                                      causal_offset=causal_offset)
+        # the two residuals only this S^2 kernel can give back: a
+        # jax.checkpoint whose policy saves SAVED_RESIDUALS keeps them and
+        # its backward does not run the kernel again (q, k, v are still
+        # recomputed); anywhere else a name is the identity
+        out = checkpoint_name(out, SAVED_RESIDUALS[0])
+        lse = checkpoint_name(lse, SAVED_RESIDUALS[1])
         return out, (q, k, v, out, lse)
     out = _attn_ref_kv(q, k, v, scale, causal, kv_valid, causal_offset)
     return out, (q, k, v, out, None)

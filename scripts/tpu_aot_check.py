@@ -137,8 +137,8 @@ def axk1_engine():
 
 
 def check_train_step(devices):
-    """bench.py's llama7b_layer geometry (B=8, S=2048, full remat) on one
-    chip: flash attention fwd+bwd and rms_norm fwd+bwd."""
+    """bench.py's llama7b_layer geometry (B=8, S=2048, default remat) on
+    one chip: flash attention fwd+bwd and rms_norm fwd+bwd."""
     import jax
     import jax.numpy as jnp
     from paddle_tpu.models import llama as L

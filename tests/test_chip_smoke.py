@@ -38,7 +38,7 @@ def test_rehearse_every_phase_at_toy_size(monkeypatch, capsys):
     #                         chip's 10%; only the flow is rehearsed
     # off the chip no Pallas kernel is in any lowering
     monkeypatch.setattr(chip_smoke, "require_kernels",
-                        lambda lowered, names, where: {})
+                        lambda lowered, names, where, sites=None: {})
     chip_smoke.run_phases(sz, n_devices=4, interpret=True)
     out = capsys.readouterr().out
     for phase in ("serve", "parity", "train", "fence", "4chips.serve",
