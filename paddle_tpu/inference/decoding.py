@@ -87,8 +87,12 @@ def _serving_module(model_config):
     ``ops.paged_attention.CacheLayout``; K and V of
     ``num_key_value_heads`` x ``head_dim`` where it names none),
     ``attention_windows(config)`` (per layer the sliding window, None
-    where attention is full) and the step's last return value (a small
-    int32 routing record, handed out with the tokens)."""
+    where attention is full), ``state_layout(config)`` (what a ROW keeps in
+    the model's recurrent layers, a ``kvcache.state.StateLayout``: the
+    step then takes and returns the state's arrays after the cache's, in
+    ``pools``, and starts a row whose first token is at position 0 from
+    zeros) and the step's last return value (a small int32 routing
+    record, handed out with the tokens)."""
     name = getattr(model_config, "serving_module", _LLAMA)
     module = importlib.import_module(name)
     missing = [n for n in _MODEL_PROTOCOL if not hasattr(module, n)]
@@ -241,9 +245,26 @@ class ContinuousBatchingEngine:
         layout = getattr(self._L, "cache_layout", None)
         layout = (layout(mcfg) if layout is not None else kv_cache_layout(
             mcfg.num_key_value_heads, mcfg.head_dim))
-        pool_args = (mcfg.num_hidden_layers, pool, page_size)
+        # pages for the layers that attend: all of them unless the layout
+        # names fewer
+        page_layers = layout.layers or mcfg.num_hidden_layers
+        pool_args = (page_layers, pool, page_size)
         pool_kw = dict(dtype=mcfg.dtype, mesh=mesh, mp_axis=mp_axis,
                        layout=layout)
+        # a model with recurrent layers also keeps a fixed-size state a
+        # ROW (kvcache/state.py): owned by the slot, carried through the
+        # step beside the pages. A prefix hit shares pages but not the
+        # state at that boundary, and a rejected draft rolls pages back
+        # but not a state: refused until the pool keeps snapshots
+        state_layout = getattr(self._L, "state_layout", None)
+        if state_layout is not None:
+            for on, option in ((prefix_cache, "prefix_cache"),
+                               (speculative, "speculative")):
+                if on:
+                    raise ValueError(
+                        f"{self._L.__name__} keeps a recurrent state a "
+                        f"row: {option}=True needs state snapshots, which "
+                        "the state pool does not keep")
         if prefix_cache:
             # shared-ownership pool + radix prefix index: retired prompts
             # stay resident and later requests prefill only their suffix
@@ -269,6 +290,11 @@ class ContinuousBatchingEngine:
                                                        or speculative)
         # host slot state
         self._slot_rid = [None] * num_slots       # rid occupying each slot
+        if state_layout is not None:
+            from ..kvcache.state import RowStatePool
+            # the manager audits and reports it with the pages
+            self.mgr.state = RowStatePool(state_layout(mcfg), num_slots,
+                                          owners=self._slot_rid)
         self._queue: list = []                    # pending _Request
         self._live: Dict[int, _Request] = {}      # rid -> request (slotted)
         self._finished: Dict[int, list] = {}
@@ -391,7 +417,7 @@ class ContinuousBatchingEngine:
         windows = getattr(self._L, "attention_windows", None)
         self._layer_windows = (
             tuple(windows(mcfg)) if windows is not None
-            else (None,) * mcfg.num_hidden_layers)
+            else (None,) * page_layers)
         self._window_layers = tuple(
             collections.Counter(self._layer_windows).items())
         # HBM memory ledger (observability/memory.py): when armed, every
@@ -1037,6 +1063,8 @@ class ContinuousBatchingEngine:
         pools = tuple(
             abstract(p.shape, p.dtype, spec) for p, spec in zip(
                 self.mgr.pools, self.mgr.layout.pool_specs(self._mp_axis)))
+        pools += tuple(abstract(a.shape, a.dtype)
+                       for a in self.mgr.arrays[len(pools):])
         if self._fused_tail:        # jit.fusion.pack_plan's two uploads
             plan = [abstract((4, K, tb), jnp.int32),
                     abstract((3, K, R), jnp.int32)]
@@ -1188,7 +1216,9 @@ class ContinuousBatchingEngine:
         ``causal_pairs`` query-key pairs pass the mask. A model with
         sliding-window layers (its serving module's ``attention_windows``)
         adds ``window_skipped_pages``: live pages a full mask would have
-        listed and the window did not, the same mean.
+        listed and the window did not, the same mean. A model with a state a
+        row (``state_layout``) adds ``state_row_rounds``, ``state_resets``
+        and ``state_bytes_per_row`` (at the end).
         Computed on every dispatch (a few vectorised numpy lines)."""
         from ..ops.paged_attention import (ragged_block_pages,
                                            ragged_first_pages,
@@ -1231,6 +1261,18 @@ class ContinuousBatchingEngine:
         if len(self._window_layers) > 1 or self._layer_windows[0] is not None:
             record["window_skipped_pages"] = round(
                 int(full_pages.sum()) - attended / layers)
+        state = self.mgr.state
+        if state is not None:
+            # a model with a state a row: the rows whose state each
+            # micro-round advanced (a row with a token that round), summed
+            # over the rounds; the rows that started from zeros (their first
+            # token at position 0); one row's state, one layer, as counted
+            worked = np.zeros((self.chunk, self.num_slots + 1), bool)
+            worked[np.arange(self.chunk)[:, None], token_row] = True
+            record["state_row_rounds"] = int(worked[:, :-1].sum())
+            record["state_resets"] = int(
+                ((positions == 0) & (token_row >= 0)).sum())
+            record["state_bytes_per_row"] = state.layout.row_layer_nbytes
         return record
 
     @staticmethod
@@ -1316,11 +1358,13 @@ class ContinuousBatchingEngine:
             gtable = self._arena.device_table()
             bt = jnp.asarray(self._bt)
         with phase("cbe.dispatch", **record):       # enqueue only
-            (toks, self._tok_dev, self._gstate_dev, self.mgr.pools,
+            # the cache's arrays, pages then the rows' state: one donated
+            # pytree in, the same out
+            (toks, self._tok_dev, self._gstate_dev, self.mgr.arrays,
              *aux) = self._unified_step(
                 params, *plan_dev,
                 self._tok_dev, self._gstate_dev, greset, self._samp_dev,
-                gtable, self.mgr.pools, bt)
+                gtable, self.mgr.arrays, bt)
         with phase("cbe.fence"):
             if fresh:
                 jax.block_until_ready(toks)

@@ -82,7 +82,7 @@ memory_armed = [False]
 #: every accounting class the ledger reports (fixed: dashboards and the
 #: MetricHistory rings key on these)
 MEM_CLASSES = ("weights", "kv_live", "kv_spec", "kv_cached", "kv_free",
-               "optimizer")
+               "row_state", "optimizer")
 
 #: retained pools (a pool is one engine's paged KV manager); bounded so
 #: short-lived test engines cannot grow the process-global ledger forever
@@ -192,19 +192,23 @@ def plan_capacity(*, num_layers: int, num_kv_heads: Optional[int] = None,
                   page_size: int, dtype_bytes: int, hbm_bytes: int,
                   weight_bytes: int = 0,
                   max_seq_len: Optional[int] = None,
-                  token_elems: Optional[int] = None) -> CapacityPlan:
+                  token_elems: Optional[int] = None,
+                  state_bytes: int = 0) -> CapacityPlan:
     """Model geometry + page size + dtype + HBM budget → pool capacity.
 
     ``hbm_bytes`` is the device budget; ``weight_bytes`` (resident model
     parameters) is carved out first and the remainder becomes the paged
     KV pool. With ``max_seq_len`` the plan also reports how many
     max-length sequences fit concurrently (the engine's ``num_slots``
-    ceiling for a worst-case admission policy)."""
+    ceiling for a worst-case admission policy). ``state_bytes``: what the
+    rows of a model with recurrent layers keep beside the pages
+    (``kvcache.state.RowStatePool.nbytes``: slots x a row's state), carved
+    out with the weights."""
     if page_size <= 0 or num_layers <= 0:
         raise ValueError("geometry must be positive")
     pb = page_nbytes(num_layers, page_size, num_kv_heads, head_dim,
                      dtype_bytes, token_elems)
-    kv_budget = max(0, int(hbm_bytes) - int(weight_bytes))
+    kv_budget = max(0, int(hbm_bytes) - int(weight_bytes) - int(state_bytes))
     total = kv_budget // pb
     usable = max(0, total - 1)            # page 0 is the reserved pad page
     pages_per_seq = None
@@ -243,7 +247,7 @@ class _Pool:
     __slots__ = ("label", "page_bytes", "usable_pages", "num_pages",
                  "page_size", "pool_bytes", "verdict", "split", "held",
                  "tails", "meta", "cache_stats", "observes", "refcounted",
-                 "ref", "chips")
+                 "ref", "chips", "state_bytes")
 
     def __init__(self, label: str):
         self.label = label
@@ -253,6 +257,7 @@ class _Pool:
         self.num_pages = 0
         self.page_size = 0
         self.pool_bytes = 0
+        self.state_bytes = 0                # the rows' recurrent state
         self.refcounted = False
         self.chips = 1                      # TP mesh degree (head-sharded)
         self.verdict: Dict[str, Any] = {}
@@ -486,6 +491,8 @@ class MemoryLedger:
             pool.usable_pages = int(mgr.usable_pages)
             pool.page_bytes = _mgr_page_nbytes(mgr)
             pool.pool_bytes = sum(int(p.nbytes) for p in mgr.pools)
+            state = getattr(mgr, "state", None)
+            pool.state_bytes = int(state.nbytes) if state is not None else 0
             # planner verdict: re-derive the plan from the pool's own
             # geometry + byte size; it must predict capacity exactly
             shape = mgr.pools[0].shape     # (L, P, page) + a token's entry
@@ -493,7 +500,8 @@ class MemoryLedger:
                 num_layers=int(shape[0]), page_size=int(shape[2]),
                 token_elems=int(mgr.layout.token_elems),
                 dtype_bytes=int(mgr.pools[0].dtype.itemsize),
-                hbm_bytes=pool.pool_bytes)
+                hbm_bytes=pool.pool_bytes + pool.state_bytes,
+                state_bytes=pool.state_bytes)
             pool.verdict = plan_verdict(plan, mgr)
             self._pools[key] = pool
             while len(self._pools) > MAX_POOLS:
@@ -583,6 +591,8 @@ class MemoryLedger:
                 for p in self._pools.values():
                     nb += p.split.get(cls, 0) * p.page_bytes
                 self._set_class_locked(cls, nb)
+            self._set_class_locked("row_state", sum(
+                p.state_bytes for p in self._pools.values()))
             if audit:
                 self.audits += 1
                 total_b = (free + live + cached) * pb
@@ -752,6 +762,7 @@ class MemoryLedger:
                     "num_pages": p.num_pages,
                     "usable_pages": p.usable_pages,
                     "pool_bytes": p.pool_bytes,
+                    "state_bytes": p.state_bytes,
                     "planner": p.verdict,
                     "pages": dict(p.split),
                     "bytes": {cls: pages * pb

@@ -526,8 +526,28 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
     byte-identical to the single-chip kernel over their head slice
     (attention has no cross-head reduction, so there is no collective
     here at all). The XLA path ignores ``mesh``: GSPMD partitions the
-    gather/einsum graph from the operand shardings alone."""
+    gather/einsum graph from the operand shardings alone.
+
+    Pools WITHOUT a head axis, (P, page, d): multi-query attention, every
+    query head on the one K and V a token keeps (a head axis of one would be
+    padded to a sublane tile on the device). Full attention on one chip; on
+    the Pallas path the latent kernel's row-local walk with V as a pool of
+    its own (:func:`mla_paged_attention_pallas`), under this kernel's
+    name."""
     from ._common import use_pallas
+    # not a traced-shape branch: the pools' RANK is their layout (a head
+    # axis or none), fixed by the model's cache layout at construction
+    # tpu-lint: disable=trace-shape-branch
+    if k_pages.ndim == 3:
+        if window is not None:
+            raise ValueError("pools without a head axis take no window")
+        if use_pallas():
+            return mla_paged_attention_pallas(
+                q, k_pages, block_tables, token_row, positions, kv_lens,
+                scale, v_pool=v_pages, name="ragged_paged_attention")
+        return ragged_paged_attention_array(
+            q, k_pages[:, :, None], v_pages[:, :, None], block_tables,
+            token_row, positions, kv_lens, scale)
     if use_pallas():
         # not a traced-shape branch: Mesh.shape is the STATIC axis-degree
         # mapping of a construction-time mesh (engine compile keys carry
@@ -625,9 +645,8 @@ def mla_paged_attention_array(q, pool, block_tables, token_row, positions,
 
 def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
                           tok_start_ref, tok_count_ref, positions_ref, q_ref,
-                          pool_hbm, o_ref, m_ref, l_ref, acc_ref, buf, sems,
-                          *, page: int, group: int, page_bits: int,
-                          scale: float, value_dim: int):
+                          *refs, page: int, group: int, page_bits: int,
+                          scale: float, value_dim: int, value_pool: bool):
     keys = group * page
     tile = _MLA_TOKEN_TILE
     heads, d = q_ref.shape[1:]
@@ -636,8 +655,16 @@ def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
     # false only in the one step of a call that has no live block
     live = i < n_live_ref[0]
     half = i % 2
-    fetch, wait = _block_dma(block_tables_ref, page_bits, group,
-                             [(pool_hbm, buf, sems)])
+    if value_pool:
+        # the values are a pool of their own (K and V without a head axis:
+        # multi-query attention), copied beside the keys' block
+        (pool_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref, buf, v_buf,
+         sems) = refs
+        pools = [(pool_hbm, buf, sems.at[0]), (v_hbm, v_buf, sems.at[1])]
+    else:
+        pool_hbm, o_ref, m_ref, l_ref, acc_ref, buf, sems = refs
+        pools = [(pool_hbm, buf, sems)]
+    fetch, wait = _block_dma(block_tables_ref, page_bits, group, pools)
     _stream_blocks(i, n_live_ref[0], work_ref, fetch)
 
     @pl.when(i == 0)
@@ -652,7 +679,8 @@ def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
         # the block, read once: keys are its rows as they lie (no head
         # axis, nothing to transpose), values their first lanes
         block = buf[half].reshape(keys, d)
-        values = block[:, :value_dim]
+        values = (v_buf[half].reshape(keys, value_dim) if value_pool
+                  else block[:, :value_dim])
         key_pos = j * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, keys), 1)
         t0, n = tok_start_ref[r], tok_count_ref[r]
@@ -720,7 +748,8 @@ def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
 def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
                                kv_lens, scale: Optional[float] = None,
                                value_dim: Optional[int] = None,
-                               interpret: bool = False):
+                               interpret: bool = False, v_pool=None,
+                               name: str = "mla_paged_attention"):
     """Pallas latent ragged kernel: same contract as
     :func:`mla_paged_attention_array`, with each row's tokens CONTIGUOUS in
     the packed axis (the engine's plan packs them so).
@@ -737,10 +766,17 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     tiles of ``_MLA_TOKEN_TILE`` tokens and then single tokens, where the
     GQA kernel folds every block into all T x rep query rows under a mask. Queries,
     softmax state and output stay (tokens, heads, ·) as they come: nothing
-    is laid out before or after the call."""
+    is laid out before or after the call.
+
+    ``v_pool`` (P, page, dv): the values are a pool of their own and not the
+    keys' first lanes, i.e. multi-query attention over K and V pools without
+    a head axis; :func:`ragged_paged_attention` calls it so, under ITS name
+    (``name`` is the kernel's name in a device trace)."""
     t, nh, d = q.shape
     page = pool.shape[1]
     n_rows, max_pages = block_tables.shape
+    if v_pool is not None:
+        value_dim = v_pool.shape[-1]
     value_dim = d if value_dim is None else value_dim
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     page_bits = _work_item_bits(max_pages)
@@ -751,25 +787,26 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     tok_start = jnp.argmax(mine, axis=1).astype(jnp.int32)
 
     whole = lambda i, *_: (0, 0, 0)
+    pools = (pool,) if v_pool is None else (pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # block_tables, work list, n_live, each row's first token and token
         # count, positions
         num_scalar_prefetch=6,
         grid=(jnp.maximum(n_live, 1),),
-        in_specs=[pl.BlockSpec((t, nh, d), whole),
-                  pl.BlockSpec(memory_space=pl.ANY)],
+        in_specs=[pl.BlockSpec((t, nh, d), whole)]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
         out_specs=pl.BlockSpec((t, nh, value_dim), whole),
         scratch_shapes=[
             pltpu.VMEM((t, nh, 128), jnp.float32),
             pltpu.VMEM((t, nh, 128), jnp.float32),
             pltpu.VMEM((t, nh, value_dim), jnp.float32),
-            pltpu.VMEM((2, group, page, d), pool.dtype),
-            pltpu.SemaphoreType.DMA((2,)),
-        ],
+        ] + [pltpu.VMEM((2, group, page, p.shape[-1]), p.dtype)
+             for p in pools]
+        + [pltpu.SemaphoreType.DMA((2,) * len(pools))],
     )
     kernel = functools.partial(
         _mla_attention_kernel, page=page, group=group, page_bits=page_bits,
-        scale=s, value_dim=value_dim)
+        scale=s, value_dim=value_dim, value_pool=v_pool is not None)
     # queries and output are whole-array blocks (double-buffered by the
     # pipeline) beside the float32 state: past the default scoped limit at
     # the serving shapes (32 tokens x 64 heads: ~16 MB)
@@ -777,17 +814,18 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     lanes = lambda n: -(-n // 128) * 128
     vmem = (2 * t * nh * (lanes(d) + lanes(value_dim)) * item
             + t * nh * (256 + lanes(value_dim)) * 4
-            + 2 * group * page * lanes(d) * item)
+            + 2 * group * page * sum(lanes(p.shape[-1]) for p in pools)
+            * item)
     return pl.pallas_call(
         kernel,
-        name="mla_paged_attention",
+        name=name,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((t, nh, value_dim), pool.dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=int(vmem) + (16 << 20)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), work, n_live.reshape(1), tok_start,
-      tok_count, positions.astype(jnp.int32), q, pool)
+      tok_count, positions.astype(jnp.int32), q, *pools)
 
 
 def mla_paged_attention(q, pool, block_tables, token_row, positions, kv_lens,
@@ -818,6 +856,9 @@ class CacheLayout:
     and the pool lives on one chip."""
     entries: Tuple[Tuple[int, ...], ...]
     head_axis: Optional[int] = None
+    #: layers that keep pages (None: every layer of the model; a model whose
+    #: other layers keep a recurrent state names the few that attend)
+    layers: Optional[int] = None
 
     @property
     def token_elems(self) -> int:
@@ -913,6 +954,10 @@ class PagedKVCacheManager:
             jnp.zeros((num_layers, num_pages, page_size) + tuple(entry),
                       dtype, device=sharding)
             for entry, sharding in zip(self.layout.entries, shardings))
+        #: what a ROW keeps in a model's recurrent layers, beside the pages
+        #: (``kvcache.state.RowStatePool``; the engine sets it for a model
+        #: with a state layout): audited and reported with the pages
+        self.state = None
         self._free: List[int] = list(range(num_pages - 1, 0, -1))  # 0 reserved
         self._tables: dict = {}   # seq_id -> List[int]
         self._lens: dict = {}     # seq_id -> int
@@ -938,6 +983,20 @@ class PagedKVCacheManager:
     @v_pages.setter
     def v_pages(self, value):
         self.pools = (self.pools[0], value)
+
+    @property
+    def arrays(self) -> Tuple:
+        """Every array the engine's step takes and returns: the pools, then
+        the rows' state where the model keeps one."""
+        return self.pools + (self.state.arrays if self.state is not None
+                             else ())
+
+    @arrays.setter
+    def arrays(self, value):
+        n = len(self.pools)
+        self.pools = tuple(value[:n])
+        if self.state is not None:
+            self.state.arrays = tuple(value[n:])
 
     # -- allocation ---------------------------------------------------------
 
@@ -1097,6 +1156,8 @@ class PagedKVCacheManager:
                 f"page conservation violated: {len(free)} free + "
                 f"{len(owned_set)} owned = {total} != "
                 f"{self.usable_pages} usable")
+        if self.state is not None:
+            self.state.check_conservation(self._tables)
 
     # -- multi-chip layout (TP-sharded serving) ------------------------------
 

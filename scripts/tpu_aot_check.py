@@ -14,8 +14,9 @@ Checked at Llama-2-7B width (h=4096, 32x128 heads, ff=11008, bf16) and
 2 layers: the engine's unified step on one chip and on
 ``serving_mesh(4)``, the one-chip hybrid train step, and the unified step
 of an AFMoE engine at Trinity-Mini's widths (a window in the ragged kernel,
-the grouped expert product) and of an A.X-K1 engine (the latent ragged
-kernel over a pool without a head axis) — each must compile and carry its Pallas
+the grouped expert product), of an A.X-K1 engine (the latent ragged
+kernel over a pool without a head axis) and of a Jamba engine (the selective
+scan over each row's state, multi-query pools without a head axis) — each must compile and carry its Pallas
 kernels, by name, in the lowering. What it
 cannot show is whether the programs RUN correctly; that is
 ``chip_smoke.py``'s job, on the chip.
@@ -136,6 +137,22 @@ def axk1_engine():
                                     prefix_cache=True)
 
 
+def jamba_engine():
+    """An engine at Jamba2-3B's published sizes (all 28 layers, the whole
+    vocabulary; 128 rows of 4,096 positions, a token pool): the unified step
+    with the
+    selective scan over each row's state, the ragged kernel over K and V
+    pools WITHOUT a head axis (multi-query attention) and rms_norm. The
+    scanned runs of Mamba layers cost the compile nothing (~6 s whole)."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    from paddle_tpu.models import jamba as J
+
+    cfg = J.JambaConfig(dtype=jnp.bfloat16)
+    return ContinuousBatchingEngine(cfg, num_slots=128, page_size=16,
+                                    max_seq_len=4096, num_pages=1025)
+
+
 def check_train_step(devices):
     """bench.py's llama7b_layer geometry (B=8, S=2048, default remat) on
     one chip: flash attention fwd+bwd and rms_norm fwd+bwd."""
@@ -175,6 +192,10 @@ def run_checks():
                 axk1_engine(), devices, 1,
                 expect=("mla_paged_attention", "rms_norm_fwd",
                         "moe_grouped_matmul")),
+            "jamba_unified_step_mp1": check_unified_step(
+                jamba_engine(), devices, 1,
+                expect=("mamba_ragged_scan", "ragged_paged_attention",
+                        "rms_norm_fwd")),
             "train_step": check_train_step(devices),
         }
 

@@ -163,6 +163,20 @@ def test_phases_once_in_order_disjoint_and_covering(run):
     assert covered >= 0.95 * whole
 
 
+def test_a_model_without_a_state_layout_carries_its_pools_alone(run):
+    """Llama names no ``state_layout``: the engine keeps no state pool, what
+    its step takes and returns is the page pools and nothing else, and its
+    record has the ten keys and none of a state model's three
+    (``tests/test_jamba_model.py`` holds those)."""
+    mgr = run.eng.mgr
+    assert mgr.state is None
+    assert mgr.arrays == mgr.pools and len(mgr.pools) == 2
+    records = [e[3] for e in run.events if e[0] == "cbe.dispatch"]
+    assert records and all(
+        not {"state_row_rounds", "state_resets", "state_bytes_per_row"}
+        & set(r) for r in records)
+
+
 def test_record_matches_the_plan_and_the_tokens(run):
     eng = run.eng
     records = [e[3] for e in run.events if e[0] == "cbe.dispatch"]

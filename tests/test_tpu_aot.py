@@ -35,6 +35,12 @@ def test_serving_and_train_steps_compile_for_v5e_with_their_kernels():
     assert axk1["mla_paged_attention"] == 2
     assert axk1["moe_grouped_matmul"] == 3
     assert "ragged_paged_attention" not in axk1
+    # Jamba: the scan in the Mamba layers' scanned body, the ragged kernel
+    # at its two attention layers over pools without a head axis
+    jamba = out["jamba_unified_step_mp1"]["kernels"]
+    assert jamba["mamba_ragged_scan"] >= 1
+    assert jamba["ragged_paged_attention"] == 2
+    assert "mla_paged_attention" not in jamba
     # one call site each: a second forward site would be the S^2 kernel
     # replayed under remat (the backward reads the saved out and lse)
     train = out["train_step"]["kernels"]
