@@ -407,14 +407,32 @@ def parity_phase(sz: Sizes, interpret: bool = False) -> None:
         *a, scale=0.13, value_dim=lat_v, interpret=interpret))
     mla_ref = jax.jit(lambda *a: pa.mla_paged_attention_array(
         *a, scale=0.13, value_dim=lat_v))
-    for name, spans in mixes.items():
+    # and one mix of its own: rows whose tables name the SAME leading pages
+    # (prefix-cache hits borrow them), which the kernel folds once for the
+    # whole group under the lowest row's item: decode rows and a prefill
+    # row on up to three shared blocks, more members than a tile of tokens,
+    # and a row with pages of its own beside them
+    borrowed = min(3, width // group - 1) * group
+    shared_tables = tables.copy()
+    shared_tables[1:rows - 3, :borrowed] = tables[0, :borrowed]
+    base = borrowed * sz.page
+    shared = [(r, base + 5 + 9 * r, 1) for r in range(rows - 4)] \
+        + [(rows - 4, base + 1, 3), (rows - 1, 3 * sz.page + 2, 1)]
+    for name, spans in list(mixes.items()) + [("shared", shared)]:
         token_row, positions, kv_lens = packed(spans)
-        args = (q_lat, lat_pool, jnp.asarray(tables, jnp.int32),
+        args = (q_lat, lat_pool,
+                jnp.asarray(shared_tables if name == "shared" else tables,
+                            jnp.int32),
                 jnp.asarray(token_row), jnp.asarray(positions),
                 jnp.asarray(kv_lens))
         if not interpret and name == "dense":
             require_kernels(mla.lower(*args), ("mla_paged_attention",),
                             "latent parity")
+        if name == "shared":
+            check(int(pa.ragged_shared_blocks(
+                shared_tables, kv_lens, sz.page).sum())
+                == (rows - 4) * (borrowed // group),
+                "latent kernel (shared): the mix's rows share no block")
         got = mla(*args)
         pad = token_row < 0
         check(bool(jnp.all(got[pad] == 0)),

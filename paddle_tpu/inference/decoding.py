@@ -1216,14 +1216,21 @@ class ContinuousBatchingEngine:
         ``causal_pairs`` query-key pairs pass the mask. A model with
         sliding-window layers (its serving module's ``attention_windows``)
         adds ``window_skipped_pages``: live pages a full mask would have
-        listed and the window did not, the same mean. A model with a state a
-        row (``state_layout``) adds ``state_row_rounds``, ``state_resets``
-        and ``state_bytes_per_row`` (at the end).
+        listed and the window did not, the same mean. A model whose pools
+        have no head axis (the latent kernel's walk) adds ``shared_pages``:
+        the attended pages that its calls fold under ANOTHER row's work item,
+        because both rows' block tables name them (a borrowed prefix;
+        ``ragged_shared_blocks``), the same mean; what each ROW attends
+        (``attended_pages``, ``grid_steps``, ``causal_pairs``) counts them
+        all the same. A model with a state a row (``state_layout``) adds
+        ``state_row_rounds``, ``state_resets`` and ``state_bytes_per_row``
+        (at the end).
         Computed on every dispatch (a few vectorised numpy lines)."""
         from ..ops.paged_attention import (ragged_block_pages,
                                            ragged_first_pages,
                                            ragged_live_blocks,
-                                           ragged_live_pages)
+                                           ragged_live_pages,
+                                           ragged_shared_blocks)
         ps = self.page_size
         width = self._table_width
         group = ragged_block_pages(ps, width)
@@ -1261,6 +1268,10 @@ class ContinuousBatchingEngine:
         if len(self._window_layers) > 1 or self._layer_windows[0] is not None:
             record["window_skipped_pages"] = round(
                 int(full_pages.sum()) - attended / layers)
+        if self.mgr.layout.head_axis is None:
+            # every layer's call sees the same table and spans
+            record["shared_pages"] = group * int(
+                ragged_shared_blocks(self._bt, kv_lens, ps).sum())
         state = self.mgr.state
         if state is not None:
             # a model with a state a row: the rows whose state each

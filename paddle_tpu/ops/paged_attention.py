@@ -227,6 +227,126 @@ def ragged_live_blocks(kv_lens, page: int, max_pages: int,
     return (-(-pages // g)).sum(axis=-1)
 
 
+def ragged_shared_blocks(block_tables, kv_lens, page: int) -> np.ndarray:
+    """Leading blocks each row of each latent kernel call folds under ANOTHER
+    row's work item, on the host in numpy, for the engine's work record: the
+    twin of :func:`_shared_block_groups` (its ``nshared``; tests hold the two
+    equal). ``block_tables`` (R, W): ONE table for all the calls;
+    ``kv_lens`` (..., R), one call per row of the leading axes; returns
+    ``kv_lens``' shape. Rows pair up by their first block's ids, each
+    (row, leader) pair that occurs is compared once, and a call bounds the
+    result by both rows' full blocks."""
+    tables = np.asarray(block_tables)
+    n_rows, width = tables.shape
+    kv = np.asarray(kv_lens, np.int64)
+    group = ragged_block_pages(page, width)
+    n_blocks = width // group
+    calls = kv.reshape(-1, n_rows)
+    full = np.minimum(calls // (group * page), n_blocks)
+    rows = np.arange(n_rows)
+    shared = np.zeros_like(calls)
+    # candidates by the first page's id, then by the first block's G ids
+    same = tables[:, None, 0] == tables[None, :, 0]         # (R, R)
+    same &= (full > 0).any(0)[None, :]
+    if same.sum() == np.count_nonzero(same.diagonal()):
+        return shared.reshape(kv.shape)                     # nothing shared
+    first = tables[:, :group]
+    same &= (first[:, None] == first[None, :]).all(-1)
+    # a call's leader of a row: the lowest row with a full first block of
+    # the same ids (argmax: the first True; a row agrees with itself)
+    lead = np.where(full > 0, (same[None] & (full > 0)[:, None, :]).argmax(-1),
+                    rows)
+    call, row = np.nonzero(lead != rows)
+    if len(call):
+        leader = lead[call, row]
+        pairs, of = np.unique(row * n_rows + leader, return_inverse=True)
+        cut = n_blocks * group
+        differ = tables[pairs // n_rows, :cut] != tables[pairs % n_rows, :cut]
+        common = np.where(differ.any(-1), differ.argmax(-1) // group,
+                          n_blocks)
+        blocks = -(-_listed_pages(calls, page, width, None) // group)
+        shared[call, row] = np.minimum(
+            np.minimum(common[of], blocks[call, row] - 1),
+            np.minimum(full[call, row], full[call, leader]))
+    return shared.reshape(kv.shape)
+
+
+def _shared_block_groups(block_tables, kv_lens, page: int):
+    """Which rows of one latent kernel call hold the same leading blocks, in
+    the program, from the block table's equalities and ``kv_lens`` alone (a
+    layer's page offset added to every entry changes nothing). A block is
+    G = :func:`ragged_block_pages` pages; it is SHARED by two rows where
+    all its G ids agree and it lies inside both rows' FULL blocks
+    (``kv_lens // (G x page)``: ids past a row's live pages are stale, and
+    the block a row writes this call is its own). Returns two (R,) int32
+    vectors:
+
+    - ``nshared[r]``: the leading blocks row ``r`` has in common with its
+      leader; 0 for a leader, for a row that shares nothing and for a row
+      without a full block (``kv_lens`` 0: no token this round). Never a
+      row's last block: every row keeps an item of its own, the one that
+      writes its output.
+    - ``lead[r]``: the lowest row whose full first block has the same ids
+      where ``nshared[r]`` > 0, else ``r`` itself.
+
+    O(R^2 G + R W) integer work."""
+    n_rows, max_pages = block_tables.shape
+    group = ragged_block_pages(page, max_pages)
+    n_blocks = max_pages // group
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    kv = kv_lens.astype(jnp.int32)
+    full = jnp.minimum(kv // (group * page), n_blocks)
+    blocks = block_tables[:, :n_blocks * group].reshape(
+        n_rows, n_blocks, group)
+    first = blocks[:, 0]
+    same = jnp.all(first[:, None] == first[None, :], axis=-1) \
+        & (full > 0)[:, None] & (full > 0)[None, :]
+    lead = jnp.where(full > 0,
+                     jnp.min(jnp.where(same, rows[None, :], n_rows), axis=1),
+                     rows)
+    differs = jnp.any(blocks != jnp.take(blocks, lead, axis=0), axis=-1)
+    common = jnp.min(jnp.where(
+        differs, jnp.arange(n_blocks, dtype=jnp.int32)[None, :], n_blocks),
+        axis=1)
+    row_blocks = (jnp.minimum((kv + (page - 1)) // page, max_pages)
+                  + (group - 1)) // group
+    nshared = jnp.minimum(jnp.minimum(common, row_blocks - 1),
+                          jnp.minimum(full, jnp.take(full, lead)))
+    nshared = jnp.where(lead == rows, 0, nshared)
+    return nshared, jnp.where(nshared > 0, lead, rows)
+
+
+def _follower_tokens(mine, nshared, lead, n_blocks: int):
+    """The tokens a leader's items fold beside the leader's own, as lists
+    the kernel reads with scalar loads: the followers' tokens, sorted by
+    leader, then by their row's shared blocks (most first), then by packed
+    index, so that the tokens that hold a leader's block ``b`` (``nshared``
+    of their row > ``b``) are a PREFIX of the leader's stretch. ``mine``
+    (R, T): token ``t`` is row ``r``'s. Returns ``ftok`` (T,) packed token
+    indices, ``fshare`` (T,) their rows' ``nshared``, ``fstart`` (R,) and
+    ``flen`` (R,): a leader's stretch of the two; all int32. O(T^2 + R T)
+    integer work, no sort."""
+    n_rows, t = mine.shape
+    idx = jnp.arange(t, dtype=jnp.int32)
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    share_t = jnp.sum(jnp.where(mine, nshared[:, None], 0), axis=0)
+    lead_t = jnp.sum(jnp.where(mine, lead[:, None], 0), axis=0)
+    follows = share_t > 0
+    past = n_rows * (n_blocks + 1) * t          # every follower's key < past
+    assert past + t < 2 ** 31
+    key = jnp.where(
+        follows, (lead_t * (n_blocks + 1) + (n_blocks - share_t)) * t, past
+    ) + idx
+    rank = jnp.sum(key[None, :] < key[:, None], axis=1, dtype=jnp.int32)
+    lands = rank[None, :] == idx[:, None]       # [slot, token]
+    ftok = jnp.sum(jnp.where(lands, idx[None, :], 0), axis=1)
+    fshare = jnp.sum(jnp.where(lands, share_t[None, :], 0), axis=1)
+    of = follows[None, :] & (lead_t[None, :] == rows[:, None])
+    before = follows[None, :] & (lead_t[None, :] < rows[:, None])
+    return (ftok, fshare, jnp.sum(before, axis=1, dtype=jnp.int32),
+            jnp.sum(of, axis=1, dtype=jnp.int32))
+
+
 def _work_item_bits(max_pages: int) -> int:
     """Bits of a work item that hold the page index (static; the row sits
     above them, and a list that SMEM can hold is far from 31 bits)."""
@@ -644,14 +764,16 @@ def mla_paged_attention_array(q, pool, block_tables, token_row, positions,
 
 
 def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
-                          tok_start_ref, tok_count_ref, positions_ref, q_ref,
+                          tok_start_ref, tok_count_ref, fstart_ref,
+                          flen_ref, ftok_ref, fshare_ref, positions_ref,
+                          q_ref,
                           *refs, page: int, group: int, page_bits: int,
                           scale: float, value_dim: int, value_pool: bool):
     keys = group * page
     tile = _MLA_TOKEN_TILE
-    heads, d = q_ref.shape[1:]
+    t_slots, heads, d = q_ref.shape
     i = pl.program_id(0)
-    r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
+    r, j, _, last = _unpack_work_item(work_ref[i], page_bits)
     # false only in the one step of a call that has no live block
     live = i < n_live_ref[0]
     half = i % 2
@@ -676,38 +798,44 @@ def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
     @pl.when(live)
     def _compute():
         wait(half)
-        # the block, read once: keys are its rows as they lie (no head
-        # axis, nothing to transpose), values their first lanes
-        block = buf[half].reshape(keys, d)
-        values = (v_buf[half].reshape(keys, value_dim) if value_pool
-                  else block[:, :value_dim])
         key_pos = j * page + jax.lax.broadcasted_iota(
             jnp.int32, (1, keys), 1)
-        t0, n = tok_start_ref[r], tok_count_ref[r]
+        # a token's state starts at its row's block 0, whichever row's item
+        # folds it (a follower's first listed block is not its first block)
+        first = j == 0
 
-        def fold(t, tt: int):
-            """Fold the block into tokens ``t .. t + tt - 1`` of the row:
-            ``tt x heads`` query rows, and no other token's."""
+        def fold(toks):
+            """Fold the block into the tokens ``toks`` (packed indices, of
+            any rows that hold the block): ``len(toks) x heads`` query rows
+            in one operand, and no other token's."""
+            tt = len(toks)
             rows = tt * heads
-            q = q_ref[pl.ds(t, tt)].reshape(rows, d)
-            pos = positions_ref[t]
+            # the block: keys are its rows as they lie (no head axis,
+            # nothing to transpose), values their first lanes. Read where
+            # it is folded: one value held across the regions below costs
+            # every step ~0.15 us (scratch timing, PERF.md section 6, PR 34)
+            block = buf[half].reshape(keys, d)
+            values = (v_buf[half].reshape(keys, value_dim) if value_pool
+                      else block[:, :value_dim])
+
+            def load(ref, lanes):
+                return jnp.stack([ref[t] for t in toks]).reshape(rows, lanes)
+
+            q = load(q_ref, d)
+            pos = positions_ref[toks[0]]
             if tt > 1:
                 token_of = jax.lax.broadcasted_iota(
                     jnp.int32, (rows, 1), 0) // heads
                 for k in range(1, tt):
-                    pos = jnp.where(token_of == k, positions_ref[t + k], pos)
+                    pos = jnp.where(token_of == k, positions_ref[toks[k]],
+                                    pos)
             sc = jax.lax.dot_general(
                 q, block, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale  # (rows, keys)
             sc = jnp.where(key_pos <= pos, sc, _NEG_INF)
-            at = pl.ds(t, tt)
-            # a row's first block starts its tokens' state
-            m_prev = jnp.where(
-                first, _NEG_INF, m_ref[at].reshape(rows, 128)[:, :1])
-            l_prev = jnp.where(
-                first, 0.0, l_ref[at].reshape(rows, 128)[:, :1])
-            acc_prev = jnp.where(
-                first, 0.0, acc_ref[at].reshape(rows, value_dim))
+            m_prev = jnp.where(first, _NEG_INF, load(m_ref, 128)[:, :1])
+            l_prev = jnp.where(first, 0.0, load(l_ref, 128)[:, :1])
+            acc_prev = jnp.where(first, 0.0, load(acc_ref, value_dim))
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.exp(sc - m_new)
@@ -715,34 +843,56 @@ def _mla_attention_kernel(block_tables_ref, work_ref, n_live_ref,
             acc = acc_prev * alpha + jax.lax.dot_general(
                 p.astype(values.dtype), values, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)     # (rows, value_dim)
-            m_ref[at] = jnp.broadcast_to(m_new, (rows, 128)).reshape(
+            m_new = jnp.broadcast_to(m_new, (rows, 128)).reshape(
                 tt, heads, 128)
-            l_ref[at] = jnp.broadcast_to(l_new, (rows, 128)).reshape(
+            l_new = jnp.broadcast_to(l_new, (rows, 128)).reshape(
                 tt, heads, 128)
-            acc_ref[at] = acc.reshape(tt, heads, value_dim)
+            acc = acc.reshape(tt, heads, value_dim)
+            for k, t in enumerate(toks):
+                m_ref[t], l_ref[t], acc_ref[t] = m_new[k], l_new[k], acc[k]
 
-            @pl.when(last)
-            def _finalize():
-                out = acc / jnp.where(l_new == 0.0, 1.0, l_new)
-                o_ref[at] = out.reshape(tt, heads, value_dim).astype(
-                    o_ref.dtype)
+        # The item's tokens: the row's own, then, where the row leads a
+        # group, the followers' tokens that hold this block too (a prefix
+        # of the leader's stretch of ``ftok``: :func:`_follower_tokens`).
+        # They are folded in operands of ``_MLA_TOKEN_TILE`` tokens and one
+        # shorter operand for the rest, whichever rows they belong to, so
+        # the block is copied once and loaded into the MXU once for all of
+        # them. Per token the blocks still arrive in ascending order.
+        own, t0 = tok_count_ref[r], tok_start_ref[r]
+        f0 = fstart_ref[r]
+        held = jax.lax.fori_loop(
+            0, flen_ref[r], lambda k, c: c + (
+                fshare_ref[f0 + k] * group > j).astype(jnp.int32),
+            jnp.int32(0))
+        n = own + held
 
-        # the row's tokens are contiguous in the packed axis: whole tiles
-        # of ``_MLA_TOKEN_TILE`` tokens (a prefill row fills the MXU's
-        # rows), then the rest one token at a time (a decode row is one
-        # token: its heads)
-        n_tiles = n // tile
+        def token(k):
+            follower = ftok_ref[jnp.clip(f0 + k - own, 0, t_slots - 1)]
+            return jnp.where(k < own, t0 + k, follower)
 
         def tile_body(k, carry):
-            fold(t0 + k * tile, tile)
+            fold([token(k * tile + s) for s in range(tile)])
             return carry
 
-        def token_body(k, carry):
-            fold(t0 + n_tiles * tile + k, 1)
-            return carry
+        jax.lax.fori_loop(0, n // tile, tile_body, 0)
+        rest = n % tile
+        for tt in range(1, tile):
+            @pl.when(rest == tt)
+            def _fold_rest(tt=tt):
+                fold([token(n - rest + s) for s in range(tt)])
 
-        jax.lax.fori_loop(0, n_tiles, tile_body, 0)
-        jax.lax.fori_loop(0, n - n_tiles * tile, token_body, 0)
+        @pl.when(last)
+        def _finalize():
+            # the row's last item (its own: a shared block is never a row's
+            # last) normalises the row's tokens
+            def write(k, carry):
+                t = tok_start_ref[r] + k
+                l_t = l_ref[t][:, :1]
+                o_ref[t] = (acc_ref[t] / jnp.where(l_t == 0.0, 1.0, l_t)
+                            ).astype(o_ref.dtype)
+                return carry
+
+            jax.lax.fori_loop(0, tok_count_ref[r], write, 0)
 
 
 def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
@@ -761,12 +911,25 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     the pool has no head axis, so a block of G pages is ONE (G x page, d)
     operand that is read once, its rows the keys of every head and its
     first ``value_dim`` lanes the values; and a work item folds its block
-    into the query rows of ITS row only (the row's tokens x heads, found by
-    the row's first token and token count, both scalar-prefetched), in
-    tiles of ``_MLA_TOKEN_TILE`` tokens and then single tokens, where the
-    GQA kernel folds every block into all T x rep query rows under a mask. Queries,
-    softmax state and output stay (tokens, heads, ·) as they come: nothing
-    is laid out before or after the call.
+    into the query rows of the rows that HOLD it only (their tokens x
+    heads, found by each row's first token and token count, both
+    scalar-prefetched), in operands of up to ``_MLA_TOKEN_TILE`` tokens,
+    where the GQA kernel folds every block into all T x rep query rows
+    under a mask. Queries, softmax state and output stay (tokens, heads, ·)
+    as they come: nothing is laid out before or after the call.
+
+    A work item is a block and ALL the rows that hold it. Rows whose block
+    tables name the same leading pages (prefix-cache hits borrow a
+    document's pages) form a group (:func:`_shared_block_groups`: from the
+    table's equalities and ``kv_lens``, nothing else): the lowest row lists
+    the shared blocks, its followers list only what lies past them, and the
+    leader's step folds its own tokens and those of the followers that hold
+    the block (:func:`_follower_tokens`: a prefix of a sorted list) in the
+    same operands, so a shared block is copied once and loaded into the MXU
+    once a call and not once a row. Every token still folds its row's
+    blocks in ascending order at the same precisions. With no two rows on
+    one page the groups are single rows and the list, the copies and the
+    folds are those of a kernel that knows no groups.
 
     ``v_pool`` (P, page, dv): the values are a pool of their own and not the
     keys' first lanes, i.e. multi-query attention over K and V pools without
@@ -781,8 +944,14 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     page_bits = _work_item_bits(max_pages)
     group = ragged_block_pages(page, max_pages)
-    work, n_live = _ragged_work_list(kv_lens, page, max_pages)
+    # rows whose tables name the same leading blocks: a follower lists only
+    # the blocks past those its leader's items fold for it
+    nshared, lead = _shared_block_groups(block_tables, kv_lens, page)
+    work, n_live = _ragged_work_list(kv_lens, page, max_pages,
+                                     first_pages=nshared * group)
     mine = token_row[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
+    ftok, fshare, fstart, flen = _follower_tokens(
+        mine, nshared, lead, max_pages // group)
     tok_count = jnp.sum(mine, axis=1, dtype=jnp.int32)
     tok_start = jnp.argmax(mine, axis=1).astype(jnp.int32)
 
@@ -790,8 +959,9 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
     pools = (pool,) if v_pool is None else (pool, v_pool)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # block_tables, work list, n_live, each row's first token and token
-        # count, positions
-        num_scalar_prefetch=6,
+        # count, its followers' stretch of their token list, that list and
+        # each listed token's shared blocks, positions
+        num_scalar_prefetch=10,
         grid=(jnp.maximum(n_live, 1),),
         in_specs=[pl.BlockSpec((t, nh, d), whole)]
         + [pl.BlockSpec(memory_space=pl.ANY)] * len(pools),
@@ -825,7 +995,8 @@ def mla_paged_attention_pallas(q, pool, block_tables, token_row, positions,
             vmem_limit_bytes=int(vmem) + (16 << 20)),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), work, n_live.reshape(1), tok_start,
-      tok_count, positions.astype(jnp.int32), q, *pools)
+      tok_count, fstart, flen, ftok, fshare, positions.astype(jnp.int32), q,
+      *pools)
 
 
 def mla_paged_attention(q, pool, block_tables, token_row, positions, kv_lens,

@@ -193,6 +193,79 @@ def test_engine_serves_axk1_and_a_prefix_hit_gives_the_cold_runs_tokens(
         assert deficit.max() <= 1e-4
 
 
+def _serve_side_by_side(prefix_cache):
+    """One 140-token document (a whole block of 128 tokens = 32 pages of 4,
+    and three more pages), written by one cold request, then asked four
+    questions at once: four rows that decode SIDE BY SIDE on its pages, the
+    lowest row (the group's leader) retiring first. Returns (the four
+    requests' tokens, the work records of the dispatches that wrote the
+    document, those of the four requests' dispatches, the engine)."""
+    cfg = X.axk1_tiny()
+    params = _weights(cfg, 2)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(seed=0), num_slots=4, page_size=4,
+        max_seq_len=288, chunk=4, prefix_cache=prefix_cache)
+    records, record = [], eng._dispatch_record
+    eng._dispatch_record = lambda *a: records.append(record(*a)) or records[-1]
+    sched = ServingScheduler(eng)
+    rng = np.random.RandomState(4)
+    draw = lambda n: rng.randint(1, cfg.vocab_size, (n,)).astype(np.int32)
+    document = draw(140)
+    sched.submit(np.concatenate([document, draw(5)]), max_new_tokens=4)
+    while sched.pending:
+        sched.step(params)
+    written = len(records)
+    handles = [sched.submit(np.concatenate([document, draw(n)]),
+                            max_new_tokens=new)
+               for n, new in ((3, 6), (9, 14), (6, 14), (4, 10))]
+    while sched.pending:
+        sched.step(params)
+    eng.mgr.check_conservation()
+    return ([h.stream.tokens for h in handles], records[:written],
+            records[written:], eng)
+
+
+def test_rows_side_by_side_on_one_document_give_the_cold_runs_tokens(
+        monkeypatch):
+    """Four prefix hits whose block tables name the SAME pages decode side
+    by side: the latent kernel (interpret mode, inside the engine's one
+    step program) folds the document's whole block once, under the lowest
+    row's item, for all of them; when that row retires the next one leads.
+    Every request's tokens are those of an engine without a prefix cache
+    (private pages, nothing shared, the XLA twin), and the work record
+    counts the pages folded under another row's item."""
+    from paddle_tpu.ops import paged_attention as pa
+    cold, _, cold_records, cold_eng = _serve_side_by_side(False)
+    assert cold_eng.cache is None
+    assert all(r["shared_pages"] == 0 for r in cold_records)
+    traced = []
+
+    def interpreted(*args, **kw):
+        traced.append(1)
+        return pa.mla_paged_attention_pallas(*args, **kw, interpret=True)
+
+    monkeypatch.setattr(pa, "mla_paged_attention", interpreted)
+    warm, writing, records, eng = _serve_side_by_side(True)
+    assert traced                     # the kernel is in the step program
+    assert [len(t) for t in warm] == [6, 14, 14, 10] and warm == cold
+    snap = eng.cache.snapshot()
+    assert snap["hits"] == 4 and snap["cached_tokens"] == 4 * 140
+    # one row alone shares nothing; four rows on one document: each round,
+    # every row with a token but the lowest folds the block's 32 pages
+    # under the leader's item
+    assert all(r["shared_pages"] == 0 for r in writing)
+    assert all(r["shared_pages"] % 32 == 0
+               and r["shared_pages"] <= r["attended_pages"] for r in records)
+    assert records[0]["live_rows"] == 4 and records[0]["shared_pages"] > 0
+    # where every live row decodes in each of the 4 micro-rounds, all but
+    # the lowest are followers; the leader has retired by then (three rows
+    # live: the next lowest leads), and one row alone shares nothing
+    steady = [r for r in records if r["decode_tokens"] == 4 * r["live_rows"]]
+    assert {r["live_rows"] for r in steady} == {3, 1}
+    assert all(r["shared_pages"] == 4 * (r["live_rows"] - 1) * 32
+               for r in steady)
+
+
 def test_the_pool_is_one_latent_array_and_no_v(served):
     eng, cfg = served["eng"], served["cfg"]
     assert cfg.latent_dim == 40 and cfg.entry_dim == 128
@@ -208,12 +281,15 @@ def test_the_pool_is_one_latent_array_and_no_v(served):
 
 def test_the_latent_paths_spans_carry_the_work_record_and_routing_stats(
         served):
-    """The tracing the repo has, written for the latent path too: the SAME
-    ten keys on ``cbe.dispatch``, the four routing stats on ``cbe.unpack``
-    (for the experts held), and ``cached_tokens`` on ``cbe.upload``."""
+    """The tracing the repo has, written for the latent path too: the
+    ten keys on ``cbe.dispatch`` and ``shared_pages`` (a pool without a head
+    axis; 0 here: no prompt shares a whole block of 96 tokens), the four
+    routing stats on ``cbe.unpack`` (for the experts held), and
+    ``cached_tokens`` on ``cbe.upload``."""
     events = served["events"]
     records = [e[3] for e in events if e[0] == "cbe.dispatch"]
-    assert records and all(set(r) == RECORD_KEYS for r in records)
+    assert records and all(set(r) == RECORD_KEYS | {"shared_pages"}
+                           and r["shared_pages"] == 0 for r in records)
     assert all(r["page_size"] == 4 and r["attended_pages"] > 0
                and r["causal_pairs"] > 0 for r in records)
     unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
@@ -228,6 +304,30 @@ def test_the_latent_paths_spans_carry_the_work_record_and_routing_stats(
     assert sum(u["cached_tokens"] for u in uploads) == 40 + 24
     assert all(u["cached_tokens"] == 0 for u in uploads
                if not u["admitted"])
+
+
+def test_the_shared_page_share_reader(monkeypatch):
+    """``kernel.mla_paged_attention.shared_page_share``: 100 x shared /
+    attended pages over the complete dispatches' records; silent (None, no
+    raise) on a program whose records lack the counter, as the parent's
+    do, and without a trace."""
+    from perfbench import program_trace
+    reader = harness.load_module(
+        "perfbench/layer_metrics/"
+        "kernel.mla_paged_attention.shared_page_share.py")
+    trace = lambda records: {"dispatches": [{"record": r} for r in records]}
+    seen = [None]
+    monkeypatch.setattr(program_trace, "for_obs", lambda obs: seen[0])
+    assert reader.read(object()) is None
+    seen[0] = trace([{"attended_pages": 300_000, "shared_pages": 180_000},
+                     {"attended_pages": 100_000, "shared_pages": 60_000}])
+    assert reader.read(object()) == pytest.approx(60.0)
+    seen[0] = trace([{"attended_pages": 300_000}])
+    assert reader.read(object()) is None
+    seen[0] = trace([{"attended_pages": 0, "shared_pages": 0}])
+    assert reader.read(object()) is None
+    seen[0] = trace([])
+    assert reader.read(object()) is None
 
 
 def test_engine_refuses_a_mesh_of_several_chips():

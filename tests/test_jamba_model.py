@@ -465,7 +465,10 @@ def test_dispatch_record_gains_the_states_three_keys(tmp_path):
     records = [e[3] for e in _host_events(str(tmp_path))
                if e[0] == "cbe.dispatch"]
     assert len(records) == len(plans) > 0
-    assert all(set(r) == RECORD_KEYS | STATE_KEYS for r in records)
+    # pools without a head axis: the latent kernel's walk, which counts the
+    # pages folded under another row's item (none: no prefix cache here)
+    assert all(set(r) == RECORD_KEYS | STATE_KEYS | {"shared_pages"}
+               and r["shared_pages"] == 0 for r in records)
     assert all(isinstance(v, int) for r in records for v in r.values())
     layout = J.state_layout(cfg)
     for rec, (token_row, positions) in zip(records, plans):

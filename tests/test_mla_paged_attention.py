@@ -19,13 +19,19 @@ _BLOCKS = dict(page=16, width=20)
 _TINY = dict(page=4, width=8)          # G = 8: the whole table is one block
 
 
-def _case(rows, t, n_rows=4, heads=4, d=48, page=16, width=20, seed=0):
+def _case(rows, t, n_rows=4, heads=4, d=48, page=16, width=20, seed=0,
+          share=()):
     """Packed arguments of one call: ``rows`` = [(row, first position,
-    tokens)], packed in that order into ``t`` slots; the rest are pads."""
+    tokens)], packed in that order into ``t`` slots; the rest are pads.
+    ``share`` = [(row, other row, pages)]: the row's table names the other
+    row's first ``pages`` pages (a borrowed prefix, or ids gone stale where
+    they lie past the row's live pages)."""
     rng = np.random.RandomState(seed)
     n_pages = n_rows * width + 1
     pool = rng.randn(n_pages, page, d).astype(np.float32)
     tables = (1 + rng.permutation(n_pages - 1)).reshape(n_rows, width)
+    for row, other, pages in share:
+        tables[row, :pages] = tables[other, :pages]
     token_row = np.full((t,), -1, np.int32)
     positions = np.zeros((t,), np.int32)
     kv_lens = np.zeros((n_rows,), np.int32)
@@ -38,6 +44,9 @@ def _case(rows, t, n_rows=4, heads=4, d=48, page=16, width=20, seed=0):
     q = rng.randn(t, heads, d).astype(np.float32)
     return (q, pool, tables.astype(np.int32), token_row, positions, kv_lens)
 
+
+# a table of 40 pages = five blocks of G = 8 pages = 128 positions each
+_SHARED = dict(page=16, width=40)
 
 _MIXES = {
     # decode rows on both sides of a page and a block (128 keys) boundary,
@@ -60,30 +69,139 @@ _MIXES = {
     "blocks_empty_call": dict(rows=[], t=8, **_BLOCKS),
     "tiny_one_block_tables": dict(
         rows=[(0, 3, 1), (1, 4, 1), (3, 9, 11)], t=16, **_TINY),
+    # rows whose tables name the same leading pages (a borrowed prefix):
+    # four rows on three shared full blocks + private tails, decode and
+    # prefill members mixed (9 tokens: more than one tile)
+    "shared_three_blocks_decode_and_prefill": dict(
+        rows=[(0, 400, 1), (1, 390, 6), (2, 500, 1), (3, 385, 1)], t=12,
+        share=[(1, 0, 24), (2, 0, 24), (3, 0, 24)], **_SHARED),
+    # more members than one tile of tokens, all decoding; row 4 alone
+    "shared_more_members_than_a_tile": dict(
+        rows=[(r, 260 + 17 * r, 1) for r in range(7)], t=8, n_rows=7,
+        share=[(r, 0, 16) for r in (1, 2, 3, 5, 6)], **_SHARED),
+    # the group's lowest row has no token this call: the next one leads
+    "shared_lowest_row_idle": dict(
+        rows=[(1, 300, 1), (2, 280, 3), (3, 520, 1)], t=8,
+        share=[(1, 0, 16), (2, 0, 16), (3, 0, 16)], **_SHARED),
+    # two depths: rows 0 and 1 share four blocks, row 2 two of them; row 3
+    # shares three PAGES with them, not a block: nothing
+    "shared_two_depths": dict(
+        rows=[(0, 530, 1), (1, 515, 2), (2, 300, 1), (3, 200, 5)], t=12,
+        share=[(1, 0, 32), (2, 0, 16), (3, 0, 3)], **_SHARED),
+    # equal ids past a row's live pages are stale: row 1's table is row 0's
+    # whole, its 200 positions hold ONE full block; row 2 sits at exactly
+    # two full blocks (its newest token's block is its own)
+    "shared_stale_ids_and_a_full_last_block": dict(
+        rows=[(0, 600, 1), (1, 199, 1), (2, 250, 6)], t=8,
+        share=[(1, 0, 40), (2, 0, 40)], **_SHARED),
+}
+
+#: shared leading blocks of each row (``nshared``), where a mix shares any
+_SHARED_BLOCKS = {
+    "shared_three_blocks_decode_and_prefill": [0, 3, 3, 3],
+    "shared_more_members_than_a_tile": [0, 2, 2, 2, 0, 2, 2],
+    "shared_lowest_row_idle": [0, 0, 2, 2],
+    "shared_two_depths": [0, 4, 2, 0],
+    "shared_stale_ids_and_a_full_last_block": [0, 1, 1, 0],
 }
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
-@pytest.mark.parametrize("mix", sorted(_MIXES))
-def test_mla_pallas_interpret_matches_its_twin(mix, dtype):
+@pytest.mark.parametrize("mix,values", [(mix, "lanes") for mix in sorted(_MIXES)]
+                         + [(mix, "pool") for mix in sorted(_SHARED_BLOCKS)]
+                         + [("blocks_decode_and_prefill", "pool")])
+def test_mla_pallas_interpret_matches_its_twin(mix, values, dtype):
     """The kernel (interpret mode) against the gather/mask twin: pad slots
     exactly 0, everything finite, live tokens equal to float32 rounding;
     in the served dtype (bfloat16 operands, float32 softmax state) to a
-    bfloat16 step or two of the outputs' size."""
+    bfloat16 step or two of the outputs' size. ``values`` ``"pool"``: V a
+    pool of its own (multi-query attention through this kernel, as Jamba's
+    attention layers run it), against the GQA twin on one KV head."""
     args = _case(**_MIXES[mix])
     token_row = args[3]
     jargs = [jnp.asarray(a) for a in args]
     jargs[:2] = [a.astype(dtype) for a in jargs[:2]]        # queries, pool
-    ref = np.asarray(pa.mla_paged_attention_array(
-        *jargs, scale=0.3, value_dim=32), np.float32)
-    out = np.asarray(pa.mla_paged_attention_pallas(
-        *jargs, scale=0.3, value_dim=32, interpret=True), np.float32)
+    if values == "pool":
+        v_pool = jnp.asarray(np.random.RandomState(9).randn(
+            *args[1].shape[:2], 32), dtype)
+        ref = pa.ragged_paged_attention_array(
+            jargs[0], jargs[1][:, :, None],
+            jnp.pad(v_pool, ((0, 0), (0, 0), (0, 16)))[:, :, None],
+            *jargs[2:], scale=0.3)[..., :32]
+        out = pa.mla_paged_attention_pallas(
+            *jargs, scale=0.3, v_pool=v_pool, interpret=True)
+    else:
+        ref = pa.mla_paged_attention_array(*jargs, scale=0.3, value_dim=32)
+        out = pa.mla_paged_attention_pallas(
+            *jargs, scale=0.3, value_dim=32, interpret=True)
+    ref, out = np.asarray(ref, np.float32), np.asarray(out, np.float32)
     real = token_row >= 0
     assert out.shape == ref.shape == (len(token_row), 4, 32)
     tol = dict(rtol=1e-5, atol=2e-6) if dtype == "float32" \
         else dict(rtol=2e-2, atol=2e-2)
     np.testing.assert_allclose(out[real], ref[real], **tol)
     assert np.all(np.isfinite(out)) and np.all(out[~real] == 0.0)
+
+
+@pytest.mark.parametrize("mix", sorted(_MIXES))
+def test_groups_of_rows_that_share_leading_blocks(mix):
+    """Which rows a call folds under another row's item: the in-program
+    groups (``nshared``, each row's leader and the followers' token lists
+    the kernel's steps read), the host's numpy twin that the
+    engine's ``shared_pages`` counts from, and the work list: a follower
+    lists what lies past its shared blocks, a table with nothing shared
+    gives the list it gave before, item for item."""
+    case = _MIXES[mix]
+    _, _, tables, token_row, _, kv_lens = _case(**case)
+    page, width = case["page"], case["width"]
+    group = pa.ragged_block_pages(page, width)
+    nshared, lead = [np.asarray(x) for x in pa._shared_block_groups(
+        jnp.asarray(tables) + 1000, jnp.asarray(kv_lens), page)]
+    want = _SHARED_BLOCKS.get(mix, [0] * len(kv_lens))
+    assert nshared.tolist() == want
+    host = pa.ragged_shared_blocks(tables, np.stack([kv_lens, kv_lens]), page)
+    assert host.tolist() == [want, want]
+    # the followers' tokens, as the kernel reads them: each leader's stretch
+    # holds its followers' tokens and no other, most shared blocks first
+    n_rows = len(kv_lens)
+    assert all(lead[r] == r or (nshared[r] > 0 and nshared[lead[r]] == 0
+                                and lead[r] < r) for r in range(n_rows))
+    mine = token_row[None, :] == np.arange(n_rows)[:, None]
+    ftok, fshare, fstart, flen = [np.asarray(x) for x in pa._follower_tokens(
+        jnp.asarray(mine), jnp.asarray(nshared), jnp.asarray(lead),
+        width // group)]
+    assert sorted(ftok.tolist()) == list(range(len(token_row)))
+    listed = []
+    for r in range(n_rows):
+        stretch = slice(fstart[r], fstart[r] + flen[r])
+        toks, shares = ftok[stretch], fshare[stretch]
+        assert all(lead[token_row[t]] == r and token_row[t] != r
+                   for t in toks)
+        assert shares.tolist() == [nshared[token_row[t]] for t in toks]
+        assert (np.diff(shares) <= 0).all()
+        listed += toks.tolist()
+    assert sorted(listed) == [t for t, m in enumerate(token_row)
+                              if m >= 0 and nshared[m] > 0]
+    # the work list: its length is the host's count of blocks, and with
+    # nothing shared it is the list of a call that knows no groups
+    work, n_live = pa._ragged_work_list(
+        jnp.asarray(kv_lens), page, width, first_pages=jnp.asarray(
+            nshared * group))
+    assert int(n_live) == int(pa.ragged_live_blocks(
+        kv_lens, page, width, nshared * group))
+    plain, n_plain = pa._ragged_work_list(jnp.asarray(kv_lens), page, width)
+    saved = sum(want)
+    assert int(n_plain) - int(n_live) == saved
+    if not saved:
+        assert np.array_equal(np.asarray(work), np.asarray(plain))
+    else:
+        row, j, _, last = pa._unpack_work_item(
+            np.asarray(work)[:int(n_live)], pa._work_item_bits(width))
+        for r in np.nonzero(nshared)[0]:
+            # a follower's items start past its shared blocks and end with
+            # an item of its own
+            assert j[row == r].min() == nshared[r] * group
+            assert last[row == r].sum() == 1
 
 
 def test_twin_is_plain_attention_over_the_rows_entries():
