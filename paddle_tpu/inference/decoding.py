@@ -31,6 +31,7 @@ from ..observability.journal import token_checksum
 from ..observability.memory import memory_armed, memory_ledger
 from ..observability.profiling import chain_armed as _chain_armed
 from ..observability.profiling import note_chain as _note_chain
+from ..observability.runtime import collections as gc_collections
 from ..observability.runtime import recompiles
 from ..profiler.record import emit_spans, make_span, phase, spans_armed
 from . import constrain as _constrain
@@ -190,6 +191,9 @@ class ContinuousBatchingEngine:
                  grammar_states: int = 0):
         from ..ops.paged_attention import (PagedKVCacheManager,
                                            kv_cache_layout)
+        # a serving process counts its collector's pauses from its first
+        # engine on (one hook however many engines; none at import)
+        gc_collections.install()
         # the ONE place the engine learns which model it serves: the
         # config's class names the module that holds its step
         self._L = _serving_module(model_config)
@@ -736,15 +740,19 @@ class ContinuousBatchingEngine:
                 # instead of draining to the free list. Positions past the
                 # kept output may hold over-decoded garbage, but those
                 # never complete a block (full blocks end <= kept length).
-                toks = ([int(t) for t in req.prompt]
-                        + [int(t) for t in out])
-                if self._speculative and out:
-                    # the last delivered token may be the verify bonus —
-                    # committed but never fed back, so its K/V slot was
-                    # never written. Index one token short so a future
-                    # cache hit can never attend a hole.
-                    toks = toks[:-1]
-                self.cache.insert(toks, self.mgr._tables[rid])
+                # The span holds the conversion to Python ints too: at 16k
+                # tokens that, not the tree's walk, is most of the cost.
+                with phase("paddle_serving.prefix_insert") as span:
+                    toks = ([int(t) for t in req.prompt]
+                            + [int(t) for t in out])
+                    if self._speculative and out:
+                        # the last delivered token may be the verify bonus
+                        # — committed but never fed back, so its K/V slot
+                        # was never written. Index one token short so a
+                        # future cache hit can never attend a hole.
+                        toks = toks[:-1]
+                    adopted = self.cache.insert(toks, self.mgr._tables[rid])
+                    span.set_metadata(tokens=len(toks), pages=adopted)
             if self.finish_callback is not None:
                 self.finish_callback(rid, out)
         self.mgr.free(rid)

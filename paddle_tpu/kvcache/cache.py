@@ -28,6 +28,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 from ..observability.events import emit_event
 from ..observability.registry import get_registry
+from ..profiler.record import phase
 from .policy import LRUEvictionPolicy
 from .pool import RefcountedKVCacheManager
 from .radix import RadixTree
@@ -74,7 +75,12 @@ class PrefixCache:
     def _capped_match(self, prompt: Sequence[int], touch: bool
                       ) -> Tuple[List[int], int, Optional[int]]:
         lp = len(prompt)
-        nodes = self.tree.match(prompt, touch=touch)
+        # the walk by its caller: the engine's admission (``lookup``) or
+        # the scheduler's sizing (``peek``)
+        with phase("paddle_serving.prefix_lookup" if touch
+                   else "paddle_serving.prefix_peek", tokens=lp) as span:
+            nodes = self.tree.match(prompt, touch=touch)
+            span.set_metadata(blocks=len(nodes))
         pages = [nd.page for nd in nodes]
         cow_src: Optional[int] = None
         if pages and len(pages) * self.page_size >= lp:
@@ -213,12 +219,14 @@ class PrefixCache:
         """Return up to ``n_pages`` cached pages to the free list, LRU
         leaves first; ``protect`` shields pages an in-flight admission is
         about to share. Returns the number actually freed."""
-        victims = self.policy.select(self.tree, self.mgr.refcount,
-                                     n_pages, protect)
-        for victim in victims:        # children precede parents
-            self.tree.remove(victim)
-            self.mgr.evict_cached(victim.page)
-        freed = len(victims)
+        with phase("paddle_serving.prefix_evict", asked=int(n_pages)) as span:
+            victims = self.policy.select(self.tree, self.mgr.refcount,
+                                         n_pages, protect)
+            for victim in victims:        # children precede parents
+                self.tree.remove(victim)
+                self.mgr.evict_cached(victim.page)
+            freed = len(victims)
+            span.set_metadata(pages=freed)
         if freed:
             self.stats["evictions"] += freed
             self._c_evict.inc(freed)

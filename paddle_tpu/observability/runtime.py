@@ -12,6 +12,10 @@ this module gives it:
   ``paddle_runtime_recompiles_total{fn=…}`` exactly once per new shape
   signature and logs a structured event carrying the shapes, so a shape
   leak that silently retraces per step becomes a counter you can alert on;
+* **collector pauses**: once installed (by the first serving engine, never
+  at import), :data:`collections` counts every garbage collection per
+  generation with its total and longest pause, and writes each as a
+  ``paddle_serving.gc`` span into any profiler session's trace;
 * the **single-boolean fast path**: ``dispatch_armed[0]`` is the ONE flag
   ``apply`` checks per dispatch. It is recomputed only when telemetry is
   switched or a profiler capture window opens/closes, so a fully disarmed
@@ -21,7 +25,9 @@ this module gives it:
 
 from __future__ import annotations
 
+import gc
 import threading
+import time
 from typing import Dict, Optional, Tuple
 
 from .events import emit_event
@@ -185,8 +191,76 @@ class RecompileDetector:
             self._seen.clear()
 
 
+class CollectionCounter:
+    """The interpreter's garbage collections, per generation: how many, the
+    summed pause and the longest one in ns. A collection holds the
+    interpreter lock for every thread and lands inside whichever host phase
+    was running, so a long one reads as that phase's time unless it is
+    named: the same hook enters a ``paddle_serving.gc``
+    :data:`~paddle_tpu.profiler.record.phase` at ``"start"`` and leaves it
+    at ``"stop"``, on the thread that set the collection off (built only
+    inside a profiler session: the hook runs at every collection,
+    generation 0 included).
+
+    :meth:`install` hooks ``gc.callbacks`` ONCE, however often it is
+    called; importing the package installs nothing. Collections never nest
+    (the interpreter runs one at a time), so one pending start suffices."""
+
+    def __init__(self):
+        self.installed = False
+        self._phase = None
+        self._t0 = 0
+        self._span = None
+        self._stats = self._zero()
+
+    @staticmethod
+    def _zero() -> Dict[int, list]:
+        # generation -> [collections, total pause ns, longest pause ns]
+        return {g: [0, 0, 0] for g in range(3)}
+
+    def install(self) -> None:
+        if self.installed:
+            return
+        from ..profiler.record import phase     # record imports this file
+        self._phase = phase
+        gc.callbacks.append(self._hook)
+        self.installed = True
+
+    def _hook(self, event: str, info: dict) -> None:
+        if event == "start":
+            if self._phase.is_enabled():
+                self._span = self._phase(
+                    "paddle_serving.gc", generation=info["generation"])
+                self._span.__enter__()
+            self._t0 = time.perf_counter_ns()
+        elif self._t0:
+            pause = time.perf_counter_ns() - self._t0
+            self._t0 = 0
+            span, self._span = self._span, None
+            if span is not None:
+                span.__exit__(None, None, None)
+            s = self._stats[info["generation"]]
+            s[0] += 1
+            s[1] += pause
+            if pause > s[2]:
+                s[2] = pause
+
+    def snapshot(self) -> Dict[str, object]:
+        return {
+            "installed": self.installed,
+            "generations": {
+                str(g): {"collections": n, "pause_ns_total": total,
+                         "pause_ns_longest": longest}
+                for g, (n, total, longest) in sorted(self._stats.items())}}
+
+    def reset(self) -> None:
+        """Zero the counts; the hook stays installed."""
+        self._stats = self._zero()
+
+
 telemetry = DispatchTelemetry()
 recompiles = RecompileDetector()
+collections = CollectionCounter()
 get_registry().register_sink("paddle_runtime_ops", telemetry._lines,
                              telemetry._snapshot)
 _rearm()

@@ -69,11 +69,13 @@ from ..observability.flight import flight_recorder
 from ..observability.journal import journal, journal_armed
 from ..observability.memory import (memory_armed, memory_ledger,
                                     pool_occupancy)
+from ..observability.runtime import collections as gc_collections
 from ..observability.step_timer import StepTimer
 from ..observability.timeline import span_collector, timeline_armed
 from ..observability.timeseries import history_armed
 from ..observability.trace import new_trace_id, trace_context
-from ..profiler.record import emit_span, emit_spans, make_span, spans_armed
+from ..profiler.record import (emit_span, emit_spans, make_span, phase,
+                               spans_armed)
 from .metrics import ServingMetrics
 from .stream import ServingError, TokenStream
 
@@ -657,8 +659,16 @@ class ServingScheduler:
         wave boundary."""
         if not self._queue:
             return              # steady decode: nothing to admit, and
-        # the span/byte prelude below is armed-loop cost per step
+        # the span/byte prelude of the loop is armed-loop cost per step
+        with phase("paddle_serving.admit", queued=len(self._queue)) as span:
+            handed, deferred = self._admit_queued()
+            span.set_metadata(handed=handed, deferred=deferred)
+
+    def _admit_queued(self):
+        """``_admit``'s loop over a queue that holds something. Returns
+        (requests handed to the engine, 1 if the loop broke for pages)."""
         now = self._clock()
+        handed = deferred = 0
         armed = spans_armed()
         mgr = self.engine.mgr
         headroom = self.engine.num_free_slots - self.engine.num_queued
@@ -697,6 +707,7 @@ class ServingScheduler:
                 memory_ledger.note_admission_reject(
                     mgr, request_id=req.rid, need_pages=need,
                     free_pages=free_pages, trace_id=req.trace_id)
+                deferred = 1
                 break               # wait for a completion to free pages
             protect.extend(reusing)
             self._queue.pop(0)
@@ -744,6 +755,8 @@ class ServingScheduler:
                                  trace_id=req.trace_id)
             headroom -= 1
             free_pages -= need
+            handed += 1
+        return handed, deferred
 
     # -- robustness ---------------------------------------------------------
 
@@ -928,6 +941,10 @@ class ServingScheduler:
             "shed": dict(self.metrics.shed),
             "step_ms": self.step_timer.step_ms.summary(),
             "tokens_per_s": self.step_timer.tokens_per_s,
+            # the collector's pauses since the first engine was built: a
+            # tail gap between tokens that is a collection's shows here
+            # as a longest pause
+            "collections": gc_collections.snapshot(),
         }
         cache = getattr(self.engine, "cache", None)
         if cache is not None:
