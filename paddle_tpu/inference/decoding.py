@@ -740,11 +740,12 @@ class ContinuousBatchingEngine:
                 # instead of draining to the free list. Positions past the
                 # kept output may hold over-decoded garbage, but those
                 # never complete a block (full blocks end <= kept length).
-                # The span holds the conversion to Python ints too: at 16k
-                # tokens that, not the tree's walk, is most of the cost.
+                # The sequence goes to the index as ONE int32 array: the
+                # tree reads it once a walk, so the span holds a copy of
+                # the tokens and the walk, and no per-token conversion.
                 with phase("paddle_serving.prefix_insert") as span:
-                    toks = ([int(t) for t in req.prompt]
-                            + [int(t) for t in out])
+                    toks = np.concatenate(
+                        [req.prompt, np.asarray(out, np.int32)])
                     if self._speculative and out:
                         # the last delivered token may be the verify bonus
                         # — committed but never fed back, so its K/V slot

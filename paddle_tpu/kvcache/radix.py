@@ -2,12 +2,20 @@
 
 The prefix cache's index (the tree SGLang's RadixAttention and the TPU
 ragged-paged-attention layout in PAPERS.md make cheap to exploit): one
-node per ``page_size``-token block, child edges keyed by the block's token
-tuple, each node holding the physical page id whose KV encodes exactly
-those tokens at their absolute positions. A prefix lookup walks full
-blocks from the root; the matched node path IS the list of reusable
+node per ``page_size``-token block, child edges keyed by the block's tokens
+as ``int32`` bytes, each node holding the physical page id whose KV encodes
+exactly those tokens at their absolute positions. A prefix lookup walks
+full blocks from the root; the matched node path IS the list of reusable
 pages. Page ownership/refcounts live in :mod:`.pool`; this module is pure
 host-side index structure (no device arrays, no refcounts).
+
+A walk (:meth:`RadixTree.match`, :meth:`RadixTree.insert`) reads its token
+sequence ONCE, into one contiguous ``int32`` buffer, and keys block ``i`` by
+that buffer's bytes ``[i * 4 * page_size, (i + 1) * 4 * page_size)``: two
+keys are equal exactly when the blocks' tokens are, whatever the caller
+passed (an ``int32`` or ``int64`` array, a list of Python ints; token ids
+are vocabulary indices and fit ``int32``, which is how every ``submit``
+holds a prompt). The key's type is private to this module.
 
 Blocks are only ever cached WHOLE — a page whose tokens are partially
 garbage can never be indexed, so a match is always byte-trustworthy.
@@ -17,17 +25,20 @@ from __future__ import annotations
 
 from typing import Dict, Iterator, List, Optional, Sequence, Tuple
 
+import numpy as np
+
 
 class RadixNode:
-    """One cached token block: ``key`` (the block's token tuple) edges
-    from ``parent``; ``page`` is the physical page holding its KV."""
+    """One cached token block: ``key`` (the block's tokens as ``int32``
+    bytes) edges from ``parent``; ``page`` is the physical page holding
+    its KV."""
 
     __slots__ = ("children", "parent", "key", "page", "last_access")
 
     def __init__(self, parent: Optional["RadixNode"] = None,
-                 key: Optional[Tuple[int, ...]] = None,
+                 key: Optional[bytes] = None,
                  page: Optional[int] = None, last_access: int = 0):
-        self.children: Dict[Tuple[int, ...], RadixNode] = {}
+        self.children: Dict[bytes, RadixNode] = {}
         self.parent = parent
         self.key = key
         self.page = page
@@ -67,9 +78,11 @@ class RadixTree:
         self._clock += 1
         return self._clock
 
-    def _block(self, tokens: Sequence[int], i: int) -> Tuple[int, ...]:
-        ps = self.page_size
-        return tuple(int(t) for t in tokens[i * ps:(i + 1) * ps])
+    def _buffer(self, tokens: Sequence[int]) -> Tuple[bytes, int]:
+        """``tokens`` read once: ``(buffer, width)`` with block ``i``'s key
+        at ``buffer[i * width:(i + 1) * width]``."""
+        return (np.ascontiguousarray(tokens, np.int32).tobytes(),
+                4 * self.page_size)
 
     def match(self, tokens: Sequence[int], touch: bool = True
               ) -> List[RadixNode]:
@@ -78,8 +91,9 @@ class RadixTree:
         admission sizing — pass False so sizing never distorts LRU)."""
         node, out = self.root, []
         stamp = self.tick() if touch else None
-        for i in range(len(tokens) // self.page_size):
-            child = node.children.get(self._block(tokens, i))
+        buf, w = self._buffer(tokens)
+        for end in range(w, len(buf) + 1, w):
+            child = node.children.get(buf[end - w:end])
             if child is None:
                 break
             if stamp is not None:
@@ -100,19 +114,20 @@ class RadixTree:
         stamp = self.tick()
         adopted: List[int] = []
         dup: List[int] = []
-        for i in range(min(len(tokens) // self.page_size, len(pages))):
-            blk = self._block(tokens, i)
+        buf, w = self._buffer(tokens)
+        for end, page in zip(range(w, len(buf) + 1, w), pages):
+            blk, page = buf[end - w:end], int(page)
             child = node.children.get(blk)
             if child is None:
-                child = RadixNode(parent=node, key=blk, page=int(pages[i]),
+                child = RadixNode(parent=node, key=blk, page=page,
                                   last_access=stamp)
                 node.children[blk] = child
-                self._by_page[child.page] = child
-                adopted.append(child.page)
+                self._by_page[page] = child
+                adopted.append(page)
             else:
                 child.last_access = stamp
-                if int(pages[i]) != child.page:
-                    dup.append(int(pages[i]))
+                if page != child.page:
+                    dup.append(page)
             node = child
         return adopted, dup
 

@@ -323,6 +323,37 @@ def test_a_repeated_prompt_walks_its_cached_blocks_and_gc_is_named(tmp_path):
     assert 0 < paused_ns <= spans_ns
 
 
+def test_a_hit_a_miss_and_a_retirement_keep_their_spans_and_stats(tmp_path):
+    """ISSUE 36 (the index reads a sequence once a walk and keys a block by
+    its bytes): what ``prefix.walk_ms_per_dispatch`` and
+    ``prefix.blocks_walked_per_dispatch`` read is still written, with the
+    counts a hand walk gives. A prompt that misses, one that shares the
+    first's two leading blocks and then diverges, one that shares nothing:
+    ``blocks`` is the full blocks matched, ``pages`` the blocks of the
+    retired sequence that were new to the tree."""
+    cfg, params, eng, sched = _build(3, False)
+    _warm(cfg, params, sched)
+    first = list(range(5, 14))                       # 9 tokens: 2 full blocks
+    second = first[:2 * PAGE] + [20, 21, 22]         # diverges in block 2
+    third = [30, 31, 32, 33, 34]
+    prompts = [first, second, third]
+
+    def body():
+        for prompt in prompts:
+            sched.submit(np.asarray(prompt, np.int32), max_new_tokens=MAX_NEW)
+            _drain(sched, params)
+    events = _traced(tmp_path, body)
+    stats = {n: [e[3] for e in events if e[0] == n] for n in HOST_SPANS}
+    walked = [{"tokens": len(p), "blocks": b}
+              for p, b in zip(prompts, (0, 2, 0))]
+    assert stats["paddle_serving.prefix_peek"] == walked
+    assert stats["paddle_serving.prefix_lookup"] == walked
+    assert stats["paddle_serving.prefix_insert"] == [
+        {"tokens": len(p) + MAX_NEW,
+         "pages": (len(p) + MAX_NEW) // PAGE - shared}
+        for p, shared in zip(prompts, (0, 2, 0))]
+
+
 def test_a_model_without_a_state_layout_carries_its_pools_alone(run):
     """Llama names no ``state_layout``: the engine keeps no state pool, what
     its step takes and returns is the page pools and nothing else, and its

@@ -5,11 +5,12 @@ disabled, >=50% of prefill tokens skipped on warm shared-prefix traffic,
 and the page conservation invariant holding after every engine step."""
 
 import re
+from collections.abc import Sequence
 
 import numpy as np
 import pytest
 
-from paddle_tpu.kvcache import (LRUEvictionPolicy, PrefixCache,
+from paddle_tpu.kvcache import (LRUEvictionPolicy, PrefixCache, RadixNode,
                                 RefcountedKVCacheManager, RadixTree)
 
 
@@ -62,6 +63,146 @@ def test_radix_remove_leaf_only():
     assert t.match([1, 2, 3, 4]) == [inner]
     t.remove(inner)          # now a leaf
     assert len(t) == 0
+
+
+class _TupleKeyedTree(RadixTree):
+    """The index as it was before ISSUE 36, kept here as the reference:
+    every block's key built as ``tuple(int(t) ...)``, a token at a time.
+    ``remove`` / ``leaves`` / ``tick`` never look inside a key."""
+
+    def _block(self, tokens, i):
+        ps = self.page_size
+        return tuple(int(t) for t in tokens[i * ps:(i + 1) * ps])
+
+    def match(self, tokens, touch=True):
+        node, out = self.root, []
+        stamp = self.tick() if touch else None
+        for i in range(len(tokens) // self.page_size):
+            child = node.children.get(self._block(tokens, i))
+            if child is None:
+                break
+            if stamp is not None:
+                child.last_access = stamp
+            out.append(child)
+            node = child
+        return out
+
+    def insert(self, tokens, pages):
+        node = self.root
+        stamp = self.tick()
+        adopted, dup = [], []
+        for i in range(min(len(tokens) // self.page_size, len(pages))):
+            blk = self._block(tokens, i)
+            child = node.children.get(blk)
+            if child is None:
+                child = RadixNode(parent=node, key=blk, page=int(pages[i]),
+                                  last_access=stamp)
+                node.children[blk] = child
+                self._by_page[child.page] = child
+                adopted.append(child.page)
+            else:
+                child.last_access = stamp
+                if int(pages[i]) != child.page:
+                    dup.append(int(pages[i]))
+            node = child
+        return adopted, dup
+
+
+_AS = {"int32": lambda seq: np.asarray(seq, np.int32),
+       "int64": lambda seq: np.asarray(seq, np.int64),
+       "list": lambda seq: [int(t) for t in seq]}
+
+
+@pytest.mark.parametrize("kind", ["int32", "int64", "list", "by_turns"])
+def test_radix_walks_equal_the_tuple_keyed_reference(kind):
+    """ISSUE 36: a key is equal exactly when the block's tokens are, so
+    the index keyed by a block's bytes and the one keyed by its tuple of
+    Python ints answer alike under one seeded interleaving of matches
+    (touching and peeking), inserts and LRU evictions, whatever type the
+    caller hands the sequence in: the matched pages, ``(adopted,
+    duplicates)``, the eviction order, the size and every node's stamp
+    agree at every step."""
+    ps = 4
+    rng = np.random.RandomState(36)
+    new, ref = RadixTree(ps), _TupleKeyedTree(ps)
+    policy = LRUEvictionPolicy()
+    # ids whose int32 bytes differ in every byte position
+    vocab = np.array([0, 1, 2, 255, 256, 65536, 2 ** 24, 2 ** 31 - 1])
+    docs = [vocab[rng.randint(0, len(vocab), 40)] for _ in range(5)]
+    kinds = sorted(_AS)
+    next_page = 0
+    for step in range(600):
+        doc = docs[rng.randint(len(docs))]
+        seq = np.concatenate([                   # a shared prefix of random
+            doc[:rng.randint(0, len(doc) + 1)],  # length and a ragged tail
+            vocab[rng.randint(0, len(vocab), rng.randint(0, 9))]])
+        as_kind = _AS[kinds[step % 3] if kind == "by_turns" else kind]
+        op = rng.rand()
+        if op < 0.4:
+            n = rng.randint(0, len(seq) // ps + 2)   # short AND long tables
+            pages = list(range(next_page, next_page + n))
+            next_page += n
+            assert new.insert(as_kind(seq), pages) \
+                == ref.insert(seq.tolist(), pages)
+        elif op < 0.8:
+            touch = bool(rng.randint(2))
+            assert [nd.page for nd in new.match(as_kind(seq), touch=touch)] \
+                == [nd.page for nd in ref.match(seq.tolist(), touch=touch)]
+        else:
+            n = rng.randint(1, 6)
+            protect = [p for p in ref.pages if rng.rand() < 0.2]
+            victims = policy.select(new, lambda _p: 0, n, protect)
+            ref_victims = policy.select(ref, lambda _p: 0, n, protect)
+            assert [v.page for v in victims] == [v.page for v in ref_victims]
+            for v, rv in zip(victims, ref_victims):
+                new.remove(v)
+                ref.remove(rv)
+        assert len(new) == len(ref)
+        assert {p: nd.last_access for p, nd in new._by_page.items()} \
+            == {p: nd.last_access for p, nd in ref._by_page.items()}
+    assert len(new) > 20 and next_page > 5 * len(new)   # it grew AND evicted
+
+
+class _CountedTokens(Sequence):
+    """A token sequence that counts how often it is read, by what."""
+
+    def __init__(self, tokens):
+        self._tokens = np.asarray(tokens, np.int32)
+        self.reads = {"getitem": 0, "iter": 0, "array": 0}
+
+    def __len__(self):
+        return len(self._tokens)
+
+    def __getitem__(self, i):
+        self.reads["getitem"] += 1
+        return self._tokens[i]
+
+    def __iter__(self):
+        self.reads["iter"] += 1
+        return iter(self._tokens)
+
+    def __array__(self, dtype=None, copy=None):
+        self.reads["array"] += 1
+        return self._tokens.astype(dtype or np.int32, copy=bool(copy))
+
+
+@pytest.mark.parametrize("walk", ["match", "peek", "insert", "reinsert"])
+def test_a_walk_reads_its_sequence_once(walk):
+    """ISSUE 36: ``match`` and ``insert`` turn the sequence into block
+    keys ONCE, not once a block or once a token: 64 blocks, at most two
+    reads (numpy may ask ``__array__`` twice), whatever the walk finds."""
+    ps, n_blocks = 4, 64
+    toks = np.random.RandomState(1).randint(0, 1000, ps * n_blocks + 3)
+    tree = RadixTree(ps)
+    if walk != "insert":
+        tree.insert(toks, list(range(n_blocks)))
+    counted = _CountedTokens(toks)
+    if walk in ("match", "peek"):
+        assert len(tree.match(counted, touch=walk == "match")) == n_blocks
+    else:
+        adopted, dup = tree.insert(counted, list(range(100, 100 + n_blocks)))
+        assert len(adopted if walk == "insert" else dup) == n_blocks
+    assert 1 <= sum(counted.reads.values()) <= 2, counted.reads
 
 
 # ---------------------------------------------------------------------------
@@ -311,7 +452,7 @@ def test_pool_invariants_random_interleavings():
 # ---------------------------------------------------------------------------
 
 def _setup_engine(prefix_cache, max_new=6, num_slots=2, num_pages=None,
-                  seed=3):
+                  seed=3, speculative=False):
     from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
                                                GenerationConfig)
     from paddle_tpu.models import llama as L
@@ -320,7 +461,8 @@ def _setup_engine(prefix_cache, max_new=6, num_slots=2, num_pages=None,
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=max_new),
         num_slots=num_slots, page_size=4, max_seq_len=32, chunk=3,
-        num_pages=num_pages, prefix_cache=prefix_cache)
+        num_pages=num_pages, prefix_cache=prefix_cache,
+        speculative=speculative)
     return cfg, params, eng
 
 
@@ -332,6 +474,37 @@ def _shared_prefix_prompts(cfg, n=4, sys_len=12, seed=0):
                                         (int(rng.randint(2, 8)),)
                                         ).astype(np.int32)])
             for _ in range(n)]
+
+
+@pytest.mark.parametrize("speculative", [False, True],
+                         ids=["plain", "speculative"])
+def test_retire_hands_the_index_one_array(speculative):
+    """ISSUE 36: a retiring request's prompt and kept output reach
+    ``cache.insert`` as ONE integer array (the walk reads it once; no
+    per-token conversion ahead of it): as long as the prompt plus the
+    output, one token short under speculation (the verify bonus's K/V
+    slot may never have been written)."""
+    cfg, params, eng = _setup_engine(prefix_cache=True,
+                                     speculative=speculative)
+    handed = []
+    insert = eng.cache.insert
+
+    def counting_insert(tokens, pages):
+        handed.append(tokens)
+        return insert(tokens, pages)
+
+    eng.cache.insert = counting_insert
+    prompts = _shared_prefix_prompts(cfg, n=3)
+    outs = eng.serve(params, prompts)
+    assert len(handed) == len(prompts)
+    for got in handed:
+        assert isinstance(got, np.ndarray) and got.dtype.kind == "i"
+    want = [np.concatenate([p, np.asarray(o, np.int32)])
+            [:-1 if speculative else None] for p, o in zip(prompts, outs)]
+    # retirement order is not submission order
+    assert sorted(a.tolist() for a in handed) \
+        == sorted(a.tolist() for a in want)
+    eng.mgr.check_conservation()
 
 
 def test_generation_byte_identical_cache_on_vs_off():
