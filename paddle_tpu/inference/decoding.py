@@ -249,8 +249,9 @@ class ContinuousBatchingEngine:
         layout = getattr(self._L, "cache_layout", None)
         layout = (layout(mcfg) if layout is not None else kv_cache_layout(
             mcfg.num_key_value_heads, mcfg.head_dim))
-        # pages for the layers that attend: all of them unless the layout
-        # names fewer
+        # pages for the CACHE layers: one a model layer unless the layout
+        # names another count (fewer where only some layers attend, more
+        # where a layer attends twice)
         page_layers = layout.layers or mcfg.num_hidden_layers
         pool_args = (page_layers, pool, page_size)
         pool_kw = dict(dtype=mcfg.dtype, mesh=mesh, mp_axis=mp_axis,
@@ -1300,11 +1301,18 @@ class ContinuousBatchingEngine:
         """The ``cbe.unpack`` span's integer stats from a dispatch's routing
         record ``aux`` (rounds, expert layers, 3: experts hit, largest
         expert load, assignments; ``ops.moe_ops.grouped_expert_ffn``): sums
-        over the dispatch's ``expert_calls`` = rounds x expert layers."""
-        return {"experts_hit": int(aux[..., 0].sum()),
-                "expert_calls": int(aux[..., 0].size),
-                "expert_assignments": int(aux[..., 2].sum()),
-                "max_expert_load": int(aux[..., 1].sum())}
+        over the dispatch's ``expert_calls`` = rounds x expert layers, all
+        four among the experts HELD. A model whose router has zero-compute
+        experts records 5 numbers a call: the assignments that chose one
+        and the router's (valid tokens x k) ride along as two more."""
+        stats = {"experts_hit": int(aux[..., 0].sum()),
+                 "expert_calls": int(aux[..., 0].size),
+                 "expert_assignments": int(aux[..., 2].sum()),
+                 "max_expert_load": int(aux[..., 1].sum())}
+        if aux.shape[-1] > 3:
+            stats["zero_expert_assignments"] = int(aux[..., 3].sum())
+            stats["router_assignments"] = int(aux[..., 4].sum())
+        return stats
 
     def _step_unified(self, params) -> int:
         """One ragged round: host-only admission, ONE dispatch serving

@@ -431,9 +431,13 @@ def _gmm_work_list(group_sizes, m: int, tm: int):
     each ``(tile << 17) | (expert << 1) | is the tile's first item``.
     Returns (items (m // tm + E - 1,), n_items, starts (E,), ends (E,)).
     An expert nobody chose is in no pair: it costs no step and no weight
-    read. A tile no expert reaches (rows past the last group: pad slots)
-    takes one step, which zeroes its rows; it names the last expert hit, so
-    that step re-reads no weights."""
+    read. A tile no expert reaches (rows past the last group: pad slots,
+    and the assignments to experts that are not held) is in no pair either:
+    the kernel's output starts as zeros and stays so there. With a chip's
+    share of the experts and k choices a token most of the ``T x k`` rows
+    are such, and at one step a tile and column tile they were three
+    quarters of the kernel's steps. Where nobody chose anything the list is
+    one item, tile 0 with expert 0 (a grid has at least one step)."""
     n_exp = group_sizes.shape[0]
     n_tiles = m // tm
     sizes = group_sizes.astype(jnp.int32)
@@ -442,10 +446,8 @@ def _gmm_work_list(group_sizes, m: int, tm: int):
     lo = jnp.arange(n_tiles, dtype=jnp.int32)[:, None] * tm
     hit = ((sizes > 0)[None, :] & (starts[None, :] < lo + tm)
            & (ends[None, :] > lo))                          # (tiles, E)
-    experts = jnp.arange(n_exp, dtype=jnp.int32)
-    last_hit = jnp.max(jnp.where(sizes > 0, experts, 0))
-    empty = ~jnp.any(hit, axis=1, keepdims=True)
-    hit = hit | (empty & (experts == last_hit)[None, :])
+    # never an empty grid: tile 0 owns nothing and is zeroed once more
+    hit = hit.at[0, 0].set(hit[0, 0] | ~jnp.any(hit))
     flat = hit.reshape(-1)
     # every tile's experts are contiguous and neighbouring tiles share at
     # most one, so tiles + E - 1 items always suffice
@@ -458,8 +460,9 @@ def _gmm_work_list(group_sizes, m: int, tm: int):
 
 
 def _gmm_kernel(work_ref, starts_ref, ends_ref, base_ref, lhs_ref, rhs_ref,
-                out_ref, *, tm: int):
+                zeros_ref, out_ref, *, tm: int):
     del base_ref                            # read by rhs's index map
+    del zeros_ref                           # the output as it starts
     item = work_ref[pl.program_id(1)]
     tile, expert, first = item >> 17, (item >> 1) & 0xFFFF, (item & 1) == 1
     rows = tile * tm + jax.lax.broadcasted_iota(jnp.int32, (tm, 1), 0)
@@ -486,7 +489,9 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, work=None,
     step streams one expert's (k, tn) weight block and multiplies it with
     one tile of rows, keeping the rows that expert owns — so the call reads
     the weights of the experts hit, once (twice for an expert whose rows
-    straddle two tiles), and nothing of the others. ``work``: the list from
+    straddle two tiles), and nothing of the others. The output starts as
+    zeros (an aliased operand that is never copied), so a row tile that is
+    in no pair costs no step and reads as zeros. ``work``: the list from
     :func:`_gmm_work_list`, for callers that make several products over one
     grouping. ``layer`` (a traced index): ``rhs`` is (layers, E, k, n) and
     the blocks are read IN PLACE from that layer — sliced out first, a
@@ -515,6 +520,7 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, work=None,
                          lambda c, i, w, s, e, b: (tile_of(i, w), 0)),
             pl.BlockSpec((1, k, tn), lambda c, i, w, s, e, b: (
                 b[0] + ((w[i] >> 1) & 0xFFFF), 0, c)),
+            pl.BlockSpec(memory_space=pl.ANY),      # zeros: never copied
         ],
         out_specs=pl.BlockSpec((tm, tn),
                                lambda c, i, w, s, e, b: (tile_of(i, w), c)),
@@ -524,8 +530,10 @@ def moe_grouped_matmul_pallas(lhs, rhs, group_sizes, work=None,
         name="moe_grouped_matmul",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), lhs.dtype),
+        # the tiles no step visits keep what the output starts as
+        input_output_aliases={6: 0},
         interpret=interpret,
-    )(items, starts, ends, base, lhs, rhs)
+    )(items, starts, ends, base, lhs, rhs, jnp.zeros((m, n), lhs.dtype))
 
 
 def moe_grouped_matmul_array(lhs, rhs, group_sizes):
@@ -546,7 +554,7 @@ def moe_grouped_matmul_array(lhs, rhs, group_sizes):
 
 def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
                        w_gate, w_up, w_down, first_expert: int = 0,
-                       layer=None):
+                       layer=None, n_routed: Optional[int] = None):
     """Dropless routed-expert SwiGLU: ``sum_j expert_weight[t, j] *
     expert_{expert_idx[t, j]}(x[t])`` over the experts HELD here.
 
@@ -562,8 +570,17 @@ def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
     (a traced index, inside a scan over layers): the weights are a whole
     stack, (layers, E, ...), and that layer's are used where they lie.
 
+    ``n_routed``: the router's width in experts that HAVE weights, for a
+    router with zero-compute experts beyond them: an id at or beyond it
+    names an identity, ``E_e(x) = x``, which needs no weights and no other
+    chip, so it is computed here for every valid token, ``w x`` in float32.
+    (None: every id is a routed expert, and one outside the held slice adds
+    nothing.)
+
     Returns (out (T, h), stats int32 (3,): experts hit, the largest number
-    of assignments one expert received, assignments made)."""
+    of assignments one expert received, assignments made, all three among
+    the experts HELD; with ``n_routed`` two more: the assignments to
+    zero-compute experts, and the router's, valid tokens x k)."""
     from ._common import use_pallas
     t, k = expert_idx.shape
     held = w_gate.shape[-3]
@@ -590,6 +607,14 @@ def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
     y = jnp.take(y, jnp.argsort(order), axis=0).reshape(t, k, -1)
     weight = jnp.where(routed, expert_weight, 0).astype(jnp.float32)
     out = jnp.einsum("tkh,tk->th", y.astype(jnp.float32), weight)
-    stats = jnp.stack([jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes),
-                       jnp.sum(sizes, dtype=jnp.int32)])
-    return out.astype(x.dtype), stats
+    stats = [jnp.sum(sizes > 0, dtype=jnp.int32), jnp.max(sizes),
+             jnp.sum(sizes, dtype=jnp.int32)]
+    if n_routed is not None:
+        with jax.named_scope("moe.zero_experts"):
+            zero = token_valid[:, None] & (expert_idx >= n_routed)
+            kept = jnp.sum(jnp.where(zero, expert_weight, 0).astype(
+                jnp.float32), axis=1, keepdims=True)
+            out = out + kept * x.astype(jnp.float32)
+        stats += [jnp.sum(zero, dtype=jnp.int32),
+                  jnp.sum(token_valid, dtype=jnp.int32) * k]
+    return out.astype(x.dtype), jnp.stack(stats)

@@ -1027,8 +1027,9 @@ class CacheLayout:
     and the pool lives on one chip."""
     entries: Tuple[Tuple[int, ...], ...]
     head_axis: Optional[int] = None
-    #: layers that keep pages (None: every layer of the model; a model whose
-    #: other layers keep a recurrent state names the few that attend)
+    #: cache layers, the pool's leading axis (None: one a layer of the model;
+    #: a model whose other layers keep a recurrent state names the few that
+    #: attend, one whose layers attend twice names twice its depth)
     layers: Optional[int] = None
 
     @property

@@ -137,6 +137,23 @@ def axk1_engine():
                                     prefix_cache=True)
 
 
+def longcat_engine():
+    """An engine at LongCat-Flash's widths (16 of the 512 routed experts
+    held beside the 256 zero-compute ones, two layers = four cache layers,
+    an eighth of the vocabulary; 128 rows of 4,096 positions, a token pool):
+    the unified step with the latent ragged kernel twice a scanned layer and
+    the grouped expert product."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    from paddle_tpu.models import longcat_flash as F
+
+    cfg = F.LongcatFlashConfig(vocab_size=16384, num_layers=2,
+                               experts_held=16, dtype=jnp.bfloat16)
+    return ContinuousBatchingEngine(cfg, num_slots=128, page_size=16,
+                                    max_seq_len=4096, num_pages=1025,
+                                    prefix_cache=True)
+
+
 def jamba_engine():
     """An engine at Jamba2-3B's published sizes (all 28 layers, the whole
     vocabulary; 128 rows of 4,096 positions, a token pool): the unified step
@@ -190,6 +207,10 @@ def run_checks():
                         "moe_grouped_matmul")),
             "axk1_unified_step_mp1": check_unified_step(
                 axk1_engine(), devices, 1,
+                expect=("mla_paged_attention", "rms_norm_fwd",
+                        "moe_grouped_matmul")),
+            "longcat_unified_step_mp1": check_unified_step(
+                longcat_engine(), devices, 1,
                 expect=("mla_paged_attention", "rms_norm_fwd",
                         "moe_grouped_matmul")),
             "jamba_unified_step_mp1": check_unified_step(

@@ -671,3 +671,79 @@ def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
 
 def test_llama_unpack_span_carries_no_stats(run):
     assert all(e[3] == {} for e in run.events if e[0] == "cbe.unpack")
+
+
+# ---------------------------------------------------------------------------
+# a model whose router has zero-compute experts (models.longcat_flash): two
+# more routing stats; the other expert families' stats stay key for key
+# ---------------------------------------------------------------------------
+FOUR_STATS = {"experts_hit", "expert_calls", "expert_assignments",
+              "max_expert_load"}
+
+
+def _expert_engine(family):
+    import importlib
+    module = importlib.import_module(f"paddle_tpu.models.{family}")
+    cfg = getattr(module, family + "_tiny")()
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
+        num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=3,
+        prefix_cache=True)
+    return module, cfg, eng
+
+
+@pytest.mark.parametrize("family,extra", [
+    ("afmoe", set()), ("axk1", set()),
+    ("longcat_flash", {"zero_expert_assignments", "router_assignments"})])
+def test_unpack_stats_by_expert_family(tmp_path, family, extra):
+    """``cbe.unpack`` of each expert family's dispatches: the four stats of
+    the experts HELD for ``afmoe`` and ``axk1``, key for key what they were;
+    for a router with zero-compute experts also the assignments that chose
+    an identity and the router's own (valid tokens x k), and held +
+    identities are all of them where every routed expert is held."""
+    module, cfg, eng = _expert_engine(family)
+    params = module.init_stacked_params(cfg, seed=3)
+    sched = ServingScheduler(eng, SchedulerConfig(max_queue_depth=64))
+    _warm(cfg, params, sched)
+    plans, plain = [], eng._plan_step
+
+    def spy():
+        out = plain()
+        plans.append(out[0][2].copy())
+        return out
+    eng._plan_step = spy
+
+    def body():
+        for p in _prompts(cfg, 4, seed=1):
+            sched.submit(p, max_new_tokens=MAX_NEW)
+        _drain(sched, params)
+    events = _traced(tmp_path, body)
+    unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
+    assert len(unpacks) == len(plans) > 0
+    assert all(set(u) == FOUR_STATS | extra for u in unpacks)
+    if extra:
+        for stats, token_row in zip(unpacks, plans):
+            assert stats["expert_calls"] == 3 * cfg.num_layers
+            assert stats["router_assignments"] == int(
+                (token_row >= 0).sum()) * cfg.moe_topk * cfg.num_layers
+            assert stats["expert_assignments"] \
+                + stats["zero_expert_assignments"] \
+                == stats["router_assignments"]
+        assert sum(u["zero_expert_assignments"] for u in unpacks) > 0
+        assert sum(u["expert_assignments"] for u in unpacks) > 0
+
+
+def test_expert_stats_of_a_record_with_zero_compute_experts_by_hand():
+    """Laid out by hand: 2 rounds x 2 layers of (hit, max, made, identities,
+    the router's); a record of three numbers a call gives the four stats it
+    always gave."""
+    stats = ContinuousBatchingEngine._expert_stats
+    aux = np.array([[[5, 3, 12, 20, 48], [4, 6, 12, 16, 48]],
+                    [[0, 0, 0, 4, 12], [1, 2, 2, 3, 12]]])
+    assert stats(aux) == {
+        "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
+        "max_expert_load": 11, "zero_expert_assignments": 43,
+        "router_assignments": 120}
+    assert stats(aux[..., :3]) == {
+        "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
+        "max_expert_load": 11}

@@ -35,6 +35,12 @@ def test_serving_and_train_steps_compile_for_v5e_with_their_kernels():
     assert axk1["mla_paged_attention"] == 2
     assert axk1["moe_grouped_matmul"] == 3
     assert "ragged_paged_attention" not in axk1
+    # LongCat-Flash: the latent kernel at both sub-layers of the ONE scanned
+    # layer, the grouped product's three, no GQA kernel anywhere
+    longcat = out["longcat_unified_step_mp1"]["kernels"]
+    assert longcat["mla_paged_attention"] == 2
+    assert longcat["moe_grouped_matmul"] == 3
+    assert "ragged_paged_attention" not in longcat
     # Jamba: the scan in the Mamba layers' scanned body, the ragged kernel
     # at its two attention layers over pools without a head axis
     jamba = out["jamba_unified_step_mp1"]["kernels"]
