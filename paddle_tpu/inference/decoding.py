@@ -93,7 +93,9 @@ def _serving_module(model_config):
     step then takes and returns the state's arrays after the cache's, in
     ``pools``, and starts a row whose first token is at position 0 from
     zeros) and the step's last return value (a small int32 routing
-    record, handed out with the tokens)."""
+    record, handed out with the tokens), whose columns
+    ``routing_columns(config)`` names (``ops.moe_ops.expert_stat_names()``,
+    SwiGLU experts' three, where the module has none)."""
     name = getattr(model_config, "serving_module", _LLAMA)
     module = importlib.import_module(name)
     missing = [n for n in _MODEL_PROTOCOL if not hasattr(module, n)]
@@ -425,6 +427,12 @@ class ContinuousBatchingEngine:
             else (None,) * page_layers)
         self._window_layers = tuple(
             collections.Counter(self._layer_windows).items())
+        #: the names of the columns of the routing record the model's step
+        #: returns, where it returns one (``_expert_stats``)
+        from ..ops.moe_ops import expert_stat_names
+        columns = getattr(self._L, "routing_columns", None)
+        self._routing_columns = (columns(mcfg) if columns
+                                 else expert_stat_names())
         # HBM memory ledger (observability/memory.py): when armed, every
         # step feeds the pool's byte split + per-request holdings and
         # runs the byte conservation audit alongside check_conservation.
@@ -1297,21 +1305,20 @@ class ContinuousBatchingEngine:
         return record
 
     @staticmethod
-    def _expert_stats(aux) -> Dict[str, int]:
+    def _expert_stats(aux, columns) -> Dict[str, int]:
         """The ``cbe.unpack`` span's integer stats from a dispatch's routing
-        record ``aux`` (rounds, expert layers, 3: experts hit, largest
-        expert load, assignments; ``ops.moe_ops.grouped_expert_ffn``): sums
-        over the dispatch's ``expert_calls`` = rounds x expert layers, all
-        four among the experts HELD. A model whose router has zero-compute
-        experts records 5 numbers a call: the assignments that chose one
-        and the router's (valid tokens x k) ride along as two more."""
-        stats = {"experts_hit": int(aux[..., 0].sum()),
-                 "expert_calls": int(aux[..., 0].size),
-                 "expert_assignments": int(aux[..., 2].sum()),
-                 "max_expert_load": int(aux[..., 1].sum())}
-        if aux.shape[-1] > 3:
-            stats["zero_expert_assignments"] = int(aux[..., 3].sum())
-            stats["router_assignments"] = int(aux[..., 4].sum())
+        record ``aux`` (rounds, expert layers, a number a column), whose
+        columns the model module's ``routing_columns`` names
+        (``ops.moe_ops.expert_stat_names``): ``expert_calls`` = rounds x
+        expert layers, and each column's sum over them under its name.
+        Every expert family records ``experts_hit``, ``max_expert_load``
+        and ``expert_assignments`` (among the experts HELD); a router with
+        zero-compute experts adds ``zero_expert_assignments`` and
+        ``router_assignments`` (valid tokens x k), ReGLU experts
+        ``expert_active_units`` and ``expert_units``."""
+        stats = {"expert_calls": int(aux[..., 0].size)}
+        stats.update((name, int(aux[..., i].sum()))
+                     for i, name in enumerate(columns))
         return stats
 
     def _step_unified(self, params) -> int:
@@ -1401,7 +1408,8 @@ class ContinuousBatchingEngine:
             toks = np.asarray(toks)                    # the one fence
             # a model with experts: its routing record comes with the
             # tokens, and its sums ride on the unpack span
-            routed = self._expert_stats(np.asarray(aux[0])) if aux else {}
+            routed = self._expert_stats(
+                np.asarray(aux[0]), self._routing_columns) if aux else {}
         if armed_chain:
             tc1 = time.perf_counter_ns()
             if self._fused_tail:

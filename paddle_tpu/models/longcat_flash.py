@@ -62,7 +62,7 @@ from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops import rope as rope_ops
-from ..ops.moe_ops import grouped_expert_ffn
+from ..ops.moe_ops import expert_stat_names, grouped_expert_ffn
 from ..ops.paged_attention import CacheLayout
 from ..ops.rms_norm import rms_norm_replicated
 from .axk1 import absorb_queries
@@ -225,6 +225,12 @@ def param_nbytes(config: LongcatFlashConfig) -> int:
     """Device bytes of ``init_stacked_params(config)``."""
     return sum(math.prod(shape) * jnp.dtype(dt).itemsize
                for shape, dt in _shapes(config).values())
+
+
+def routing_columns(config: LongcatFlashConfig) -> Tuple[str, ...]:
+    """The names of the columns of the routing record the step returns
+    (``ops.moe_ops.grouped_expert_ffn``'s stats)."""
+    return expert_stat_names(n_routed=config.n_routed_experts)
 
 
 def serving_param_specs(config: LongcatFlashConfig) -> Dict[str, P]:

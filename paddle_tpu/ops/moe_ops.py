@@ -552,11 +552,33 @@ def moe_grouped_matmul_array(lhs, rhs, group_sizes):
     return jnp.where(owned[:, None], out, jnp.zeros_like(out))
 
 
+#: the expert activations ``grouped_expert_ffn`` offers: an expert is
+#: ``down(act(x W_gate) * (x W_up))``, SwiGLU with ``silu``, ReGLU with
+#: ``relu``
+_ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def expert_stat_names(n_routed: Optional[int] = None,
+                      activation: str = "silu") -> Tuple[str, ...]:
+    """The names of the columns of ``grouped_expert_ffn``'s stats, in
+    order, for a call with these options: what a model's routing record
+    names its columns by."""
+    names = ("experts_hit", "max_expert_load", "expert_assignments")
+    if n_routed is not None:
+        names += ("zero_expert_assignments", "router_assignments")
+    if activation == "relu":
+        names += ("expert_active_units", "expert_units")
+    return names
+
+
 def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
                        w_gate, w_up, w_down, first_expert: int = 0,
-                       layer=None, n_routed: Optional[int] = None):
-    """Dropless routed-expert SwiGLU: ``sum_j expert_weight[t, j] *
-    expert_{expert_idx[t, j]}(x[t])`` over the experts HELD here.
+                       layer=None, n_routed: Optional[int] = None,
+                       activation: str = "silu"):
+    """Dropless routed experts, each ``down(act(x W_gate) * (x W_up))``
+    with ``act`` named by ``activation`` (``silu``: SwiGLU, ``relu``:
+    ReGLU): ``sum_j expert_weight[t, j] * expert_{expert_idx[t, j]}(x[t])``
+    over the experts HELD here.
 
     x: (T, h); expert_idx / expert_weight: (T, k), the router's choice over
     ALL its experts and the weights it gave them; token_valid: (T,) bool, a
@@ -580,7 +602,12 @@ def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
     Returns (out (T, h), stats int32 (3,): experts hit, the largest number
     of assignments one expert received, assignments made, all three among
     the experts HELD; with ``n_routed`` two more: the assignments to
-    zero-compute experts, and the router's, valid tokens x k)."""
+    zero-compute experts, and the router's, valid tokens x k; with
+    ``relu`` two more after those: the intermediate units ReLU left
+    non-zero over the assignments made (``expert_active_units``) and the
+    units computed for them (``expert_units``, assignments x the expert
+    width), pad slots and rows of no held expert in neither).
+    ``expert_stat_names`` names the columns."""
     from ._common import use_pallas
     t, k = expert_idx.shape
     held = w_gate.shape[-3]
@@ -601,8 +628,8 @@ def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
         def gmm(lhs, rhs, group_sizes):
             return moe_grouped_matmul_array(
                 lhs, rhs if layer is None else rhs[layer], group_sizes)
-    act = (jax.nn.silu(gmm(rows, w_gate, sizes))
-           * gmm(rows, w_up, sizes))
+    gate = gmm(rows, w_gate, sizes)
+    act = _ACTIVATIONS[activation](gate) * gmm(rows, w_up, sizes)
     y = gmm(act.astype(x.dtype), w_down, sizes)     # (T * k, h), sorted
     y = jnp.take(y, jnp.argsort(order), axis=0).reshape(t, k, -1)
     weight = jnp.where(routed, expert_weight, 0).astype(jnp.float32)
@@ -617,4 +644,11 @@ def grouped_expert_ffn(x, expert_idx, expert_weight, token_valid,
             out = out + kept * x.astype(jnp.float32)
         stats += [jnp.sum(zero, dtype=jnp.int32),
                   jnp.sum(token_valid, dtype=jnp.int32) * k]
+    if activation == "relu":
+        # the assignments made are the first ``stats[2]`` sorted rows: pad
+        # slots and rows of no held expert lie past them
+        made = stats[2]
+        made_rows = jnp.arange(t * k, dtype=jnp.int32)[:, None] < made
+        stats += [jnp.sum((gate > 0) & made_rows, dtype=jnp.int32),
+                  made * w_gate.shape[-1]]
     return out.astype(x.dtype), jnp.stack(stats)

@@ -15,8 +15,10 @@ Checked at Llama-2-7B width (h=4096, 32x128 heads, ff=11008, bf16) and
 ``serving_mesh(4)``, the one-chip hybrid train step, and the unified step
 of an AFMoE engine at Trinity-Mini's widths (a window in the ragged kernel,
 the grouped expert product), of an A.X-K1 engine (the latent ragged
-kernel over a pool without a head axis) and of a Jamba engine (the selective
-scan over each row's state, multi-query pools without a head axis) — each must compile and carry its Pallas
+kernel over a pool without a head axis), of a Jamba engine (the selective
+scan over each row's state, multi-query pools without a head axis) and of a
+SmallThinker engine (the windowed ragged kernel at 7 query heads a KV head,
+ReGLU experts) — each must compile and carry its Pallas
 kernels, by name, in the lowering. What it
 cannot show is whether the programs RUN correctly; that is
 ``chip_smoke.py``'s job, on the chip.
@@ -170,6 +172,22 @@ def jamba_engine():
                                     max_seq_len=4096, num_pages=1025)
 
 
+def smallthinker_engine():
+    """An engine at SmallThinker-21BA3B's widths (all 64 experts, a global
+    and a window layer, an eighth of the vocabulary; 32 rows of 16,384
+    positions, a token pool): the unified step with the windowed ragged
+    kernel at 7 query heads a KV head and the grouped ReGLU product."""
+    import jax.numpy as jnp
+    from paddle_tpu.inference.decoding import ContinuousBatchingEngine
+    from paddle_tpu.models import smallthinker as S
+
+    cfg = S.SmallThinkerConfig(vocab_size=18992, num_hidden_layers=2,
+                               dtype=jnp.bfloat16)
+    return ContinuousBatchingEngine(cfg, num_slots=32, page_size=16,
+                                    max_seq_len=16384, num_pages=1025,
+                                    prefix_cache=True)
+
+
 def check_train_step(devices):
     """bench.py's llama7b_layer geometry (B=8, S=2048, default remat) on
     one chip: flash attention fwd+bwd and rms_norm fwd+bwd."""
@@ -217,6 +235,10 @@ def run_checks():
                 jamba_engine(), devices, 1,
                 expect=("mamba_ragged_scan", "ragged_paged_attention",
                         "rms_norm_fwd")),
+            "smallthinker_unified_step_mp1": check_unified_step(
+                smallthinker_engine(), devices, 1,
+                expect=("ragged_paged_attention", "rms_norm_fwd",
+                        "moe_grouped_matmul")),
             "train_step": check_train_step(devices),
         }
 

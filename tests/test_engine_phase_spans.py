@@ -26,6 +26,7 @@ from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
 from paddle_tpu.inference.sampling import SamplerConfig
 from paddle_tpu.models import llama as L
 from paddle_tpu.observability.runtime import collections
+from paddle_tpu.ops.moe_ops import expert_stat_names
 from paddle_tpu.ops.paged_attention import ragged_block_pages
 from paddle_tpu.profiler import Profiler, ProfilerTarget
 from paddle_tpu.serving import SchedulerConfig, ServingScheduler
@@ -634,7 +635,10 @@ def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
     A, cfg, eng = _afmoe_engine()
     # laid out by hand: 2 rounds x 2 expert layers of (hit, max, made)
     aux = np.array([[[5, 3, 12], [4, 6, 12]], [[0, 0, 0], [1, 2, 2]]])
-    assert eng._expert_stats(aux) == {
+    # a module without ``routing_columns``: SwiGLU experts' three columns
+    assert not hasattr(A, "routing_columns")
+    assert eng._routing_columns == expert_stat_names()
+    assert eng._expert_stats(aux, eng._routing_columns) == {
         "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
         "max_expert_load": 11}
     params = A.init_stacked_params(cfg, seed=3)
@@ -736,14 +740,43 @@ def test_unpack_stats_by_expert_family(tmp_path, family, extra):
 def test_expert_stats_of_a_record_with_zero_compute_experts_by_hand():
     """Laid out by hand: 2 rounds x 2 layers of (hit, max, made, identities,
     the router's); a record of three numbers a call gives the four stats it
-    always gave."""
+    always gave. The columns are the ones ``grouped_expert_ffn`` names."""
     stats = ContinuousBatchingEngine._expert_stats
     aux = np.array([[[5, 3, 12, 20, 48], [4, 6, 12, 16, 48]],
                     [[0, 0, 0, 4, 12], [1, 2, 2, 3, 12]]])
-    assert stats(aux) == {
+    assert stats(aux, expert_stat_names(n_routed=8)) == {
         "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
         "max_expert_load": 11, "zero_expert_assignments": 43,
         "router_assignments": 120}
-    assert stats(aux[..., :3]) == {
+    assert stats(aux[..., :3], expert_stat_names()) == {
         "experts_hit": 10, "expert_calls": 4, "expert_assignments": 26,
         "max_expert_load": 11}
+
+
+def test_unpack_stats_of_relu_experts_count_the_active_units(tmp_path):
+    """``cbe.unpack`` of a model with ReGLU experts (``models.smallthinker``):
+    the four stats of the experts held and two more, the units ReLU left
+    active and the units computed (assignments x the expert width); no
+    zero-compute pair. The SwiGLU families' spans stay as they were (the
+    test above)."""
+    module, cfg, eng = _expert_engine("smallthinker")
+    params = module.init_stacked_params(cfg, seed=3)
+    sched = ServingScheduler(eng, SchedulerConfig(max_queue_depth=64))
+    _warm(cfg, params, sched)
+
+    def body():
+        for p in _prompts(cfg, 4, seed=1):
+            sched.submit(p, max_new_tokens=MAX_NEW)
+        _drain(sched, params)
+    events = _traced(tmp_path, body)
+    unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
+    assert unpacks and all(
+        set(u) == FOUR_STATS | {"expert_active_units", "expert_units"}
+        for u in unpacks)
+    for stats in unpacks:
+        assert stats["expert_calls"] == 3 * cfg.num_hidden_layers
+        assert stats["expert_units"] == \
+            stats["expert_assignments"] * cfg.moe_ffn_hidden_size
+        assert 0 <= stats["expert_active_units"] <= stats["expert_units"]
+    active = sum(u["expert_active_units"] for u in unpacks)
+    assert 0.3 < active / sum(u["expert_units"] for u in unpacks) < 0.7
