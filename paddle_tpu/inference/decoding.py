@@ -1234,30 +1234,47 @@ class ContinuousBatchingEngine:
         ``causal_pairs`` query-key pairs pass the mask. A model with
         sliding-window layers (its serving module's ``attention_windows``)
         adds ``window_skipped_pages``: live pages a full mask would have
-        listed and the window did not, the same mean. A model whose pools
-        have no head axis (the latent kernel's walk) adds ``shared_pages``:
+        listed and the window did not, the same mean, and ``shared_pages``:
         the attended pages that its calls fold under ANOTHER row's work item,
-        because both rows' block tables name them (a borrowed prefix;
-        ``ragged_shared_blocks``), the same mean; what each ROW attends
-        (``attended_pages``, ``grid_steps``, ``causal_pairs``) counts them
-        all the same. A model with a state a row (``state_layout``) adds
-        ``state_row_rounds``, ``state_resets`` and ``state_bytes_per_row``
-        (at the end).
+        because both rows' block tables name them (a borrowed prefix), the
+        same mean. The GQA kernel groups a call's rows whose tables start
+        with the same page id; the leader is the row whose earliest token
+        comes first (under any window its list starts first), each
+        follower's shared run ends where its table and the leader's differ,
+        within both rows' full pages, and the follower lists only what lies
+        past the run and inside its window; one softmax state a call lets a
+        follower's tokens take the leader's blocks
+        (``ops.paged_attention.ragged_shared_pages``, the host's twin of the
+        kernel's grouping). A model whose pools have no head axis (the
+        latent kernel's walk) adds ``shared_pages`` too, counted in blocks
+        of leading pages (``ragged_shared_blocks``). What each ROW attends
+        (``attended_pages``, ``grid_steps``, ``causal_pairs``) counts shared
+        pages all the same. Llama's record keeps its ten keys: a model
+        without window layers writes neither window key, and
+        ``tests/perfbench/test_perfbench_program_trace.py`` holds the
+        benchmark's reading of that record to them. A model with a state a
+        row (``state_layout``) adds ``state_row_rounds``, ``state_resets``
+        and ``state_bytes_per_row`` (at the end).
         Computed on every dispatch (a few vectorised numpy lines)."""
         from ..ops.paged_attention import (ragged_block_pages,
                                            ragged_first_pages,
                                            ragged_live_blocks,
                                            ragged_live_pages,
-                                           ragged_shared_blocks)
+                                           ragged_shared_blocks,
+                                           ragged_shared_pages)
         ps = self.page_size
         width = self._table_width
         group = ragged_block_pages(ps, width)
         full_pages = ragged_live_pages(kv_lens, ps, width)
         seen = (positions + 1)[token_row >= 0]
+        windowed = len(self._window_layers) > 1 \
+            or self._layer_windows[0] is not None
+        gqa_shared = windowed and self.mgr.layout.head_axis is not None
         # per kind of layer (a window, or None): live pages and blocks of
-        # each micro-round and the pairs the mask lets through, weighted by
-        # how many layers are of that kind
-        attended = steps = pairs = 0
+        # each micro-round, the pairs the mask lets through and the pages
+        # folded under another row's item, weighted by how many layers are
+        # of that kind
+        attended = steps = pairs = shared = 0
         for window, share in self._window_layers:
             first = None if window is None else ragged_first_pages(
                 token_row, positions, self.num_slots, ps, window)
@@ -1268,6 +1285,9 @@ class ContinuousBatchingEngine:
             steps += share * group * int(np.maximum(blocks, 1).sum())
             pairs += share * int((seen if window is None
                                   else np.minimum(seen, window)).sum())
+            if gqa_shared:
+                shared += share * int(ragged_shared_pages(
+                    self._bt, token_row, positions, kv_lens, ps, first).sum())
         layers = len(self._layer_windows)
         n = self._dispatches
         self._dispatches = n + 1
@@ -1283,9 +1303,11 @@ class ContinuousBatchingEngine:
             "causal_pairs": round(pairs / layers),
             "page_size": ps,
         }
-        if len(self._window_layers) > 1 or self._layer_windows[0] is not None:
+        if windowed:
             record["window_skipped_pages"] = round(
                 int(full_pages.sum()) - attended / layers)
+        if gqa_shared:
+            record["shared_pages"] = round(shared / layers)
         if self.mgr.layout.head_axis is None:
             # every layer's call sees the same table and spans
             record["shared_pages"] = group * int(

@@ -291,6 +291,9 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
     n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
     kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
     vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
+    # the ragged kernel's row groups: once a step, every layer
+    groups = pa.ragged_row_groups(block_tables, token_row, pos_c, kv_lens,
+                                  page)
 
     def attention(xc, kp, vp, lp, l, window, ropes):
         a = rms(xc, lp["ln_in"]).astype(dt)
@@ -304,7 +307,7 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
         attn = pa.ragged_paged_attention(
             q, kp, vp, block_tables + l * pool_p, token_row, pos_c, kv_lens,
             scale=1.0 / math.sqrt(d), mesh=mesh, mp_axis=mp_axis,
-            window=window)                                  # (T, nh, d)
+            window=window, groups=groups)                   # (T, nh, d)
         gated = attn.reshape(t, nh * d) * jax.nn.sigmoid(
             _mm(a, lp["wg"])).astype(attn.dtype)
         xo = xc + rms(_mm(gated, lp["wo"]), lp["ln_post_attn"]).astype(f32)

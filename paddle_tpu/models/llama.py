@@ -1003,6 +1003,9 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
     n_layers, pool_p = k_pages.shape[0], k_pages.shape[1]
     kp_flat = k_pages.reshape((n_layers * pool_p,) + k_pages.shape[2:])
     vp_flat = v_pages.reshape((n_layers * pool_p,) + v_pages.shape[2:])
+    # the ragged kernel's row groups and work list: once a step, every layer
+    groups = pa.ragged_row_groups(block_tables, token_row, pos_c, kv_lens,
+                                  page)
 
     def body(carry, lp_l):
         xc, kp, vp = carry
@@ -1019,7 +1022,7 @@ def ragged_step(params, ids, token_row, positions, kv_lens, last_idx,
         attn = pa.ragged_paged_attention(
             q[0], kp, vp, block_tables + l * pool_p, token_row, pos_c,
             kv_lens, scale=1.0 / math.sqrt(d),
-            mesh=mesh, mp_axis=mp_axis)                    # (T, nh, d)
+            mesh=mesh, mp_axis=mp_axis, groups=groups)     # (T, nh, d)
         xo = xc + _mm_prefill(attn.reshape(1, t, -1),
                               lp["wo"]).astype(xc.dtype)
         xn2 = rms(xo, lp["ln2"])

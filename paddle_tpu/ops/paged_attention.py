@@ -24,7 +24,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -168,13 +168,10 @@ def ragged_paged_attention_array(q, k_pages, v_pages, block_tables, token_row,
     return jnp.einsum("ths,tshd->thd", probs.astype(v.dtype), v)
 
 
-def ragged_first_pages(token_row, positions, n_rows: int, page: int,
-                       window: int) -> np.ndarray:
-    """First page each row's work list holds under a sliding ``window``, on
-    the host in numpy (the engine's work record): the page of the oldest
-    key the row's EARLIEST token of the call still sees, ``max(0, min
-    position - window + 1) // page``; 0 for a row with no token.
-    ``token_row`` / ``positions``: (..., T); returns (..., n_rows)."""
+def _first_positions(token_row, positions, n_rows: int) -> np.ndarray:
+    """Each row's EARLIEST position of each call, on the host in numpy; 0 for
+    a row with no token. ``token_row`` / ``positions``: (..., T); returns
+    (..., n_rows)."""
     token_row = np.asarray(token_row)
     lead = token_row.shape[:-1]
     tr = token_row.reshape(-1, token_row.shape[-1])
@@ -183,9 +180,18 @@ def ragged_first_pages(token_row, positions, n_rows: int, page: int,
     first = np.full((tr.shape[0], n_rows), big, np.int64)
     call, slot = np.nonzero(tr >= 0)
     np.minimum.at(first, (call, tr[call, slot]), pos[call, slot])
-    first = np.where(first == big, 0, first)
-    return (np.maximum(first - window + 1, 0) // page).reshape(
-        lead + (n_rows,))
+    return np.where(first == big, 0, first).reshape(lead + (n_rows,))
+
+
+def ragged_first_pages(token_row, positions, n_rows: int, page: int,
+                       window: int) -> np.ndarray:
+    """First page each row's work list holds under a sliding ``window``, on
+    the host in numpy (the engine's work record): the page of the oldest
+    key the row's EARLIEST token of the call still sees, ``max(0, min
+    position - window + 1) // page``; 0 for a row with no token.
+    ``token_row`` / ``positions``: (..., T); returns (..., n_rows)."""
+    first = _first_positions(token_row, positions, n_rows)
+    return np.maximum(first - window + 1, 0) // page
 
 
 def ragged_block_pages(page: int, max_pages: int) -> int:
@@ -268,6 +274,49 @@ def ragged_shared_blocks(block_tables, kv_lens, page: int) -> np.ndarray:
         shared[call, row] = np.minimum(
             np.minimum(common[of], blocks[call, row] - 1),
             np.minimum(full[call, row], full[call, leader]))
+    return shared.reshape(kv.shape)
+
+
+def ragged_shared_pages(block_tables, token_row, positions, kv_lens,
+                        page: int, first_pages=None) -> np.ndarray:
+    """Listed pages each row of each GQA kernel call folds under ANOTHER
+    row's work item, on the host in numpy, for the engine's work record: the
+    twin of :func:`_shared_page_groups` (its ``run`` less the row's first
+    listed page, where that is less; tests hold the two equal).
+    ``block_tables`` (R, W): ONE table for all the calls; ``token_row`` /
+    ``positions`` (..., T) and ``kv_lens`` (..., R), one call per row of the
+    leading axes; ``first_pages`` (..., R) where a window starts each row's
+    list past page 0 (:func:`ragged_first_pages`). Returns ``kv_lens``'
+    shape."""
+    tables = np.asarray(block_tables)
+    n_rows, width = tables.shape
+    kv = np.asarray(kv_lens, np.int64)
+    calls = kv.reshape(-1, n_rows)
+    full = np.minimum(calls // page, width)
+    shared = np.zeros_like(calls)
+    same = tables[:, None, 0] == tables[None, :, 0]         # (R, R)
+    if same.sum() == n_rows:
+        return shared.reshape(kv.shape)                     # nothing shared
+    rows = np.arange(n_rows)
+    # a call's leader of a row: of the rows with a full page whose table
+    # starts with the same id, the one whose earliest token comes first
+    held = full > 0
+    key = _first_positions(token_row, positions, n_rows).reshape(
+        calls.shape) * n_rows + rows
+    cand = same[None] & held[:, None, :] & held[:, :, None]
+    lead = np.where(held, np.where(cand, key[:, None, :],
+                                   np.iinfo(np.int64).max).argmin(-1), rows)
+    call, row = np.nonzero(lead != rows)
+    if len(call):
+        leader = lead[call, row]
+        pairs, of = np.unique(row * n_rows + leader, return_inverse=True)
+        differ = tables[pairs // n_rows] != tables[pairs % n_rows]
+        common = np.where(differ.any(-1), differ.argmax(-1), width)
+        run = np.minimum(common[of], np.minimum(full[call, row],
+                                                full[call, leader]))
+        start = 0 if first_pages is None else np.asarray(
+            first_pages, np.int64).reshape(calls.shape)[call, row]
+        shared[call, row] = np.maximum(run - start, 0)
     return shared.reshape(kv.shape)
 
 
@@ -360,32 +409,91 @@ def _unpack_work_item(item, page_bits: int):
             (item & 2) == 2, (item & 1) == 1)
 
 
-def _row_first_pages(token_row, positions, n_rows: int, page: int, window):
-    """In-program twin of :func:`ragged_first_pages` for one call:
-    (n_rows,) int32."""
+def _row_first_positions(token_row, positions, n_rows: int):
+    """In-program twin of :func:`_first_positions` for one call: (n_rows,)
+    int32."""
     big = jnp.iinfo(jnp.int32).max
     mine = token_row[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
     first = jnp.min(jnp.where(mine, positions[None, :].astype(jnp.int32),
                               big), axis=1)
-    first = jnp.where(first == big, 0, first)
-    return jnp.maximum(first - window + 1, 0) // page
+    return jnp.where(first == big, 0, first)
 
 
-def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
+def _window_first_pages(first_positions, page: int, window):
+    """In-program twin of :func:`ragged_first_pages` for one call, from
+    :func:`_row_first_positions`: (n_rows,) int32."""
+    return jnp.maximum(first_positions - window + 1, 0) // page
+
+
+def _shared_page_groups(block_tables, kv_lens, first_positions, page: int):
+    """Which rows of one GQA kernel call read the same leading pages, in the
+    program, from the block table's equalities, ``kv_lens`` and each row's
+    earliest position of the call (:func:`_row_first_positions`) alone.
+    Rows whose tables start with the same page id, where both hold a FULL
+    page (``kv_lens // page``: ids past a row's live pages are stale, and a
+    page a row writes this call is its own), form a group. Its leader is
+    the row whose earliest token comes first, ties to the lowest row: a
+    window's first page grows with the position, so under ANY window the
+    leader's list starts no later than a member's, and one grouping serves
+    every layer of a step, windowed or not. Returns three (R,) int32:
+
+    - ``run[r]``: pages 0 .. ``run[r]`` - 1 of row ``r`` are folded by its
+      leader's items: up to the first page where the two tables differ,
+      bounded by both rows' full pages (in pages: the mask works per key,
+      so the two rows' blocks need not align); 0 for a leader and for a
+      row alone.
+    - ``lead[r]``: the leader, ``r`` itself where ``run[r]`` is 0.
+    - ``order[k]``: the row whose items come k-th in the work list: a
+      group's leader, then its followers, groups by their leader; so every
+      token still meets its keys in ascending order.
+
+    A layer's page offset added to every entry changes nothing. O(R^2 + R W)
+    integer work."""
+    n_rows, max_pages = block_tables.shape
+    rows = jnp.arange(n_rows, dtype=jnp.int32)
+    full = jnp.minimum(kv_lens.astype(jnp.int32) // page, max_pages)
+    held = full > 0
+    first = block_tables[:, 0]
+    same = (first[:, None] == first[None, :]) & held[:, None] & held[None, :]
+    key = first_positions.astype(jnp.int32) * n_rows + rows
+    lead = jnp.where(held, jnp.min(jnp.where(
+        same, key[None, :], jnp.iinfo(jnp.int32).max), axis=1) % n_rows, rows)
+    differs = block_tables != jnp.take(block_tables, lead, axis=0)
+    common = jnp.min(jnp.where(
+        differs, jnp.arange(max_pages, dtype=jnp.int32)[None, :], max_pages),
+        axis=1)
+    run = jnp.where(lead == rows, 0, jnp.minimum(
+        common, jnp.minimum(full, jnp.take(full, lead))))
+    rank = (lead * 2 + (lead != rows)) * n_rows + rows
+    rank = jnp.sum(rank[None, :] < rank[:, None], axis=1, dtype=jnp.int32)
+    order = jnp.sum(jnp.where(rank[None, :] == rows[:, None], rows[None, :],
+                              0), axis=1, dtype=jnp.int32)
+    return run, lead, order
+
+
+def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None,
+                      order=None):
     """The kernel's grid as data: the live blocks of one call, row-major,
     one packed int32 each (see :func:`_unpack_work_item`). A block is up to
     G = :func:`ragged_block_pages` consecutive pages of one row, counted
     from the row's first listed page; only a row's last block can hold
     fewer. Returns (items (n_rows * ceil(max_pages / G),), n_live); only
     the first ``n_live`` items are work, the rest hold in-range indices.
-    Without ``first_pages`` it depends on ``kv_lens`` alone, so under a
-    scan over layers it is loop-invariant. ``first_pages`` (n_rows,): a row
+    Without ``first_pages`` it depends on ``kv_lens`` alone.
+    ``first_pages`` (n_rows,): a row
     lists only pages ``first_pages[r] .. ceil(kv_lens[r] / page) - 1``,
-    what a sliding window can reach (:func:`_row_first_pages`), and its
-    blocks count from ``first_pages[r]``, not from a multiple of G."""
+    what a sliding window can reach (:func:`_window_first_pages`) or what lies
+    past the pages another row's items fold for it
+    (:func:`_shared_page_groups`), and its blocks count from
+    ``first_pages[r]``, not from a multiple of G. ``order`` (n_rows,): the
+    rows in the order their items are listed (by index without it)."""
     n_rows = kv_lens.shape[0]
     page_bits = _work_item_bits(max_pages)
     group = ragged_block_pages(page, max_pages)
+    if order is not None:
+        kv_lens = jnp.take(kv_lens, order)
+        if first_pages is not None:
+            first_pages = jnp.take(first_pages, order)
     # the min keeps over-decoded rows (kv_lens past the table span) inside
     # their table
     pages_r = jnp.minimum((kv_lens.astype(jnp.int32) + (page - 1)) // page,
@@ -403,8 +511,17 @@ def _ragged_work_list(kv_lens, page: int, max_pages: int, first_pages=None):
     row = jnp.sum(done, axis=0, dtype=jnp.int32)
     first = jnp.sum(jnp.where(done, blocks_r, 0), axis=0, dtype=jnp.int32)
     last = jnp.sum(ends <= i + 1, axis=0, dtype=jnp.int32) > row
-    # items past the list: keep their indices inside the block table
-    row = jnp.minimum(row, n_rows - 1)
+    if order is None:
+        # items past the list: keep their indices inside the block table
+        row = jnp.minimum(row, n_rows - 1)
+    else:
+        # the listed row's index, as a reduction of ``done`` too: order[0]
+        # plus the steps the order takes over the rows done (past the list:
+        # the last row)
+        order_r = order.astype(jnp.int32)[:, None]
+        row = order_r[0, 0] + jnp.sum(jnp.where(done, jnp.concatenate(
+            [order_r[1:] - order_r[:-1], jnp.zeros((1, 1), jnp.int32)]), 0),
+            axis=0, dtype=jnp.int32)
     b = i[0] - first                        # the block's number in its row
     is_first = (b == 0).astype(jnp.int32)
     j = b * group
@@ -466,32 +583,29 @@ def _stream_blocks(i, n_live, work_ref, fetch):
 
 
 def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
-                             window_ref, token_row_ref, positions_ref, q_ref,
+                             window_ref, token_row_ref, ends_ref,
+                             token_lead_ref, lead_ends_ref, q_ref,
                              k_hbm, v_hbm, o_ref, m_ref, l_ref, acc_ref,
                              k_buf, v_buf, sems, *, page: int, group: int,
                              page_bits: int, scale: float, windowed: bool):
     keys = group * page
     i = pl.program_id(0)
-    r, j, first, last = _unpack_work_item(work_ref[i], page_bits)
+    r, j, _, _ = _unpack_work_item(work_ref[i], page_bits)
+    n_live = n_live_ref[0]
     # false only in the one step of a call that has no live block
-    live = i < n_live_ref[0]
+    live = i < n_live
     half = i % 2
     fetch, wait = _block_dma(
         block_tables_ref, page_bits, group,
         [(k_hbm, k_buf, sems.at[0]), (v_hbm, v_buf, sems.at[1])])
-    _stream_blocks(i, n_live_ref[0], work_ref, fetch)
+    _stream_blocks(i, n_live, work_ref, fetch)
 
     @pl.when(i == 0)
-    def _zero_out():
-        # pad slots (token_row -1) and tokens of rows with no page belong
-        # to no work item and are never merged; zero the whole output
-        # once so their lanes hold finite values (uninitialized VMEM
-        # garbage scattered into the pool could poison masked softmax
-        # lanes of OTHER rows via 0 * NaN)
-        o_ref[...] = jnp.zeros_like(o_ref)
-
-    @pl.when(live & first)
     def _init():
+        # ONE softmax state a call, not one a row: a follower's tokens take
+        # their leader's blocks beside their own. Until its first visible
+        # key a token absorbs masked steps (m = _NEG_INF, p = 1), which that
+        # key's rescale by exp(_NEG_INF - m) = 0 wipes
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
@@ -509,16 +623,20 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
             pages = buf[half].reshape((keys,) + buf.shape[3:])
             return pages.astype(jnp.float32).swapaxes(0, 1)
 
-        tr = token_row_ref[...]                     # (T*rep, 1) int32
-        pos = positions_ref[...]                    # (T*rep, 1) int32
+        end = ends_ref[...]                         # (T*rep, 1): position + 1
         key_pos = j * page + jax.lax.broadcasted_iota(
-            jnp.int32, (tr.shape[0], keys), 1)      # (T*rep, G*page)
-        mask = (tr == r) & (key_pos <= pos)
+            jnp.int32, (end.shape[0], keys), 1)     # (T*rep, G*page)
+        # the keys of row r's block a token sees: its own row's, up to
+        # itself; a follower of row r's, those of its shared run too. (Two
+        # conjunctions: a select of each token's end from narrow columns,
+        # or a column kept in scratch, made every step slower on the chip)
+        mask = ((token_row_ref[...] == r) & (key_pos < end)) | (
+            (token_lead_ref[...] == r) & (key_pos < lead_ends_ref[...]))
         if windowed:
             # a block wholly below a token's window leaves that token's
             # state at its init (m = _NEG_INF, p = 1): the first block with
             # a key it sees rescales that by exp(_NEG_INF - m) = 0
-            mask = mask & (key_pos > pos - window_ref[0])
+            mask = mask & (key_pos >= end - window_ref[0])
         s = jax.lax.dot_general(
             q, block(k_buf), (((2,), (2,)), ((0,), (0,))),
             preferred_element_type=jnp.float32) * scale   # (nkv, T*rep, keys)
@@ -536,24 +654,66 @@ def _ragged_attention_kernel(block_tables_ref, work_ref, n_live_ref,
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
 
-    @pl.when(live & last)
+    @pl.when(i == jnp.maximum(n_live, 1) - 1)
     def _finalize():
+        # every token once, at the call's last step. A token no key reached
+        # (a pad slot) still holds what masked steps left (m = _NEG_INF):
+        # its output is zero, so that no garbage is scattered into the pool
+        # to poison masked lanes of other rows via 0 * NaN
         l = l_ref[:, :, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[...] / safe_l
-        mine = (token_row_ref[...] == r)[None]      # (1, T*rep, 1)
-        o_ref[...] = jnp.where(mine, out.astype(o_ref.dtype), o_ref[...])
+        out = acc_ref[...] / jnp.where(l == 0.0, 1.0, l)
+        o_ref[...] = jnp.where(m_ref[:, :, :1] > _NEG_INF, out,
+                               0.0).astype(o_ref.dtype)
+
+
+class RaggedRowGroups(NamedTuple):
+    """What every layer's GQA kernel call of one step shares
+    (:func:`ragged_row_groups`): the row groups and, for a layer without a
+    window, the work list. All int32."""
+    first_positions: jax.Array  # (R,) each row's earliest position, or 0
+    run: jax.Array              # (R,) pages its leader's items fold for it
+    order: jax.Array            # (R,) the rows in work-list order
+    token_lead: jax.Array       # (T,) the token's row's leader, -1: none
+    lead_ends: jax.Array        # (T,) where the keys it takes from the
+    #                             leader's blocks end: run or itself + 1
+    work: jax.Array             # the work list without a window
+    n_live: jax.Array           # () its length
+
+
+def ragged_row_groups(block_tables, token_row, positions, kv_lens,
+                      page: int) -> RaggedRowGroups:
+    """The GQA kernel's row groups for one step (:func:`_shared_page_groups`)
+    and its work list where no window cuts it, from the block table's
+    equalities, ``kv_lens`` and the tokens' rows and positions alone. Every
+    layer's call of a step shares them, whatever its window and whatever
+    page offset its table carries: compute them once, OUTSIDE a scan over
+    layers, and hand them to each call (``groups=``), for XLA keeps such
+    integer work inside a loop's body (it does not hoist the broadcasts it
+    is made of). ``page``: the pools' page size."""
+    n_rows, max_pages = block_tables.shape
+    first_positions = _row_first_positions(token_row, positions, n_rows)
+    run, lead, order = _shared_page_groups(block_tables, kv_lens,
+                                           first_positions, page)
+    work, n_live = _ragged_work_list(kv_lens, page, max_pages, run, order)
+    mine = token_row[None, :] == jnp.arange(n_rows, dtype=jnp.int32)[:, None]
+    token_lead = jnp.sum(jnp.where(mine, jnp.where(run > 0, lead, -1)[:, None]
+                                   + 1, 0), axis=0) - 1
+    lead_ends = jnp.minimum(jnp.sum(jnp.where(mine, run[:, None] * page, 0),
+                                    axis=0), positions.astype(jnp.int32) + 1)
+    return RaggedRowGroups(first_positions, run, order, token_lead,
+                           lead_ends, work, n_live)
 
 
 def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                   token_row, positions, kv_lens,
                                   scale: Optional[float] = None,
-                                  interpret: bool = False, window=None):
+                                  interpret: bool = False, window=None,
+                                  groups: Optional[RaggedRowGroups] = None):
     """Pallas ragged kernel: same contract as
     :func:`ragged_paged_attention_array`.
 
     The grid is a work list of the call's live blocks
-    (:func:`_ragged_work_list`, built here from ``kv_lens``), its extent
+    (:func:`_ragged_work_list`, built from ``kv_lens``), its extent
     their number — a traced scalar, so a call costs what its rows attend
     to, not rows x table width. A block is up to G consecutive pages of
     one row, G = :func:`ragged_block_pages` (128 keys: one lane width, one
@@ -563,19 +723,40 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     and block table (one copy a page slot: 2 DMAs a page cost a third of
     what 2G auto-pipelined operands did; a slot past the row's last page is
     clipped into the table and masked by position), and folds them in ONE
-    online-softmax merge into every packed token that belongs to the row
-    — decode and prefill tokens alike, so a mixed batch is one dispatch
+    online-softmax merge into every packed token that may see them —
+    decode and prefill tokens alike, so a mixed batch is one dispatch
     whose shape is invariant to the request mix (PAPERS.md ragged paged
     attention). Inside, heads lead: queries, softmax state and output are
     (nkv, T x rep, ·), laid out here before and after the call, so a step
-    relays out nothing but the block's K and V. A call with no live block
-    takes one step, which copies nothing and zeroes the output.
+    relays out nothing but the block's K and V.
+
+    A block that several rows' tables name is copied and folded ONCE. Rows
+    whose tables start with the same page id (prefix-cache hits borrow a
+    document's pages) form a group (:func:`_shared_page_groups`: from the
+    table's equalities, ``kv_lens`` and the rows' earliest positions,
+    nothing else): its leader is the row whose earliest token comes first
+    (ties to the lowest row), so under any window its list starts no later
+    than a member's; each follower's shared run is its pages up to the
+    first where its table and the leader's differ, within both rows' full
+    pages, and the follower lists only what lies past it. A step of row
+    r's item folds its block into every query row under a mask that admits
+    row r's own tokens and the tokens of r's followers whose run holds the
+    key, at no extra MXU or VPU work: every step multiplies the block
+    against all T x rep query rows anyway. The softmax state is one a CALL,
+    set at the first step, and every token is normalised at the last, so a
+    follower's tokens take their leader's blocks beside their own; the
+    list puts a leader's items before its followers', so every token still
+    folds its keys in ascending order. With no two rows on one page the
+    groups are single rows, and the list, the copies and the outputs are
+    bit for bit those of a kernel that knows no groups. A call with no live
+    block takes one step, which copies nothing and zeroes the output.
 
     ``window`` (None, or an int32 scalar that may be traced): the mask also
     asks ``key_pos > position - window``, and each row's list starts at the
-    first page its earliest token of the call can still see, so a window
-    row costs the blocks its window reaches. None traces neither: the
-    program and its outputs are what they were without the argument.
+    first page its earliest token of the call can still see (or past its
+    shared run, whichever is later), so a window row costs the blocks its
+    window reaches. None traces neither. ``groups``: the step's
+    :func:`ragged_row_groups` (computed here where not given).
     """
     t, nh, d = q.shape
     page = k_pages.shape[1]
@@ -585,22 +766,28 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
     s = scale if scale is not None else 1.0 / math.sqrt(d)
     page_bits = _work_item_bits(max_pages)
     group = ragged_block_pages(page, max_pages)
+    if groups is None:
+        groups = ragged_row_groups(block_tables, token_row, positions,
+                                   kv_lens, page)
     windowed = window is not None
     window = jnp.asarray(window if windowed else 0, jnp.int32).reshape(1)
-    work, n_live = _ragged_work_list(
-        kv_lens, page, max_pages,
-        _row_first_pages(token_row, positions, n_rows, page, window[0])
-        if windowed else None)
+    work, n_live = groups.work, groups.n_live
+    if windowed:
+        # a row lists what lies past its shared run and inside its window
+        first = _window_first_pages(groups.first_positions, page, window[0])
+        work, n_live = _ragged_work_list(
+            kv_lens, page, max_pages, jnp.maximum(groups.run, first),
+            groups.order)
 
     def per_head_row(x):                # (T,) -> (T*rep, 1), as q's rows
         return jnp.repeat(x.astype(jnp.int32), rep).reshape(t * rep, 1)
 
+    per_token = pl.BlockSpec((t * rep, 1), lambda i, *_: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,  # block_tables, work list, n_live, window
         grid=(jnp.maximum(n_live, 1),),
         in_specs=[
-            pl.BlockSpec((t * rep, 1), lambda i, *_: (0, 0)),
-            pl.BlockSpec((t * rep, 1), lambda i, *_: (0, 0)),
+            per_token, per_token, per_token, per_token,
             pl.BlockSpec((nkv, t * rep, d), lambda i, *_: (0, 0, 0)),
             pl.BlockSpec(memory_space=pl.ANY),
             pl.BlockSpec(memory_space=pl.ANY),
@@ -625,7 +812,8 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
         out_shape=jax.ShapeDtypeStruct((nkv, t * rep, d), v_pages.dtype),
         interpret=interpret,
     )(block_tables.astype(jnp.int32), work, n_live.reshape(1), window,
-      per_head_row(token_row), per_head_row(positions),
+      per_head_row(token_row), per_head_row(positions + 1),
+      per_head_row(groups.token_lead), per_head_row(groups.lead_ends),
       q.reshape(t, nkv, rep, d).swapaxes(0, 1).reshape(nkv, t * rep, d),
       k_pages, v_pages)
     return out.reshape(nkv, t, rep, d).swapaxes(0, 1).reshape(t, nh, d)
@@ -633,7 +821,8 @@ def ragged_paged_attention_pallas(q, k_pages, v_pages, block_tables,
 
 def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
                            positions, kv_lens, scale: Optional[float] = None,
-                           mesh=None, mp_axis: str = "mp", window=None):
+                           mesh=None, mp_axis: str = "mp", window=None,
+                           groups: Optional[RaggedRowGroups] = None):
     """Dispatcher: Pallas ragged kernel on TPU (FLAGS_use_pallas_kernels),
     XLA gather/mask fallback elsewhere — selected automatically, same
     contract either way (see ragged_paged_attention_array).
@@ -647,6 +836,9 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
     (attention has no cross-head reduction, so there is no collective
     here at all). The XLA path ignores ``mesh``: GSPMD partitions the
     gather/einsum graph from the operand shardings alone.
+
+    ``groups``: the step's :func:`ragged_row_groups`, computed once
+    outside a scan over layers; only the Pallas kernel reads it.
 
     Pools WITHOUT a head axis, (P, page, d): multi-query attention, every
     query head on the one K and V a token keeps (a head axis of one would be
@@ -677,10 +869,10 @@ def ragged_paged_attention(q, k_pages, v_pages, block_tables, token_row,
                 and mesh.shape[mp_axis] > 1:
             return _ragged_paged_attention_shard_mapped(
                 q, k_pages, v_pages, block_tables, token_row, positions,
-                kv_lens, scale, mesh, mp_axis, window=window)
+                kv_lens, scale, mesh, mp_axis, window=window, groups=groups)
         return ragged_paged_attention_pallas(
             q, k_pages, v_pages, block_tables, token_row, positions,
-            kv_lens, scale, window=window)
+            kv_lens, scale, window=window, groups=groups)
     return ragged_paged_attention_array(
         q, k_pages, v_pages, block_tables, token_row, positions, kv_lens,
         scale, window=window)
@@ -690,7 +882,7 @@ def _ragged_paged_attention_shard_mapped(q, k_pages, v_pages, block_tables,
                                          token_row, positions, kv_lens,
                                          scale, mesh, mp_axis: str,
                                          interpret: bool = False,
-                                         window=None):
+                                         window=None, groups=None):
     """The Pallas ragged kernel over a head-sharded pool: shard_map over
     ``mp_axis`` with whole GQA groups per chip. q: (T, nh, d) sharded on
     heads; pools: (LP, page, nkv, d) sharded on kv heads; metadata
@@ -700,23 +892,27 @@ def _ragged_paged_attention_shard_mapped(q, k_pages, v_pages, block_tables,
     from jax.sharding import PartitionSpec as P
     from ..core.compat import shard_map
 
-    def local(q_l, kp_l, vp_l, bt, tr, pos, kvl, *win):
+    # a window (a scalar) and the step's row groups are replicated operands
+    extra = {name: x for name, x in (
+        ("window", None if window is None else jnp.asarray(window, jnp.int32)),
+        ("groups", groups)) if x is not None}
+
+    def local(q_l, kp_l, vp_l, bt, tr, pos, kvl, *values):
         return ragged_paged_attention_pallas(
             q_l, kp_l, vp_l, bt, tr, pos, kvl, scale, interpret=interpret,
-            window=win[0] if win else None)
+            **dict(zip(extra, values)))
 
-    # a window is one more replicated scalar operand
-    win = () if window is None else (jnp.asarray(window, jnp.int32),)
     return shard_map(
         local, mesh=mesh,
         in_specs=(P(None, mp_axis, None),
                   P(None, None, mp_axis, None),
                   P(None, None, mp_axis, None),
                   P(None, None), P(None), P(None), P(None))
-        + (P(),) * len(win),
+        + (P(),) * len(extra),
         out_specs=P(None, mp_axis, None),
         check_vma=False,
-    )(q, k_pages, v_pages, block_tables, token_row, positions, kv_lens, *win)
+    )(q, k_pages, v_pages, block_tables, token_row, positions, kv_lens,
+      *extra.values())
 
 
 # ---------------------------------------------------------------------------

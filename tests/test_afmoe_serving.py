@@ -164,8 +164,8 @@ def test_windowed_work_list_matches_python_loop(plan):
         blocks = -(-(pages - lo) // group)
         want += [(r, lo + b * group, int(b == 0), int(b == blocks - 1))
                  for b in range(blocks)]
-    first_pages = pa._row_first_pages(
-        jnp.asarray(token_row), jnp.asarray(positions), len(kv_lens),
+    first_pages = pa._window_first_pages(pa._row_first_positions(
+        jnp.asarray(token_row), jnp.asarray(positions), len(kv_lens)),
         page, jnp.int32(window))
     items, n_live = pa._ragged_work_list(
         jnp.asarray(kv_lens, jnp.int32), page, width, first_pages)

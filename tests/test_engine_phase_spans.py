@@ -594,8 +594,13 @@ def _afmoe_engine(chunk=3):
 def test_afmoe_record_counts_the_windows_pages_by_hand():
     """Two of three layers slide over 8 positions: the record's page counts
     are the per-layer MEAN, and ``window_skipped_pages`` the mean of what
-    the window left out, for a plan laid out by hand."""
+    the window left out, for a plan laid out by hand; ``shared_pages`` is 0
+    where no two rows' tables name one page, and the mean of what rows
+    that borrow another's leading pages fold under its items where they
+    do."""
     _, _, eng = _afmoe_engine()
+    # every row's private pages
+    eng._bt[:] = 1 + np.arange(eng._bt.size).reshape(eng._bt.shape)
     kv_lens = np.zeros((3, SLOTS), np.int32)
     token_row = np.full((3, 8), -1, np.int32)   # a wider packed axis
     positions = np.zeros_like(token_row)
@@ -613,7 +618,8 @@ def test_afmoe_record_counts_the_windows_pages_by_hand():
     rec = eng._dispatch_record(token_row, positions, kv_lens,
                                [1, 0, 0], [0, 5, 5])
     full, windowed = 6 + 2 + 4, 3 + 2 + 4
-    assert set(rec) == RECORD_KEYS | {"window_skipped_pages"}
+    assert set(rec) == RECORD_KEYS | {"window_skipped_pages", "shared_pages"}
+    assert rec["shared_pages"] == 0
     assert rec["attended_pages"] == round((full + 2 * windowed) / 3) == 10
     assert rec["window_skipped_pages"] == round(
         full - (full + 2 * windowed) / 3) == 2
@@ -623,10 +629,24 @@ def test_afmoe_record_counts_the_windows_pages_by_hand():
     pairs_full = 21 + (3 + 4 + 5 + 6 + 7) + (10 + 11 + 12 + 13 + 14)
     pairs_win = 8 + (3 + 4 + 5 + 6 + 7) + 5 * 8
     assert rec["causal_pairs"] == round((pairs_full + 2 * pairs_win) / 3)
-    # a Llama engine's record of the same plan has no such key
+    # row 1 borrows row 0's first 3 pages: in round 2 it is the only row
+    # with a token, so nothing is shared; with row 0 decoding beside it at
+    # position 20 (leader: row 1's earliest token, 9, comes first) row 0
+    # folds pages 0-2 under row 1's items in the global layer, and in the
+    # window layers none (row 0's window starts at page 3)
+    eng._bt[1, :3] = eng._bt[0, :3]
+    kv_lens[2, 0] = 21
+    token_row[2, 5], positions[2, 5] = 0, 20
+    rec = eng._dispatch_record(token_row, positions, kv_lens,
+                               [2, 0, 0], [0, 5, 5])
+    assert rec["shared_pages"] == round(3 / 3) == 1
+    # a Llama engine's record of the same plan has no such key: ten keys
     _, _, llama_eng, _ = _build(3, False)
+    llama_eng._bt[:] = eng._bt[:llama_eng._bt.shape[0],
+                               :llama_eng._bt.shape[1]]
     assert set(llama_eng._dispatch_record(
         token_row, positions, kv_lens, [1, 0, 0], [0, 5, 5])) == RECORD_KEYS
+    assert len(RECORD_KEYS) == 10
 
 
 def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):

@@ -178,9 +178,10 @@ def test_mesh_resize_helpers():
 def test_sharded_pallas_wrapper_interpret_parity(page, width, window):
     """The TPU path's shard_map wrapper around the Pallas ragged kernel
     (per-chip GQA slices, replicated metadata, the window one more
-    replicated scalar) matches the XLA reference elementwise — run in
-    Pallas interpret mode on the CPU mesh, rows of one block and of
-    several."""
+    replicated scalar, the step's row groups computed outside it as the
+    models do) matches the XLA reference elementwise — run in Pallas
+    interpret mode on the CPU mesh, rows of one block and of several, two
+    of them on the same leading pages (a prefix hit)."""
     from paddle_tpu.ops import paged_attention as pa
     rng = np.random.RandomState(0)
     n_rows, nkv, nh, d, T = 3, 4, 4, 8, 10
@@ -191,6 +192,8 @@ def test_sharded_pallas_wrapper_interpret_parity(page, width, window):
     bt = np.zeros((n_rows, width), np.int32)
     for r in range(n_rows):
         bt[r] = 1 + r * width + np.arange(width)
+    # row 2's table borrows row 1's first pages
+    bt[2, :min(width, 8)] = bt[1, :min(width, 8)]
     token_row = np.array([0, 0, 0, 1, 1, 2, -1, -1, -1, -1], np.int32)
     if page == 4:
         positions = np.array([0, 1, 2, 5, 6, 3, 0, 0, 0, 0], np.int32)
@@ -201,10 +204,14 @@ def test_sharded_pallas_wrapper_interpret_parity(page, width, window):
     ref = pa.ragged_paged_attention_array(
         q, kp, vp, jnp.asarray(bt), jnp.asarray(token_row),
         jnp.asarray(positions), jnp.asarray(kv_lens), window=window)
+    groups = pa.ragged_row_groups(
+        jnp.asarray(bt), jnp.asarray(token_row), jnp.asarray(positions),
+        jnp.asarray(kv_lens), page)
+    assert int(np.asarray(groups.run).sum()) > 0
     got = pa._ragged_paged_attention_shard_mapped(
         q, kp, vp, jnp.asarray(bt), jnp.asarray(token_row),
         jnp.asarray(positions), jnp.asarray(kv_lens), None,
-        serving_mesh(2), "mp", interpret=True, window=window)
+        serving_mesh(2), "mp", interpret=True, window=window, groups=groups)
     real = np.asarray(token_row) >= 0
     np.testing.assert_allclose(np.asarray(got)[real],
                                np.asarray(ref)[real], rtol=2e-5,

@@ -460,3 +460,216 @@ def test_config_is_a_dataclass_of_the_published_keys():
             "rope_layout", "sliding_window_layout", "sliding_window_size",
             "tie_word_embeddings", "max_position_embeddings"} <= names
     assert S.SmallThinkerConfig.serving_module == "paddle_tpu.models.smallthinker"
+
+
+# ---------------------------------------------------------------------------
+# rows that share a document: the GQA kernel folds a shared page once
+# ---------------------------------------------------------------------------
+# page 16, table width 20 (G = 8); ``documents``: (owner, pages, borrowers):
+# each borrower's first ``pages`` table slots name the owner's pages, as
+# prefix-cache hits borrow a document's; (kv_lens, spans, documents, window)
+_SHARED_CASES = {
+    # two documents; doc B's leader is a prefill row above both of its
+    # followers; row 6 holds doc A in its table but has no token (kv_lens 0);
+    # in the window layer row 4's window starts past doc A's 9 pages
+    "two_documents_decode_and_prefill": (
+        [150, 100, 160, 120, 300, 90, 0],
+        [(0, 149, 1), (1, 99, 1), (2, 150, 10), (3, 119, 1), (4, 299, 1),
+         (5, 84, 6)], [(0, 9, [2, 4, 6]), (1, 5, [3, 5])], 100),
+    # the leader (row 2, earliest token 160) is not the lowest row; under a
+    # window of 128 row 1's window starts at page 7, below row 0's (10): had
+    # row 0 led, row 1 would lose pages 7-9
+    "leader_not_the_lowest_row": (
+        [301, 251, 176, 0], [(0, 300, 1), (1, 250, 1), (2, 160, 16)],
+        [(2, 10, [0, 1, 3])], 128),
+    # two rows at one position on one table: the follower's every page is
+    # shared, and it lists nothing of its own
+    "a_follower_that_lists_nothing": (
+        [128, 128, 12], [(0, 127, 1), (1, 127, 1), (2, 0, 12)],
+        [(0, 8, [1])], 50),
+    # private tables only
+    "share_nothing": (
+        [150, 0, 96, 33, 260],
+        [(0, 149, 1), (2, 90, 6), (3, 32, 1), (4, 259, 1)], [], 64),
+}
+
+
+def _shared_case(name, windowed):
+    """The case's kernel arguments (14 query heads on 2 KV heads: rep 7, as
+    the cell) and its window (None for a global layer)."""
+    kv_lens, spans, documents, window = _SHARED_CASES[name]
+    shared = [(b, slot, owner) for owner, pages, borrowers in documents
+              for b in borrowers for slot in range(pages)]
+    q, *rest = _packed_case(kv_lens, spans, shared, page=16, width=20, t=24)
+    q = np.random.RandomState(11).randn(24, 14, q.shape[2]).astype(
+        np.float32)
+    return [q] + rest, window if windowed else None
+
+
+def _gqa_kernel(args, window):
+    kw = {} if window is None else dict(window=jnp.int32(window))
+    return np.asarray(pa.ragged_paged_attention_pallas(
+        *[jnp.asarray(a) for a in args], interpret=True, **kw))
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["global", "window"])
+@pytest.mark.parametrize("case", sorted(_SHARED_CASES))
+def test_rows_on_one_document_fold_its_pages_once_and_match_the_array(
+        case, windowed):
+    """The kernel (interpret mode) where rows' tables name the same leading
+    pages, against the XLA reference, in a global and a window layer: every
+    live token to float32 rounding, pad slots exactly 0; where rows share,
+    the call lists fewer blocks than its rows attend; where nothing is
+    shared, each row's outputs are bit for bit those of a call that holds
+    that row alone (no other row's items touch its softmax state)."""
+    args, window = _shared_case(case, windowed)
+    q, kp, vp, bt, token_row, positions, kv_lens = args
+    jargs = [jnp.asarray(a) for a in args]
+    ref = np.asarray(pa.ragged_paged_attention_array(*jargs, window=window))
+    out = _gqa_kernel(args, window)
+    real = token_row >= 0
+    np.testing.assert_allclose(out[real], ref[real], rtol=1e-5, atol=1e-6)
+    assert np.all(np.isfinite(out)) and np.all(out[~real] == 0.0)
+    n_rows, page = len(kv_lens), 16
+    first = (None if window is None else pa.ragged_first_pages(
+        token_row, positions, n_rows, page, window))
+    shared = pa.ragged_shared_pages(bt, token_row, positions, kv_lens, page,
+                                    first)
+    if _SHARED_CASES[case][2]:
+        assert shared.sum() > 0
+        return
+    assert shared.sum() == 0
+    for r in np.unique(token_row[real]):
+        alone = token_row == r
+        solo = [q, kp, vp, bt, np.where(alone, r, -1).astype(np.int32),
+                positions, np.where(np.arange(n_rows) == r, kv_lens, 0)]
+        assert _gqa_kernel(solo, window)[alone].tobytes() == \
+            out[alone].tobytes()
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["global", "window"])
+@pytest.mark.parametrize("case", sorted(_SHARED_CASES))
+def test_the_hosts_shared_pages_are_the_programs_groups(case, windowed):
+    """The in-program groups (each row's leader and shared run, the list's
+    row order) against the host's numpy twin that the engine's
+    ``shared_pages`` counts from: a leader's earliest token comes first in
+    its group and its window starts no later than a member's, its items
+    come before its followers', a follower lists what lies past its run and
+    inside its window, and the list's length is the host's count of
+    blocks. A layer's page offset on every entry changes nothing."""
+    args, window = _shared_case(case, windowed)
+    _, _, bt, token_row, positions, kv_lens = args[1:]
+    n_rows, page, width = len(kv_lens), 16, bt.shape[1]
+    tr, pos, kv = (jnp.asarray(a) for a in (token_row, positions, kv_lens))
+    first_pos = pa._row_first_positions(tr, pos, n_rows)
+    run, lead, order = [np.asarray(x) for x in pa._shared_page_groups(
+        jnp.asarray(bt) + 1000, kv, first_pos, page)]
+    start = np.zeros(n_rows, np.int64) if window is None else np.asarray(
+        pa._window_first_pages(first_pos, page, jnp.int32(window)))
+    host_first = None if window is None else pa.ragged_first_pages(
+        token_row, positions, n_rows, page, window)
+    host = pa.ragged_shared_pages(bt, token_row[None], positions[None],
+                                  kv_lens[None], page,
+                                  None if host_first is None
+                                  else host_first[None])
+    assert host.tolist() == [np.maximum(run - start, 0).tolist()]
+    first_pos = np.asarray(first_pos)
+    for r in np.nonzero(run)[0]:
+        leader = lead[r]
+        assert run[leader] == 0 and bt[r, 0] == bt[leader, 0]
+        assert (first_pos[leader], leader) < (first_pos[r], r)
+        assert start[leader] <= start[r]
+        assert 0 < run[r] <= min(kv_lens[r], kv_lens[leader]) // page
+        assert list(order).index(leader) < list(order).index(r)
+    assert sorted(order.tolist()) == list(range(n_rows))
+    listed = np.maximum(start, run)
+    work, n_live = pa._ragged_work_list(kv, page, width, jnp.asarray(listed),
+                                        jnp.asarray(order))
+    assert int(n_live) == int(pa.ragged_live_blocks(kv_lens, page, width,
+                                                    listed))
+    rows, js, _, _ = pa._unpack_work_item(np.asarray(work)[:int(n_live)],
+                                          pa._work_item_bits(width))
+    # row by row in the list's order, each row from its first listed page
+    seen = [order[k] for k in range(n_rows)
+            if -(-min(kv_lens[order[k]], width * page) // page)
+            > listed[order[k]]]
+    assert [r for i, r in enumerate(rows) if i == 0 or rows[i - 1] != r] \
+        == seen
+    for r in seen:
+        assert js[rows == r].min() == listed[r]
+    if not _SHARED_CASES[case][2]:
+        assert order.tolist() == list(range(n_rows))
+        assert not run.any()
+
+
+def test_the_shared_page_groups_of_two_documents_by_hand():
+    """Case ``two_documents_decode_and_prefill``, counted by hand: doc A
+    (9 pages) is led by row 0 (earliest token 149), doc B (5 pages) by the
+    prefill row 5 (84); row 6 holds doc A but has no token. Under a window
+    of 100 a row's list starts at the page of its earliest token less 99,
+    and row 4's (page 12) lies past doc A."""
+    args, _ = _shared_case("two_documents_decode_and_prefill", True)
+    _, _, bt, token_row, positions, kv_lens = args[1:]
+    host = lambda first: pa.ragged_shared_pages(
+        bt, token_row, positions, kv_lens, 16, first).tolist()
+    assert host(None) == [0, 5, 9, 5, 9, 0, 0]
+    first = pa.ragged_first_pages(token_row, positions, 7, 16, 100)
+    assert first.tolist() == [3, 0, 3, 1, 12, 0, 0]
+    assert host(first) == [0, 5, 6, 4, 0, 0, 0]
+
+
+def _serve_side_by_side(prefix_cache):
+    """A 40-token document written by one request, then four requests on it
+    that decode side by side (their block tables name the document's 10
+    pages where the prefix cache is on). Returns (the four requests' tokens,
+    the work records of their dispatches, the engine)."""
+    cfg = S.smallthinker_tiny()
+    params = _weights(cfg, 2)
+    eng = ContinuousBatchingEngine(
+        cfg, GenerationConfig(seed=0), num_slots=4, page_size=4,
+        max_seq_len=96, chunk=4, prefix_cache=prefix_cache)
+    records, record = [], eng._dispatch_record
+    eng._dispatch_record = lambda *a: records.append(record(*a)) or records[-1]
+    sched = ServingScheduler(eng)
+    rng = np.random.RandomState(4)
+    draw = lambda n: rng.randint(1, cfg.vocab_size, (n,)).astype(np.int32)
+    document = draw(40)
+    sched.submit(np.concatenate([document, draw(5)]), max_new_tokens=4)
+    while sched.pending:
+        sched.step(params)
+    written = len(records)
+    handles = [sched.submit(np.concatenate([document, draw(n)]),
+                            max_new_tokens=new)
+               for n, new in ((3, 6), (9, 14), (6, 14), (4, 10))]
+    while sched.pending:
+        sched.step(params)
+    eng.mgr.check_conservation()
+    return [h.stream.tokens for h in handles], records[written:], eng
+
+
+def test_rows_side_by_side_on_one_document_give_the_cold_runs_tokens(
+        monkeypatch):
+    """Four prefix hits whose block tables name the SAME document pages
+    decode side by side through the GQA kernel (interpret mode, inside the
+    engine's one step program, global and window layers): every request's
+    tokens are those of an engine without a prefix cache (private pages,
+    nothing shared, the XLA twin), and the work record counts the pages
+    folded under another row's item."""
+    cold, cold_records, cold_eng = _serve_side_by_side(False)
+    assert cold_eng.cache is None
+    assert all(r["shared_pages"] == 0 for r in cold_records)
+    traced = []
+
+    def interpreted(*args, mesh=None, mp_axis=None, **kw):
+        traced.append(1)
+        return pa.ragged_paged_attention_pallas(*args, **kw, interpret=True)
+
+    monkeypatch.setattr(pa, "ragged_paged_attention", interpreted)
+    warm, records, eng = _serve_side_by_side(True)
+    assert traced                     # the kernel is in the step program
+    assert [len(t) for t in warm] == [6, 14, 14, 10] and warm == cold
+    snap = eng.cache.snapshot()
+    assert snap["hits"] == 4 and snap["cached_tokens"] == 4 * 40
+    assert all(0 <= r["shared_pages"] <= r["attended_pages"]
+               for r in records)
+    assert records[0]["live_rows"] == 4 and records[0]["shared_pages"] > 0
