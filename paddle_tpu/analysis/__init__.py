@@ -36,6 +36,7 @@ exits 1 on any unbaselined finding or stale baseline entry. Tests use
 from __future__ import annotations
 
 import functools
+import gc
 from pathlib import Path
 from typing import Optional, Sequence
 
@@ -61,14 +62,26 @@ def run_repo(root: Optional[Path] = None,
              rules: Optional[Sequence] = None,
              baseline_path: Optional[Path] = BASELINE_PATH,
              roots: Optional[Sequence[str]] = None) -> Report:
-    """One full analysis run over the repo (or any compatible tree)."""
-    project = Project(root or REPO_ROOT,
-                      roots=roots or ("paddle_tpu", "tests", "benchmarks"))
-    baseline = (Baseline.load(baseline_path)
-                if baseline_path is not None else Baseline())
-    engine = AnalysisEngine(rules if rules is not None else default_rules(),
-                            baseline)
-    return engine.run(project)
+    """One full analysis run over the repo (or any compatible tree).
+
+    The cycle collector is paused for the run: it allocates about a
+    million AST nodes that stay live until it ends, and each collection
+    they trigger scans the whole process heap to free nothing (inside the
+    test suite, that heap holds millions of jax objects)."""
+    enabled = gc.isenabled()
+    gc.disable()
+    try:
+        project = Project(root or REPO_ROOT,
+                          roots=roots or ("paddle_tpu", "tests",
+                                          "benchmarks"))
+        baseline = (Baseline.load(baseline_path)
+                    if baseline_path is not None else Baseline())
+        engine = AnalysisEngine(
+            rules if rules is not None else default_rules(), baseline)
+        return engine.run(project)
+    finally:
+        if enabled:
+            gc.enable()
 
 
 @functools.lru_cache(maxsize=1)

@@ -59,10 +59,15 @@ def is_jit_expr(node: ast.AST) -> bool:
     return d is not None and d.split(".")[-1] in _JIT_NAMES
 
 
+_NESTED_SCOPES = (ast.FunctionDef, ast.AsyncFunctionDef, ast.Lambda,
+                  ast.ClassDef)
+
+
 class FunctionInfo:
     """One def/lambda with enough context to resolve its calls."""
 
-    __slots__ = ("module", "node", "qualname", "class_name", "scope")
+    __slots__ = ("module", "node", "qualname", "class_name", "scope",
+                 "_own")
 
     def __init__(self, module: SourceModule, node: ast.AST, qualname: str,
                  class_name: Optional[str],
@@ -74,26 +79,34 @@ class FunctionInfo:
         #: names visible where this function is DEFINED (enclosing defs
         #: + module top level) — used to resolve bare-name calls
         self.scope = scope
+        self._own: Optional[Tuple[ast.AST, ...]] = None
 
     @property
     def name(self) -> str:
         return getattr(self.node, "name", "<lambda>")
 
-    def own_nodes(self) -> Iterable[ast.AST]:
-        """Walk this function's body WITHOUT descending into nested
-        function/class definitions (those are separate graph nodes)."""
-        body = (self.node.body if isinstance(self.node.body, list)
-                else [self.node.body])
-        stack: List[ast.AST] = list(body)
-        while stack:
-            n = stack.pop()
-            yield n
-            for child in ast.iter_child_nodes(n):
-                if isinstance(child, (ast.FunctionDef,
-                                      ast.AsyncFunctionDef,
-                                      ast.Lambda, ast.ClassDef)):
-                    continue
-                stack.append(child)
+    def own_nodes(self) -> Tuple[ast.AST, ...]:
+        """This function's body WITHOUT nested function/class definitions
+        (those are separate graph nodes), walked once and kept: many
+        rules read the same function's own nodes, and each used to walk
+        them again."""
+        if self._own is None:
+            body = (self.node.body if isinstance(self.node.body, list)
+                    else [self.node.body])
+            out: List[ast.AST] = []
+            stack: List[ast.AST] = list(body)
+            while stack:
+                n = stack.pop()
+                out.append(n)
+                for name in n._fields:
+                    value = getattr(n, name, None)
+                    for child in (value if isinstance(value, list)
+                                  else (value,)):
+                        if isinstance(child, ast.AST) \
+                                and not isinstance(child, _NESTED_SCOPES):
+                            stack.append(child)
+            self._own = tuple(out)
+        return self._own
 
     def param_names(self) -> Set[str]:
         a = self.node.args

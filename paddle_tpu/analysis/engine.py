@@ -34,6 +34,29 @@ from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 _SUPPRESS_RE = re.compile(r"#\s*tpu-lint:\s*disable=([A-Za-z0-9_\-, ]+)")
 
+
+def flat_walk(tree: ast.AST) -> List[ast.AST]:
+    """Every node under ``tree`` in ``ast.walk``'s order (breadth first,
+    children in field order), reading ``_fields`` directly: ``ast.walk``
+    spends most of its time in the generators of ``iter_child_nodes`` and
+    ``iter_fields``, and this walk of the whole tree is the largest cost
+    after the parse."""
+    out = [tree]
+    append = out.append
+    i = 0
+    while i < len(out):
+        node = out[i]
+        i += 1
+        for name in node._fields:
+            value = getattr(node, name, None)
+            if isinstance(value, list):
+                for item in value:
+                    if isinstance(item, ast.AST):
+                        append(item)
+            elif isinstance(value, ast.AST):
+                append(value)
+    return out
+
 #: sub-directories of the repo root the analyzer looks at by default
 DEFAULT_ROOTS: Tuple[str, ...] = ("paddle_tpu", "tests", "benchmarks")
 
@@ -89,7 +112,7 @@ class SourceModule:
         this instead of re-walking the tree (the walk, not the parse,
         dominated rule time)."""
         if self._nodes is None:
-            self._nodes = list(ast.walk(self.tree))
+            self._nodes = flat_walk(self.tree)
         return self._nodes
 
     def nodes_of(self, *types):
@@ -166,6 +189,7 @@ class Project:
                 self.modules.append(mod)
         self._by_rel = {m.rel: m for m in self.modules}
         self._index = None
+        self._lock_classes = None
 
     def module(self, rel: str) -> Optional[SourceModule]:
         return self._by_rel.get(rel)
@@ -184,6 +208,16 @@ class Project:
             from .callgraph import ProjectIndex
             self._index = ProjectIndex(self)
         return self._index
+
+    @property
+    def lock_classes(self):
+        """Lazily built ``(rel, locks._ClassScan)`` list of the
+        lock-owning classes in the lock rules' scope: both lock rules read
+        it, and each class is scanned once."""
+        if self._lock_classes is None:
+            from .locks import scan_lock_classes
+            self._lock_classes = scan_lock_classes(self)
+        return self._lock_classes
 
 
 class Baseline:

@@ -23,7 +23,7 @@ import ast
 from typing import Dict, Iterable, List, Optional, Set, Tuple
 
 from .callgraph import dotted
-from .engine import Finding, Project
+from .engine import Finding, Project, flat_walk
 
 SCOPE_PREFIXES = ("paddle_tpu/serving/", "paddle_tpu/observability/")
 
@@ -44,7 +44,7 @@ def _lock_attrs(cls: ast.ClassDef) -> Set[str]:
     out: Set[str] = set()
     for item in cls.body:
         if isinstance(item, ast.FunctionDef) and item.name == "__init__":
-            for node in ast.walk(item):
+            for node in flat_walk(item):
                 if not isinstance(node, ast.Assign):
                     continue
                 if not (isinstance(node.value, ast.Call)):
@@ -63,7 +63,7 @@ def _lock_attrs(cls: ast.ClassDef) -> Set[str]:
 def _with_lock_blocks(fn: ast.FunctionDef, locks: Set[str]
                       ) -> List[Tuple[ast.With, str]]:
     out = []
-    for node in ast.walk(fn):
+    for node in flat_walk(fn):
         if isinstance(node, ast.With):
             for item in node.items:
                 ce = item.context_expr
@@ -114,7 +114,7 @@ class _ClassScan:
             blocks = _with_lock_blocks(m, self.locks)
             self._blocks[id(m)] = blocks
             for blk, _ in blocks:
-                for sub in ast.walk(blk):
+                for sub in flat_walk(blk):
                     attr = _self_attr(sub)
                     if attr is not None and attr not in self.locks:
                         self.guarded.add(attr)
@@ -123,13 +123,17 @@ class _ClassScan:
         return self._blocks.get(id(m), [])
 
 
-def _iter_lock_classes(project: Project) -> Iterable[Tuple[str, _ClassScan]]:
+def scan_lock_classes(project: Project) -> List[Tuple[str, _ClassScan]]:
+    """The lock-owning classes in scope, each scanned once
+    (``Project.lock_classes`` keeps the list for both lock rules)."""
+    scans = []
     for mod in project.iter_modules(SCOPE_PREFIXES):
         for node in mod.tree.body:
             if isinstance(node, ast.ClassDef):
                 scan = _ClassScan(mod.rel, node)
                 if scan.locks:
-                    yield mod.rel, scan
+                    scans.append((mod.rel, scan))
+    return scans
 
 
 class LockUnguardedWriteRule:
@@ -142,13 +146,13 @@ class LockUnguardedWriteRule:
 
     def run(self, project: Project) -> Iterable[Finding]:
         out: List[Finding] = []
-        for rel, scan in _iter_lock_classes(project):
+        for rel, scan in project.lock_classes:
             for m in scan.methods:
                 if m.name in ("__init__", "__new__", "__del__") \
                         or m.name.endswith("_locked"):
                     continue
                 blocks = scan.blocks(m)
-                for node in ast.walk(m):
+                for node in flat_walk(m):
                     attr = self._mutated_attr(node)
                     if attr is None or attr not in scan.guarded:
                         continue
@@ -202,7 +206,7 @@ class LockBlockingCallRule:
 
     def run(self, project: Project) -> Iterable[Finding]:
         out: List[Finding] = []
-        for rel, scan in _iter_lock_classes(project):
+        for rel, scan in project.lock_classes:
             for m in scan.methods:
                 if m.name.endswith("_locked"):
                     # caller holds the lock: the whole body is a region
@@ -212,7 +216,7 @@ class LockBlockingCallRule:
                     regions = [blk for blk, _ in scan.blocks(m)]
                 seen: Set[int] = set()      # nested with-lock blocks
                 for region in regions:      # must not double-report
-                    for node in ast.walk(region):
+                    for node in flat_walk(region):
                         if id(node) in seen:
                             continue
                         seen.add(id(node))

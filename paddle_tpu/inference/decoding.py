@@ -127,6 +127,23 @@ class _Request:
     grammar: Any = None                   # TokenDFA; None -> unconstrained
     gstart: int = -1                      # arena GLOBAL start state
     gstate_host: int = -1                 # host DFA mirror (LOCAL ids)
+    planned: int = 0                      # tokens the plans so far emit
+
+
+@dataclass
+class _Flight:
+    """A unified dispatch enqueued and not yet fenced: its device outputs
+    and what the host needs to deliver them."""
+    n: int                  # the work record's ``n``
+    rids: tuple             # per slot the request the plan gave a row, or None
+    toks: Any               # (K, R) emitted tokens, on the device
+    aux: tuple              # the routing record, where the model returns one
+    emit: np.ndarray        # (K, R) which of ``toks`` are emissions
+    emit_counts: list
+    fed: list
+    t0_ns: int              # armed spans: when the dispatch was uploaded
+    tc0: int                # armed chain taps: the same, on their clock
+    compile_t0: Optional[float]   # the dispatch that compiled the program
 
 
 class ContinuousBatchingEngine:
@@ -172,7 +189,19 @@ class ContinuousBatchingEngine:
     outputs chain across steps without overlap and a finished prefill's
     first sample arrives with the row's first decode round — and
     positions are mirrored host-side analytically instead of being read
-    back.
+    back. So a dispatch's plan needs nothing of the dispatch before it
+    but the rows that stop: ``step()`` enqueues the next dispatch BEFORE
+    it fences the running one, and the host's work of a round (unpack,
+    audit, the caller's scheduling, admission, plan, upload) runs while
+    the device computes. It does so while every slot is taken or a
+    request waits; a free slot with none waiting is one an arrival may take
+    at the next plan, and a dispatch enqueued ahead would hold that arrival
+    back one dispatch, so there the round fences first, as a synchronous
+    loop does. At most one dispatch is in flight between two calls; a row
+    whose budget ends inside a dispatch is not planned into the next one,
+    and a row that stops without warning (EOS, ``cancel``) keeps its slot
+    and pages until the dispatch still writing them is fenced, its tokens
+    there dropped.
 
     Service API:
       ``submit(prompt) -> rid``; ``step(params)`` runs one admit+ragged
@@ -314,6 +343,14 @@ class ContinuousBatchingEngine:
         self._tok_dev = jnp.zeros((num_slots,), jnp.int32)
         self._pos = np.zeros((num_slots,), np.int32)
         self._bt = np.zeros((num_slots, self._table_width), np.int32)
+        # unified dispatches enqueued and not yet fenced, oldest first: at
+        # most one between two calls of step(), two inside one
+        self._flights: collections.deque = collections.deque()
+        # slots of rows that stopped while a dispatch in flight still
+        # writes their pages: slot -> (the newest such dispatch's n, the
+        # request, its output or None), released at that dispatch's fence
+        self._held: Dict[int, tuple] = {}
+        self._landing = False       # inside ``_land``'s delivery
         # per-row sampling epilogue state (inference/sampling.py): the
         # (seeds, temps, top_k, top_p) HOST mirrors admission writes,
         # like ``_pos`` and ``_bt``; the device copies the step program
@@ -557,10 +594,13 @@ class ContinuousBatchingEngine:
 
     def cancel(self, rid: int) -> bool:
         """Abort a request mid-flight. Queued: dropped before admission.
-        Live: the slot is retired immediately — pages return to the pool,
-        the block-table row points back at the garbage page, and nothing
-        lands in the finished map (the caller initiated the abort, so no
-        finish_callback fires either). Returns False for unknown/done rids.
+        Live: the slot is retired — pages return to the pool, the
+        block-table row points back at the garbage page, and nothing lands
+        in the finished map (the caller initiated the abort, so no
+        finish_callback fires either). Where the dispatch in flight still
+        writes the row, the pages and slot return at its fence: the next
+        step's, or at once when no live row is left to step for. Returns
+        False for unknown/done rids.
         """
         for i, req in enumerate(self._queue):
             if req.rid == rid:
@@ -568,6 +608,8 @@ class ContinuousBatchingEngine:
                 return True
         if rid in self._live:
             self._retire(self._slot_rid.index(rid), cancelled=True)
+            if not self._live and not self._landing:
+                self._drain()
             return True
         return False
 
@@ -734,42 +776,66 @@ class ContinuousBatchingEngine:
         """Free a finished (or cancelled) slot: pages back to the pool,
         output to the finished map, slot table pointed at the reserved
         garbage page. Cancelled slots free resources but produce no
-        finished entry and no finish_callback."""
+        finished entry and no finish_callback. A row that a dispatch in
+        flight still writes (it stopped without warning after being
+        planned into it) is finished now and keeps its pages and slot,
+        unplanned, until that dispatch's fence (``_held``)."""
         self._flush_win(s)
         rid = self._slot_rid[s]
         req = self._live.pop(rid)
         req.done = True
+        out = None
         if not cancelled:
             out = req.tokens[:self._budget(req)]
             self._finished[rid] = out
             self._finished_crc[rid] = token_checksum(out)
-            if self.cache is not None:
-                # index the finished prefix BEFORE release: pages backing
-                # its full token blocks stay resident (refcount 0, cached)
-                # instead of draining to the free list. Positions past the
-                # kept output may hold over-decoded garbage, but those
-                # never complete a block (full blocks end <= kept length).
-                # The sequence goes to the index as ONE int32 array: the
-                # tree reads it once a walk, so the span holds a copy of
-                # the tokens and the walk, and no per-token conversion.
-                with phase("paddle_serving.prefix_insert") as span:
-                    toks = np.concatenate(
-                        [req.prompt, np.asarray(out, np.int32)])
-                    if self._speculative and out:
-                        # the last delivered token may be the verify bonus
-                        # — committed but never fed back, so its K/V slot
-                        # was never written. Index one token short so a
-                        # future cache hit can never attend a hole.
-                        toks = toks[:-1]
-                    adopted = self.cache.insert(toks, self.mgr._tables[rid])
-                    span.set_metadata(tokens=len(toks), pages=adopted)
-            if self.finish_callback is not None:
-                self.finish_callback(rid, out)
+        writer = max((f.n for f in self._flights if f.rids[s] == rid),
+                     default=None)
+        if writer is None:
+            self._insert_prefix(req, out)
+        else:
+            self._held[s] = (writer, req, out)
+        if out is not None and self.finish_callback is not None:
+            self.finish_callback(rid, out)
+        if writer is None:
+            self._free_slot(s, rid)
+
+    def _insert_prefix(self, req: "_Request", out) -> None:
+        """Index a finished sequence's prefix BEFORE its pages are freed:
+        pages backing its full token blocks stay resident (refcount 0,
+        cached) instead of draining to the free list. Positions past the
+        kept output may hold over-decoded garbage, but those never
+        complete a block (full blocks end <= kept length). The sequence
+        goes to the index as ONE int32 array: the tree reads it once a
+        walk, so the span holds a copy of the tokens and the walk, and no
+        per-token conversion. Nothing for a cancelled row (``out`` None)."""
+        if out is None or self.cache is None:
+            return
+        with phase("paddle_serving.prefix_insert") as span:
+            toks = np.concatenate([req.prompt, np.asarray(out, np.int32)])
+            if self._speculative and out:
+                # the last delivered token may be the verify bonus —
+                # committed but never fed back, so its K/V slot was never
+                # written. Index one token short so a future cache hit can
+                # never attend a hole.
+                toks = toks[:-1]
+            adopted = self.cache.insert(toks, self.mgr._tables[req.rid])
+            span.set_metadata(tokens=len(toks), pages=adopted)
+
+    def _free_slot(self, s: int, rid: int) -> None:
         self.mgr.free(rid)
         self._slot_rid[s] = None
         self._bt[s] = 0
         self._pos[s] = 0
         self._pend[s] = None
+
+    def _release_held(self, upto: int) -> None:
+        """Release the stopped rows that no dispatch after ``upto`` (now
+        fenced) writes: prefix insert, pages, slot."""
+        for s in [s for s, held in self._held.items() if held[0] <= upto]:
+            _, req, out = self._held.pop(s)
+            self._insert_prefix(req, out)
+            self._free_slot(s, req.rid)
 
     def _deliver_tokens(self, s, tokens) -> bool:
         """Unpack one slot's emitted tokens: append to the request, fire
@@ -804,11 +870,11 @@ class ContinuousBatchingEngine:
             req.tokens.append(t)
             if self.token_callback is not None:
                 self.token_callback(rid, t)
-                if self._slot_rid[s] != rid:
+                if req.done:
                     return False   # callback cancelled this request
             if self._complete(req):
                 break
-        if self._slot_rid[s] != rid:
+        if req.done:
             return False           # already retired by a reentrant cancel
         if self._complete(req):
             self._retire(s)
@@ -817,13 +883,18 @@ class ContinuousBatchingEngine:
 
     def step(self, params) -> int:
         """One admit + decode round (ONE device->host transfer: the
-        step's emitted tokens). Returns the live count after the round.
+        emitted tokens of the dispatch it fences). Returns the live count
+        after the round: 0 only with no dispatch in flight.
 
         Admission is host bookkeeping only and the round is ONE ragged
-        dispatch — newly admitted prompts join the current step's packed
-        batch immediately, alongside every decoding row. Speculative
-        mode folds draft verification into the same single dispatch
-        (``_step_spec``)."""
+        dispatch — newly admitted prompts join the next dispatch's packed
+        batch, alongside every decoding row. While every slot is taken or
+        a request waits, a round enqueues that dispatch, then fences and
+        delivers the one before it (a round with none in flight enqueues
+        two and delivers the first); otherwise it fences and delivers the
+        one in flight, or enqueues its own and delivers that.
+        Speculative mode folds draft verification into the same single
+        dispatch and fences it in the same round (``_step_spec``)."""
         with phase("cbe.step"):
             if self._mesh is not None:
                 params = self._place_params(params)
@@ -1100,6 +1171,14 @@ class ContinuousBatchingEngine:
             abstract(table.shape, table.dtype), pools,
             abstract((R, self._table_width), jnp.int32))
 
+    def _plan_rows(self) -> list:
+        """The slots the next plan gives a row: live, with budget left past
+        what the plans before emit (a stopped row is live no more)."""
+        live = self._live
+        return [s for s, rid in enumerate(self._slot_rid)
+                if rid in live
+                and live[rid].planned < self._budget(live[rid])]
+
     def _plan_step(self):
         """Host-side layout of one unified step: simulate ``chunk``
         micro-rounds over the live slots, packing each round's tokens
@@ -1121,11 +1200,10 @@ class ContinuousBatchingEngine:
         emit_counts = [0] * n_rows            # per-slot decode rounds
         fed = [0] * n_rows                    # prefill tokens consumed
         pos = self._pos.astype(np.int64).copy()
-        rem = {s: len(self._pend[s]) for s in range(n_rows)
-               if self._slot_rid[s] is not None and self._pend[s] is not None}
+        live = self._plan_rows()
+        rem = {s: len(self._pend[s]) for s in live
+               if self._pend[s] is not None}
         for k in range(K):
-            live = [s for s in range(n_rows)
-                    if self._slot_rid[s] is not None]
             budget = tb - sum(1 for s in live if rem.get(s, 0) == 0)
             take = {}
             for s in live:
@@ -1179,8 +1257,7 @@ class ContinuousBatchingEngine:
         in tests/test_fusion.py)."""
         from ..jit.fusion import pack_plan
         K, tb, n_rows = self.chunk, self._step_tokens, self.num_slots
-        live = [s for s in range(n_rows)
-                if self._slot_rid[s] is not None]
+        live = self._plan_rows()
         if live and all(self._pend[s] is None for s in live):
             nl = len(live)
             lv = np.asarray(live, np.int64)
@@ -1214,7 +1291,7 @@ class ContinuousBatchingEngine:
         return plan_tt, plan_tr, emit, emit_counts, fed
 
     def _dispatch_record(self, token_row, positions, kv_lens, emit_counts,
-                         fed) -> Dict[str, int]:
+                         fed, bt=None) -> Dict[str, int]:
         """What one unified dispatch asks of the device, as integers from
         the plan arrays — they ride on the ``cbe.dispatch`` span into any
         profiler trace, where ``perfbench/program_trace.py`` reads the
@@ -1254,7 +1331,8 @@ class ContinuousBatchingEngine:
         ``tests/perfbench/test_perfbench_program_trace.py`` holds the
         benchmark's reading of that record to them. A model with a state a
         row (``state_layout``) adds ``state_row_rounds``, ``state_resets``
-        and ``state_bytes_per_row`` (at the end).
+        and ``state_bytes_per_row`` (at the end). ``bt``: the block tables
+        the dispatch reads (default: the engine's mirror).
         Computed on every dispatch (a few vectorised numpy lines)."""
         from ..ops.paged_attention import (ragged_block_pages,
                                            ragged_first_pages,
@@ -1264,6 +1342,7 @@ class ContinuousBatchingEngine:
                                            ragged_shared_pages)
         ps = self.page_size
         width = self._table_width
+        bt = self._bt if bt is None else bt
         group = ragged_block_pages(ps, width)
         full_pages = ragged_live_pages(kv_lens, ps, width)
         seen = (positions + 1)[token_row >= 0]
@@ -1287,7 +1366,7 @@ class ContinuousBatchingEngine:
                                   else np.minimum(seen, window)).sum())
             if gqa_shared:
                 shared += share * int(ragged_shared_pages(
-                    self._bt, token_row, positions, kv_lens, ps, first).sum())
+                    bt, token_row, positions, kv_lens, ps, first).sum())
         layers = len(self._layer_windows)
         n = self._dispatches
         self._dispatches = n + 1
@@ -1297,7 +1376,7 @@ class ContinuousBatchingEngine:
             "token_slots": self.chunk * self._step_tokens,
             "prefill_tokens": sum(fed),
             "decode_tokens": sum(emit_counts),
-            "live_rows": self.num_slots - self._slot_rid.count(None),
+            "live_rows": len(self._plan_rows()),
             "attended_pages": round(attended / layers),
             "grid_steps": round(steps / layers),
             "causal_pairs": round(pairs / layers),
@@ -1311,7 +1390,7 @@ class ContinuousBatchingEngine:
         if self.mgr.layout.head_axis is None:
             # every layer's call sees the same table and spans
             record["shared_pages"] = group * int(
-                ragged_shared_blocks(self._bt, kv_lens, ps).sum())
+                ragged_shared_blocks(bt, kv_lens, ps).sum())
         state = self.mgr.state
         if state is not None:
             # a model with a state a row: the rows whose state each
@@ -1344,20 +1423,82 @@ class ContinuousBatchingEngine:
         return stats
 
     def _step_unified(self, params) -> int:
-        """One ragged round: host-only admission, ONE dispatch serving
-        the mixed prefill+decode batch, unpack. The single device→host
-        transfer is the step's emitted tokens.
+        """One ragged round: host-only admission, then ONE dispatch
+        enqueued for the mixed prefill+decode batch BEFORE the dispatch
+        in flight is fenced and unpacked, so the host's work of the round
+        runs while the device computes. With none in flight the round
+        enqueues its own dispatch and, where a row goes on past it and
+        every slot is taken or a request waits, the next; with a slot free
+        and none waiting it enqueues nothing ahead. It always delivers the
+        oldest. The single device→host transfer is the fenced dispatch's
+        emitted tokens.
 
         Every stretch of host work sits in a ``phase`` (``cbe.admit`` ..
         ``cbe.audit``), so a profiler trace says what the host did in each
-        gap the device idles through."""
+        gap the device idles through; ``cbe.fence`` and ``cbe.unpack``
+        belong to the dispatch before the one in ``cbe.dispatch``."""
         with phase("cbe.admit"):
             admitted = self._admit()
-        if not self._live:
+        if not self._live and not self._flights:
             if self._check_invariants:
                 with phase("cbe.audit"):
                     self.mgr.check_conservation()
             return 0
+        cached = self._admitted_cached
+        # one dispatch ahead of the fence while every slot is taken or a
+        # request waits; with a slot free and none waiting, none ahead: an
+        # arrival before the next round joins the next dispatch. A round
+        # whose fence failed before left two, and fences again first
+        depth = 2 if self._queue or None not in self._slot_rid else 1
+        while len(self._flights) < depth and self._plan_rows():
+            self._launch(params, admitted, cached)
+            admitted = cached = 0
+        if self._flights:
+            self._landing = True
+            try:
+                self._land()
+            finally:
+                self._landing = False
+            if not self._live:
+                self._drain()
+        if self.cache is not None:
+            with phase("cbe.audit"):
+                if self._check_invariants:
+                    # the ownership-model anchor: every page is free, live
+                    # (refcounted) or cached — checked after EVERY ragged
+                    # step, COW suffix rows included
+                    self.mgr.check_conservation()
+                self.cache.update_gauges()
+        return len(self._live)
+
+    def _launch(self, params, admitted: int, cached: int) -> None:
+        """Plan, upload and enqueue one unified dispatch from the host
+        mirrors as the plans before it left them; it joins ``_flights``
+        unfenced, and only then do its rows count its tokens as planned.
+        Where planning, upload or enqueue raises, the mirrors the plan
+        advanced (positions, pending prompts, a grammar restart) go back
+        as they were, so a retry plans the same rows again."""
+        undo = (self._pos.copy(), list(self._pend), self._greset.copy(),
+                self._greset_dirty)
+        try:
+            flight = self._enqueue(params, admitted, cached)
+        except BaseException:
+            self._pos, self._greset, self._greset_dirty = (
+                undo[0], undo[2], undo[3])
+            self._pend[:] = undo[1]
+            raise
+        self._flights.append(flight)
+        for s, rid in enumerate(flight.rids):
+            if rid is not None:
+                self._live[rid].planned += flight.emit_counts[s]
+        # tokens that actually run through prefill THIS step (cancelled
+        # mid-prefill requests never inflate the skip-ratio math)
+        self._prefill_tokens += sum(flight.fed)
+
+    def _enqueue(self, params, admitted: int, cached: int) -> "_Flight":
+        """``_launch``'s plan, upload and enqueue. Its inputs are the
+        dispatch before's device outputs (the token and grammar carries,
+        the donated cache arrays)."""
         fresh = (self._unified_step is None
                  or self._unified_flags != _prefill_flags())
         if fresh:
@@ -1379,41 +1520,47 @@ class ContinuousBatchingEngine:
         # (jit/fusion.py); disarmed cost is one list index per step
         armed_chain = _chain_armed[0]
         tc0 = time.perf_counter_ns() if armed_chain else 0
+        rows = self._plan_rows()
+        rids = [None] * self.num_slots
+        for s in rows:
+            rids[s] = self._slot_rid[s]
         with phase("cbe.plan"):
+            # the dispatch reads a COPY of the block tables, in which a row
+            # it does not plan (free, spent or stopped) is the garbage
+            # page's: a retirement writes the mirror while the upload may
+            # still read it
+            bt = np.zeros_like(self._bt)
+            bt[rows] = self._bt[rows]
             if self._fused_tail:
                 plan_tt, plan_tr, emit, emit_counts, fed = \
                     self._plan_step_packed()
                 plan = (plan_tt, plan_tr)
                 record = self._dispatch_record(
-                    plan_tt[2], plan_tt[3], plan_tr[0], emit_counts, fed)
+                    plan_tt[2], plan_tt[3], plan_tr[0], emit_counts, fed, bt)
             else:
                 plan, emit, emit_counts, fed = self._plan_step()
                 record = self._dispatch_record(
-                    plan[2], plan[3], plan[4], emit_counts, fed)
+                    plan[2], plan[3], plan[4], emit_counts, fed, bt)
         if armed_chain:
             tc1 = time.perf_counter_ns()
             _note_chain(op_name="cbe.plan_step", dur_ns=tc1 - tc0)
             tc0 = tc1
-        # tokens that actually run through prefill THIS step (cancelled
-        # mid-prefill requests never inflate the skip-ratio math)
-        self._prefill_tokens += sum(fed)
-        if fresh:
-            c0 = time.perf_counter()   # dispatch-only window
+        c0 = time.perf_counter() if fresh else None  # dispatch-only window
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
         # how often admission happens and how often it costs a transfer:
         # the requests this step admitted, the prompt tokens of theirs the
         # prefix cache already held (so a trace shows whether a dispatch's
-        # new rows were hits), and 1 where one of them changed a row's
-        # sampler parameters or restarts a grammar state (the mirrors then
-        # ride this upload)
+        # new rows were hits), 1 where one of them changed a row's sampler
+        # parameters or restarts a grammar state (the mirrors then ride
+        # this upload), and 1 where the dispatch before is still unfenced
         row_state = int(self._samp_dirty or self._greset_dirty)
         with phase("cbe.upload", admitted=admitted,
-                   row_state_uploads=row_state,
-                   cached_tokens=self._admitted_cached):
+                   row_state_uploads=row_state, cached_tokens=cached,
+                   ahead=int(bool(self._flights))):
             plan_dev = [jnp.asarray(a) for a in plan]
             greset = self._upload_row_state()
             gtable = self._arena.device_table()
-            bt = jnp.asarray(self._bt)
+            bt = jnp.asarray(bt)
         with phase("cbe.dispatch", **record):       # enqueue only
             # the cache's arrays, pages then the rows' state: one donated
             # pytree in, the same out
@@ -1422,31 +1569,47 @@ class ContinuousBatchingEngine:
                 params, *plan_dev,
                 self._tok_dev, self._gstate_dev, greset, self._samp_dev,
                 gtable, self.mgr.arrays, bt)
+        return _Flight(record["n"], tuple(rids), toks, tuple(aux), emit,
+                       emit_counts, fed, t0_ns, tc0, c0)
+
+    def _land(self) -> None:
+        """Fence the oldest dispatch in flight and deliver its tokens to
+        the rows it planned that are still live; a row that stopped after
+        it was planned (EOS, ``cancel``) has its tokens dropped
+        (``dropped_tokens``). Then the stopped rows no later dispatch
+        writes give their pages and slots back. A failed fence leaves the
+        dispatch in flight, for the retry to fence again."""
+        f = self._flights[0]
         with phase("cbe.fence"):
-            if fresh:
-                jax.block_until_ready(toks)
-                recompiles.observe_compile("cbe.unified_step",
-                                           time.perf_counter() - c0)
-            toks = np.asarray(toks)                    # the one fence
+            if f.compile_t0 is not None:
+                jax.block_until_ready(f.toks)
+                recompiles.observe_compile(
+                    "cbe.unified_step", time.perf_counter() - f.compile_t0)
+            toks = np.asarray(f.toks)                  # the one fence
             # a model with experts: its routing record comes with the
             # tokens, and its sums ride on the unpack span
             routed = self._expert_stats(
-                np.asarray(aux[0]), self._routing_columns) if aux else {}
+                np.asarray(f.aux[0]), self._routing_columns) \
+                if f.aux else {}
+        self._flights.popleft()
+        armed_chain = _chain_armed[0]
         if armed_chain:
-            tc1 = time.perf_counter_ns()
+            tc0 = time.perf_counter_ns()
             if self._fused_tail:
                 _note_chain(op_name="cbe.fused_unified_step",
-                            dur_ns=tc1 - tc0)
+                            dur_ns=tc0 - f.tc0)
             else:
-                _note_chain(op_name="cbe.unified_step", dur_ns=tc1 - tc0)
+                _note_chain(op_name="cbe.unified_step", dur_ns=tc0 - f.tc0)
             if self._epilogue_active():
                 # the sampling epilogue runs inside the dispatch above;
                 # this zero-duration tap makes it visible to the fusion
                 # pass's chain mining (REGIONS["sampling_epilogue"])
                 _note_chain(op_name="cbe.sample_epilogue", dur_ns=0)
-            tc0 = tc1
-        with phase("cbe.unpack", **routed):
-            if t0_ns:
+        emit, emit_counts, fed = f.emit, f.emit_counts, f.fed
+        with phase("cbe.unpack", **routed) as span:
+            rows = [s for s, rid in enumerate(f.rids)
+                    if rid is not None and rid in self._live]
+            if f.t0_ns:
                 # per-request phase bookkeeping over the dispatch window:
                 # the trace keeps its prefill/decode lanes even though
                 # both ride one program. Runs EVERY armed step, so it only
@@ -1456,12 +1619,10 @@ class ContinuousBatchingEngine:
                 t1_ns = time.perf_counter_ns()
                 batch: list = []
                 win = self._win
-                for s in range(self.num_slots):
-                    if self._slot_rid[s] is None:
-                        continue
+                for s in rows:
                     c = emit_counts[s]
-                    f = fed[s]
-                    if (c == 0) != (f == 0):
+                    f_s = fed[s]
+                    if (c == 0) != (f_s == 0):
                         # steady-state single-phase round: extend the
                         # window inline (no function call — this branch
                         # is the armed hot path every decode step takes)
@@ -1469,18 +1630,23 @@ class ContinuousBatchingEngine:
                         kind = "decode" if c else "prefill"
                         if w is not None and w[0] == kind:
                             w[2] = t1_ns
-                            w[3] += c or f
+                            w[3] += c or f_s
                             continue
-                    if f > 0:
-                        self._note_win(s, "prefill", t0_ns, t1_ns, f, batch)
+                    if f_s > 0:
+                        self._note_win(s, "prefill", f.t0_ns, t1_ns, f_s,
+                                       batch)
                     if c:
-                        self._note_win(s, "decode", t0_ns, t1_ns, c, batch)
+                        self._note_win(s, "decode", f.t0_ns, t1_ns, c,
+                                       batch)
                 if batch:
                     emit_spans(batch)
-            for s in range(self.num_slots):
-                if self._slot_rid[s] is None:
+            dropped = 0
+            for s, rid in enumerate(f.rids):
+                if rid is None:
                     continue
-                if self._fused_tail and emit_counts[s] == self.chunk:
+                if rid not in self._live:
+                    dropped += emit_counts[s]
+                elif self._fused_tail and emit_counts[s] == self.chunk:
                     # fused-tail fast unpack: the slot emitted every
                     # round, so its column IS the emission (no K-wide
                     # mask filter)
@@ -1489,18 +1655,25 @@ class ContinuousBatchingEngine:
                     self._deliver_tokens(
                         s, (toks[k, s] for k in range(self.chunk)
                             if emit[k, s]))
+            self._release_held(f.n)
+            span.set_metadata(dropped_tokens=dropped)
         if armed_chain:
             _note_chain(op_name="cbe.decode_tail",
                         dur_ns=time.perf_counter_ns() - tc0)
-        if self.cache is not None:
-            with phase("cbe.audit"):
-                if self._check_invariants:
-                    # the ownership-model anchor: every page is free, live
-                    # (refcounted) or cached — checked after EVERY ragged
-                    # step, COW suffix rows included
-                    self.mgr.check_conservation()
-                self.cache.update_gauges()
-        return len(self._live)
+
+    def _drain(self) -> None:
+        """Fence what is still in flight once no row is live: its tokens
+        all belong to stopped rows, and its fence is what gives their
+        pages back. Where a fence fails, the device writes nothing more
+        of that dispatch: the rows it held are released all the same, and
+        the failure is raised."""
+        try:
+            while self._flights:
+                self._land()
+        finally:
+            if self._flights:
+                self._flights.clear()
+                self._release_held(self._dispatches)
 
     # -- speculative decoding (draft + verify in ONE ragged dispatch) --------
 

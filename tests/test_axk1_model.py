@@ -295,7 +295,8 @@ def test_the_latent_paths_spans_carry_the_work_record_and_routing_stats(
     unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
     assert len(unpacks) == len(records)
     assert all(set(u) == {"expert_calls", "experts_hit",
-                          "expert_assignments", "max_expert_load"}
+                          "expert_assignments", "max_expert_load",
+                          "dropped_tokens"}
                for u in unpacks)
     layers = served["cfg"].num_expert_layers
     assert all(u["expert_calls"] == 4 * layers for u in unpacks)

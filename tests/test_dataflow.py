@@ -972,7 +972,9 @@ def test_backend_flag_flip_retraces_unified_step():
     recompile instead of silently serving the old backend."""
     from paddle_tpu.flags import get_flags, set_flags
     eng, params = _tiny_engine()
-    eng.submit(np.arange(1, 6, dtype=np.int32))
+    # a budget that outlasts the two dispatches the first step enqueues:
+    # the flip reaches the next dispatch the engine enqueues
+    eng.submit(np.arange(1, 6, dtype=np.int32), max_new_tokens=12)
     eng.step(params)
     first = eng._unified_step
     flags0 = eng._unified_flags

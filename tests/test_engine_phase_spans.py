@@ -9,6 +9,12 @@ Since ISSUE 35 the host work that had no name has one: the scheduler's
 / ``prefix_lookup`` / ``prefix_insert`` / ``prefix_evict`` and the
 collector's ``paddle_serving.gc``, each inside the phase it runs in.
 
+The engine enqueues the next dispatch before it fences the one in flight:
+``cbe.fence`` and ``cbe.unpack`` belong to the dispatch before the one in
+``cbe.dispatch``, ``cbe.upload`` says whether one was unfenced (``ahead``)
+and ``cbe.unpack`` how many tokens it dropped for rows that had stopped
+(``dropped_tokens``).
+
 A tiny engine as ``tests/test_serving.py`` builds one, traced on the CPU
 with ``python_tracer_level = 0``."""
 
@@ -182,18 +188,33 @@ def test_one_round_and_one_engine_step_per_scheduler_step(run):
 
 
 def test_phases_once_in_order_disjoint_and_covering(run):
+    """The next dispatch is enqueued BEFORE the one in flight is fenced
+    while every slot is taken or a request waits: a step that finds one in
+    flight enqueues at most one, a step that finds none enqueues its own
+    and, where a row goes on past it, maybe the next; each step that has
+    one in flight fences and unpacks the oldest (budget stops only here:
+    never a second), and at most one is left in flight. ``ahead`` on
+    ``cbe.upload`` is 1 exactly where one was unfenced."""
     steps = [e for e in run.events if e[0] == "cbe.step"]
-    dispatching, uncovered = 0, 0
+    dispatching, dispatched, flying, single, uncovered = 0, 0, 0, 0, 0
     for step in steps:
         inside = _inside(run.events, step)
         inner = [e for e in inside if e[0].startswith("cbe.")]
         names = [e[0] for e in inner]
-        if "cbe.dispatch" not in names:
+        d = names.count("cbe.dispatch")
+        if not d and "cbe.fence" not in names:
             assert set(names) <= {"cbe.admit", "cbe.audit"}
             continue
         dispatching += 1
         # prefix cache on: every phase's code runs in a dispatching step
-        assert names == INNER
+        assert names == INNER[:1] + INNER[1:4] * d + INNER[4:]
+        assert d <= (2 if flying == 0 else 1)
+        ahead = [e[3]["ahead"] for e in inner if e[0] == "cbe.upload"]
+        assert ahead == [int(flying + i > 0) for i in range(d)]
+        single += d == 1
+        flying += d - 1
+        assert 0 <= flying <= 1
+        dispatched += d
         for a, b in zip(inner, inner[1:]):
             assert a[2] <= b[1], (a[0], b[0])               # no overlap
         # nothing of the program's lies BETWEEN two phases: every other
@@ -203,7 +224,10 @@ def test_phases_once_in_order_disjoint_and_covering(run):
             assert e[0] == GC or e[0].startswith("cbe.") or any(
                 p[1] <= e[1] and e[2] <= p[2] for p in inner), e[0]
         uncovered += (step[2] - step[1]) - sum(e[2] - e[1] for e in inner)
-    assert dispatching == len(run.plans) > 0
+    assert flying == 0
+    assert dispatched == len(run.plans) > 0
+    # one each of the seven phases in most steps
+    assert single > dispatching / 2
     # what is left is the frame's teardown (the uploads' device buffers)
     # and the taps between phases: ~60 us of a ~2 ms CPU step here. No
     # share of the step: on a machine six workers share the clock runs on
@@ -413,7 +437,7 @@ def test_record_matches_the_plan_and_the_tokens(run):
             run.delivered + len(run.prompts) * (run.chunk - 1)
 
 
-UPLOAD_KEYS = {"admitted", "row_state_uploads", "cached_tokens"}
+UPLOAD_KEYS = {"admitted", "row_state_uploads", "cached_tokens", "ahead"}
 
 
 def test_upload_span_counts_admissions_and_no_row_state_upload(run):
@@ -432,6 +456,10 @@ def test_upload_span_counts_admissions_and_no_row_state_upload(run):
     assert all(u["cached_tokens"] >= 0 for u in uploads)
     assert all(u["cached_tokens"] == 0 for u in uploads
                if u["admitted"] == 0)
+    # ``ahead``: 1 where the dispatch before was still unfenced; the serve
+    # starts with none in flight
+    assert all(u["ahead"] in (0, 1) for u in uploads)
+    assert uploads[0]["ahead"] == 0 and sum(u["ahead"] for u in uploads) > 0
 
 
 @pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
@@ -682,7 +710,8 @@ def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
     assert len(unpacks) == len(plans) == len(records) > 0
     for stats, token_row, rec in zip(unpacks, plans, records):
         assert set(stats) == {"experts_hit", "expert_calls",
-                              "expert_assignments", "max_expert_load"}
+                              "expert_assignments", "max_expert_load",
+                              "dropped_tokens"}
         assert stats["expert_calls"] == 3 * cfg.num_expert_layers
         # dropless: every packed token's every choice, in every layer
         assert stats["expert_assignments"] == int((token_row >= 0).sum()) \
@@ -694,7 +723,10 @@ def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
 
 
 def test_llama_unpack_span_carries_no_stats(run):
-    assert all(e[3] == {} for e in run.events if e[0] == "cbe.unpack")
+    """No routing stats: what every unpack carries, the tokens it dropped
+    for rows that had stopped (none: budget stops only)."""
+    assert all(e[3] == {"dropped_tokens": 0}
+               for e in run.events if e[0] == "cbe.unpack")
 
 
 # ---------------------------------------------------------------------------
@@ -703,6 +735,8 @@ def test_llama_unpack_span_carries_no_stats(run):
 # ---------------------------------------------------------------------------
 FOUR_STATS = {"experts_hit", "expert_calls", "expert_assignments",
               "max_expert_load"}
+#: what every unpack carries beside them
+DROPPED = {"dropped_tokens"}
 
 
 def _expert_engine(family):
@@ -744,7 +778,7 @@ def test_unpack_stats_by_expert_family(tmp_path, family, extra):
     events = _traced(tmp_path, body)
     unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
     assert len(unpacks) == len(plans) > 0
-    assert all(set(u) == FOUR_STATS | extra for u in unpacks)
+    assert all(set(u) == FOUR_STATS | extra | DROPPED for u in unpacks)
     if extra:
         for stats, token_row in zip(unpacks, plans):
             assert stats["expert_calls"] == 3 * cfg.num_layers
@@ -792,6 +826,7 @@ def test_unpack_stats_of_relu_experts_count_the_active_units(tmp_path):
     unpacks = [e[3] for e in events if e[0] == "cbe.unpack"]
     assert unpacks and all(
         set(u) == FOUR_STATS | {"expert_active_units", "expert_units"}
+        | DROPPED
         for u in unpacks)
     for stats in unpacks:
         assert stats["expert_calls"] == 3 * cfg.num_hidden_layers

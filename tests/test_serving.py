@@ -151,12 +151,15 @@ def test_mid_decode_cancellation_frees_slot_and_pages():
         if h0.stream.tokens:
             break
     assert h0.state == RequestState.RUNNING and len(h0.stream.tokens) >= 1
+    slot = eng._slot_rid.index(h0.engine_rid)
     assert sched.cancel(h0.rid)
-    # slot + pages reclaimed immediately, stream closed as cancelled
-    assert eng._slot_rid.count(None) == 1
+    # stream closed as cancelled at once; the slot and pages come back at
+    # the fence of the dispatch still in flight, which the next step makes
     assert h0.state == RequestState.CANCELLED
     assert h0.stream.finish_reason == "cancelled"
     assert not sched.cancel(h0.rid)         # idempotent: already finished
+    sched.step(params)
+    assert eng._slot_rid[slot] is None
     sched.run(params, max_steps=200)        # survivor completes normally
     assert h1.stream.result() == _greedy_ref(params, cfg, ps[1], 8)
     assert eng.mgr.num_free_pages == free0

@@ -100,6 +100,104 @@ def test_speed_budget_and_single_parse(monkeypatch):
         "a rule is re-parsing instead of sharing the engine's trees")
 
 
+_WALK_SAMPLE = '''
+import os
+@dec(x=lambda y: y + 1)
+class C(Base, metaclass=M):
+    k: int = 3
+    def f(self, a, *b, c=[1, 2], **d) -> None:
+        with self._lock, open(a) as fh:
+            g = [i for i in b if i]
+            def inner(z=lambda: 0):
+                return z
+        try:
+            yield {k: v for k, v in d.items()}
+        except (ValueError, KeyError) as e:
+            raise RuntimeError(f"{e!r}") from e
+        match a:
+            case [1, *rest] if rest:
+                pass
+f = lambda q, *, r=2: (q, r)
+'''
+
+
+@pytest.mark.parametrize("source", ["sample", "decoding", "analysis"])
+def test_flat_walk_and_own_nodes_keep_the_ast_walks_order(source):
+    """``flat_walk`` lists ``ast.walk``'s nodes in its order, and a
+    function's kept own nodes are the depth-first walk the rules were
+    written against: nested defs, lambdas and classes left out."""
+    from paddle_tpu.analysis.engine import flat_walk
+    root = analysis.REPO_ROOT
+    text = {"sample": _WALK_SAMPLE,
+            "decoding": (root / "paddle_tpu/inference/decoding.py"
+                         ).read_text(),
+            "analysis": (root / "paddle_tpu/analysis/dataflow.py"
+                         ).read_text()}[source]
+    tree = ast.parse(text)
+    assert [id(n) for n in flat_walk(tree)] == \
+        [id(n) for n in ast.walk(tree)]
+
+    def reference(node):
+        out, stack = [], list(node.body if isinstance(node.body, list)
+                              else [node.body])
+        while stack:
+            n = stack.pop()
+            out.append(n)
+            stack.extend(c for c in ast.iter_child_nodes(n)
+                         if not isinstance(c, (ast.FunctionDef,
+                                               ast.AsyncFunctionDef,
+                                               ast.Lambda, ast.ClassDef)))
+        return out
+
+    from paddle_tpu.analysis.callgraph import FunctionInfo
+    fns = [n for n in ast.walk(tree)
+           if isinstance(n, (ast.FunctionDef, ast.AsyncFunctionDef,
+                             ast.Lambda))]
+    assert len(fns) >= 4
+    for fn in fns:
+        fi = FunctionInfo(None, fn, "q", None, {})
+        first = fi.own_nodes()
+        assert [id(n) for n in first] == [id(n) for n in reference(fn)]
+        assert fi.own_nodes() is first          # walked once, then kept
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_run_repo_pauses_the_collector_and_restores_it(enabled,
+                                                       monkeypatch):
+    import gc
+    seen = []
+    real_run = AnalysisEngine.run
+
+    def run(self, project):
+        seen.append(gc.isenabled())
+        return real_run(self, project)
+
+    monkeypatch.setattr(AnalysisEngine, "run", run)
+    was = gc.isenabled()
+    (gc.enable if enabled else gc.disable)()
+    try:
+        analysis.run_repo(rules=[RULES_BY_ID["layer-http"]])
+        assert gc.isenabled() is enabled
+    finally:
+        (gc.enable if was else gc.disable)()
+    assert seen == [False]
+
+
+def test_lock_rules_scan_each_class_once(monkeypatch):
+    from paddle_tpu.analysis import locks
+    scans = []
+    real_init = locks._ClassScan.__init__
+
+    def counting_init(self, rel, cls):
+        scans.append((rel, cls.name))
+        real_init(self, rel, cls)
+
+    monkeypatch.setattr(locks._ClassScan, "__init__", counting_init)
+    rep = analysis.run_repo(rules=list(LOCK_RULES))
+    assert scans and len(scans) == len(set(scans))
+    assert not rep.new and not rep.stale
+
+
 def test_cli_json_and_text(capsys, tmp_path):
     from paddle_tpu.analysis.__main__ import main
     # acceptance: the CLI exits 0 on the real tree against the baseline
