@@ -1,18 +1,17 @@
-"""Fusion admission harness: measured ABBA A/B for every fused region.
+"""Fusion admission harness: measured ABBA A/B for the fused region.
 
-The fusion pass's three hard gates run HERE, not in prose:
+The fusion pass's three hard gates run HERE, not in prose, on its one
+region (the optimizer chain):
 
-* **byte-identical** — fused and unfused runs must emit exactly the
-  same tokens / commit exactly the same parameter bits (asserted, not
-  sampled);
-* **recompile-count-neutral** — each engine variant compiles its step
-  program exactly once across the length-diverse storm;
-* **measured win** — interleaved A/B/B/A repetitions, medians reported;
-  the one-line JSON is sentinel-comparable (``scripts/bench_sentinel.py
-  --fresh``) so a later PR cannot quietly regress an admitted fusion.
+* **byte-identical** — fused and eager steps must commit exactly the
+  same parameter bits (asserted, not sampled);
+* **recompile-count-neutral** — the fused step compiles its program
+  exactly once across the run;
+* **measured win** — interleaved A/B/B/A repetitions, medians reported
+  in one JSON line.
 
 Run: ``python benchmarks/bench_fusion.py`` (CPU smoke with
-``JAX_PLATFORMS=cpu``; a real chip scales the workload up).
+``JAX_PLATFORMS=cpu``).
 """
 
 import json
@@ -29,57 +28,6 @@ from _telemetry import metrics_snapshot, run_header  # noqa: E402
 
 def _median(xs):
     return statistics.median(xs)
-
-
-def _decode_tail_ab(cfg, params, *, n_req, max_new, num_slots, chunk,
-                    prompt_lens, max_seq_len, reps=3):
-    """Interleaved ABBA serve() storms over warm engines; returns the
-    A/B medians plus the two hard gates' results."""
-    from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
-                                               GenerationConfig)
-    from paddle_tpu.observability.runtime import recompiles
-
-    def mk(fused):
-        return ContinuousBatchingEngine(
-            cfg, GenerationConfig(max_new_tokens=max_new),
-            num_slots=num_slots, page_size=16, max_seq_len=max_seq_len,
-            chunk=chunk, fused_tail=fused, check_invariants=False)
-
-    rng = np.random.RandomState(1)
-    lens = rng.randint(prompt_lens[0], prompt_lens[1] + 1, n_req)
-    prompts = [rng.randint(1, cfg.vocab_size, (int(n),)).astype(np.int32)
-               for n in lens]
-
-    rc0 = recompiles.count("cbe.unified_step")
-    eng_a, eng_b = mk(False), mk(True)
-    # warm both (compile outside every timing window)
-    out_a = eng_a.serve(params, prompts)
-    out_b = eng_b.serve(params, prompts)
-    assert out_a == out_b, "fused decode tail not byte-identical"
-    recompile_neutral = (recompiles.count("cbe.unified_step") - rc0) == 2
-
-    def timed(eng):
-        t0 = time.perf_counter()
-        out = eng.serve(params, prompts)
-        wall = time.perf_counter() - t0
-        assert out == out_a
-        return sum(len(t) for t in out) / wall
-
-    a_runs, b_runs = [], []
-    for _ in range(reps):
-        a_runs.append(timed(eng_a))          # A
-        b_runs.append(timed(eng_b))          # B
-        b_runs.append(timed(eng_b))          # B
-        a_runs.append(timed(eng_a))          # A
-    a_med, b_med = _median(a_runs), _median(b_runs)
-    return {
-        "tokens_per_s_unfused": round(a_med, 2),
-        "tokens_per_s": round(b_med, 2),
-        "ratio": round(b_med / a_med, 4),
-        "byte_identical": True,
-        "recompile_neutral": recompile_neutral,
-        "reps": reps * 2,
-    }
 
 
 def _optimizer_ab(n_params=24, steps=20, reps=3):
@@ -158,40 +106,20 @@ def _optimizer_ab(n_params=24, steps=20, reps=3):
 def main():
     import jax
 
-    from paddle_tpu.models import llama as L
     from paddle_tpu.ops._common import is_tpu_platform
 
     on_tpu = is_tpu_platform(jax.devices()[0].platform)
-    if on_tpu:
-        cfg = L.llama_tiny(num_hidden_layers=8, hidden_size=1024)
-        storm = dict(n_req=64, max_new=64, num_slots=16, chunk=8,
-                     prompt_lens=(16, 128), max_seq_len=256)
-    else:
-        cfg = L.llama_tiny(num_hidden_layers=2)
-        storm = dict(n_req=32, max_new=24, num_slots=8, chunk=4,
-                     prompt_lens=(3, 30), max_seq_len=64)
-    params = L.init_stacked_params(cfg, seed=0)
-
-    tail = _decode_tail_ab(cfg, params, **storm)
     opt = _optimizer_ab()
-
     out = {
         **run_header("fusion"),
-        "metric": "fusion_ab_cpu_smoke" if not on_tpu else
-                  "fusion_ab_v5e",
+        "metric": "fusion_optimizer_ab_v5e" if on_tpu else
+                  "fusion_optimizer_ab_cpu_smoke",
         "unit": "x_speedup",
-        # primary sentinel fields: fused decode-tail throughput and the
-        # decode-tail speedup ratio (both regress LOW)
-        "tokens_per_s": tail["tokens_per_s"],
-        "value": tail["ratio"],
-        "decode_tail": tail,
+        "steps_per_s": opt["steps_per_s"],
+        "value": opt["ratio"],
         "optimizer_chain": opt,
-        "gates": {
-            "byte_identical": tail["byte_identical"]
-            and opt["byte_identical"],
-            "recompile_neutral": tail["recompile_neutral"]
-            and opt["recompile_neutral"],
-        },
+        "gates": {"byte_identical": opt["byte_identical"],
+                  "recompile_neutral": opt["recompile_neutral"]},
     }
     out["metrics_snapshot"] = metrics_snapshot()
     print(json.dumps(out))

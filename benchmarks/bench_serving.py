@@ -163,12 +163,11 @@ def main():
     # ISSUE 10 acceptance: every request in a fleet storm (speculation
     # on AND off, one mid-storm replica kill) reconstructs into a
     # complete span tree whose exclusive segments sum to the measured
-    # e2e within 1%; the hot-chain profile is the fusion-pass input
+    # e2e within 1%
     out["timeline"] = {
         "spec_off": _timeline_storm(speculative=False),
         "spec_on": _timeline_storm(speculative=True),
     }
-    out["hot_chains"] = _hot_chains()
     # ISSUE 16: the in-program sampling epilogue. Greedy vs sampled vs
     # JSON-constrained storms (same engine geometry), a mixed-config
     # storm holding the O(1)-recompile line, and sampled speculation's
@@ -270,55 +269,6 @@ def _timeline_storm(speculative, n_req=8):
     return {"requests": n_req, "complete_trees": complete,
             "reconcile_max_err_pct": round(max_err * 100, 4),
             "failover_segments": failovers}
-
-
-def _hot_chains():
-    """Continuous-profiling artifact — the fusion pass's input, now fed
-    by the REAL decode tail: the engine's armed plan/dispatch/unpack
-    taps (inference/decoding.py) plus an eager epilogue chain, profiled
-    together so the exported chains resolve to the symbols
-    ``jit/fusion.py`` rewrites. The one-line JSON carries the top
-    chains AND the pass's verdict on them (admitted regions / skips)."""
-    import numpy as _np
-
-    import paddle_tpu as paddle
-    from paddle_tpu.inference.decoding import (ContinuousBatchingEngine,
-                                               GenerationConfig)
-    from paddle_tpu.jit.fusion import FusionPass
-    from paddle_tpu.models import llama as L
-    from paddle_tpu.observability.profiling import chain_profiler
-    from paddle_tpu.observability.runtime import telemetry
-
-    cfg = L.llama_tiny(num_hidden_layers=2)
-    params = L.init_stacked_params(cfg, seed=0)
-    eng = ContinuousBatchingEngine(
-        cfg, GenerationConfig(max_new_tokens=8), num_slots=2,
-        page_size=4, max_seq_len=64, chunk=3)
-    rng = _np.random.RandomState(5)
-    prompts = [rng.randint(1, cfg.vocab_size, (int(n),)).astype(_np.int32)
-               for n in (5, 9, 13, 7)]
-    eng.serve(params, prompts[:1])            # compile outside the window
-    telemetry.enable()
-    chain_profiler.reset()
-    chain_profiler.arm()
-    eng.serve(params, prompts)
-    x = paddle.to_tensor(_np.ones((8, 8), _np.float32))
-    for _ in range(64):
-        y = x * 2.0
-        y = y + x
-        y = paddle.clip(y, 0.0, 8.0)
-        y = paddle.scale(y, scale=0.25)
-    chain_profiler.disarm()
-    doc = chain_profiler.profile(top_n=5, workload="decode_tail")
-    plan = FusionPass().plan(doc)
-    return {"top": doc["chains"], "symbols": doc["symbols"],
-            "transitions": doc["transitions"],
-            "fusion_plan": {
-                "admitted": sorted({c.region.name
-                                    for c in plan.candidates}),
-                "skipped": [{"chain": "->".join(s["chain"]),
-                             "reason": s["reason"]}
-                            for s in plan.skipped]}}
 
 
 def _storm(cfg, params, *, n_req, max_new, num_slots, chunk,

@@ -313,9 +313,8 @@ class LayerDepsRule:
             "serving", "inference", "kvcache", "models", "resilience",
             "distributed"),
         # the fusion pass consumes SYMBOLS (the hot-chain artifact +
-        # ProjectIndex) and injected callables, never the serving stack
-        # it optimizes — region installation is duck-typed, and the
-        # decode-tail builders receive the model step as an argument
+        # ProjectIndex), never the serving stack — its one region
+        # installs on a duck-typed optimizer
         "paddle_tpu/jit/fusion.py": (
             "serving", "inference", "kvcache", "models"),
     }

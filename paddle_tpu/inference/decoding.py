@@ -29,8 +29,6 @@ from ..flags import flag_value
 from ..observability.events import emit_event
 from ..observability.journal import token_checksum
 from ..observability.memory import memory_armed, memory_ledger
-from ..observability.profiling import chain_armed as _chain_armed
-from ..observability.profiling import note_chain as _note_chain
 from ..observability.runtime import collections as gc_collections
 from ..observability.runtime import recompiles
 from ..profiler.record import emit_spans, make_span, phase, spans_armed
@@ -142,7 +140,6 @@ class _Flight:
     emit_counts: list
     fed: list
     t0_ns: int              # armed spans: when the dispatch was uploaded
-    tc0: int                # armed chain taps: the same, on their clock
     compile_t0: Optional[float]   # the dispatch that compiled the program
 
 
@@ -217,8 +214,7 @@ class ContinuousBatchingEngine:
                  check_invariants: bool = True,
                  step_tokens: Optional[int] = None,
                  speculative: bool = False, spec_k: int = 4,
-                 drafter=None, fused_tail: bool = False,
-                 mesh=None, mp_axis: str = "mp",
+                 drafter=None, mesh=None, mp_axis: str = "mp",
                  grammar_states: int = 0):
         from ..ops.paged_attention import (PagedKVCacheManager,
                                            kv_cache_layout)
@@ -397,14 +393,6 @@ class ContinuousBatchingEngine:
                                 max(num_slots, chunk, page_size), num_slots)
         self._unified_step = None
         self._unified_flags = None      # host state baked into the program
-        # profile-guided fusion (jit/fusion.py decode_tail region,
-        # default OFF): the step program is built by the fused builders
-        # — identical compute graph fed from a PACKED two-upload plan,
-        # the spec verify epilogue moves in-program, and steady-state
-        # all-decode rounds plan through a vectorized fast path. Tokens
-        # are byte-identical fused on/off; the admission gate lives in
-        # benchmarks/bench_fusion.py.
-        self._fused_tail = bool(fused_tail)
         self._pend = [None] * num_slots   # per-slot unfed prompt suffix
         # coalesced per-slot span windows ([kind, t0_ns, t1_ns, units]):
         # armed steps MERGE each slot's prefill/decode activity into one
@@ -1009,26 +997,6 @@ class ContinuousBatchingEngine:
         device array."""
         return tuple(jnp.asarray(a.copy()) for a in mirrors)
 
-    def _epilogue_active(self) -> bool:
-        """Any live request exercising the sampling epilogue (sampled or
-        constrained rows) — gates the armed ``cbe.sample_epilogue``
-        profiling tap."""
-        return any(r.sampler is not None or r.grammar is not None
-                   for r in self._live.values())
-
-    def enable_fused_tail(self) -> "ContinuousBatchingEngine":
-        """Install the profile-guided decode-tail megaregion (the
-        fusion pass's ``decode_tail`` region). Idempotent. Enabled
-        before the first step it keeps the engine's ONE compile-cache
-        miss; flipping mid-serve drops the compiled program and rebuilds
-        on the next step — a counted miss, same contract as a baked-in
-        flags flip."""
-        if not self._fused_tail:
-            self._fused_tail = True
-            self._unified_step = None
-            self._spec_step = None
-        return self
-
     def _build_unified_step(self, mesh=None):
         """ONE compiled program for every step the engine will ever run:
         ``chunk`` micro-rounds of the ragged model step
@@ -1051,28 +1019,6 @@ class ContinuousBatchingEngine:
         # cost (greedy output is byte-identical either way)
         epilogue = self._epilogue_on
         tail = _sampling.sample_rows if epilogue else _sampling.greedy_rows
-        if self._fused_tail:
-            # the fused decode-tail twin: SAME compute graph (the
-            # builder receives the model step + sampling epilogue as
-            # injected callables) fed from the packed plan —
-            # byte-identical emitted tokens, one compile, two plan
-            # uploads
-            from ..jit import fusion as _fusion
-
-            def model_step(params, ids, token_row, positions, kv_lens,
-                           last_idx, pools, bt, gst, gtable):
-                hook = (lambda lg: _constrain.mask_logits(
-                    lg.astype(jnp.float32), gst, gtable)) \
-                    if epilogue else None
-                return _split_step(L.ragged_step(
-                    params, ids, token_row, positions, kv_lens, last_idx,
-                    *pools, bt, mcfg, mesh=mesh,
-                    mp_axis=mp_axis, logits_epilogue=hook),
-                    len(pools))[:2]
-
-            return _fusion.build_fused_unified_step(
-                model_step, tail, _constrain.reset_states, n_rows)
-
         def run(params, ids, use_carry, token_row, positions, kv_lens,
                 last_idx, sample_mask, tok, gstate, greset, samp, gtable,
                 pools, bt):
@@ -1154,14 +1100,10 @@ class ContinuousBatchingEngine:
                 self.mgr.pools, self.mgr.layout.pool_specs(self._mp_axis)))
         pools += tuple(abstract(a.shape, a.dtype)
                        for a in self.mgr.arrays[len(pools):])
-        if self._fused_tail:        # jit.fusion.pack_plan's two uploads
-            plan = [abstract((4, K, tb), jnp.int32),
-                    abstract((3, K, R), jnp.int32)]
-        else:
-            plan = [abstract((K, n), dt) for n, dt in (
-                (tb, jnp.int32), (tb, jnp.bool_), (tb, jnp.int32),
-                (tb, jnp.int32), (R, jnp.int32), (R, jnp.int32),
-                (R, jnp.bool_))]
+        plan = [abstract((K, n), dt) for n, dt in (
+            (tb, jnp.int32), (tb, jnp.bool_), (tb, jnp.int32),
+            (tb, jnp.int32), (R, jnp.int32), (R, jnp.int32),
+            (R, jnp.bool_))]
         table = self._arena.device_table()
         return self._build_unified_step(mesh).lower(
             params, *plan, abstract((R,), jnp.int32),
@@ -1246,49 +1188,6 @@ class ContinuousBatchingEngine:
                              else self._pend[s][fed[s]:])
         return (ids, use_carry, token_row, positions, kv_lens, last_idx,
                 sample_mask), emit, emit_counts, fed
-
-    def _plan_step_packed(self):
-        """Fused-tail planning: the same plan arrays as
-        :meth:`_plan_step` packed into TWO int32 uploads
-        (``jit.fusion.pack_plan``), with a vectorized fast path for the
-        steady-state round where every live slot is decoding — the
-        K×slots Python simulation collapses to a handful of numpy
-        broadcasts (byte-equality with the generic planner is asserted
-        in tests/test_fusion.py)."""
-        from ..jit.fusion import pack_plan
-        K, tb, n_rows = self.chunk, self._step_tokens, self.num_slots
-        live = self._plan_rows()
-        if live and all(self._pend[s] is None for s in live):
-            nl = len(live)
-            lv = np.asarray(live, np.int64)
-            ids = np.zeros((K, tb), np.int32)
-            use_carry = np.zeros((K, tb), bool)
-            use_carry[:, :nl] = True
-            token_row = np.full((K, tb), -1, np.int32)
-            token_row[:, :nl] = lv
-            positions = np.zeros((K, tb), np.int32)
-            base = self._pos[lv].astype(np.int64)
-            k_col = np.arange(K, dtype=np.int64)[:, None]
-            positions[:, :nl] = base[None, :] + k_col
-            kv_lens = np.zeros((K, n_rows), np.int32)
-            kv_lens[:, lv] = base[None, :] + k_col + 1
-            last_idx = np.zeros((K, n_rows), np.int32)
-            last_idx[:, lv] = np.arange(nl, dtype=np.int64)[None, :]
-            sample_mask = np.zeros((K, n_rows), bool)
-            sample_mask[:, lv] = True
-            emit = np.zeros((K, n_rows), bool)
-            emit[:, lv] = True
-            self._pos[lv] = (base + K).astype(np.int32)
-            emit_counts = [0] * n_rows
-            for s in live:
-                emit_counts[s] = K
-            fed = [0] * n_rows
-            plan = (ids, use_carry, token_row, positions, kv_lens,
-                    last_idx, sample_mask)
-        else:
-            plan, emit, emit_counts, fed = self._plan_step()
-        plan_tt, plan_tr = pack_plan(*plan)
-        return plan_tt, plan_tr, emit, emit_counts, fed
 
     def _dispatch_record(self, token_row, positions, kv_lens, emit_counts,
                          fed, bt=None) -> Dict[str, int]:
@@ -1511,15 +1410,9 @@ class ContinuousBatchingEngine:
             recompiles.record_miss(
                 "cbe.unified_step",
                 (self.num_slots, self.chunk, self._step_tokens,
-                 self._table_width, self._fused_tail, self.num_chips,
-                 self._epilogue_on)
+                 self._table_width, self.num_chips, self._epilogue_on)
                 + self._unified_flags)
             self._unified_step = self._build_unified_step()
-        # armed-only continuous-profiling taps: the plan -> dispatch ->
-        # unpack phases are the fusion pass's decode_tail signature
-        # (jit/fusion.py); disarmed cost is one list index per step
-        armed_chain = _chain_armed[0]
-        tc0 = time.perf_counter_ns() if armed_chain else 0
         rows = self._plan_rows()
         rids = [None] * self.num_slots
         for s in rows:
@@ -1531,20 +1424,9 @@ class ContinuousBatchingEngine:
             # still read it
             bt = np.zeros_like(self._bt)
             bt[rows] = self._bt[rows]
-            if self._fused_tail:
-                plan_tt, plan_tr, emit, emit_counts, fed = \
-                    self._plan_step_packed()
-                plan = (plan_tt, plan_tr)
-                record = self._dispatch_record(
-                    plan_tt[2], plan_tt[3], plan_tr[0], emit_counts, fed, bt)
-            else:
-                plan, emit, emit_counts, fed = self._plan_step()
-                record = self._dispatch_record(
-                    plan[2], plan[3], plan[4], emit_counts, fed, bt)
-        if armed_chain:
-            tc1 = time.perf_counter_ns()
-            _note_chain(op_name="cbe.plan_step", dur_ns=tc1 - tc0)
-            tc0 = tc1
+            plan, emit, emit_counts, fed = self._plan_step()
+            record = self._dispatch_record(
+                plan[2], plan[3], plan[4], emit_counts, fed, bt)
         c0 = time.perf_counter() if fresh else None  # dispatch-only window
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
         # how often admission happens and how often it costs a transfer:
@@ -1570,7 +1452,7 @@ class ContinuousBatchingEngine:
                 self._tok_dev, self._gstate_dev, greset, self._samp_dev,
                 gtable, self.mgr.arrays, bt)
         return _Flight(record["n"], tuple(rids), toks, tuple(aux), emit,
-                       emit_counts, fed, t0_ns, tc0, c0)
+                       emit_counts, fed, t0_ns, c0)
 
     def _land(self) -> None:
         """Fence the oldest dispatch in flight and deliver its tokens to
@@ -1592,19 +1474,6 @@ class ContinuousBatchingEngine:
                 np.asarray(f.aux[0]), self._routing_columns) \
                 if f.aux else {}
         self._flights.popleft()
-        armed_chain = _chain_armed[0]
-        if armed_chain:
-            tc0 = time.perf_counter_ns()
-            if self._fused_tail:
-                _note_chain(op_name="cbe.fused_unified_step",
-                            dur_ns=tc0 - f.tc0)
-            else:
-                _note_chain(op_name="cbe.unified_step", dur_ns=tc0 - f.tc0)
-            if self._epilogue_active():
-                # the sampling epilogue runs inside the dispatch above;
-                # this zero-duration tap makes it visible to the fusion
-                # pass's chain mining (REGIONS["sampling_epilogue"])
-                _note_chain(op_name="cbe.sample_epilogue", dur_ns=0)
         emit, emit_counts, fed = f.emit, f.emit_counts, f.fed
         with phase("cbe.unpack", **routed) as span:
             rows = [s for s, rid in enumerate(f.rids)
@@ -1646,20 +1515,12 @@ class ContinuousBatchingEngine:
                     continue
                 if rid not in self._live:
                     dropped += emit_counts[s]
-                elif self._fused_tail and emit_counts[s] == self.chunk:
-                    # fused-tail fast unpack: the slot emitted every
-                    # round, so its column IS the emission (no K-wide
-                    # mask filter)
-                    self._deliver_tokens(s, toks[:, s])
                 else:
                     self._deliver_tokens(
                         s, (toks[k, s] for k in range(self.chunk)
                             if emit[k, s]))
             self._release_held(f.n)
             span.set_metadata(dropped_tokens=dropped)
-        if armed_chain:
-            _note_chain(op_name="cbe.decode_tail",
-                        dur_ns=time.perf_counter_ns() - tc0)
 
     def _drain(self) -> None:
         """Fence what is still in flight once no row is live: its tokens
@@ -1700,24 +1561,6 @@ class ContinuousBatchingEngine:
         # until the first sampler/grammar submit (see _build_unified_step)
         tail = (_sampling.spec_sample_rows if self._epilogue_on
                 else _sampling.spec_greedy_rows)
-        if self._fused_tail:
-            # fused decode tail, spec flavour: the same single ragged
-            # dispatch plus the verify epilogue IN-PROGRAM — greedy rows
-            # the vectorized accepted-prefix count, sampled rows the
-            # rejection-sampling verifier (jit/fusion.py)
-            from ..jit import fusion as _fusion
-
-            def model_step(params, ids, token_row, positions, kv_lens,
-                           cand_idx, pools, bt):
-                return _split_step(L.ragged_step(
-                    params, ids, token_row, positions, kv_lens, cand_idx,
-                    *pools, bt, mcfg, mesh=mesh, mp_axis=mp_axis),
-                    len(pools))[:2]
-
-            return _fusion.build_fused_spec_step(
-                model_step, tail, _constrain.reset_states, self.spec_k,
-                n_rows)
-
         def run(params, ids, token_row, positions, kv_lens, cand_idx,
                 drafts, draft_len, sampled, gstate, greset, samp, gtable,
                 pools, bt):
@@ -1880,8 +1723,7 @@ class ContinuousBatchingEngine:
     def _verify_spec(self, toks, info, accepted):
         """Host commit over the dispatch's per-row verified tokens
         (``toks (slots, k+1)``, ``accepted (slots,)`` — both computed
-        in-program by the verify/sampling epilogue, fused and unfused
-        alike): deliver the accepted drafted prefix plus the epilogue's
+        in-program by the verify/sampling epilogue): deliver the accepted drafted prefix plus the epilogue's
         token at the first rejected lane (greedy: the model's own
         argmax; sampled: the rejection-sampling residual draw / the
         bonus draw), roll the paged KV back on rejection, deliver
@@ -1942,23 +1784,14 @@ class ContinuousBatchingEngine:
             recompiles.record_miss(
                 "cbe.spec_step",
                 (self.num_slots, self._spec_tokens, self.spec_k,
-                 self._table_width, self._fused_tail, self.num_chips,
-                 self._epilogue_on)
+                 self._table_width, self.num_chips, self._epilogue_on)
                 + self._spec_flags)
             self._spec_step = self._build_spec_step()
-        armed_chain = _chain_armed[0]
-        tc0 = time.perf_counter_ns() if armed_chain else 0
         plan, info, fed, drafts, draft_len, sampled = self._plan_spec()
-        if armed_chain:
-            tc1 = time.perf_counter_ns()
-            _note_chain(op_name="cbe.plan_step", dur_ns=tc1 - tc0)
-            tc0 = tc1
         self._prefill_tokens += sum(fed)
         if fresh:
             c0 = time.perf_counter()
         t0_ns = time.perf_counter_ns() if spans_armed() else 0
-        # fused and unfused spec programs share one signature since the
-        # verify/sampling epilogue moved in-program for both
         toks, accepted, self._gstate_dev, self.mgr.pools = self._spec_step(
             params, *(jnp.asarray(a) for a in plan),
             jnp.asarray(drafts), jnp.asarray(draft_len),
@@ -1972,16 +1805,6 @@ class ContinuousBatchingEngine:
                                        time.perf_counter() - c0)
         toks = np.asarray(toks)                    # the one fence
         accepted = np.asarray(accepted)
-        if armed_chain:
-            tc1 = time.perf_counter_ns()
-            if self._fused_tail:
-                _note_chain(op_name="cbe.fused_spec_step",
-                            dur_ns=tc1 - tc0)
-            else:
-                _note_chain(op_name="cbe.spec_step", dur_ns=tc1 - tc0)
-            if self._epilogue_active():
-                _note_chain(op_name="cbe.sample_epilogue", dur_ns=0)
-            tc0 = tc1
         if t0_ns:
             t1_ns = time.perf_counter_ns()
             batch = []
@@ -2004,9 +1827,6 @@ class ContinuousBatchingEngine:
                               "drafted": len(info[s][2])}))
             emit_spans(batch)
         self._verify_spec(toks, info, accepted)
-        if armed_chain:
-            _note_chain(op_name="cbe.decode_tail",
-                        dur_ns=time.perf_counter_ns() - tc0)
         if self._check_invariants:
             # the ownership-model anchor, now also covering draft
             # growth and rejection rollback: audited after EVERY

@@ -20,8 +20,8 @@ without forking the program:
   plain int inputs, no key state threads across steps and no global
   stream couples rows. Streams are therefore seeded-replayable
   (same seed => same tokens) regardless of batch composition, chunk
-  size, fused/unfused tail, TP degree, or a mid-stream failover resume
-  (the position IS the counter).
+  size, TP degree, or a mid-stream failover resume (the position IS
+  the counter).
 * **Greedy is temperature == 0**, computed as ``argmax`` over the same
   (grammar-masked) logits — for unconstrained rows the mask is a no-op
   and the argmax is bit-identical to the pre-sampling engine.
@@ -45,10 +45,9 @@ without forking the program:
   independence-safe.
 
 Grammar masking/advance live in ``inference/constrain.py``; the
-engine applies the mask via the model's ``logits_epilogue`` hook (or
-inside the injected fused-tail epilogue) BEFORE this module's
-temperature/top-k/top-p processing, so constrained rows renormalize
-over legal tokens only.
+engine applies the mask via the model's ``logits_epilogue`` hook BEFORE
+this module's temperature/top-k/top-p processing, so constrained rows
+renormalize over legal tokens only.
 """
 
 from __future__ import annotations
@@ -198,7 +197,7 @@ def process_logits(logits, temps, top_k, top_p):
 def sample_rows(logits, pos_next, samp, gstate, gtable):
     """The unified-step epilogue: per-row logits -> (token, grammar
     state). ``logits (rows, V)`` must already carry the grammar mask
-    (the model's ``logits_epilogue`` hook / the fused tail applies
+    (the model's ``logits_epilogue`` hook applies
     :func:`constrain.mask_logits` first); ``pos_next (rows,)`` is the
     sequence position the sampled token will occupy (= the row's
     attended length this micro-round) — it is the PRNG counter.

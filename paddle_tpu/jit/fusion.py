@@ -7,27 +7,24 @@ ops resolve to ``ProjectIndex`` symbols — and this module is the
 consumer. :class:`FusionPass` reads the artifact, maps ranked chains to
 declared **fusable regions**, and rewrites them into single jitted
 megaregions (PAPERS.md: MPK "Mega-Kernelizing Tensor Programs", Neptune
-operator fusion): the unified ragged step's decode tail on the serving
-side, and the grad-transform → optimizer-update chain on the training
-side.
+operator fusion). The one declared region is the eager grad-transform →
+optimizer-update chain of the training side.
 
 Admission discipline (the hard gates, enforced by
 ``benchmarks/bench_fusion.py`` + ``tests/test_fusion.py``):
 
-* **byte-identical** outputs fused vs. unfused — the decode tail keeps
-  the exact compute graph of the unfused program (only host plumbing
-  and epilogue placement change), and the optimizer megaregion replays
-  the optimizer's own ``_update``/grad-clip code through the
-  **eager-granularity stager** below, so fusing never changes a single
-  bit of training state;
-* **recompile-count-neutral** — fused programs have shape-invariant
-  signatures like their unfused twins (the O(1)-recompile invariant
-  from the unified-step PR);
+* **byte-identical** outputs fused vs. unfused — the optimizer
+  megaregion replays the optimizer's own ``_update``/grad-clip code
+  through the **eager-granularity stager** below, so fusing never
+  changes a single bit of training state;
+* **recompile-count-neutral** — the fused program's signature depends
+  only on parameter shapes, never on the step or the learning rate;
 * **measured ABBA win** recorded in BASELINE.md before a fusion ships
   enabled.
 
 Degradation contract: a stale artifact (symbols renamed/moved since the
-capture, or an incompatible schema) produces structured
+capture, or an incompatible schema) or a chain no region declares
+produces structured
 ``fusion_skipped`` events — one deduped event per chain per process —
 and ``paddle_fusion_skipped_total{reason}`` counts, never an exception.
 
@@ -46,11 +43,9 @@ untouched, and the megaregion stays one dispatch — the win is the
 eliminated per-op host overhead, which is exactly what the profiler's
 hot chains measure.
 
-Layering: this module consumes *symbols and injected callables*, never
-the serving/inference stack — tpu-lint's ``layer-deps`` STRICT contract
-bans those imports at any scope. Region installation is duck-typed
-(``engine.enable_fused_tail()``), and the decode-tail program builders
-receive the model step function as an argument.
+Layering: this module consumes *symbols*, never the serving/inference
+stack — tpu-lint's ``layer-deps`` STRICT contract bans those imports at
+any scope.
 """
 
 from __future__ import annotations
@@ -96,7 +91,7 @@ _skip_noted: set = set()
 
 #: region name -> weakly-referenced installed targets; the active gauge
 #: reflects whether any install target is still ALIVE, re-evaluated on
-#: every plan()/apply() (a dropped fused engine must not report an
+#: every plan()/apply() (a dropped fused optimizer must not report an
 #: active megaregion forever — same liveness discipline as the memory
 #: ledger's pool table)
 _installed_targets: Dict[str, Any] = {}
@@ -109,13 +104,9 @@ def _refresh_active_gauges() -> None:
         _active.set(1.0 if alive else 0.0, region=region)
 
 
-def _note_install(region: str, target: Any) -> None:
+def _note_install(region: str, target: "FusedOptimizerStep") -> None:
     import weakref
-    try:
-        ref = weakref.ref(target)
-    except TypeError:               # unweakrefable target: pin forever
-        ref = (lambda t=target: t)
-    _installed_targets.setdefault(region, []).append(ref)
+    _installed_targets.setdefault(region, []).append(weakref.ref(target))
 
 
 # ---------------------------------------------------------------------------
@@ -125,12 +116,10 @@ def _note_install(region: str, target: Any) -> None:
 class FusionRegion:
     """A declared fusable region: a named rewrite this tree knows how to
     install, matched against hot chains by op signature. ``signatures``
-    are contiguous op subsequences as they appear in the artifact;
-    ``target`` names the keyword :meth:`FusionPlan.apply` installs on."""
+    are contiguous op subsequences as they appear in the artifact."""
 
     name: str
     signatures: Tuple[Tuple[str, ...], ...]
-    target: str                     # "engine" | "optimizer"
     doc: str = ""
 
     def match(self, ops: Sequence[str]) -> Optional[Tuple[str, ...]]:
@@ -146,36 +135,14 @@ class FusionRegion:
 
 #: built-in regions (a test/bench may register more via REGIONS)
 REGIONS: Dict[str, FusionRegion] = {
-    "decode_tail": FusionRegion(
-        name="decode_tail",
-        signatures=(("cbe.unified_step", "cbe.decode_tail"),
-                    ("cbe.plan_step", "cbe.unified_step"),
-                    ("cbe.spec_step", "cbe.decode_tail")),
-        target="engine",
-        doc="unified ragged step's decode tail: packed plan upload, "
-            "fused greedy/verify epilogue, vectorized steady-state "
-            "planning (ContinuousBatchingEngine.enable_fused_tail)"),
     "optimizer_chain": FusionRegion(
         name="optimizer_chain",
         signatures=(("grad_clip", "optimizer_update"),
                     ("optimizer_update", "optimizer_update"),
                     ("optimizer_update",)),
-        target="optimizer",
         doc="eager grad transform -> per-param optimizer update chain "
             "fused into ONE bit-exact jitted megaregion "
             "(FusedOptimizerStep)"),
-    "sampling_epilogue": FusionRegion(
-        name="sampling_epilogue",
-        signatures=(("cbe.unified_step", "cbe.sample_epilogue"),
-                    ("cbe.sample_epilogue", "cbe.decode_tail"),
-                    ("cbe.spec_step", "cbe.sample_epilogue")),
-        target="engine",
-        doc="the distribution-faithful sampling epilogue (grammar mask "
-            "-> temperature/top-k/top-p -> counter-keyed categorical / "
-            "rejection-sampling verify) fused into the same decode-tail "
-            "program as the ragged step — mixed greedy/sampled/"
-            "constrained rows in ONE dispatch "
-            "(ContinuousBatchingEngine.enable_fused_tail)"),
 }
 
 
@@ -191,8 +158,8 @@ class FusionCandidate:
 @dataclass
 class FusionPlan:
     """The pass output: chains mapped to installable regions plus the
-    structured skips. ``apply`` installs each candidate on the matching
-    duck-typed target and reports what it did."""
+    structured skips. ``apply`` installs each candidate on the optimizer
+    and reports what it did."""
 
     candidates: List[FusionCandidate] = field(default_factory=list)
     skipped: List[Dict[str, Any]] = field(default_factory=list)
@@ -203,33 +170,22 @@ class FusionPlan:
                 return c
         return None
 
-    def apply(self, engine=None, optimizer=None) -> Dict[str, Any]:
-        """Install every planned region whose target was passed.
-        Returns ``{region name: installed object}``; regions whose
-        target is absent (or lacks the install surface) are skipped
-        with reason ``target-unsupported`` — never an exception."""
+    def apply(self, optimizer) -> Dict[str, Any]:
+        """Install every planned region on ``optimizer``. Returns
+        ``{region name: installed object}``; an optimizer that lacks
+        the install surface (or rejects it) is skipped with reason
+        ``target-unsupported`` — never an exception."""
         installed: Dict[str, Any] = {}
         for cand in self.candidates:
             name = cand.region.name
             if name in installed:
                 continue
-            target = {"engine": engine,
-                      "optimizer": optimizer}.get(cand.region.target)
-            if target is None:
-                continue
             # idempotence: re-applying over an already-installed region
             # must not re-count the admission or re-emit the event
-            if cand.region.target == "engine":
-                already = bool(getattr(target, "_fused_tail", False))
-            else:
-                already = isinstance(getattr(target, "_fused_step", None),
-                                     FusedOptimizerStep)
+            already = isinstance(getattr(optimizer, "_fused_step", None),
+                                 FusedOptimizerStep)
             try:
-                if cand.region.target == "engine":
-                    target.enable_fused_tail()
-                    installed[name] = target
-                else:
-                    installed[name] = install_optimizer_fusion(target)
+                installed[name] = install_optimizer_fusion(optimizer)
             except Exception as exc:
                 # the degradation contract covers installation too: a
                 # target without the surface (AttributeError) or one
@@ -238,8 +194,7 @@ class FusionPlan:
                 _note_skip(cand.ops, "target-unsupported", region=name,
                            error=f"{type(exc).__name__}: {exc}")
                 continue
-            _note_install(name, installed[name] if
-                          cand.region.target == "optimizer" else target)
+            _note_install(name, installed[name])
             if already:
                 continue
             _admitted_total.inc(region=name)
@@ -471,9 +426,9 @@ def _eval_guarded(jaxpr, consts, lim, *args):
 
 def stage_eager(fn: Callable, *example_args):
     """Trace ``fn`` once over ``example_args`` (shape/dtype only) and
-    return ``staged(lim, *args)`` evaluating it with per-op contraction
-    fences — the callable a megaregion jits to stay bit-identical to
-    the eager chain it replaces."""
+    return the jitted ``staged(lim, *args)`` evaluating it with per-op
+    contraction fences — the program a megaregion dispatches to stay
+    bit-identical to the eager chain it replaces."""
     closed = jax.make_jaxpr(fn)(*example_args)
 
     def staged(lim, *args):
@@ -481,7 +436,7 @@ def stage_eager(fn: Callable, *example_args):
         del treedef  # the jaxpr's invars ARE the flat order
         outs = _eval_guarded(closed.jaxpr, closed.consts, lim, *flat)
         return outs
-    return staged, closed
+    return jax.jit(staged), closed
 
 
 # ---------------------------------------------------------------------------
@@ -597,13 +552,12 @@ class FusedOptimizerStep:
         # two passes; 4 slots/param + 8 covers every shipped optimizer
         scal_dim = 4 * len(params) + 8
         dummy = jnp.zeros((scal_dim,), jnp.float32)
-        staged, _ = stage_eager(whole, dummy, pv, gv, sv)
+        jitted, _ = stage_eager(whole, dummy, pv, gv, sv)
         if len(stager.slots) > scal_dim:     # pragma: no cover - guard
             raise RuntimeError(
                 f"optimizer staged {len(stager.slots)} host scalars > "
                 f"slot vector {scal_dim}")
         out_tree = jax.tree_util.tree_structure((pv, sv))
-        jitted = jax.jit(staged)
         return jitted, stager, scal_dim, out_tree
 
     # -- the service surface (Optimizer.step delegates here) ----------------
@@ -651,109 +605,3 @@ def install_optimizer_fusion(optimizer) -> FusedOptimizerStep:
     fused = FusedOptimizerStep(optimizer)
     optimizer._fused_step = fused
     return fused
-
-
-# ---------------------------------------------------------------------------
-# Region: decode_tail — fused unified/spec step program builders
-# ---------------------------------------------------------------------------
-def pack_plan(ids, use_carry, token_row, positions, kv_lens, last_idx,
-              sample_mask):
-    """Pack one unified-step plan into two int32 uploads: the token-axis
-    group (4, K, step_tokens) and the row-axis group (3, K, rows) — two
-    host→device transfers per step instead of seven."""
-    plan_tt = np.stack([ids, use_carry.astype(np.int32), token_row,
-                        positions]).astype(np.int32)
-    plan_tr = np.stack([kv_lens, last_idx,
-                        sample_mask.astype(np.int32)]).astype(np.int32)
-    return plan_tt, plan_tr
-
-
-def build_fused_unified_step(model_step: Callable, sample_fn: Callable,
-                             reset_fn: Callable, num_rows: int):
-    """The fused decode-tail twin of the engine's unified ragged step:
-    same compute graph (``model_step`` per micro-round, the sampling
-    epilogue, the carry select) — byte-identical tokens by construction
-    — fed from the packed plan of :func:`pack_plan`.
-
-    ``model_step(params, ids, token_row, positions, kv_lens, last_idx,
-    pools, bt, gstate, gtable) -> (logits, pools)``, ``pools`` the tuple
-    of the cache's arrays (the grammar state rides into the model's logits
-    epilogue hook so masking happens before the sampler);
-    ``sample_fn(logits, pos_next, samp, gstate, gtable) ->
-    ((rows,) int32 tokens, (rows,) int32 grammar states)`` — the
-    counter-based epilogue needs no key input, so no PRNG state
-    threads through the scan carry. ``reset_fn(gstate, greset) ->
-    gstate`` applies admission's per-row restart of the grammar carry
-    once, before the first micro-round (``constrain.reset_states``).
-    """
-
-    def run(params, plan_tt, plan_tr, tok, gstate, greset, samp, gtable,
-            pools, bt):
-        gstate = reset_fn(gstate, greset)
-        ids = plan_tt[0]
-        use_carry = plan_tt[1].astype(bool)
-        token_row = plan_tt[2]
-        positions = plan_tt[3]
-        kv_lens = plan_tr[0]
-        last_idx = plan_tr[1]
-        sample_mask = plan_tr[2].astype(bool)
-
-        def micro(carry, xs):
-            tok, gst, pools = carry
-            ids_k, uc_k, tr_k, pos_k, kvl_k, li_k, sm_k = xs
-            row_c = jnp.clip(tr_k, 0, num_rows - 1)
-            ids_eff = jnp.where(uc_k, jnp.take(tok, row_c), ids_k)
-            logits, pools = model_step(params, ids_eff, tr_k, pos_k,
-                                       kvl_k, li_k, pools, bt,
-                                       gst, gtable)
-            nxt, ngst = sample_fn(logits, kvl_k, samp, gst, gtable)
-            emit = tok
-            tok = jnp.where(sm_k, nxt, tok)
-            gst = jnp.where(sm_k, ngst, gst)
-            return (tok, gst, pools), emit
-
-        (tok, gstate, pools), toks = jax.lax.scan(
-            micro, (tok, gstate, pools),
-            (ids, use_carry, token_row, positions, kv_lens, last_idx,
-             sample_mask))
-        return toks, tok, gstate, pools
-
-    return jax.jit(run, donate_argnums=(8,))
-
-
-def build_fused_spec_step(model_step: Callable, spec_sample_fn: Callable,
-                          reset_fn: Callable, spec_k: int, num_rows: int):
-    """The fused decode-tail twin of the speculative step: the same
-    single ragged dispatch plus the **verify epilogue in-program** — a
-    vectorized accepted-prefix count (greedy rows) / rejection-sampling
-    accept-and-residual-resample (sampled rows) per row replaces the
-    host's per-token compare loop. Greedy candidate tokens (and
-    therefore every greedy committed token) stay byte-identical to the
-    unfused program.
-
-    ``spec_sample_fn(logits (rows, k+1, V), drafts, draft_len,
-    pos_base, samp, gstate, gtable) -> (toks (rows, k+1), accepted
-    (rows,), gstate')``. ``sampled (rows,) bool`` gates which rows
-    really committed a token this round — only those advance their
-    grammar state (a mid-prefill constrained row must not advance on a
-    garbage candidate). ``reset_fn`` as in
-    :func:`build_fused_unified_step`.
-    """
-    k1 = spec_k + 1
-
-    def run(params, ids, token_row, positions, kv_lens, cand_idx,
-            drafts, draft_len, sampled, gstate, greset, samp, gtable,
-            pools, bt):
-        gstate = reset_fn(gstate, greset)
-        logits, pools = model_step(params, ids, token_row, positions,
-                                   kv_lens, cand_idx, pools, bt)
-        lg = logits.reshape(num_rows, k1, -1)
-        pos_base = jnp.take(positions,
-                            cand_idx.reshape(num_rows, k1)[:, 0])
-        toks, accepted, ngst = spec_sample_fn(lg, drafts, draft_len,
-                                              pos_base, samp, gstate,
-                                              gtable)
-        gstate = jnp.where(sampled, ngst, gstate)
-        return toks, accepted, gstate, pools
-
-    return jax.jit(run, donate_argnums=(13,))

@@ -62,12 +62,11 @@ def run_metadata() -> Dict[str, str]:
 
 def note_chain(*, op_name: str, dur_ns: Optional[float] = None) -> None:
     """Armed-only chain tap for host code that dispatches whole
-    *compiled programs* rather than eager ops — the serving engine's
-    plan/dispatch/unpack phases and the fused megaregions. Call with a
-    literal ``op_name=`` keyword: :func:`dispatch_sites` resolves the
-    literal to the enclosing function exactly like a ``core.dispatch``
-    op, so profiled step phases map to engine symbols in the artifact.
-    One list-index when disarmed."""
+    *compiled programs* rather than eager ops — the optimizer's eager
+    chain and its fused megaregion. Call with a literal ``op_name=``
+    keyword: :func:`dispatch_sites` resolves the literal to the
+    enclosing function exactly like a ``core.dispatch`` op. One
+    list-index when disarmed."""
     if not chain_armed[0]:
         return
     chain_profiler.note(op_name)

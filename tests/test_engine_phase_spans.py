@@ -20,6 +20,7 @@ with ``python_tracer_level = 0``."""
 
 import gc
 import glob
+import importlib
 import os
 import types
 
@@ -55,13 +56,19 @@ HOST_SPANS = {
 GC = "paddle_serving.gc"
 
 
-def _build(chunk, fused_tail):
-    cfg = L.llama_tiny(num_hidden_layers=6)
-    params = L.init_stacked_params(cfg, seed=3)
+def _build(chunk, family="llama"):
+    """A tiny engine of ``family`` (a module of ``paddle_tpu.models``) behind
+    a scheduler: Llama six layers deep, any other family at its ``_tiny``."""
+    if family == "llama":
+        module, cfg = L, L.llama_tiny(num_hidden_layers=6)
+    else:
+        module = importlib.import_module(f"paddle_tpu.models.{family}")
+        cfg = getattr(module, family + "_tiny")()
+    params = module.init_stacked_params(cfg, seed=3)
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
         num_slots=SLOTS, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=chunk,
-        prefix_cache=True, fused_tail=fused_tail)
+        prefix_cache=True)
     sched = ServingScheduler(eng, SchedulerConfig(max_queue_depth=64))
     return cfg, params, eng, sched
 
@@ -121,8 +128,8 @@ def _traced(tmp_path, body):
     return _host_events(str(tmp_path))
 
 
-def _traced_serve(tmp_path, chunk, fused_tail, n_requests=5):
-    cfg, params, eng, sched = _build(chunk, fused_tail)
+def _traced_serve(tmp_path, chunk, family="llama", n_requests=5):
+    cfg, params, eng, sched = _build(chunk, family)
     _warm(cfg, params, sched)
     # one traced serve: the events, what the planner laid out for each
     # dispatch (token_row, positions, kv_lens), what was sent and delivered
@@ -141,23 +148,13 @@ def _traced_serve(tmp_path, chunk, fused_tail, n_requests=5):
             len(req.prompt) + len(req.tokens[:eng._budget(req)]))
         return retire(s, cancelled)
     sched._admit, eng._retire = admit_spy, retire_spy
-    if fused_tail:
-        packed = eng._plan_step_packed
+    plain = eng._plan_step
 
-        def spy():
-            out = packed()
-            run.plans.append((out[0][2].copy(), out[0][3].copy(),
-                              out[1][0].copy()))
-            return out
-        eng._plan_step_packed = spy
-    else:
-        plain = eng._plan_step
-
-        def spy():
-            out = plain()
-            run.plans.append(tuple(a.copy() for a in out[0][2:5]))
-            return out
-        eng._plan_step = spy
+    def spy():
+        out = plain()
+        run.plans.append(tuple(a.copy() for a in out[0][2:5]))
+        return out
+    eng._plan_step = spy
     run.prompts = _prompts(cfg, n_requests, seed=1)
 
     def body():
@@ -170,11 +167,15 @@ def _traced_serve(tmp_path, chunk, fused_tail, n_requests=5):
     return run
 
 
-@pytest.fixture(scope="module", params=[(1, False), (3, False), (3, True)],
-                ids=["chunk1-plain", "chunk3-plain", "chunk3-fused"])
+@pytest.fixture(scope="module", params=[
+    (1, "llama"), (3, "llama"), (3, "smallthinker")],
+    ids=["chunk1-plain", "chunk3-plain", "chunk3-smallthinker"])
 def run(request, tmp_path_factory):
-    chunk, fused = request.param
-    r = _traced_serve(tmp_path_factory.mktemp("trace"), chunk, fused)
+    """A traced serve of Llama at chunk 1 and 3, and of SmallThinker (window
+    layers, experts, a GQA cache) at chunk 3: what each test expects of a
+    family it reads from the engine, never from the family's name."""
+    chunk, family = request.param
+    r = _traced_serve(tmp_path_factory.mktemp("trace"), chunk, family)
     r.chunk = chunk
     return r
 
@@ -312,7 +313,7 @@ def test_a_repeated_prompt_walks_its_cached_blocks_and_gc_is_named(tmp_path):
     page`` blocks, the second insert adopts nothing; and with the automatic
     collector off, the session holds one ``paddle_serving.gc`` span a forced
     collection, with its generation."""
-    cfg, params, eng, sched = _build(3, False)
+    cfg, params, eng, sched = _build(3)
     _warm(cfg, params, sched)
     prompt = _prompts(cfg, 1, seed=5)[0][:3].tolist() + [7] * 8     # 11
     was_enabled = gc.isenabled()
@@ -356,7 +357,7 @@ def test_a_hit_a_miss_and_a_retirement_keep_their_spans_and_stats(tmp_path):
     first's two leading blocks and then diverges, one that shares nothing:
     ``blocks`` is the full blocks matched, ``pages`` the blocks of the
     retired sequence that were new to the tree."""
-    cfg, params, eng, sched = _build(3, False)
+    cfg, params, eng, sched = _build(3)
     _warm(cfg, params, sched)
     first = list(range(5, 14))                       # 9 tokens: 2 full blocks
     second = first[:2 * PAGE] + [20, 21, 22]         # diverges in block 2
@@ -380,51 +381,86 @@ def test_a_hit_a_miss_and_a_retirement_keep_their_spans_and_stats(tmp_path):
 
 
 def test_a_model_without_a_state_layout_carries_its_pools_alone(run):
-    """Llama names no ``state_layout``: the engine keeps no state pool, what
-    its step takes and returns is the page pools and nothing else, and its
-    record has the ten keys and none of a state model's three
-    (``tests/test_jamba_model.py`` holds those)."""
+    """Llama and SmallThinker name no ``state_layout``: the engine keeps no
+    state pool, what its step takes and returns is the page pools (one an
+    entry of the cache layout) and nothing else, and its record has none of
+    a state model's three keys (``tests/test_jamba_model.py`` holds
+    those)."""
     mgr = run.eng.mgr
     assert mgr.state is None
-    assert mgr.arrays == mgr.pools and len(mgr.pools) == 2
+    assert mgr.arrays == mgr.pools
+    assert len(mgr.pools) == len(mgr.layout.entries)
     records = [e[3] for e in run.events if e[0] == "cbe.dispatch"]
     assert records and all(
         not {"state_row_rounds", "state_resets", "state_bytes_per_row"}
         & set(r) for r in records)
 
 
+def _layer_by_hand(window, token_row, positions, kv_lens, width, group):
+    """What one layer's kernel calls of a dispatch walk, by hand: (pages,
+    page slots of its grid steps, causal pairs). Page j of row s runs in
+    round k iff j * page_size < kv_lens[k, s] and, under a sliding
+    ``window``, the row's earliest token of the round still sees a key of
+    page j; the grid walks each round's blocks of G pages a row, and one
+    step in a round that has none."""
+    pages = steps = pairs = 0
+    for k in range(token_row.shape[0]):
+        blocks = 0
+        for s in range(kv_lens.shape[1]):
+            mine = positions[k][token_row[k] == s]
+            first = 0 if window is None or not len(mine) else \
+                max(0, int(mine.min()) - window + 1) // PAGE
+            listed = sum(1 for j in range(first, width)
+                         if j * PAGE < kv_lens[k, s])
+            pages += listed
+            blocks += -(-listed // group)
+        steps += group * max(1, blocks)
+        seen = positions[k][token_row[k] >= 0] + 1
+        pairs += int((seen if window is None
+                      else np.minimum(seen, window)).sum())
+    return pages, steps, pairs
+
+
 def test_record_matches_the_plan_and_the_tokens(run):
+    """The record's page, step and pair counts are the per-layer mean of
+    what each layer walks by hand (one window a layer, as the engine's
+    ``_layer_windows`` names them); a family with window layers adds what
+    the windows skipped; distinct prompts share no page."""
     eng = run.eng
     records = [e[3] for e in run.events if e[0] == "cbe.dispatch"]
     assert len(records) == len(run.plans)
-    assert all(set(r) == RECORD_KEYS for r in records)
+    windows = eng._layer_windows
+    windowed = any(w is not None for w in windows)
+    extra = set(records[0]) - RECORD_KEYS
+    assert extra <= {"window_skipped_pages", "shared_pages"}
+    assert ("window_skipped_pages" in extra) == windowed
+    assert all(set(r) == RECORD_KEYS | extra for r in records)
     assert all(isinstance(v, int) for r in records for v in r.values())
     ordinals = [r["n"] for r in records]
     assert ordinals == list(range(ordinals[0], ordinals[0] + len(records)))
     width = eng._table_width
+    group = ragged_block_pages(PAGE, width)
     for rec, (token_row, positions, kv_lens) in zip(records, run.plans):
         assert rec["rounds"] == run.chunk and rec["page_size"] == PAGE
         assert rec["token_slots"] == run.chunk * eng._step_tokens
-        # the kernel walks each micro-round's live blocks of G pages, and
-        # one step in a round that has none: G page slots a step
-        group = ragged_block_pages(PAGE, width)
-        assert rec["grid_steps"] == group * sum(
-            max(1, sum(-(-min(-(-int(kv_lens[k, s]) // PAGE), width) // group)
-                       for s in range(SLOTS)))
-            for k in range(run.chunk))
+        walked = [_layer_by_hand(w, token_row, positions, kv_lens, width,
+                                 group) for w in windows]
+        pages, steps, pairs = (sum(c) / len(windows) for c in zip(*walked))
+        assert rec["attended_pages"] == round(pages)
+        assert rec["grid_steps"] == round(steps)
+        assert rec["causal_pairs"] == round(pairs)
+        if windowed:
+            full = _layer_by_hand(None, token_row, positions, kv_lens, width,
+                                  group)[0]
+            assert rec["window_skipped_pages"] == round(full - pages)
+        assert rec.get("shared_pages", 0) == 0
         assert rec["attended_pages"] <= rec["grid_steps"] <= \
             group * (rec["attended_pages"] + rec["rounds"])
         assert 1 <= rec["live_rows"] <= SLOTS
-        # the kernel's own test, step by step: page j of row s runs in
-        # round k iff j * page_size < kv_lens[k, s]
-        assert rec["attended_pages"] == sum(
-            1 for k in range(run.chunk) for s in range(SLOTS)
-            for j in range(width) if j * PAGE < kv_lens[k, s])
-        assert rec["causal_pairs"] == sum(
-            int(positions[k, t]) + 1 for k in range(run.chunk)
-            for t in range(token_row.shape[1]) if token_row[k, t] >= 0)
         assert rec["prefill_tokens"] + rec["decode_tokens"] == \
             int((token_row >= 0).sum())
+    # sequences longer than a window: the windows leave pages out
+    assert not windowed or sum(r["window_skipped_pages"] for r in records)
     # distinct prompts, nothing cached: every prompt token is fed once
     assert sum(r["prefill_tokens"] for r in records) == \
         sum(len(p) for p in run.prompts)
@@ -462,8 +498,7 @@ def test_upload_span_counts_admissions_and_no_row_state_upload(run):
     assert uploads[0]["ahead"] == 0 and sum(u["ahead"] for u in uploads) > 0
 
 
-@pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
-def test_row_state_uploads_once_for_a_changed_row(tmp_path, fused_tail):
+def test_row_state_uploads_once_for_a_changed_row(tmp_path):
     """A sampled request changes its row's mirrors: the step that admits
     it uploads them (1), every later step of its decode does not, and a
     greedy request into the same slot resets the row with one more."""
@@ -472,7 +507,7 @@ def test_row_state_uploads_once_for_a_changed_row(tmp_path, fused_tail):
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=MAX_NEW, seed=3),
         num_slots=1, page_size=PAGE, max_seq_len=MAX_SEQ, chunk=2,
-        prefix_cache=True, fused_tail=fused_tail)
+        prefix_cache=True)
     prompts = _prompts(cfg, 3, seed=4)
     subs = [dict(sampler=SamplerConfig(temperature=0.8, seed=11)), {}, {}]
     eng.submit(prompts[0], **subs[0])       # compile the full epilogue
@@ -505,7 +540,7 @@ def test_record_counts_one_grid_step_for_an_empty_round():
     one step that zeroes its output, a starved row costs none, and a span
     past the table clamps to the table's width. The table here is one
     block wide (G = its 8 pages), so every live row is one step."""
-    _, _, eng, _ = _build(3, False)
+    _, _, eng, _ = _build(3)
     width = eng._table_width
     assert ragged_block_pages(PAGE, width) == width
     kv_lens = np.zeros((3, SLOTS), np.int32)
@@ -550,13 +585,11 @@ def test_record_counts_the_page_slots_of_rows_of_several_blocks():
 
 
 @pytest.mark.parametrize("hook", [True, False], ids=["hook", "nohook"])
-@pytest.mark.parametrize("fused_tail", [False, True], ids=["plain", "fused"])
-def test_token_streams_identical_with_and_without_a_session(
-        tmp_path, fused_tail, hook):
+def test_token_streams_identical_with_and_without_a_session(tmp_path, hook):
     """... and with and without the collector's hook: the traced serve runs
     with it (its engine installed it), the plain one without."""
-    traced = _traced_serve(tmp_path, 3, fused_tail)
-    cfg, params, eng, sched = _build(3, fused_tail)
+    traced = _traced_serve(tmp_path, 3)
+    cfg, params, eng, sched = _build(3)
     assert collections.installed
     if not hook:
         gc.callbacks.remove(collections._hook)
@@ -575,7 +608,7 @@ def test_token_streams_identical_with_and_without_a_session(
 def test_profiler_capture_holds_each_round_once(tmp_path):
     """Under ``paddle_tpu.profiler.Profiler`` the light round span enters
     ONE annotation (and one HostSpan), the engine phases ride along."""
-    cfg, params, eng, sched = _build(3, False)
+    cfg, params, eng, sched = _build(3)
     _warm(cfg, params, sched)
     prof = Profiler(targets=[ProfilerTarget.TPU], log_dir=str(tmp_path))
     with prof:
@@ -669,7 +702,7 @@ def test_afmoe_record_counts_the_windows_pages_by_hand():
                                [2, 0, 0], [0, 5, 5])
     assert rec["shared_pages"] == round(3 / 3) == 1
     # a Llama engine's record of the same plan has no such key: ten keys
-    _, _, llama_eng, _ = _build(3, False)
+    _, _, llama_eng, _ = _build(3)
     llama_eng._bt[:] = eng._bt[:llama_eng._bt.shape[0],
                                :llama_eng._bt.shape[1]]
     assert set(llama_eng._dispatch_record(
@@ -723,10 +756,20 @@ def test_afmoe_unpack_span_carries_the_routing_stats(tmp_path):
 
 
 def test_llama_unpack_span_carries_no_stats(run):
-    """No routing stats: what every unpack carries, the tokens it dropped
-    for rows that had stopped (none: budget stops only)."""
-    assert all(e[3] == {"dropped_tokens": 0}
-               for e in run.events if e[0] == "cbe.unpack")
+    """The unpack span carries exactly the family's routing columns: none
+    where the step program returns no routing record (Llama), else
+    ``expert_calls`` and a sum of each column the model names; and beside
+    them the tokens it dropped for rows that had stopped (none: budget
+    stops only)."""
+    eng = run.eng
+    outs = jax.tree_util.tree_leaves(eng.lower_unified_step().out_info)
+    # tokens, carry, grammar state and the cache's arrays, then the record
+    routed = len(outs) > 3 + len(eng.mgr.arrays)
+    want = {"dropped_tokens"} | (
+        {"expert_calls", *eng._routing_columns} if routed else set())
+    unpacks = [e[3] for e in run.events if e[0] == "cbe.unpack"]
+    assert unpacks and all(set(u) == want for u in unpacks)
+    assert all(u["dropped_tokens"] == 0 for u in unpacks)
 
 
 # ---------------------------------------------------------------------------

@@ -1,18 +1,17 @@
 """Profile-guided fusion pass (ISSUE 13, ROADMAP item 1).
 
-The three admission gates, tested end to end:
+The admission gates, tested end to end:
 
-* **byte-identical** — fused-tail storms (prefix cache on/off, spec
-  on/off, mid-decode admission) emit exactly the unfused engine's greedy
-  tokens, and the fused optimizer megaregion commits bit-identical
-  params/accumulators vs. the eager ``Optimizer.step()`` for every
-  shipped optimizer family;
-* **recompile-count-neutral** — fused programs compile exactly as often
-  as their unfused twins across a length-diverse storm;
+* **byte-identical** — the fused optimizer megaregion commits
+  bit-identical params/accumulators vs. the eager ``Optimizer.step()``
+  for every shipped optimizer family;
+* **recompile-count-neutral** — the fused program compiles once per
+  parameter set, never per step;
 * **graceful degradation** — stale artifacts (symbols that no longer
-  resolve in the ProjectIndex) and schema mismatches become structured
-  ``fusion_skipped`` events (one deduped event per chain per process),
-  never an exception.
+  resolve in the ProjectIndex), schema mismatches and chains no region
+  declares (the serving engine's old ``cbe.*`` taps among them) become
+  structured ``fusion_skipped`` events (one deduped event per chain per
+  process), never an exception.
 """
 
 import json
@@ -39,14 +38,11 @@ from paddle_tpu.optimizer import optimizer as O
 # fixtures
 # ---------------------------------------------------------------------------
 
-def _engine(fused, prefix_cache=False, speculative=False, max_new=6,
-            num_slots=2, chunk=3, **kw):
+def _engine(max_new=6, num_slots=2, chunk=3):
     cfg = L.llama_tiny(num_hidden_layers=2)
     eng = ContinuousBatchingEngine(
         cfg, GenerationConfig(max_new_tokens=max_new),
-        num_slots=num_slots, page_size=4, max_seq_len=64, chunk=chunk,
-        prefix_cache=prefix_cache, fused_tail=fused,
-        speculative=speculative, **kw)
+        num_slots=num_slots, page_size=4, max_seq_len=64, chunk=chunk)
     return cfg, eng
 
 
@@ -54,9 +50,6 @@ def _prompts(cfg, lens, seed=1):
     rng = np.random.RandomState(seed)
     return [rng.randint(1, cfg.vocab_size, (int(n),)).astype(np.int32)
             for n in lens]
-
-
-_STORM_LENS = (5, 12, 3, 9, 17, 2, 7, 30)
 
 
 def _params(cfg):
@@ -72,23 +65,30 @@ def _artifact(chains, symbols=None, schema=1, kind="paddle_tpu.hot_chains"):
                        for i, ops in enumerate(chains)]}
 
 
+def _optimizer():
+    return O.SGD(0.1, parameters=[Parameter(jnp.ones((4, 4), jnp.float32))])
+
+
+#: a chain of the serving engine's phase names: no region declares one
+_ENGINE_CHAIN = ("cbe.plan_step", "cbe.unified_step", "cbe.decode_tail")
+
+
 # ---------------------------------------------------------------------------
 # the pass: artifact -> plan -> apply
 # ---------------------------------------------------------------------------
 
 def test_plan_maps_ranked_chains_to_regions():
-    doc = _artifact([("cbe.plan_step", "cbe.unified_step",
-                      "cbe.decode_tail"),
+    doc = _artifact([_ENGINE_CHAIN,
                      ("grad_clip", "optimizer_update"),
                      ("multiply", "add", "clip")])
     plan = F.FusionPass().plan(doc)
     names = [c.region.name for c in plan.candidates]
-    assert names == ["decode_tail", "optimizer_chain"]
-    assert plan.candidates[0].matched == ("cbe.unified_step",
-                                          "cbe.decode_tail")
-    # the eager math chain maps to no declared region: structured skip
+    assert names == ["optimizer_chain"]
+    assert plan.candidates[0].matched == ("grad_clip", "optimizer_update")
+    # the engine's chain and the eager math chain map to no declared
+    # region: structured skips
     assert {tuple(s["chain"]): s["reason"] for s in plan.skipped} == {
-        ("multiply", "add", "clip"): "no-region"}
+        _ENGINE_CHAIN: "no-region", ("multiply", "add", "clip"): "no-region"}
 
 
 def test_stale_artifact_skips_symbol_missing_never_raises(tmp_path):
@@ -139,40 +139,39 @@ def test_fusion_skipped_event_deduped_per_chain(tmp_path):
 
 
 def test_apply_installs_on_duck_typed_targets():
-    doc = _artifact([("cbe.unified_step", "cbe.decode_tail"),
+    """The region installs on whatever it is handed as the optimizer: a
+    real one, and an object that merely takes the attribute."""
+    doc = _artifact([_ENGINE_CHAIN,
                      ("optimizer_update", "optimizer_update")])
     plan = F.FusionPass().plan(doc)
-    cfg, eng = _engine(False)
-    p = Parameter(jnp.ones((4, 4), jnp.float32))
-    opt = O.SGD(0.1, parameters=[p])
-    installed = plan.apply(engine=eng, optimizer=opt)
-    assert set(installed) == {"decode_tail", "optimizer_chain"}
-    assert eng._fused_tail
+    assert [s["reason"] for s in plan.skipped] == ["no-region"]
+    opt = _optimizer()
+    installed = plan.apply(optimizer=opt)
+    assert set(installed) == {"optimizer_chain"}
     assert isinstance(opt._fused_step, F.FusedOptimizerStep)
-    # idempotent + partial targets
-    assert plan.apply(optimizer=opt)["optimizer_chain"] is opt._fused_step
+    # idempotent
+    assert plan.apply(opt)["optimizer_chain"] is opt._fused_step
+    duck = type("Duck", (), {})()
+    assert plan.apply(duck)["optimizer_chain"] is duck._fused_step
     snap = get_registry().snapshot()
     assert snap.get("paddle_fusion_active", {})
 
 
 def test_apply_on_rejecting_target_skips_never_raises():
-    """The degradation contract covers installation: a target that
-    REJECTS the fused tail (ValueError from ``enable_fused_tail``) —
-    apply() turns that into a target-unsupported skip instead of
-    propagating."""
-    doc = _artifact([("cbe.unified_step", "cbe.decode_tail")])
+    """The degradation contract covers installation: an optimizer that
+    REJECTS the fused step (ValueError as it is set) — apply() turns that
+    into a target-unsupported skip instead of propagating."""
+    doc = _artifact([("grad_clip", "optimizer_update")])
     plan = F.FusionPass().plan(doc)
 
     class Rejecting:
-        _fused_tail = False
-
-        def enable_fused_tail(self):
-            raise ValueError("this engine has no decode tail to fuse")
+        def __setattr__(self, name, value):
+            raise ValueError("this optimizer takes no fused step")
 
     target = Rejecting()
-    installed = plan.apply(engine=target)
+    installed = plan.apply(optimizer=target)
     assert installed == {}
-    assert not target._fused_tail
+    assert not hasattr(target, "_fused_step")
     snap = get_registry().snapshot()
     fam = snap.get("paddle_fusion_skipped_total", {})
     assert any("target-unsupported" in k for k in fam)
@@ -182,39 +181,41 @@ def test_apply_idempotent_counts_install_once():
     """Re-applying over an already-installed region neither re-counts
     the admission nor re-emits fusion_applied (the admitted counter
     stays an install count)."""
-    doc = _artifact([("cbe.unified_step", "cbe.decode_tail")])
-    cfg, eng = _engine(False)
+    doc = _artifact([("grad_clip", "optimizer_update")])
+    opt = _optimizer()
 
     def admitted():
         fam = get_registry().snapshot().get(
             "paddle_fusion_admitted_total", {})
-        return sum(v for k, v in fam.items() if "decode_tail" in k)
+        return sum(v for k, v in fam.items() if "optimizer_chain" in k)
 
+    before = admitted()
     plan = F.FusionPass().plan(doc)
-    plan.apply(engine=eng)
+    plan.apply(optimizer=opt)
     once = admitted()
-    plan.apply(engine=eng)
-    F.FusionPass().plan(doc).apply(engine=eng)
+    assert once == before + 1
+    plan.apply(optimizer=opt)
+    F.FusionPass().plan(doc).apply(optimizer=opt)
     assert admitted() == once
 
 
 def test_active_gauge_follows_install_target_liveness():
     """paddle_fusion_active reflects whether an installed target is
-    still alive: dropping the fused engine and re-running the pass
+    still alive: dropping the fused optimizer and re-running the pass
     clears the gauge instead of reporting an active megaregion
     forever."""
     import gc
-    doc = _artifact([("cbe.unified_step", "cbe.decode_tail")])
+    doc = _artifact([("grad_clip", "optimizer_update")])
     plan = F.FusionPass().plan(doc)
-    cfg, eng = _engine(False)
-    plan.apply(engine=eng)
+    opt = _optimizer()
+    plan.apply(optimizer=opt)
 
     def active():
         fam = get_registry().snapshot().get("paddle_fusion_active", {})
-        return {k: v for k, v in fam.items() if "decode_tail" in k}
+        return {k: v for k, v in fam.items() if "optimizer_chain" in k}
 
     assert all(v == 1 for v in active().values()) and active()
-    del eng
+    del opt
     gc.collect()
     F.FusionPass().plan(doc)        # any pass run refreshes liveness
     assert all(v == 0 for v in active().values())
@@ -251,8 +252,9 @@ def test_fused_optimizer_rebuilds_on_hyperparameter_mutation():
 
 def test_end_to_end_profile_plan_apply():
     """The whole loop: arm the profiler over a real storm + a real eager
-    optimizer run, export the artifact, plan it, install both regions."""
-    cfg, eng = _engine(False)
+    optimizer run, export the artifact, plan it, install the region. The
+    engine's compiled dispatches leave no chain of their own."""
+    cfg, eng = _engine()
     params = _params(cfg)
     telemetry.enable()
     chain_profiler.reset()
@@ -270,128 +272,13 @@ def test_end_to_end_profile_plan_apply():
     finally:
         chain_profiler.disarm()
     doc = chain_profiler.profile(top_n=8, workload="e2e")
+    assert not [op for ch in doc["chains"] for op in ch["ops"]
+                if op.startswith("cbe.")]
     plan = F.FusionPass().plan(doc)
-    names = {c.region.name for c in plan.candidates}
-    assert {"decode_tail", "optimizer_chain"} <= names
-    cfg2, eng2 = _engine(False)
-    installed = plan.apply(engine=eng2)
-    assert "decode_tail" in installed and eng2._fused_tail
-
-
-# ---------------------------------------------------------------------------
-# decode tail: byte-identity + recompile neutrality
-# ---------------------------------------------------------------------------
-
-@pytest.mark.parametrize("prefix_cache", [False, True])
-def test_fused_tail_storm_byte_identical(prefix_cache):
-    cfg, base = _engine(False, prefix_cache=prefix_cache)
-    params = _params(cfg)
-    prompts = _prompts(cfg, _STORM_LENS)
-    if prefix_cache:
-        prompts[3] = np.concatenate([prompts[1], prompts[2]])
-        prompts[5] = prompts[1].copy()
-    want = base.serve(params, prompts)
-    cfg2, fused = _engine(True, prefix_cache=prefix_cache)
-    assert fused.serve(params, prompts) == want
-
-
-def test_fused_tail_recompile_neutral_across_storm():
-    """The O(1)-recompile invariant survives fusion: across a
-    length-diverse storm with mid-decode admissions both engines miss
-    the unified-step cache exactly once."""
-    cfg = L.llama_tiny(num_hidden_layers=2)
-    params = _params(cfg)
-    counts = {}
-    for fused in (False, True):
-        before = recompiles.count("cbe.unified_step")
-        eng = ContinuousBatchingEngine(
-            cfg, GenerationConfig(max_new_tokens=5), num_slots=2,
-            page_size=4, max_seq_len=64, chunk=3, fused_tail=fused)
-        prompts = _prompts(cfg, _STORM_LENS)
-        rids = [eng.submit(p) for p in prompts[:3]]
-        done = {}
-        step = 0
-        while len(done) < len(prompts):
-            eng.step(params)
-            done.update(eng.collect())
-            step += 1
-            if step == 2:               # mid-decode trickle admission
-                rids += [eng.submit(p) for p in prompts[3:]]
-        counts[fused] = recompiles.count("cbe.unified_step") - before
-    assert counts[True] == counts[False] == 1
-
-
-def test_enable_fused_tail_mid_serve_stays_byte_identical():
-    """Installing the region mid-flight rebuilds the program (a counted
-    miss) and continues the exact token streams."""
-    cfg, base = _engine(False, max_new=8, num_slots=2, chunk=2)
-    params = _params(cfg)
-    prompts = _prompts(cfg, (5, 9, 13, 7))
-    want = base.serve(params, prompts)
-
-    cfg2, eng = _engine(False, max_new=8, num_slots=2, chunk=2)
-    rids = [eng.submit(p) for p in prompts]
-    for _ in range(3):
-        eng.step(params)
-    eng.enable_fused_tail()
-    done = dict(eng.collect())
-    while len(done) < len(prompts):
-        eng.step(params)
-        done.update(eng.collect())
-    assert [done[r] for r in rids] == want
-
-
-def test_plan_fast_path_matches_generic_planner():
-    """Steady-state all-decode rounds plan through the vectorized fast
-    path — byte-equal packed arrays AND identical position mirrors."""
-    import copy
-    cfg, a = _engine(True, num_slots=4, chunk=5)
-    cfg2, b = _engine(True, num_slots=4, chunk=5)
-    for eng in (a, b):
-        # synthetic steady state: slots 0 and 2 decoding, 1/3 idle
-        eng._slot_rid[0], eng._slot_rid[2] = 11, 12
-        eng._pos[0], eng._pos[2] = 7, 3
-        eng._pend[0] = eng._pend[2] = None
-    tt_fast, tr_fast, emit_f, ec_f, fed_f = a._plan_step_packed()
-    plan, emit_g, ec_g, fed_g = b._plan_step()
-    tt_gen, tr_gen = F.pack_plan(*plan)
-    np.testing.assert_array_equal(tt_fast, tt_gen)
-    np.testing.assert_array_equal(tr_fast, tr_gen)
-    np.testing.assert_array_equal(emit_f, emit_g)
-    assert ec_f == ec_g and fed_f == fed_g
-    np.testing.assert_array_equal(a._pos, b._pos)
-    # mixed round (one slot still prefilling): falls back to generic
-    a._pend[0] = np.asarray([1, 2, 3], np.int32)
-    b._pend[0] = np.asarray([1, 2, 3], np.int32)
-    tt_fast, tr_fast, *_ = a._plan_step_packed()
-    plan, *_ = b._plan_step()
-    tt_gen, tr_gen = F.pack_plan(*plan)
-    np.testing.assert_array_equal(tt_fast, tt_gen)
-    np.testing.assert_array_equal(tr_fast, tr_gen)
-
-
-def test_spec_composition_byte_identical():
-    """fusion + speculation together stays byte-identical to both off
-    (and to each alone) — the ISSUE's composition gate."""
-    cfg, plain = _engine(False)
-    params = _params(cfg)
-    prompts = _prompts(cfg, _STORM_LENS)
-    want = plain.serve(params, prompts)
-    for fused, spec in ((True, False), (False, True), (True, True)):
-        cfg2, eng = _engine(fused, speculative=spec)
-        assert eng.serve(params, prompts) == want, (fused, spec)
-
-
-def test_fused_spec_recompile_neutral():
-    cfg = L.llama_tiny(num_hidden_layers=2)
-    params = _params(cfg)
-    counts = {}
-    for fused in (False, True):
-        before = recompiles.count("cbe.spec_step")
-        cfg2, eng = _engine(fused, speculative=True)
-        eng.serve(params, _prompts(cfg, _STORM_LENS))
-        counts[fused] = recompiles.count("cbe.spec_step") - before
-    assert counts[True] == counts[False] == 1
+    assert {c.region.name for c in plan.candidates} == {"optimizer_chain"}
+    opt2 = _optimizer()
+    installed = plan.apply(optimizer=opt2)
+    assert installed["optimizer_chain"] is opt2._fused_step
 
 
 # ---------------------------------------------------------------------------
@@ -608,26 +495,5 @@ def test_stage_eager_matches_eager_bits_on_fma_hazard_chain():
 
     eager = chain(a, b)
     staged, _ = F.stage_eager(chain, a, b)
-    out = jax.jit(staged)(jnp.float32(np.inf), a, b)[0]
+    out = staged(jnp.float32(np.inf), a, b)[0]
     assert np.array_equal(np.asarray(eager), np.asarray(out))
-
-
-def test_pack_plan_round_trip():
-    K, tb, R = 3, 6, 2
-    rng = np.random.RandomState(0)
-    ids = rng.randint(0, 100, (K, tb)).astype(np.int32)
-    uc = rng.rand(K, tb) > 0.5
-    tr = rng.randint(-1, R, (K, tb)).astype(np.int32)
-    pos = rng.randint(0, 50, (K, tb)).astype(np.int32)
-    kvl = rng.randint(0, 50, (K, R)).astype(np.int32)
-    li = rng.randint(0, tb, (K, R)).astype(np.int32)
-    sm = rng.rand(K, R) > 0.5
-    tt, trr = F.pack_plan(ids, uc, tr, pos, kvl, li, sm)
-    assert tt.shape == (4, K, tb) and trr.shape == (3, K, R)
-    np.testing.assert_array_equal(tt[0], ids)
-    np.testing.assert_array_equal(tt[1].astype(bool), uc)
-    np.testing.assert_array_equal(tt[2], tr)
-    np.testing.assert_array_equal(tt[3], pos)
-    np.testing.assert_array_equal(trr[0], kvl)
-    np.testing.assert_array_equal(trr[1], li)
-    np.testing.assert_array_equal(trr[2].astype(bool), sm)

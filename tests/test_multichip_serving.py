@@ -239,14 +239,3 @@ def test_memory_ledger_per_chip_split():
     finally:
         memory_ledger.disarm()
         memory_ledger.reset()
-
-
-def test_fused_tail_composes_with_mesh():
-    """The profile-guided fused decode tail (jit/fusion.py) rides the
-    sharded step unchanged: fused x mp=2, spec flavour included, stays
-    byte-identical to the plain single-chip engine."""
-    prompts = _prompts(3, (5, 9, 3))
-    base = _engine(1).serve(PARAMS, prompts)
-    assert _engine(2, fused_tail=True).serve(PARAMS, prompts) == base
-    assert _engine(2, fused_tail=True,
-                   speculative=True).serve(PARAMS, prompts) == base

@@ -4,13 +4,15 @@ constrained decoding.
 
 The acceptance bar: greedy stays byte-identical to the argmax-only
 epilogue; a seeded sampled request replays its exact stream across
-engine rebuilds, speculation on/off, the fused tail, TP sharding, and
+engine rebuilds, speculation on/off, model families, TP sharding, and
 router failovers; speculation under sampling is DISTRIBUTION-identical
 to non-speculative sampling (the rejection-sampling verifier's whole
 point); constrained rows emit only grammar-legal tokens; and a mixed
 greedy/sampled/constrained storm still honours the unified step's
 O(1)-recompile contract — per-request knobs are program INPUTS, never
 cache keys."""
+
+import importlib
 
 import numpy as np
 import pytest
@@ -41,28 +43,42 @@ def _prompts(n=4, lens=(4, 12), seed=1):
             for _ in range(n)]
 
 
-def _engine(max_new=8, num_slots=2, mp=1, **kw):
+def _family(name):
+    """The tiny configuration of a second family (a module of
+    ``paddle_tpu.models``, its vocabulary CFG's) and its weights: Jamba's
+    boosted as ``tests/test_jamba_model.py`` boosts them, so that a state
+    left behind in a slot moves the logits."""
+    module = importlib.import_module(f"paddle_tpu.models.{name}")
+    cfg = getattr(module, name + "_tiny")()
+    assert cfg.vocab_size == CFG.vocab_size
+    if name == "jamba":
+        from test_jamba_model import _weights
+        return cfg, _weights(cfg, 0)
+    return cfg, module.init_stacked_params(cfg, seed=0)
+
+
+def _engine(max_new=8, num_slots=2, mp=1, cfg=CFG, **kw):
     mesh = serving_mesh(mp) if mp > 1 else None
     return ContinuousBatchingEngine(
-        CFG, GenerationConfig(max_new_tokens=max_new),
+        cfg, GenerationConfig(max_new_tokens=max_new),
         num_slots=num_slots, page_size=16, max_seq_len=64, chunk=2,
         mesh=mesh, **kw)
 
 
-def _run_each(eng, prompts, subs):
+def _run_each(eng, prompts, subs, params=PARAMS):
     """Serve ``prompts``, each with its own submit arguments."""
     rids = [eng.submit(p, **s) for p, s in zip(prompts, subs)]
     out, steps = {}, 0
     while len(out) < len(prompts):
-        eng.step(PARAMS)
+        eng.step(params)
         out.update(eng.collect())
         steps += 1
         assert steps < 3000
     return [out[r] for r in rids]
 
 
-def _run(eng, prompts, **sub):
-    return _run_each(eng, prompts, [sub] * len(prompts))
+def _run(eng, prompts, params=PARAMS, **sub):
+    return _run_each(eng, prompts, [sub] * len(prompts), params)
 
 
 def _abc_vocab():
@@ -277,31 +293,31 @@ def test_spec_greedy_rows_prefix_match():
 
 def test_greedy_byte_identity_across_tails():
     """With the sampling subsystem present, default greedy decode is
-    byte-identical across the unified step, the fused tail, and
-    speculation — the epilogue's temperature<=0 path IS the old argmax."""
+    byte-identical across the unified step and speculation — the
+    epilogue's temperature<=0 path IS the old argmax."""
     prompts = _prompts(4)
     base = _run(_engine(), prompts)
-    assert _run(_engine().enable_fused_tail(), prompts) == base
     assert _run(_engine(speculative=True), prompts) == base
     # explicit temperature-0 sampler == no sampler, byte for byte
     sc = SamplerConfig(temperature=0.0, seed=123)
     assert _run(_engine(), prompts, sampler=sc) == base
 
 
-@pytest.mark.parametrize("speculative,fused", [
-    (False, False), (False, True), (True, False), (True, True),
+@pytest.mark.parametrize("speculative,family", [
+    (False, "llama"), (True, "llama"),
+    # a row that keeps a recurrent state; a step that returns a routing
+    # record beside the logits (speculation refuses a state family)
+    (False, "jamba"), (False, "smallthinker"),
 ])
-def test_seeded_replay_byte_identity(speculative, fused):
+def test_seeded_replay_byte_identity(speculative, family):
+    cfg, params = (CFG, PARAMS) if family == "llama" else _family(family)
     prompts = _prompts(3)
     sc = SamplerConfig(temperature=0.9, top_k=12, top_p=0.95, seed=77)
-    streams = []
-    for _ in range(2):
-        eng = _engine(speculative=speculative)
-        if fused:
-            eng.enable_fused_tail()
-        streams.append(_run(eng, prompts, sampler=sc))
+    streams = [_run(_engine(cfg=cfg, speculative=speculative), prompts,
+                    params, sampler=sc) for _ in range(2)]
     assert streams[0] == streams[1]
-    assert streams[0] != _run(_engine(speculative=speculative), prompts)
+    assert streams[0] != _run(_engine(cfg=cfg, speculative=speculative),
+                              prompts, params)
 
 
 @pytest.mark.parametrize("mp", [1, 2])
@@ -433,13 +449,29 @@ def test_mixed_storm_o1_recompiles_and_metrics(abc_grammar):
 # ---------------------------------------------------------------------------
 # admission writes host mirrors, not the device (ISSUE 30)
 # ---------------------------------------------------------------------------
-TAILS = ["unified", "spec", "fused"]
+TAILS = ["unified", "spec", "jamba"]
 
 
 def _tail_engine(tail, **kw):
-    """An engine on one of the three step paths."""
-    eng = _engine(speculative=tail == "spec", **kw)
-    return eng.enable_fused_tail() if tail == "fused" else eng
+    """An engine and its weights: Llama on the unified or the speculative
+    step, or Jamba on the unified one, whose row keeps a recurrent state
+    beside its sampler and grammar state."""
+    if tail == "jamba":
+        cfg, params = _family("jamba")
+        return _engine(cfg=cfg, **kw), params
+    return _engine(speculative=tail == "spec", **kw), PARAMS
+
+
+def _assert_fresh_greedy(tail, prompts, outs, **kw):
+    """Greedy rows decode as if their slot were new: Llama's equal the full
+    re-forward; Jamba's each equal the prompt served alone on a fresh
+    engine (a state left in the slot would show)."""
+    if tail != "jamba":
+        assert_greedy(PARAMS, CFG, prompts, outs, n_new=8)
+        return
+    for prompt, out in zip(prompts, outs):
+        eng, params = _tail_engine(tail, **kw)
+        assert _run(eng, [prompt], params) == [out]
 
 
 def _guard_admission(eng):
@@ -463,14 +495,14 @@ def test_greedy_admission_does_no_device_work(tail):
     mirror is dirty, and the step's grammar reset is the cached all-KEEP
     array. The parent's ``_set_row_sampler`` ran five eager
     ``.at[s].set`` programs here (a scalar transfer each)."""
-    eng = _tail_engine(tail)                     # 2 slots, 6 requests
+    eng, params = _tail_engine(tail)             # 2 slots, 6 requests
     counts = _guard_admission(eng)
     samp_dev, keep = eng._samp_dev, eng._greset_keep_dev
     prompts = _prompts(6, seed=9)
     rids = [eng.submit(p) for p in prompts]
     out, steps = {}, 0
     while len(out) < len(prompts):
-        eng.step(PARAMS)
+        eng.step(params)
         assert eng._samp_dev is samp_dev
         assert not (eng._samp_dirty or eng._greset_dirty)
         assert eng._upload_row_state() is keep   # nothing rides the plan
@@ -478,7 +510,7 @@ def test_greedy_admission_does_no_device_work(tail):
         steps += 1
         assert steps < 3000
     assert sum(counts) == len(prompts) and max(counts) <= 2
-    assert_greedy(PARAMS, CFG, prompts, [out[r] for r in rids], n_new=8)
+    _assert_fresh_greedy(tail, prompts, [out[r] for r in rids])
 
 
 def test_changed_row_state_rides_one_upload(abc_grammar):
@@ -573,15 +605,15 @@ def test_slot_reuse_never_inherits_row_state(abc_grammar, tail, first,
     kinds = _reuse_kinds(g)
     prompts = _prompts(2, seed=21)
     kw = dict(num_slots=1, grammar_states=g.n_states)
-    eng = _tail_engine(tail, **kw)
+    eng, params = _tail_engine(tail, **kw)
     _guard_admission(eng)
-    reused = _run_each(eng, prompts, [kinds[first], kinds[second]])
+    reused = _run_each(eng, prompts, [kinds[first], kinds[second]], params)
     assert len(reused[0]) == len(reused[1]) == 8
     if second == "greedy":
-        assert_greedy(PARAMS, CFG, prompts[1:], reused[1:], n_new=8)
+        _assert_fresh_greedy(tail, prompts[1:], reused[1:], **kw)
     else:
-        alone = _run_each(_tail_engine(tail, **kw), prompts[1:],
-                          [kinds[second]])
+        fresh, _ = _tail_engine(tail, **kw)
+        alone = _run_each(fresh, prompts[1:], [kinds[second]], params)
         assert reused[1] == alone[0]
     if second == "constrained":
         _assert_legal_stream(g, reused[1])
